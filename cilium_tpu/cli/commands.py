@@ -390,6 +390,18 @@ def _cmd_status(args) -> int:
             print(f"Conntrack:        {d['conntrack']['live']}/"
                   f"{d['conntrack']['capacity']} live")
         print(f"Enforcement:      {d['enforcement_mode']}")
+        dev = d.get("device")
+        if dev:
+            print(f"Device:           {dev['platform']} ({dev['device_kind']}"
+                  f" x{dev['count']}, serving on {dev['serving']};"
+                  f" configured {dev['configured']})")
+        fz = d.get("fused_kernels")
+        if fz:
+            plan = fz.get("plan") or {}
+            print(f"Fused kernels:    mode={fz['mode']}"
+                  f" active={fz['active']} interpret={fz['interpret']}"
+                  " stages=" + (",".join(k for k, v in plan.items() if v)
+                                or "none"))
         pl = d.get("pipeline")
         if pl:
             fl = pl.get("flush_reasons", {})
@@ -1034,7 +1046,7 @@ def _cmd_debug_bundle(args) -> int:
 
 
 def _cmd_classify(args) -> int:
-    """The CLI serving path. Exit codes mirror the guard taxonomy: 0
+    """The CLI serving path. Exit codes mirror the guard's error classes: 0
     served, 2 overload shed (retry), 3 unavailable (back off), 1 other."""
     from cilium_tpu.runtime.api import UnixAPIClient
     src = args.src

@@ -1,14 +1,12 @@
 """Pallas TPU megakernels for the classify interior (ROADMAP item 2:
 "close the compute ceilings with fused Pallas kernels").
 
-Why: BENCH_r05's ``compute_only`` split shows cfg3 (lpm_heavy) at 209M
-flows/s/chip against cfg2's 380M and cfg4 (l7_lite) paying p99 3.8x p50 —
-the ``datapath.compute`` span attribution (PR 3) pins the gap on the
-unfused LPM gather chain (4-level v4 / 16-level v6, each level a separate
-XLA gather materializing [N] node/best intermediates in HBM), the policy
-ladder's gather→select→gather round trips, and the double CT probe. SURVEY
-§7 step 4 prescribes "jnp-first, Pallas only where fusion wins are proven"
-— these are the proven sites.
+Why: the classify interior is a chain of gathers — the LPM walk (4-level
+v4 / 16-level v6, each level a separate XLA gather materializing [N]
+node/best intermediates in HBM), the policy ladder's gather→select→gather
+round trips, and the double CT probe. SURVEY §7 step 4 prescribes
+"jnp-first, Pallas only where fusion wins are proven"; whether fusing wins
+has not been measured on a chip (ROADMAP S7).
 
 Three kernels, each wrapping a *shared core* (the same jnp function the
 reference path executes — kernels/lpm.lpm_walk_core,
@@ -32,6 +30,11 @@ parity/fuzz suites (tests/test_fused.py) and the shadow-oracle auditor
 kernels run under ``interpret=True`` (the Pallas interpreter evaluates the
 same jnp ops), which is how tier-1 CI pins the fused path without TPU
 hardware.
+
+On the TPU none of the three is selected: Mosaic refuses every body (see
+``TPU_COMPILED_STAGES`` for the compiler's words), so ``fuse_plan`` drops
+them statically and the XLA reference serves. There is no interpret mode
+on the chip and no fallback around ``pallas_call``.
 
 Geometry gates: a stage only fuses when its tables fit the kernel-resident
 budget (``fuse_plan``) — a 1M-entry CT table or a BGP-scale trie stays on
@@ -85,20 +88,38 @@ def _nbytes(a) -> int:
     return int(a.size) * a.dtype.itemsize
 
 
+#: The stages whose kernel Mosaic compiles for the TPU: none. Measured on a
+#: TPU v5e (jax/jaxlib 0.9.0, libtpu 0.0.34; chip_smoke.py phase
+#: ``kernels`` re-checks it on every chip run). Each body indexes a
+#: VMEM-resident table with a *vector* of row indices — a general gather —
+#: and the TPU lowering accepts only the same-shape 2-D ``take_along_axis``
+#: form. The compiler's words, per stage:
+#:   lpm:    ValueError: Shape mismatch in input, indices and output
+#:   ct:     NotImplementedError: Only 2D gather is supported
+#:   policy: NotImplementedError: Only 2D gather is supported
+#: Until a body is rewritten (ROADMAP S7/D3 decide repair or deletion) the
+#: kernels run only under the Pallas interpreter, i.e. ``fused_kernels="on"``
+#: off-TPU — the CI configuration.
+TPU_COMPILED_STAGES = FusePlan(lpm=False, ct=False, policy=False)
+
+
 def fuse_plan(tensors, ct, v4_only: bool = False, rule_axis=None,
-              budget: int = 0) -> FusePlan:
+              budget: int = 0, compiled: bool = False) -> FusePlan:
     """Which stages of this geometry fit the fused kernels. ``rule_axis``
     disables the verdict kernel (the rule-sharded ladder needs a psum that
-    must stay in the surrounding shard_map body)."""
+    must stay in the surrounding shard_map body). ``compiled`` (the kernels
+    will be compiled by Mosaic, not interpreted) keeps only the stages in
+    ``TPU_COMPILED_STAGES``."""
     budget = budget or FUSED_TABLE_BYTES
     lpm_bytes = _nbytes(tensors["lpm_v4"]) \
         + (0 if v4_only else _nbytes(tensors["lpm_v6"]))
     ct_bytes = _nbytes(ct["keys"]) + _nbytes(ct["expiry"])
     policy_bytes = sum(_nbytes(tensors[k]) for k in POLICY_TENSOR_KEYS)
+    can = TPU_COMPILED_STAGES if compiled else FusePlan(True, True, True)
     return FusePlan(
-        lpm=lpm_bytes <= budget,
-        ct=ct_bytes <= budget,
-        policy=rule_axis is None and policy_bytes <= budget,
+        lpm=can.lpm and lpm_bytes <= budget,
+        ct=can.ct and ct_bytes <= budget,
+        policy=can.policy and rule_axis is None and policy_bytes <= budget,
     )
 
 

@@ -111,12 +111,10 @@ def ct_key_words(batch: BatchArrays, reverse: bool = False) -> np.ndarray:
 # --------------------------------------------------------------------------- #
 # Packed wire format: ONE contiguous uint32 array per batch.
 #
-# Transferring the batch as 12 separate arrays costs ~5x more wall time on a
-# tunneled/PCIe link than one contiguous buffer (per-transfer overhead
-# dominates); the classify step is transfer-bound, so the runtime packs on
-# the host (vectorized numpy, ~free) and unpacks on device inside the jit
-# (bit ops that XLA fuses into the pipeline). The C++ shim can emit this
-# layout directly.
+# One host→device transfer per batch instead of twelve (each transfer pays
+# a fixed overhead): the runtime packs on the host (vectorized numpy) and
+# unpacks on device inside the jit (bit ops that XLA fuses into the
+# pipeline). The C++ shim can emit this layout directly.
 #
 # Word layout per record:
 #   0-3   src words          4-7  dst words

@@ -260,7 +260,8 @@ def classify_step_exchange(tensors, ct, batch, now, world_index=0, *,
     if fused:
         from cilium_tpu.kernels import fused as fk
         plan = fk.fuse_plan(tensors, ct, v4_only=v4_only,
-                            rule_axis=rule_axis)
+                            rule_axis=rule_axis,
+                            compiled=not fused_interpret)
     else:
         plan = None
     pre = classify_pre_ct(tensors, batch, world_index, v4_only=v4_only,

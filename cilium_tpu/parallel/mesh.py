@@ -395,15 +395,7 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True):
     variants: spec construction, the per-(tensor-key-set, batch-kind) jit
     cache, device-side wire unpack, and the counter psum."""
     import jax
-    try:
-        from jax import shard_map
-    except ImportError:                 # jax < 0.6: experimental location
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    # the replication-check kwarg was renamed check_rep → check_vma
-    _check_kw = ("check_vma"
-                 if "check_vma" in inspect.signature(shard_map).parameters
-                 else "check_rep")
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     rule_sharded = mesh.shape["rules"] > 1
@@ -476,7 +468,7 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True):
                 body, mesh=mesh,
                 in_specs=(tensors_spec, ct_spec, bspec, P(), P()),
                 out_specs=(out_spec, ct_spec, counters_spec),
-                **{_check_kw: False},
+                check_vma=False,
             ), donate_argnums=(1,) if donate_ct else ())
             jits[key] = fn
         return fn(tensors, ct, batch, now, world_index)

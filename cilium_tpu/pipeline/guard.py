@@ -10,7 +10,7 @@ arbitrarily stale submissions, and repeated dispatch errors kept hammering
 a sick backend. This module holds the three mechanisms the scheduler wires
 into its hot path to extend the supervised-degradation philosophy there:
 
-- **Error taxonomy** — every way a submission can fail is a distinct
+- **Error classes** — every way a submission can fail is a distinct
   ``PipelineError`` subclass, so the serving surface (REST/CLI) can map
   overload shed (:class:`PipelineDrop`, :class:`PipelineDeadlineExceeded`
   → 429) apart from unavailability (:class:`PipelineUnavailable`,

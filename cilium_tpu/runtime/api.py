@@ -195,8 +195,12 @@ def status_doc(engine: "Engine") -> Dict:
         "services": len(engine.ctx.services.all()),
         "conntrack": {"capacity": ct["capacity"], "live": ct["live"]},
         "enforcement_mode": engine.ctx.enforcement_mode,
-        # Pallas megakernel selector state (None on jax-free backends —
-        # the oracle-backed fake has no kernels to fuse)
+        # which device serves (None on jax-free backends): what the config
+        # asked for, and the platform / device_kind / count JAX reports
+        "device": getattr(engine.datapath, "device_state", None),
+        # Pallas megakernel selector state incl. the per-stage fuse plan
+        # (None on jax-free backends — the oracle-backed fake has no
+        # kernels to fuse)
         "fused_kernels": getattr(engine.datapath, "fused_state", None),
         # flow→shard resolution surface (None on jax-free backends): host
         # steering vs the device-side ppermute exchange (rss_mode)
@@ -309,7 +313,7 @@ def ct_doc(engine: "Engine", limit: int, now: Optional[int]):
 
 def serving_error(exc: BaseException) -> Optional[Tuple[int, Dict]]:
     """Map a pipeline serving failure to (http_status, json_body), or None
-    for errors that are not part of the overload/degradation taxonomy
+    for errors that are not part of the overload/degradation error classes
     (those stay 500s). Overload shed → 429 (retryable: the pipeline is
     healthy but this submission lost the overload race); unavailability →
     503 (the backend is sick/restarting — back off)."""
@@ -374,7 +378,7 @@ def classify_doc(engine: "Engine", body: Dict) -> Tuple[int, Dict]:
         ticket = engine.submit(batch, now=now, deadline_ms=deadline_ms)
         out = ticket.result(
             timeout=engine.config.pipeline_request_timeout_s)
-    except Exception as exc:   # noqa: BLE001 — taxonomy-mapped below
+    except Exception as exc:   # noqa: BLE001 — error class mapped below
         mapped = serving_error(exc)
         if mapped is None:
             raise

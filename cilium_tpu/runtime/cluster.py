@@ -14,7 +14,13 @@ invisible to the others.
 The harness is deterministic by construction: nothing ticks on wall-clock
 controllers — the driver commands every ``mesh.step()`` and regeneration
 explicitly, so a chaos sequence (partition N syncs, kill a peer, conflict
-two claims) replays identically."""
+two claims) replays identically.
+
+Cluster nodes are CPU processes. A chip belongs to one process at a time,
+so N engine processes on one host cannot each hold it; every node pins
+``JAX_PLATFORMS=cpu`` before it imports jax, whatever the machine has and
+whatever the parent did. What this harness establishes is convergence,
+parity and failure handling — counts, never device rates."""
 
 from __future__ import annotations
 
@@ -50,7 +56,7 @@ def _node_worker(conn, node_name: str, store_dir: str,
     """One mesh node: build the engine, answer supervisor commands until
     ``stop`` (clean shutdown + withdraw) or ``exit_dirty`` (simulated
     crash: the published file stays behind for the lease to expire)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"     # see the module docstring
     import numpy as np
 
     from cilium_tpu.kernels.records import batch_from_records

@@ -29,6 +29,8 @@ class DaemonConfig:
     v4_only: bool = False
     maglev_m: int = 251            # Maglev table size (prime; prod: 16381)
     # --- device/runtime ---
+    # "tpu"/"cpu" are requirements (JITDatapath refuses to start on
+    # anything else); "auto" serves on what JAX has and reports it
     device: str = "auto"           # auto | cpu | tpu
     n_shards: int = 1              # data-parallel flow shards (mesh size)
     rule_shards: int = 1           # rule-space (verdict-row) shards
@@ -44,9 +46,11 @@ class DaemonConfig:
     rss_mode: str = "host"         # host | device
     donate_ct: bool = True
     # Pallas megakernel selector for the classify interior (kernels/fused.py):
-    # "auto" compiles the fused path on TPU and keeps the jnp reference
-    # elsewhere; "on" forces it everywhere (Pallas interpret mode off-TPU —
-    # the CPU-CI bit-identity configuration); "off" pins the jnp reference.
+    # "auto" uses the stages Mosaic compiles on a TPU (none today —
+    # kernels/fused.TPU_COMPILED_STAGES) and the jnp reference elsewhere;
+    # "on" forces the kernels (Pallas interpret mode off-TPU — the CPU-CI
+    # bit-identity configuration; refused on a TPU while no stage compiles
+    # there); "off" pins the jnp reference.
     fused_kernels: str = "auto"    # auto | on | off
     # --- lifecycle ---
     state_dir: str = "/var/run/cilium-tpu"
@@ -287,6 +291,9 @@ class DaemonConfig:
             raise ValueError("ct_capacity must be a power of two")
         if self.flowlog_mode not in ("all", "drops", "none"):
             raise ValueError(f"bad flowlog mode {self.flowlog_mode!r}")
+        if self.device not in ("auto", "cpu", "tpu"):
+            raise ValueError(
+                f"bad device {self.device!r} (auto | cpu | tpu)")
         if self.fused_kernels not in ("auto", "on", "off"):
             raise ValueError(
                 f"bad fused_kernels mode {self.fused_kernels!r} "

@@ -24,6 +24,7 @@ the test that the boundary is real.
 from __future__ import annotations
 
 import abc
+import logging
 import re
 import threading
 import time
@@ -118,19 +119,29 @@ class PlacedTensors(dict):
 
 def resolve_fused(config: DaemonConfig) -> Tuple[bool, bool]:
     """``DaemonConfig.fused_kernels`` → (fused, interpret) for the Pallas
-    classify-interior kernels (kernels/fused.py). ``auto`` compiles the
-    fused path only on TPU (the jnp reference is the right executor for
-    CPU/interpret anyway); ``on`` forces the fused path everywhere, in
-    Pallas interpret mode off-TPU — the configuration CPU CI uses to pin
-    the fused kernels bit-identical to the reference and the oracle."""
+    classify-interior kernels (kernels/fused.py). Off the TPU ``auto``
+    keeps the jnp reference and ``on`` runs the kernels in Pallas interpret
+    mode — the configuration CPU CI uses to pin them bit-identical to the
+    reference and the oracle. On the TPU the kernels are compiled or not
+    used at all, never interpreted: ``auto`` is fused exactly when some
+    stage compiles there (kernels/fused.TPU_COMPILED_STAGES), and ``on``
+    with no such stage is refused rather than quietly served by the
+    reference."""
     mode = config.fused_kernels
     if mode == "off":
         return False, False
     import jax
-    on_tpu = jax.default_backend() == "tpu"
+    if jax.default_backend() != "tpu":
+        return (True, True) if mode == "on" else (False, False)
+    from cilium_tpu.kernels.fused import TPU_COMPILED_STAGES
+    if TPU_COMPILED_STAGES.any:
+        return True, False
     if mode == "on":
-        return True, not on_tpu
-    return (True, False) if on_tpu else (False, False)   # auto
+        raise ValueError(
+            "fused_kernels='on' on a TPU, but Mosaic compiles none of the "
+            "fused stages (kernels/fused.TPU_COMPILED_STAGES); use 'auto' "
+            "or 'off'")
+    return False, False
 
 
 def normalize_ct_arrays(arrays: Dict[str, np.ndarray]
@@ -280,7 +291,24 @@ class JITDatapath(DatapathBackend):
             os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import jax
         import jax.numpy as jnp
+        from cilium_tpu.utils.compile_cache import enable_compile_cache
+        self.compile_cache_dir = enable_compile_cache()
         self._jnp = jnp
+        # which device serves: "tpu"/"cpu" are requirements, never
+        # preferences — an engine configured for the chip must not serve
+        # from a CPU without a word; "auto" takes what JAX has and says so
+        # (here, in device_state and in `cilium-tpu status`)
+        backend = jax.default_backend()
+        if self.config.device not in ("auto", backend):
+            raise RuntimeError(
+                f"DaemonConfig.device={self.config.device!r} but JAX's "
+                f"default backend is {backend!r} "
+                f"({jax.devices()[0].device_kind}); the platform is chosen "
+                f"before the first jax import (JAX_PLATFORMS)")
+        logging.getLogger("cilium_tpu.datapath").info(
+            "serving on %s (%s x%d), device=%s, compile cache %s", backend,
+            jax.devices()[0].device_kind, len(jax.devices()),
+            self.config.device, self.compile_cache_dir)
         self.n_flow_shards = max(1, self.config.n_shards)
         self.n_rule_shards = max(1, self.config.rule_shards)
         self._sharded = self.n_flow_shards * self.n_rule_shards > 1
@@ -289,6 +317,7 @@ class JITDatapath(DatapathBackend):
         # Pallas megakernel selector (kernels/fused.py): trace-time static,
         # so both classify fns below bake the choice into their jit keys
         self._fused, self._fused_interpret = resolve_fused(self.config)
+        self._fuse_plan: Optional[Dict[str, bool]] = None
         # device-side RSS (rss_mode="device", parallel/exchange.py): rows
         # arrive on chips in plain FIFO order and cross-shard CT resolves
         # with the in-kernel ring ppermute exchange — no host steering, no
@@ -521,14 +550,51 @@ class JITDatapath(DatapathBackend):
     @property
     def fused_state(self) -> Dict[str, Any]:
         """Operator-facing view of the megakernel selector: the configured
-        mode, whether the fused path is active, and whether it runs in
-        Pallas interpret mode (off-TPU ``fused_kernels=on`` — the CI
-        bit-identity configuration, not a serving configuration)."""
+        mode, whether the fused path is active, whether it runs in Pallas
+        interpret mode (off-TPU ``fused_kernels=on`` — the CI bit-identity
+        configuration, not a serving configuration), and which stages
+        ``fuse_plan`` engaged for the geometry placed last (None before
+        the first placement)."""
         return {
             "mode": self.config.fused_kernels,
             "active": self._fused,
             "interpret": self._fused_interpret,
+            "plan": self._fuse_plan,
         }
+
+    @property
+    def device_state(self) -> Dict[str, Any]:
+        """Which device serves: what the config asked for and what JAX
+        has (platform, kind, count), plus how many of them this backend's
+        mesh uses."""
+        import jax
+        devs = jax.devices()
+        return {
+            "configured": self.config.device,
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "count": len(devs),
+            "serving": self.n_flow_shards * self.n_rule_shards,
+            "compile_cache_dir": self.compile_cache_dir,
+        }
+
+    def _note_fuse_plan(self, placed: Dict) -> None:
+        """Record the per-stage fuse plan of a placement, from shapes alone
+        (no device buffer is read) — the same call classify_step makes at
+        trace time, so status shows what the compiled program contains."""
+        if not self._fused:
+            return
+        import jax
+        from cilium_tpu.kernels.fused import fuse_plan
+        # inside shard_map the kernels see one flow shard's local table
+        local = self._ct_capacity // self.n_flow_shards
+        ct = {k: jax.ShapeDtypeStruct((local,) + self._ct[k].shape[1:],
+                                      self._ct[k].dtype)
+              for k in ("keys", "expiry")}
+        self._fuse_plan = fuse_plan(
+            placed, ct, v4_only=self.config.v4_only,
+            rule_axis="rules" if self.n_rule_shards > 1 else None,
+            compiled=not self._fused_interpret)._asdict()
 
     def _maybe_reset_wire_flags(self, snap: PolicySnapshot) -> None:
         """Un-stick the widened wire formats when the NEW snapshot provably
@@ -641,6 +707,7 @@ class JITDatapath(DatapathBackend):
             placed = PlacedTensors(
                 {k: jnp.asarray(v) for k, v in snap.tensors().items()})
             self._account_placed(placed, patched=False)
+            self._note_fuse_plan(placed)
             return placed
         import jax
         from cilium_tpu.parallel.mesh import pad_snapshot_tensors
@@ -649,6 +716,7 @@ class JITDatapath(DatapathBackend):
             v, self._verdict_sharding if k == "verdict"
             else self._repl_sharding) for k, v in tensors.items()})
         self._account_placed(placed, patched=False)
+        self._note_fuse_plan(placed)
         return placed
 
     def _put_tensor(self, name, v):
@@ -783,6 +851,7 @@ class JITDatapath(DatapathBackend):
         else:
             self.patch_stats["patch_full"] += 1
         self._account_placed(new_placed, patched=True)
+        self._note_fuse_plan(new_placed)
         return new_placed
 
     def classify(self, placed, snap, batch, now):

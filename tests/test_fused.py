@@ -438,7 +438,8 @@ class TestFusedSelector:
         eng = jit_engine("on")
         try:
             assert eng.datapath.fused_state == {
-                "mode": "on", "active": True, "interpret": True}
+                "mode": "on", "active": True, "interpret": True,
+                "plan": {"lpm": True, "ct": True, "policy": True}}
             from cilium_tpu.runtime.api import status_doc
             assert status_doc(eng)["fused_kernels"]["active"] is True
         finally:
