@@ -39,6 +39,8 @@ TRACE_OFFSET_S = 1.0
 TRACE_LENGTH_S = 2.0
 PROBE_ROWS = 1024
 ARRIVALS_SEED = 1
+#: the schedule starts this long after the ring phase is armed
+START_DELAY_S = 0.25
 
 
 def say(what: str, **fields) -> None:
@@ -253,6 +255,21 @@ def resolve_rate(cell: Cell) -> Optional[float]:
     return float(t["knee_share"]) * float(cell.knee["knee_frames_per_s"])
 
 
+def stop_cap_s(cell: Cell, rate: float) -> float:
+    """How long after the open loop was last late a stop episode may last
+    (nic/nicgen.cc): the time a program that just holds the
+    knee it was measured at, ``rate / knee_share``, needs to drain a full
+    ring under the offered load. After it the program is behind by its own
+    doing, and what the ring refuses is the program's loss again."""
+    spare = rate * (1.0 / float(cell.traffic["knee_share"]) - 1.0)
+    return ring_frames(cell) / spare
+
+
+def ring_frames(cell: Cell) -> int:
+    rings = cell.config["rings"]
+    return min(int(rings["ring_size"]), int(rings["n_frames"]))
+
+
 def schedule_frames(cell: Cell, rate: Optional[float],
                     seconds: float) -> int:
     span = float(cell.traffic["warmup_s"]) + seconds
@@ -425,25 +442,39 @@ def open_live_set(sv: Served, tr: Traffic, numbers: List[Dict]
 
 def ring_phase(sv: Served, tr: Traffic, lo: int, hi: int,
                due: Optional[np.ndarray], t_stop: float,
-               at: Sequence = ()) -> Dict:
+               at: Sequence = (), rate: Optional[float] = None) -> Dict:
     """nicgen sends schedule entries [lo, hi) until ``t_stop``, then waits
     for the last verdicts. ``at``: (monotonic time, callable) pairs run
-    from this thread meanwhile, in order. → nicgen's log."""
+    from this thread meanwhile, in order. ``rate``: the open loop's, which
+    sets how long a stop episode may last. → nicgen's log."""
     from benchmarks.nic import nicgen
+    stops = {} if due is None else {
+        "ring_frames": ring_frames(sv.cell),
+        "stop_cap_s": stop_cap_s(sv.cell, rate)}
     nic = nicgen.Nic(sv.lib, sv.shim, tr.table, tr.lens, tr.sched[lo:hi],
-                     due, t_stop_s=t_stop).start()
+                     due, t_stop_s=t_stop, **stops).start()
     for t, fn in at:
         _sleep_until(t)
         fn()
     log = nic.join(timeout=max(0.0, t_stop - time.monotonic()) + 120.0)
     sv.eng.drain(timeout=60)
     say("ring", offered=log["n_offered"], accepted=log["n_accepted"],
-        refused=log["n_refused"], stalls_over_1ms=log["n_stalls"],
+        refused=log["n_refused"], **refusal_split(log),
+        stalls_over_1ms=log["n_stalls"],
         tx_drained=log["n_tx_drained"],
         samples=log["n_samples"], gaps_over_50us=log["n_gaps_over_50us"],
         max_gap_ms=round(log["max_gap_s"] * 1e3, 3),
         log_entries=log["log_t"].shape[0])
     return log
+
+
+def refusal_split(log: Dict) -> Dict:
+    """Whose loss the ring's refusals were, as nicgen labelled them."""
+    return {"refused_in_stop": log["n_refused_in_stop"],
+            "refused_on_time": log["n_refused_on_time"],
+            "refused_aftermath": log["n_refused_aftermath"],
+            "stop_episodes": log["n_stop_episodes"],
+            "stop_s": log["stop_s"]}
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
@@ -473,7 +504,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         before = {"shim": shim.stats(), "reasons": reason_counts(eng),
                   "refused": eng.metrics.insert_fail}
         warmup = float(cell.traffic["warmup_s"])
-        t_start = time.monotonic() + 0.25
+        t_start = time.monotonic() + START_DELAY_S
         run.w0, run.w1 = t_start + warmup, t_start + warmup + seconds
         if rate is not None:
             # every seed gets the same inter-arrival gaps, in another order
@@ -486,7 +517,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
                 trace_dir=_profile(run))))
         at.append((run.w1, lambda: run.stats1.update(
             snapshot_stats(eng))))
-        log = run.nic = ring_phase(sv, tr, 0, n_frames, run.due, run.w1, at)
+        log = run.nic = ring_phase(sv, tr, 0, n_frames, run.due, run.w1, at,
+                                   rate)
         run.info["setup_s"] = run.w0 - t_proc0
         if cell.traffic["loop"] == "saturate" \
                 and log["n_offered"] >= n_frames:
@@ -520,7 +552,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         result = {
             "correct": bool(correct),
             "attempted": int(log["n_offered"]),
-            "failed": int(log["n_refused"] + unverdicted),
+            # the program's loss: frames the ring refused with the
+            # generator on time, and accepted frames left without a verdict.
+            # What the ring refused in a stop of the host is under `nic`
+            "failed": int(log["n_refused_on_time"] + unverdicted),
             "metrics": metrics,
             "device": device,
         }
@@ -541,14 +576,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         result["also"] = read_metrics(run, not traced)
         if not traced:
             result["window_prefixes"] = window_prefixes(run)
-        result["numbers"] = run.numbers
         result["control"] = run.info.get("control")
         result["latency_samples"] = run.info.get("latency_samples")
         result["nic"] = {
-            "rate": rate, "stalls_over_1ms": log["n_stalls"],
+            "rate": rate, **refusal_split(log),
+            "stalls_over_1ms": log["n_stalls"],
             "gaps_over_50us": log["n_gaps_over_50us"],
             "max_gap_ms": log["max_gap_s"] * 1e3,
             "samples": log["n_samples"]}
+        # last, so that the end of a line that was cut still holds them
+        result["numbers"] = run.numbers
         return result
 
 

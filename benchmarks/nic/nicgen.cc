@@ -18,11 +18,30 @@
 //   saturate  closed on ring space: inject whenever the rx and fill rings
 //             have room. Nothing is lost; the rate delivered is the result.
 //   open      each frame has a due time; a frame the ring refuses when the
-//             loop offers it is lost (inject_t = -1), never retried. A
+//             loop offers it is lost (inject_t < 0), never retried. A
 //             frame this loop comes to late (its own thread was off the
 //             processor) is offered then, as a NIC would have put it in
 //             the ring meanwhile: latency is counted from the due time, and
 //             how late the loop ran is read from inject_t - due.
+//
+// Whose loss a refusal is (open loop). This loop spins and calls only
+// lock-free ring functions, so it comes to a frame over 1 ms late only when
+// its own thread did not run: the machine stopped it, not the server. The
+// frames due in the stop then arrive in one burst, which a NIC would have
+// spread over the stop. So coming to a frame late opens a *stop episode*:
+//   - a late frame the ring refuses in it is the host's loss;
+//   - so is an on-time frame refused in its aftermath, while the ring the
+//     burst filled still drains, but no more of those than the ring took
+//     late frames in the episode: each of them sits in a slot that an
+//     on-time frame would have found free.
+// The episode closes when the frames in flight (accepted - verdicts, as
+// this loop reads them) are back to half the ring or less: the burst has
+// been served. Or stop_cap_s after the loop was last late, whichever comes
+// first: after that the program is behind by its own doing. Refusals
+// inside an episode are the host's (inject_t = -2, n_refused_in_stop);
+// every other refusal found the generator on time and the ring full by the
+// program's doing (inject_t = -1, n_refused_on_time). Only this loop's own
+// clock opens an episode: nothing the server reports enters that.
 //
 // The loop spins. A loop that slept 100 us between iterations woke late
 // 850 times a run on the chip machine (PERF.md, PR 23).
@@ -79,7 +98,11 @@ struct NicgenRun {
   uint64_t n_sched;
   double t_stop_s;              // absolute: no frame is injected from here on
   double drain_deadline_s;      // absolute: give up waiting for verdicts
-  // out: per schedule entry, the time it entered the ring (-1: refused)
+  // open loop: when a stop episode ends (see the head of this file)
+  double stop_cap_s;            // seconds after the loop was last late
+  uint64_t ring_frames;         // frames the rings can hold in flight
+  // out: per schedule entry, the time it entered the ring; refused: -1 with
+  // the generator on time, -2 inside a stop episode
   double* inject_t;             // [n_sched]
   // out: the verdict-counter log
   double* log_t;
@@ -92,7 +115,12 @@ struct NicgenRun {
   // out: totals
   uint64_t n_offered;           // schedule entries reached
   uint64_t n_accepted;
-  uint64_t n_refused;           // open loop only
+  uint64_t n_refused;           // open loop only: in_stop + on_time
+  uint64_t n_refused_in_stop;   // the host's: inside a stop episode
+  uint64_t n_refused_on_time;   // the program's: every other refusal
+  uint64_t n_refused_aftermath; // of those in a stop, frames reached on time
+  uint64_t n_stop_episodes;     // episodes in which the ring refused a frame
+  double stop_s;                // this loop's gaps over 1 ms while injecting
   uint64_t n_tx_drained;
   uint64_t n_samples;
   uint64_t n_gaps_over_50us;    // sampling gaps longer than 50 us
@@ -120,11 +148,20 @@ int nicgen_run(NicgenRun* r) {
       !r->flow_frames || !r->flow_len || !r->sched_flow || !r->inject_t ||
       !r->log_t || !r->log_cap)
     return -1;
+  if (r->due_s && (!r->ring_frames || !(r->stop_cap_s > 0.0))) return -1;
   ShimStats st, prev;
   r->get_stats(r->shim, &prev);
   r->base_verdicts = verdicts_of(prev);
   r->n_log = 0;
   r->n_offered = r->n_accepted = r->n_refused = 0;
+  r->n_refused_in_stop = r->n_refused_on_time = r->n_refused_aftermath = 0;
+  r->n_stop_episodes = 0;
+  r->stop_s = 0.0;
+  bool in_stop = false;         // a stop episode is open
+  bool stop_counted = false;    // ... and is in n_stop_episodes
+  uint64_t late_taken = 0;      // late frames the ring took in it, less the
+                                // aftermath's refusals already set against them
+  double t_last_late = 0.0;     // when the loop last came to a frame late
   r->n_tx_drained = r->n_samples = r->n_gaps_over_50us = 0;
   r->max_gap_s = 0.0;
   r->log_overflow = r->drained = 0;
@@ -142,6 +179,7 @@ int nicgen_run(NicgenRun* r) {
 
     bool injecting = now < r->t_stop_s && k < r->n_sched && !r->stop;
     if (injecting) {
+      if (gap > 1e-3) r->stop_s += gap;
       if (r->due_s) {
         // open loop: everything due by now goes in, or is lost
         uint32_t burst = 0;
@@ -152,12 +190,33 @@ int nicgen_run(NicgenRun* r) {
                                    r->flow_frames + size_t(f) * r->frame_stride,
                                    r->flow_len[f])
                        : -1;
+          bool late = now - r->due_s[k] > 1e-3;
+          if (late) {
+            if (!in_stop) {
+              in_stop = true;
+              stop_counted = false;
+              late_taken = 0;
+            }
+            t_last_late = now;
+          }
           if (rc == 0) {
             r->inject_t[k] = now;
             r->n_accepted++;
+            if (late) late_taken++;
+          } else if (in_stop && (late || late_taken > 0)) {
+            if (!late) {
+              late_taken--;
+              r->n_refused_aftermath++;
+            }
+            if (!stop_counted) {
+              stop_counted = true;
+              r->n_stop_episodes++;
+            }
+            r->inject_t[k] = -2.0;
+            r->n_refused_in_stop++;
           } else {
             r->inject_t[k] = -1.0;
-            r->n_refused++;
+            r->n_refused_on_time++;
           }
           k++;
           burst++;
@@ -179,6 +238,7 @@ int nicgen_run(NicgenRun* r) {
         }
       }
       r->n_offered = k;
+      r->n_refused = r->n_refused_in_stop + r->n_refused_on_time;
     }
 
     double t_injected = now_s();
@@ -197,6 +257,13 @@ int nicgen_run(NicgenRun* r) {
         r->stall_stats_s[i] = t_sampled - t_drained;
       }
       r->n_stalls++;
+    }
+    if (in_stop) {
+      int64_t in_flight = int64_t(r->n_accepted) -
+                          int64_t(verdicts_of(st) - r->base_verdicts);
+      if (in_flight <= int64_t(r->ring_frames / 2) ||
+          now - t_last_late > r->stop_cap_s)
+        in_stop = false;
     }
     bool same = st.verdict_drops == prev.verdict_drops &&
                 st.verdict_passes == prev.verdict_passes &&

@@ -35,12 +35,18 @@ class NicgenRun(ctypes.Structure):
         ("frame_stride", ctypes.c_uint32), ("n_flows", ctypes.c_uint32),
         ("sched_flow", _P), ("due_s", _P), ("n_sched", ctypes.c_uint64),
         ("t_stop_s", ctypes.c_double), ("drain_deadline_s", ctypes.c_double),
+        ("stop_cap_s", ctypes.c_double), ("ring_frames", ctypes.c_uint64),
         ("inject_t", _P),
         ("log_t", _P), ("log_drops", _P), ("log_passes", _P),
         ("log_txfull", _P), ("log_stable", _P),
         ("log_cap", ctypes.c_uint64), ("n_log", ctypes.c_uint64),
         ("n_offered", ctypes.c_uint64), ("n_accepted", ctypes.c_uint64),
         ("n_refused", ctypes.c_uint64),
+        ("n_refused_in_stop", ctypes.c_uint64),
+        ("n_refused_on_time", ctypes.c_uint64),
+        ("n_refused_aftermath", ctypes.c_uint64),
+        ("n_stop_episodes", ctypes.c_uint64),
+        ("stop_s", ctypes.c_double),
         ("n_tx_drained", ctypes.c_uint64),
         ("n_samples", ctypes.c_uint64),
         ("n_gaps_over_50us", ctypes.c_uint64),
@@ -99,12 +105,15 @@ class Nic:
     uint16 hold one frame per flow; ``sched_flow`` [n] uint32 says which
     flow each schedule entry sends; ``due_s`` [n] float64 (absolute
     ``time.monotonic()`` seconds) makes the loop open, ``None`` closes it
-    on ring space. Every array stays referenced here until ``join``."""
+    on ring space. An open loop also says how many frames the rings hold
+    in flight (``ring_frames``) and how long after it was last late a stop
+    episode may last (``stop_cap_s``; nicgen.cc says what an episode is). Every array stays referenced here until ``join``."""
 
     def __init__(self, lib: ctypes.CDLL, shim, flow_frames: np.ndarray,
                  flow_len: np.ndarray, sched_flow: np.ndarray,
                  due_s: Optional[np.ndarray], t_stop_s: float,
-                 drain_s: float = 20.0, log_cap: int = 1 << 20):
+                 drain_s: float = 20.0, log_cap: int = 1 << 20,
+                 ring_frames: int = 0, stop_cap_s: float = 0.0):
         if flow_frames.dtype != np.uint8 or flow_frames.ndim != 2 \
                 or not flow_frames.flags.c_contiguous:
             raise ValueError("flow_frames must be C-contiguous uint8 [n, s]")
@@ -121,6 +130,9 @@ class Nic:
             due_s = np.ascontiguousarray(due_s, dtype=np.float64)
             if due_s.shape != sched_flow.shape:
                 raise ValueError("due_s must have one entry per frame")
+            if ring_frames <= 0 or not stop_cap_s > 0:
+                raise ValueError("an open loop needs ring_frames and "
+                                 "stop_cap_s")
         self._lib = lib
         self._keep = (flow_frames, flow_len, sched_flow, due_s, shim)
         n = sched_flow.size
@@ -149,6 +161,8 @@ class Nic:
         r.n_sched = n
         r.t_stop_s = t_stop_s
         r.drain_deadline_s = t_stop_s + drain_s
+        r.stop_cap_s = stop_cap_s
+        r.ring_frames = ring_frames
         r.inject_t = _addr(self.inject_t)
         r.log_t = _addr(self.log_t)
         r.log_drops = _addr(self.log_drops)
@@ -194,6 +208,11 @@ class Nic:
             "base_verdicts": int(r.base_verdicts),
             "n_offered": int(r.n_offered), "n_accepted": int(r.n_accepted),
             "n_refused": int(r.n_refused),
+            "n_refused_in_stop": int(r.n_refused_in_stop),
+            "n_refused_on_time": int(r.n_refused_on_time),
+            "n_refused_aftermath": int(r.n_refused_aftermath),
+            "n_stop_episodes": int(r.n_stop_episodes),
+            "stop_s": float(r.stop_s),
             "n_tx_drained": int(r.n_tx_drained),
             "n_samples": int(r.n_samples),
             "n_gaps_over_50us": int(r.n_gaps_over_50us),

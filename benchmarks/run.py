@@ -51,6 +51,9 @@ def main(argv=None) -> int:
     result = harness.run_cell(cell, args.seed, args.seconds,
                               bool(args.trace), T_PROC0)
     print(json.dumps(result), flush=True)
+    for n in result["numbers"]:
+        print("[compare] " + " ".join(f"{k}={v}" for k, v in n.items()),
+              file=sys.stderr, flush=True)
     return 0
 
 

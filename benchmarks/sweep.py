@@ -13,7 +13,12 @@ rate, the mix of the traffic file, on the one deployment that stays up
 (its conntrack table keeps the flows of the earlier steps, as a running
 node's would). A step reads:
 
-    refused      frames the ring would not take at their due time
+    refused      frames the ring would not take when they were offered, the
+                 sum of the next two (nic/nicgen.cc has the rule)
+    refused_in_stop  of those, in a stop of the host: the generator itself
+                 came to them late. They say nothing about the rate
+    refused_on_time  the others: the generator was on time and the ring was
+                 full. "Zero ring refusals" below means these
     backlog_mid  accepted frames still without a verdict, half way through
     backlog_end  the same when injection stops
     p50/p99_ms   due → verdict, over the step's second half
@@ -52,7 +57,7 @@ def sweep(cell, rates, seconds: float, seed: int, settle_s: float = 1.0):
             t_start = time.monotonic() + 0.25
             due = t_start + np.cumsum(rng.exponential(1.0 / rate, n))
             t_mid, t_stop = t_start + seconds / 2, t_start + seconds
-            log = h.ring_phase(sv, tr, lo, lo + n, due, t_stop)
+            log = h.ring_phase(sv, tr, lo, lo + n, due, t_stop, rate=rate)
             lo += n
             inj = log["inject_t"]
             ok = inj >= 0
@@ -66,6 +71,8 @@ def sweep(cell, rates, seconds: float, seed: int, settle_s: float = 1.0):
             row = {
                 "rate": rate, "offered": log["n_offered"],
                 "refused": log["n_refused"],
+                "refused_in_stop": log["n_refused_in_stop"],
+                "refused_on_time": log["n_refused_on_time"],
                 "backlog_mid": backlog(t_mid), "backlog_end": backlog(t_stop),
                 "p50_ms": float(np.percentile((vt - d)[half], 50) * 1e3)
                 if half.any() else None,

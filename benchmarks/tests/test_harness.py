@@ -17,9 +17,11 @@ import numpy as np
 import pytest
 
 from benchmarks import harness
+from benchmarks.tests import stops
 from benchmarks.tests.conftest import DATA
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+STOP_KEYS = {"refused_in_stop", "refused_on_time", "stop_episodes", "stop_s"}
 
 
 def run(tiny_manifest, name, seed, seconds=1.5, traced=False, **kw):
@@ -62,6 +64,11 @@ def test_result_line_and_fifo(runs, name):
     assert r["failed"] == 0 or (cell.traffic["loop"] == "open"
                                 and r["failed"] < r["attempted"] // 10)
     assert n["unverdicted"]["value"] == 0
+    # whose loss: only refusals that found the generator on time are failed
+    assert STOP_KEYS <= set(r["nic"])
+    assert r["failed"] == r["nic"]["refused_on_time"]
+    if cell.traffic["loop"] != "open":
+        assert not any(r["nic"][k] for k in STOP_KEYS - {"stop_s"})
     assert n["prefix_excess"]["value"] == 0           # the FIFO claim
     assert n["stable_points"]["value"] >= 100
     assert n["probe_mismatched"]["value"] == 0
@@ -103,12 +110,14 @@ def test_mesh_cell_steers(runs):
 def test_traced_run_reads_spans_and_counters(runs):
     _cell, r = runs["tiny-pods.steady80"]
     m = r["metrics"]
-    for name in ("nic.late_p99_ms", "nic.verdict_p99_ms",
-                 "feeder.harvest_to_apply_ms",
+    for name in ("nic.late_p99_ms", "nic.verdict_p99_ms", "nic.stop_ms",
+                 "nic.refused_in_stop", "feeder.harvest_to_apply_ms",
                  "pipeline.deadline_flush_share", "datapath.compute_wait_ms",
                  "startup.compiles_in_window"):
         assert name in m, name
     assert m["startup.compiles_in_window"]["value"] == 0
+    assert m["nic.stop_ms"]["value"] == r["nic"]["stop_s"] * 1e3
+    assert m["nic.refused_in_stop"]["value"] == r["nic"]["refused_in_stop"]
     assert "verdict_p50_ms" in r["also"]      # what tracing costs end to end
     assert m["datapath.compute_wait_ms"]["value"] > 0
     # no device plane in a CPU trace: the device readers find nothing to
@@ -166,6 +175,24 @@ def test_dropped_batch_comes_out_not_correct(tiny_manifest):
                    break_path=break_path)
     assert not r["correct"]
     assert not numbers(r)["prefix_excess"]["ok"]
+
+
+def test_failed_counts_what_the_ring_refused_on_time(tiny_manifest):
+    """The server alone stands for 0.9 s of a 2 s window (the feeder held
+    inside one verdict apply) while the generator keeps its schedule: the
+    1,024-frame ring fills in half a second at 2,000 frames/s, the frames
+    after that are refused on time, and those are `failed`. Nothing is
+    wrong with any verdict."""
+    cell, r = run(tiny_manifest, "tiny-dual.steady80", 11, seconds=2.0,
+                  break_path=stops.hold_server(
+                      harness.START_DELAY_S + 0.5 + 0.4, 0.9))
+    nic, n = r["nic"], numbers(r)
+    assert r["correct"], [x for x in r["numbers"] if not x["ok"]]
+    assert nic["refused_on_time"] > 300
+    assert r["failed"] == nic["refused_on_time"] + n["unverdicted"]["value"]
+    assert r["attempted"] > r["failed"] + nic["refused_in_stop"] > 0
+    assert nic["refused_aftermath"] <= nic["refused_in_stop"]
+    assert (nic["stop_episodes"] == 0) == (nic["refused_in_stop"] == 0)
 
 
 def test_a_new_cell_needs_only_new_files(tmp_path, tiny_manifest):
