@@ -169,8 +169,11 @@ def verify_configs(batch: int = 256,
             if l7:
                 b["http_method"][:] = 0
                 b["http_path"][:, 0] = ord("/")
+            # packed wires compile in the form the one-chip datapath
+            # serves them: results packed into one slab inside the jit
             fn = make_classify_fn(v4_only=v4_only, donate_ct=False,
-                                  packed=wire != "dict")
+                                  packed=wire != "dict",
+                                  slab=wire != "dict")
             if wire == "dict":
                 arg = {k: jnp.asarray(v) for k, v in b.items()}
             elif wire == "v4":

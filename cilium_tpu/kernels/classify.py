@@ -27,7 +27,9 @@ deterministic-winner semantics live in kernels/conntrack.py either way.
 
 from __future__ import annotations
 
+import dataclasses
 import threading
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -456,10 +458,25 @@ def fn_cache_stats() -> dict:
                 "evictions": _FN_EVICTIONS[0]}
 
 
+@dataclasses.dataclass(frozen=True)
+class OutSlab:
+    """The slab return form of the jitted step: every ``out`` column and
+    counter in one flat uint32 device vector (``words``), plus the layout
+    kernels/records.pack_out_jnp derived for it at trace time. The layout
+    is static pytree metadata — it rides the jit's output treedef, so the
+    host gets it back with every call at no transfer."""
+    words: Any
+    layout: tuple
+
+
+jax.tree_util.register_dataclass(OutSlab, data_fields=["words"],
+                                 meta_fields=["layout"])
+
+
 def make_classify_fn(probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
                      donate_ct: bool = True, packed: bool = False,
                      lb_probe_depth: int = 8, fused: bool = False,
-                     fused_interpret: bool = False):
+                     fused_interpret: bool = False, slab: bool = False):
     """jit-compiled classify step. CT buffers are donated (in-place update,
     no double allocation); re-traces only when array shapes change.
 
@@ -477,9 +494,17 @@ def make_classify_fn(probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
 
     ``fused``/``fused_interpret``: route the classify interior through the
     Pallas kernels (kernels/fused.py), optionally in interpreter mode (the
-    CPU-CI bit-identity configuration) — see classify_step."""
+    CPU-CI bit-identity configuration) — see classify_step.
+
+    ``slab=True``: the step returns ``(OutSlab, new_ct)`` instead of
+    ``(out, new_ct, counters)`` — a last stage inside the same jit packs
+    every out column and counter into one uint32 vector
+    (kernels/records.pack_out_jnp), so the host reads one batch's results
+    back in one transfer; ``records.unpack_out(np.asarray(s.words),
+    s.layout)`` is ``(out, counters)`` again, bit for bit. The one-chip
+    serving path's return form; the column form stays for tests/bench."""
     key = (probe_depth, v4_only, donate_ct, packed, lb_probe_depth,
-           fused, fused_interpret)
+           fused, fused_interpret, slab)
     with _FN_LOCK:
         fn = _FN_CACHE.get(key)
         if fn is not None:
@@ -490,10 +515,15 @@ def make_classify_fn(probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
         if packed:
             from cilium_tpu.kernels.records import unpack_wire_jnp
             batch = unpack_wire_jnp(batch)
-        return classify_step(tensors, ct, batch, now, world_index,
-                             probe_depth=probe_depth, v4_only=v4_only,
-                             lb_probe_depth=lb_probe_depth, fused=fused,
-                             fused_interpret=fused_interpret)
+        out, new_ct, counters = classify_step(
+            tensors, ct, batch, now, world_index,
+            probe_depth=probe_depth, v4_only=v4_only,
+            lb_probe_depth=lb_probe_depth, fused=fused,
+            fused_interpret=fused_interpret)
+        if slab:
+            from cilium_tpu.kernels.records import pack_out_jnp
+            return OutSlab(*pack_out_jnp(out, counters)), new_ct
+        return out, new_ct, counters
     fn = jax.jit(fn, donate_argnums=(1,) if donate_ct else ())
     with _FN_LOCK:
         cached = _FN_CACHE.get(key)

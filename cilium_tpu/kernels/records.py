@@ -7,6 +7,7 @@ layout doc); tests build batches from oracle PacketRecords.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -527,3 +528,104 @@ def unpack_batch_jnp(packed):
     else:
         b["http_path"] = jnp.zeros((n, C.L7_PATH_MAXLEN), dtype=jnp.uint8)
     return b
+
+
+# --------------------------------------------------------------------------- #
+# Packed verdict slab: the down-wire, ONE flat uint32 vector per batch.
+#
+# The mirror of the packed wire above: one device→host transfer per batch
+# instead of one per ``out`` column and counter (each read-back pays a
+# fixed cost that dwarfs 256 rows of payload). The jitted step packs on
+# device as its last stage (pack_out_jnp); finalize views the one
+# materialized vector back into the same keys, dtypes and shapes
+# (unpack_out). Nothing is dropped or made lazy — every column crosses for
+# every batch.
+#
+# The layout is DERIVED at trace time from the columns' own shapes and
+# dtypes, in sorted key order (the order a jit hands a dict back in):
+#   bool column      → one bit of a shared flag segment (one word per
+#                      element, up to 32 same-sized columns a segment)
+#   32-bit integer   → its words bitcast, flattened row-major (so a
+#                      [N, 4] address column is 4N contiguous words)
+#   narrower integer → widened to 32 bits, narrowed again on the host
+# and a column that IS another column (the same traced value under two
+# keys — ``ct_state_pre`` and ``status``) ships once: both keys point at
+# the one segment. A layout is a tuple of fields
+#   (group, key, dtype, offset, shape, bit)        bit < 0: word segment
+# — static and hashable, so it can ride a jit's output treedef.
+# --------------------------------------------------------------------------- #
+OUT_GROUPS = ("out", "counters")
+OutLayout = Tuple[Tuple[str, str, str, int, Tuple[int, ...], int], ...]
+
+
+def pack_out_jnp(out: Dict, counters: Dict):
+    """Device-side pack (inside jit) of the classify step's results →
+    (words [L] uint32, layout). ``unpack_out`` is the numpy twin."""
+    import jax
+    import jax.numpy as jnp
+    segs: List = []             # flat uint32 segments, in slab order
+    fields: List = []
+    shipped: Dict[int, Tuple[int, int]] = {}   # id(column) → (offset, bit)
+    open_flags: Dict[int, List[int]] = {}      # size → [seg, offset, bits]
+    end = 0
+    for group, cols in zip(OUT_GROUPS, (out, counters)):
+        for key in sorted(cols):
+            col = cols[key]
+            dt = np.dtype(col.dtype)
+            at = shipped.get(id(col))
+            if at is None:
+                flat = col.reshape(-1)
+                if dt == np.bool_:
+                    flags = open_flags.get(flat.shape[0])
+                    if flags is None or flags[2] == 32:
+                        flags = open_flags[flat.shape[0]] = [
+                            len(segs), end, 0]
+                        segs.append(jnp.zeros(flat.shape, jnp.uint32))
+                        end += flat.shape[0]
+                    seg, offset, bit = flags
+                    segs[seg] = segs[seg] | (
+                        flat.astype(jnp.uint32) << jnp.uint32(bit))
+                    flags[2] += 1
+                    at = (offset, bit)
+                else:
+                    if dt.kind not in "iu" or dt.itemsize > 4:
+                        raise TypeError(
+                            f"pack_out_jnp: {group}[{key!r}] is {dt}; the "
+                            f"slab carries bools and integers of at most "
+                            f"32 bits")
+                    if dt.itemsize < 4:
+                        flat = flat.astype(
+                            jnp.int32 if dt.kind == "i" else jnp.uint32)
+                    if flat.dtype != jnp.uint32:
+                        flat = jax.lax.bitcast_convert_type(flat, jnp.uint32)
+                    segs.append(flat)
+                    at = (end, -1)
+                    end += flat.shape[0]
+                shipped[id(col)] = at
+            fields.append((group, key, dt.name, at[0],
+                           tuple(int(d) for d in col.shape), at[1]))
+    return jnp.concatenate(segs), tuple(fields)
+
+
+def unpack_out(words: np.ndarray, layout: OutLayout
+               ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
+    """Host twin of :func:`pack_out_jnp`: the materialized slab →
+    (out_np, counters_np), key for key, dtype for dtype, shape for shape
+    what the per-column read of the same step returns. 32-bit columns are
+    views of ``words`` (no copy); bools and narrow integers are casts.
+    Like a device read-back, nothing returned is writable."""
+    groups: Dict[str, Dict[str, np.ndarray]] = {g: {} for g in OUT_GROUPS}
+    for group, key, dtype, offset, shape, bit in layout:
+        dt = np.dtype(dtype)
+        seg = words[offset:offset + math.prod(shape)]
+        if bit >= 0:
+            col = (seg & np.uint32(1 << bit)) != 0
+        elif dt.itemsize == 4:
+            col = seg.view(dt)
+        else:
+            col = seg.view(np.int32 if dt.kind == "i"
+                           else np.uint32).astype(dt)
+        col = col.reshape(shape)
+        col.flags.writeable = False
+        groups[group][key] = col
+    return groups["out"], groups["counters"]

@@ -34,6 +34,7 @@ import numpy as np
 
 from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
 from cilium_tpu.compile.snapshot import PolicySnapshot
+from cilium_tpu.kernels.records import unpack_out
 from cilium_tpu.observe.trace import (CT_GC_SPAN, PATCH_APPLY_SPAN,
                                       active as active_trace)
 from cilium_tpu.pipeline.guard import DeviceLost
@@ -383,7 +384,10 @@ class JITDatapath(DatapathBackend):
                 donate_ct=self.config.donate_ct,
                 packed=True,
                 fused=self._fused,
-                fused_interpret=self._fused_interpret)
+                fused_interpret=self._fused_interpret,
+                # ...and read-back-bound the other way: every out column
+                # and counter comes back in one packed slab, not 18 reads
+                slab=True)
             self._configured_devices = None
             self._live_ordinals = [0]
             self._mesh_cache = {}
@@ -460,6 +464,11 @@ class JITDatapath(DatapathBackend):
             "upload_cache_hits": 0,       # path dict served from device cache
             "upload_cache_misses": 0,
             "wire_flag_resets": 0,        # place() narrowed the wire format
+            # device→host crossings: batches finalized through one packed
+            # verdict slab (the one-chip path — there ``readback_columns``
+            # must stay 0) vs one read per column (the mesh paths)
+            "readback_slab": 0,
+            "readback_columns": 0,
         }
         # live-patch attribution: how each place_patch applied (delta =
         # donated device scatter; full = whole-tensor re-upload) and how
@@ -951,10 +960,19 @@ class JITDatapath(DatapathBackend):
     def classify_async(self, placed, snap, batch, now, pre_steered=False):
         """Async dispatch (SURVEY.md §5 / the pipeline's overlap stage):
         host packing + transfer + XLA enqueue happen here, synchronously and
-        in CT order; the returned finalize materializes the out pytree to
-        numpy, which is where the host actually blocks on the device. Only
-        the donated CT buffers need the lock — ``out``/``counters`` are
-        fresh (non-donated) device arrays, safe to read after the lock is
+        in CT order, and the read-back of the results is STARTED here too
+        (``copy_to_host_async`` right after the enqueue). On one chip the
+        step hands back one packed verdict slab (kernels/records
+        pack_out_jnp: every out column and the three counters in one
+        uint32 vector), so the returned finalize blocks on exactly one
+        device→host materialization — that is where the host actually
+        waits on the device — and views the slab back into the same
+        ``(out, counters)`` keys, dtypes and shapes a per-column read
+        gives (the mesh paths still read column by column). One crossing
+        each way per batch: the wire up, ``now``/``world_index`` riding
+        the call's own argument transfer as numpy scalars, the slab down.
+        Only the donated CT buffers need the lock — the slab is a fresh
+        (non-donated) device array, safe to read after the lock is
         released, and XLA sequences the donated-CT dependency chain across
         in-flight steps by itself."""
         jnp = self._jnp
@@ -991,11 +1009,19 @@ class JITDatapath(DatapathBackend):
                 with self._ct_lock:
                     self._check_placed(placed)
                     # a PlacedTensors handle is a dict SUBCLASS (not a
-                    # registered pytree): hand jit the plain-dict view
-                    out, new_ct, counters = self._classify(
-                        dict(placed), self._ct, dev_batch, jnp.uint32(now),
-                        jnp.int32(snap.world_index))
+                    # registered pytree): hand jit the plain-dict view.
+                    # now/world_index go up as numpy scalars (uint32[] /
+                    # int32[], not weak-typed: one trace for every value)
+                    # inside the call's own argument transfer — a
+                    # jnp.uint32(now) is a device program of its own
+                    slab, new_ct = self._classify(
+                        dict(placed), self._ct, dev_batch, np.uint32(now),
+                        np.int32(snap.world_index))
                     self._ct = new_ct
+                # outside the CT lock: the slab is not donated. The copy
+                # back starts behind the step now, so finalize finds it
+                # done (or waits for one transfer, never eighteen)
+                slab.words.copy_to_host_async()
         except BaseException:
             self._wire_buf_shed(wire_key)    # finalize will never run
             raise
@@ -1008,21 +1034,21 @@ class JITDatapath(DatapathBackend):
             try:
                 with tracer.span(trace_id, "datapath.compute",
                                  fused=int(self._fused)):
-                    out_np = {k: np.asarray(v) for k, v in out.items()}
-                    counters_np = {k: np.asarray(v)
-                                   for k, v in counters.items()}
+                    words = np.asarray(slab.words)
             except BaseException:
                 # a failed materialization (device error) never releases:
                 # shed the checkout count, the buffer goes to the GC
                 self._wire_buf_shed(wire_key)
                 raise
             if wire_key is not None:
-                # the device is provably done with this batch (out_np is
+                # the device is provably done with this batch (the slab is
                 # materialized): the wire buffer is safe to reuse now —
                 # and ONLY now (a dispatch that never finalizes simply
                 # sheds its buffer to the GC)
                 self._wire_buf_release(wire_key, wire_buf)
-            return out_np, counters_np
+            with self._pack_lock:
+                self.pack_stats["readback_slab"] += 1
+            return unpack_out(words, slab.layout)
         return finalize
 
     def _wire_buf(self, rows: int, words: int) -> Optional[np.ndarray]:
@@ -1110,7 +1136,6 @@ class JITDatapath(DatapathBackend):
         counts as pre-steered."""
         import jax
         from cilium_tpu.parallel.mesh import steer_batch, unsteer_outputs
-        jnp = self._jnp
         tracer, trace_id = active_trace()
         pre = pre_steered or self.n_flow_shards == 1
         scatter = None
@@ -1165,8 +1190,8 @@ class JITDatapath(DatapathBackend):
                 with self._ct_lock:
                     self._check_placed(placed)
                     out, new_ct, counters = self._classify(
-                        dict(placed), self._ct, dev_batch, jnp.uint32(now),
-                        jnp.int32(snap.world_index))
+                        dict(placed), self._ct, dev_batch, np.uint32(now),
+                        np.int32(snap.world_index))
                     self._ct = new_ct
         except BaseException as e:
             self._wire_buf_shed(wire_key)    # finalize will never run
@@ -1186,6 +1211,8 @@ class JITDatapath(DatapathBackend):
                 raise
             if wire_key is not None:
                 self._wire_buf_release(wire_key, wire_buf)
+            with self._pack_lock:
+                self.pack_stats["readback_columns"] += 1
             if scatter is not None:
                 out_np = unsteer_outputs(out_np, scatter)
             return out_np, counters_np
@@ -1209,7 +1236,6 @@ class JITDatapath(DatapathBackend):
         (the engine clamps min_bucket) and ship unpadded."""
         import jax
         from cilium_tpu.parallel.exchange import exchange_bytes
-        jnp = self._jnp
         tracer, trace_id = active_trace()
         n = self.n_flow_shards
         with tracer.span(trace_id, "datapath.pack",
@@ -1261,8 +1287,8 @@ class JITDatapath(DatapathBackend):
                 with self._ct_lock:
                     self._check_placed(placed)
                     out, new_ct, counters = self._classify(
-                        dict(placed), self._ct, dev_batch, jnp.uint32(now),
-                        jnp.int32(snap.world_index))
+                        dict(placed), self._ct, dev_batch, np.uint32(now),
+                        np.int32(snap.world_index))
                     self._ct = new_ct
         except BaseException as e:
             self._wire_buf_shed(wire_key)    # finalize will never run
@@ -1282,6 +1308,8 @@ class JITDatapath(DatapathBackend):
                 raise
             if wire_key is not None:
                 self._wire_buf_release(wire_key, wire_buf)
+            with self._pack_lock:
+                self.pack_stats["readback_columns"] += 1
             if orig_rows != rows:
                 # padded control-plane batch: outputs are already FIFO —
                 # dropping the invalid tail is the whole "un-steer"

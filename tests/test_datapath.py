@@ -313,3 +313,286 @@ class TestWireFlagReset:
         assert not jit.datapath._wire_l7      # tokens never widened it
         jit.stop()
         fake.stop()
+
+
+# --------------------------------------------------------------------------- #
+# The packed verdict slab (ISSUE 28): one crossing each way per batch
+# --------------------------------------------------------------------------- #
+SLAB_POLICY = [{
+    "endpointSelector": {"matchLabels": {"app": "web"}},
+    "egress": [{"toCIDR": ["10.0.0.0/8", "2001:db8::/32"]}],
+    "egressDeny": [{"toCIDR": ["10.66.0.0/16"]}],
+    "ingress": [{"fromEndpoints": [{"matchLabels": {"role": "fe"}}],
+                 "toPorts": [{
+                     "ports": [{"port": "80", "protocol": "TCP"}],
+                     "rules": {"http": [{"method": "GET",
+                                         "path": "/api"}]}}]}],
+}]
+
+
+class _StepRecorder:
+    """Stands in for ``JITDatapath._classify``: keeps every call's
+    arguments, calls through, and hands ``wrap`` the step's result."""
+
+    def __init__(self, fn, wrap=None):
+        self.fn, self.wrap, self.calls = fn, wrap, []
+
+    def __call__(self, *args):
+        self.calls.append(args)
+        res = self.fn(*args)
+        return self.wrap(res) if self.wrap else res
+
+
+class _CountingWords:
+    """Stands in for the slab's device vector: counts the read-back being
+    started and the materializations (``np.asarray`` lands in
+    ``__array__``), optionally failing them."""
+
+    def __init__(self, words, fail=False):
+        self.words, self.fail = words, fail
+        self.started = self.materialized = 0
+
+    def copy_to_host_async(self):
+        self.started += 1
+        self.words.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        self.materialized += 1
+        if self.fail:
+            raise RuntimeError("device error (test)")
+        return np.asarray(self.words)
+
+
+def slab_engine(ct_capacity=2048):
+    # donate_ct off: the CT state a step was handed stays readable, so the
+    # per-column step can be run again on exactly the same inputs
+    cfg = DaemonConfig(ct_capacity=ct_capacity, auto_regen=False,
+                       device="cpu", batch_size=32, donate_ct=False)
+    eng = Engine(cfg, datapath=JITDatapath(cfg))
+    eng.add_endpoint(["k8s:app=web"], ips=("192.168.1.10",), ep_id=1)
+    eng.add_endpoint(["k8s:role=fe"], ips=("192.168.1.30",), ep_id=3)
+    eng.apply_policy(SLAB_POLICY)
+    eng.regenerate()
+    return eng
+
+
+def slab_batch(eng, wire, case, base_port):
+    """32 rows for one wire form: allowed, denied and (second dispatch)
+    established and reply rows; ``padded`` leaves a tail of padding and a
+    row of an unknown endpoint invalid."""
+    n = 20 if case == "padded" else 32
+    recs = []
+    for i in range(n):
+        if i % 7 == 3:
+            recs.append(pkt("192.168.1.10", "10.66.1.1", base_port + i, 443))
+        elif wire == "wide" and i % 3 == 0:
+            recs.append(pkt("fd00::10", "2001:db8::%x" % (i + 1),
+                            base_port + i, 443))
+        elif i % 5 == 4:
+            # the reverse of an allowed egress flow: a reply once that
+            # flow is in the table
+            recs.append(pkt("10.1.2.3", "192.168.1.10", 443,
+                            base_port + i - 1, flags=C.TCP_ACK,
+                            direction=C.DIR_INGRESS))
+        else:
+            recs.append(pkt("192.168.1.10", "10.1.2.3", base_port + i, 443))
+    if case == "padded":
+        recs[5] = pkt("192.168.1.99", "10.1.2.3", base_port, 443, ep_id=77)
+    b = batch_from_records(recs, eng.active.snapshot.ep_slot_of, pad_to=32)
+    if wire == "l7":
+        for i in range(0, n, 4):
+            r = batch_from_records(
+                [pkt("192.168.1.30", "192.168.1.10", base_port + 100 + i,
+                     80, direction=C.DIR_INGRESS)],
+                eng.active.snapshot.ep_slot_of)
+            for k in b:
+                b[k][i] = r[k][0]
+            b["http_method"][i] = 0
+            b["http_path"][i, :4] = np.frombuffer(
+                b"/api" if i % 8 else b"/bad", np.uint8)
+    return b
+
+
+class TestVerdictSlab:
+    @pytest.mark.parametrize("case", ["valid", "padded", "ct_full"])
+    @pytest.mark.parametrize("wire,words", [("v4", 4), ("wide", 11),
+                                            ("l7", 5)])
+    def test_slab_finalize_equals_column_read(self, wire, words, case):
+        """What the one-chip finalize returns from the slab is, key for
+        key, dtype for dtype, bit for bit, what the per-column read of the
+        same jitted step returns on the same inputs and CT state."""
+        from cilium_tpu.kernels.classify import make_classify_fn
+        eng = slab_engine(ct_capacity=16 if case == "ct_full" else 2048)
+        dp = eng.datapath
+        rec = dp._classify = _StepRecorder(dp._classify)
+        columns = make_classify_fn(
+            probe_depth=dp.config.probe_depth, v4_only=dp.config.v4_only,
+            donate_ct=False, packed=True, fused=dp._fused,
+            fused_interpret=dp._fused_interpret)
+        act = eng.active
+        # two dispatches of the same flows: new ones, then established
+        # and reply rows on the table the first one left
+        for now in (1000, 1001):
+            b = slab_batch(eng, wire, case, 40000)
+            out, counters = dp.classify_async(
+                act.tensors, act.snapshot, b, now)()
+            tensors, ct, dev_batch, now_arg, wi = rec.calls[-1]
+            wire_arr = dev_batch[0] if isinstance(dev_batch, tuple) \
+                else dev_batch
+            assert wire_arr.shape == (32, words)
+            ref_out, _ct, ref_counters = columns(
+                tensors, ct, dev_batch, now_arg, wi)
+            for got, ref in ((out, ref_out), (counters, ref_counters)):
+                assert list(got) == list(ref)
+                for k in ref:
+                    want = np.asarray(ref[k])
+                    assert type(got[k]) is np.ndarray, k
+                    assert got[k].dtype == want.dtype, k
+                    assert got[k].shape == want.shape, k
+                    assert got[k].tobytes() == want.tobytes(), k
+            np.testing.assert_array_equal(out["ct_state_pre"], out["status"])
+            assert out["nat_dst"].shape == (32, 4)
+        # the batch did what its case is there for
+        assert out["allow"].any() and not out["allow"].all()
+        assert (out["status"] == C.CTStatus.ESTABLISHED).any()
+        if case != "ct_full":
+            assert (out["status"] == C.CTStatus.REPLY).any()
+        if case == "padded":
+            assert not b["valid"].all()
+            assert not out["allow"][~b["valid"]].any()
+        if case == "ct_full":
+            assert out["ct_full"].any()
+            assert int(counters["insert_fail"]) == int(out["ct_full"].sum())
+        if wire == "wide":
+            assert out["nat_dst"][:, 0].any()    # v6 words, not v4-mapped
+        if wire == "l7":
+            assert out["redirect"].any() or (
+                out["reason"] == C.DropReason.POLICY_L7).any()
+        assert dp.pack_stats["readback_slab"] == 2
+        assert dp.pack_stats["readback_columns"] == 0
+        eng.stop()
+
+    def test_pack_out_roundtrip_extremes(self):
+        """pack_out_jnp / unpack_out on the values a layout could mangle:
+        all-ones words, -1 in a signed column, a 4-word address column,
+        narrow integers, scalars, and one value under two keys."""
+        import jax
+        from cilium_tpu.kernels.records import pack_out_jnp, unpack_out
+        n, cells = 8, 14
+        rule = np.full((n,), -1, np.int32)
+        rule[::2] = np.iinfo(np.int32).max
+        status = np.arange(n, dtype=np.int32) - 4
+        out = {
+            "allow": np.arange(n) % 2 == 0,
+            "svc": np.ones((n,), bool),
+            "rnat": np.zeros((n,), bool),
+            "remote_identity": np.full((n,), 0xFFFFFFFF, np.uint32),
+            "matched_rule": rule,
+            "nat_dst": np.full((n, 4), 0xFFFFFFFF, np.uint32)
+            - np.arange(4 * n, dtype=np.uint32).reshape(n, 4),
+            "status": status,
+            "narrow_s": np.array([-128, 127, -1, 0, 1, 2, 3, 4], np.int8),
+            "narrow_u": np.full((n,), 0xFFFF, np.uint16),
+        }
+        counters = {
+            "by_reason_dir": np.full((cells,), 0xFFFFFFFF, np.uint32),
+            "insert_fail": np.uint32(0xFFFFFFFF),
+            "ct_evicted": np.uint32(0),
+        }
+
+        def step(out, counters):
+            out = dict(out, ct_state_pre=out["status"])
+            return pack_out_jnp(out, counters)
+
+        layouts = []
+
+        def traced(out, counters):
+            words, layout = step(out, counters)
+            layouts.append(layout)
+            return words
+
+        words = np.asarray(jax.jit(traced)(out, counters))
+        layout, = layouts
+        assert words.dtype == np.uint32 and words.ndim == 1
+        # one flag word per row for the three bools, status shipped once
+        assert words.shape[0] == n * (1 + 1 + 1 + 4 + 1 + 1 + 1) \
+            + cells + 2
+        got_out, got_counters = unpack_out(words, layout)
+        out["ct_state_pre"] = status
+        for got, ref in ((got_out, out), (got_counters, counters)):
+            assert sorted(got) == sorted(ref)
+            for k in ref:
+                want = np.asarray(ref[k])
+                assert got[k].dtype == want.dtype, k
+                assert got[k].shape == want.shape, k
+                np.testing.assert_array_equal(got[k], want, k)
+                assert not got[k].flags.writeable, k
+        assert np.shares_memory(got_out["status"], got_out["ct_state_pre"])
+        assert np.shares_memory(got_out["nat_dst"], words)
+        with pytest.raises(TypeError):
+            jax.jit(lambda x: pack_out_jnp({"f": x}, {})[0])(
+                np.zeros((4,), np.float16))
+
+    def test_one_crossing_each_way(self):
+        """N batches through the one-chip JITDatapath: N slab read-backs
+        each started at dispatch and materialized once in finalize, no
+        per-column path; ``now``/``world_index`` go up as numpy scalars
+        and a new ``now`` is not a new program."""
+        import dataclasses
+        eng = slab_engine()
+        dp = eng.datapath
+        slabs = []
+
+        def wrap(res):
+            slab, new_ct = res
+            slabs.append(_CountingWords(slab.words))
+            return dataclasses.replace(slab, words=slabs[-1]), new_ct
+
+        rec = dp._classify = _StepRecorder(dp._classify, wrap)
+        act = eng.active
+        n_batches, sizes = 5, []
+        for i in range(n_batches):
+            fin = dp.classify_async(act.tensors, act.snapshot,
+                                    slab_batch(eng, "v4", "valid", 41000),
+                                    2000 + i)
+            assert (slabs[-1].started, slabs[-1].materialized) == (1, 0)
+            out, counters = fin()
+            assert (slabs[-1].started, slabs[-1].materialized) == (1, 1)
+            assert all(type(v) is np.ndarray
+                       for v in (*out.values(), *counters.values()))
+            sizes.append(rec.fn._cache_size())
+        assert sizes == [sizes[0]] * n_batches   # one compiled program
+        for i, (_t, _ct, _b, now, wi) in enumerate(rec.calls):
+            assert type(now) is np.uint32 and int(now) == 2000 + i
+            assert type(wi) is np.int32
+            assert int(wi) == act.snapshot.world_index
+        assert dp.pack_stats["readback_slab"] == n_batches
+        assert dp.pack_stats["readback_columns"] == 0
+        assert dp._wire_out == 0
+        assert len(dp._wire_pool[(32, 4)]) == 1   # released, and reused
+        eng.stop()
+
+    def test_failed_materialization_sheds_wire_buffer(self):
+        """The fault path keeps its contract: a slab that fails to
+        materialize sheds the wire buffer — the in-flight count comes
+        down, the buffer never returns to the pool."""
+        import dataclasses
+        eng = slab_engine()
+        dp = eng.datapath
+
+        def wrap(res):
+            slab, new_ct = res
+            return dataclasses.replace(
+                slab, words=_CountingWords(slab.words, fail=True)), new_ct
+
+        dp._classify = _StepRecorder(dp._classify, wrap)
+        act = eng.active
+        fin = dp.classify_async(act.tensors, act.snapshot,
+                                slab_batch(eng, "v4", "valid", 42000), 3000)
+        assert dp._wire_out == 1
+        with pytest.raises(RuntimeError):
+            fin()
+        assert dp._wire_out == 0
+        assert not dp._wire_pool.get((32, 4))
+        assert dp.pack_stats["readback_slab"] == 0
+        eng.stop()
