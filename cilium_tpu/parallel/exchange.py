@@ -288,14 +288,19 @@ def classify_step_exchange(tensors, ct, batch, now, world_index=0, *,
     req = pack_requests(pre["fwd_keys"], b["tcp_flags"], valid,
                         allow_if_hit, allow_if_new, pre["rev_nat"])
     local_rows = req.shape[0]
-    gathered = ring_all_gather(req, axis_name, n_shards)
-    rep_all, new_ct, insert_fail, n_evicted = ct_exchange_serve(
-        ct, gathered.reshape(n_shards * local_rows, REQ_WORDS),
-        axis_name, n_shards, now, probe_depth, plan=plan,
-        fused_interpret=fused_interpret)
-    rep = ring_reduce_scatter(
-        rep_all.reshape(n_shards, local_rows, REP_WORDS), axis_name,
-        n_shards)
+    # the three phases carry names into the compiled program's op metadata
+    # (mesh programs only), so a device trace can tell them apart
+    with jax.named_scope("rss.request_gather"):
+        gathered = ring_all_gather(req, axis_name, n_shards)
+    with jax.named_scope("rss.owner_ct"):
+        rep_all, new_ct, insert_fail, n_evicted = ct_exchange_serve(
+            ct, gathered.reshape(n_shards * local_rows, REQ_WORDS),
+            axis_name, n_shards, now, probe_depth, plan=plan,
+            fused_interpret=fused_interpret)
+    with jax.named_scope("rss.reply_scatter"):
+        rep = ring_reduce_scatter(
+            rep_all.reshape(n_shards, local_rows, REP_WORDS), axis_name,
+            n_shards)
     est, reply, ct_full, entry_rnat = unpack_replies(rep)
 
     # local verdict composition from the replies — the same 3-5 → 6b → 7

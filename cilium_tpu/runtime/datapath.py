@@ -496,9 +496,12 @@ class JITDatapath(DatapathBackend):
         # request/reply buffers are transient per-dispatch device tensors;
         # the ledger's ``exchange`` group carries the PEAK bytes any
         # dispatched bucket materialized (the budget-relevant number),
-        # rss_exchange_stats() the last/peak occupancy pair
+        # rss_exchange_stats() the last/peak occupancy pair and the
+        # cumulative bytes/batches every dispatched bucket added
         self._exchange_last_bytes = 0
         self._exchange_peak_bytes = 0
+        self._exchange_bytes_total = 0
+        self._exchange_batches_total = 0
         self._account_ct_hbm()
         self._scatter_fn = None            # jitted donated row scatter
         # overlapped CT GC (kernels/conntrack.ct_sweep_chunk): cursor into
@@ -543,7 +546,9 @@ class JITDatapath(DatapathBackend):
         only): bytes the last dispatched bucket's gathered request/reply
         buffers materialized across the mesh against the worst case at
         ``batch_size`` — the device-transient twin of the wire pool's
-        host-side row."""
+        host-side row — and, cumulative over every dispatched bucket,
+        ``exchange_bytes_total`` / ``exchange_batches_total`` (Engine folds
+        them into ``rss_exchange_{bytes,batches}_total``)."""
         if not self._rss_device:
             return None
         from cilium_tpu.parallel.exchange import exchange_bytes
@@ -554,7 +559,9 @@ class JITDatapath(DatapathBackend):
             # shape-parity runs) — occupancy must never exceed capacity
             return {"capacity": max(cap, self._exchange_peak_bytes),
                     "in_use": self._exchange_last_bytes,
-                    "peak": self._exchange_peak_bytes}
+                    "peak": self._exchange_peak_bytes,
+                    "exchange_bytes_total": self._exchange_bytes_total,
+                    "exchange_batches_total": self._exchange_batches_total}
 
     @property
     def fused_state(self) -> Dict[str, Any]:
@@ -1115,6 +1122,18 @@ class JITDatapath(DatapathBackend):
             self._path_dict_dev = dev
         return dev
 
+    def _read_columns(self, tracer, trace_id, out, counters):
+        """The meshed finalizers' device→host crossing: one read per out
+        column and counter, each of an array sharded over the mesh (the
+        one-chip path reads one slab). ``datapath.readback`` spans the
+        first ``np.asarray`` to the last, inside ``datapath.compute``."""
+        with tracer.span(trace_id, "datapath.readback",
+                         arrays=len(out) + len(counters),
+                         shards=self.n_flow_shards):
+            out_np = {k: np.asarray(v) for k, v in out.items()}
+            counters_np = {k: np.asarray(v) for k, v in counters.items()}
+        return out_np, counters_np
+
     def _classify_async_sharded(self, placed, snap, batch, now,
                                 pre_steered=False):
         """The meshed overlap stage. Pre-steered batches (the pipeline's
@@ -1202,9 +1221,8 @@ class JITDatapath(DatapathBackend):
             try:
                 with tracer.span(trace_id, "datapath.compute",
                                  fused=int(self._fused)):
-                    out_np = {k: np.asarray(v) for k, v in out.items()}
-                    counters_np = {k: np.asarray(v)
-                                   for k, v in counters.items()}
+                    out_np, counters_np = self._read_columns(
+                        tracer, trace_id, out, counters)
             except BaseException as e:
                 self._wire_buf_shed(wire_key)  # failed materialization
                 self._maybe_device_lost(e)
@@ -1268,6 +1286,8 @@ class JITDatapath(DatapathBackend):
         ex_bytes = exchange_bytes(rows, n)
         with self._hbm_lock:
             self._exchange_last_bytes = ex_bytes
+            self._exchange_bytes_total += ex_bytes
+            self._exchange_batches_total += 1
             if ex_bytes > self._exchange_peak_bytes:
                 self._exchange_peak_bytes = ex_bytes
                 self._hbm_groups["exchange"] = ex_bytes
@@ -1299,9 +1319,8 @@ class JITDatapath(DatapathBackend):
             try:
                 with tracer.span(trace_id, "datapath.compute",
                                  fused=int(self._fused)):
-                    out_np = {k: np.asarray(v) for k, v in out.items()}
-                    counters_np = {k: np.asarray(v)
-                                   for k, v in counters.items()}
+                    out_np, counters_np = self._read_columns(
+                        tracer, trace_id, out, counters)
             except BaseException as e:
                 self._wire_buf_shed(wire_key)  # failed materialization
                 self._maybe_device_lost(e)

@@ -2035,6 +2035,17 @@ class Engine:
                     if d:
                         self.metrics.inc_counter(f"datapath_{k}_total", d)
                         self._pack_stats_seen[f"patch:{k}"] = v
+        # device-RSS exchange: cumulative bytes the ring materialized and
+        # batches that crossed it — same delta-fold
+        rs = getattr(self.datapath, "rss_exchange_stats", None)
+        ex = rs() if rs is not None else None
+        if ex:
+            with self._pack_fold_lock:
+                for k in ("exchange_bytes_total", "exchange_batches_total"):
+                    d = ex[k] - self._pack_stats_seen.get(f"rss:{k}", 0)
+                    if d:
+                        self.metrics.inc_counter(f"rss_{k}", d)
+                        self._pack_stats_seen[f"rss:{k}"] = ex[k]
         # make_classify_fn memo cache (kernels/classify): size gauge +
         # eviction counter, folded only when the jax-backed module is
         # actually loaded — a fake-datapath engine must stay jax-free
