@@ -541,6 +541,15 @@ def unpack_batch_jnp(packed):
 # (unpack_out). Nothing is dropped or made lazy — every column crosses for
 # every batch.
 #
+# On a mesh (parallel/mesh.py, ``slab=True``) every chip packs its own
+# rows and its copy of the psummed counters inside the shard_map body, so
+# the vector is ``n`` equal per-chip segments under ONE layout (whose
+# shapes are a chip's), sharded over 'flows' and still one transfer. An
+# ``out`` column is then the segments' pieces in shard order (arrival
+# order under device RSS, the steered geometry under host RSS: what the
+# per-column read of the sharded array gives), a counter is shard 0's
+# copy. One chip is the case n = 1.
+#
 # The layout is DERIVED at trace time from the columns' own shapes and
 # dtypes, in sorted key order (the order a jit hands a dict back in):
 #   bool column      → one bit of a shared flag segment (one word per
@@ -607,17 +616,26 @@ def pack_out_jnp(out: Dict, counters: Dict):
     return jnp.concatenate(segs), tuple(fields)
 
 
-def unpack_out(words: np.ndarray, layout: OutLayout
+def unpack_out(words: np.ndarray, layout: OutLayout, shards: int = 1
                ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
     """Host twin of :func:`pack_out_jnp`: the materialized slab →
     (out_np, counters_np), key for key, dtype for dtype, shape for shape
-    what the per-column read of the same step returns. 32-bit columns are
-    views of ``words`` (no copy); bools and narrow integers are casts.
-    Like a device read-back, nothing returned is writable."""
+    what the per-column read of the same step returns. ``shards`` is the
+    number of equal per-chip segments ``words`` holds under the one
+    ``layout`` (1 on a chip; the 'flows' width the batch was dispatched
+    with on a mesh). On one segment 32-bit columns are views of ``words``
+    (no copy); bools, narrow integers and a column gathered from several
+    segments are copies. Like a device read-back, nothing returned is
+    writable."""
     groups: Dict[str, Dict[str, np.ndarray]] = {g: {} for g in OUT_GROUPS}
+    slabs = words.reshape(shards, -1)
     for group, key, dtype, offset, shape, bit in layout:
         dt = np.dtype(dtype)
-        seg = words[offset:offset + math.prod(shape)]
+        seg = slabs[:, offset:offset + math.prod(shape)]
+        if group == "counters":
+            seg = seg[:1]              # replicated: shard 0's copy
+        else:
+            shape = (shards * shape[0],) + shape[1:]
         if bit >= 0:
             col = (seg & np.uint32(1 << bit)) != 0
         elif dt.itemsize == 4:

@@ -314,7 +314,8 @@ def rehash_ct_arrays(arrays: Dict[str, np.ndarray], n_flow_shards: int,
 def make_sharded_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
                              v4_only: bool = False, donate_ct: bool = True,
                              fused: bool = False,
-                             fused_interpret: bool = False):
+                             fused_interpret: bool = False,
+                             slab: bool = False):
     """shard_map'd + jitted classify step over ``mesh`` ('flows','rules').
 
     ``fused``/``fused_interpret`` route each shard's classify interior
@@ -336,6 +337,17 @@ def make_sharded_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
     into the classify pipeline); the path dict replicates. This is what
     lets the sharded serving path pack in place into one pooled buffer
     whose per-shard segments ARE the per-chip transfers.
+
+    ``slab=True``: the step returns ``(OutSlab, new_ct)`` instead of
+    ``(out, new_ct, counters)``, the return form of
+    ``make_classify_fn(slab=True)`` on a mesh: each chip packs its own
+    rows of every out column and its copy of the psummed counters into
+    one uint32 segment (kernels/records.pack_out_jnp) as the shard_map
+    body's last stage, and the segments come back as ONE vector sharded
+    over 'flows'. ``records.unpack_out(np.asarray(s.words), s.layout,
+    shards=mesh.shape["flows"])`` is ``(out, counters)`` again, bit for
+    bit. The serving path's return form (runtime/datapath.py); the column
+    form stays the default for tests and benches.
     """
     from cilium_tpu.kernels.classify import classify_step
 
@@ -347,13 +359,14 @@ def make_sharded_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
             probe_depth=probe_depth, v4_only=v4_only, rule_axis=rule_axis,
             fused=fused, fused_interpret=fused_interpret)
 
-    return _make_meshed_classify(mesh, body, donate_ct=donate_ct)
+    return _make_meshed_classify(mesh, body, donate_ct=donate_ct, slab=slab)
 
 
 def make_unsteered_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
                                v4_only: bool = False, donate_ct: bool = True,
                                fused: bool = False,
-                               fused_interpret: bool = False):
+                               fused_interpret: bool = False,
+                               slab: bool = False):
     """shard_map'd + jitted DEVICE-RSS classify step over ``mesh``
     ('flows','rules'): batch rows shard over 'flows' in plain ARRIVAL
     order — no host steering, no placement semantics in the row layout —
@@ -374,7 +387,9 @@ def make_unsteered_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
     takes an equal arrival-order slice).
 
     Accepts the same batch forms as :func:`make_sharded_classify_fn`
-    (column dict, packed wire, (wire, path_dict))."""
+    (column dict, packed wire, (wire, path_dict)) and gives the same
+    return forms (``slab=True``: ``(OutSlab, new_ct)``, the segments in
+    shard order, which here is arrival order)."""
     from cilium_tpu.parallel.exchange import classify_step_exchange
 
     n_flow = mesh.shape["flows"]
@@ -387,16 +402,20 @@ def make_unsteered_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
             probe_depth=probe_depth, v4_only=v4_only, rule_axis=rule_axis,
             fused=fused, fused_interpret=fused_interpret)
 
-    return _make_meshed_classify(mesh, body, donate_ct=donate_ct)
+    return _make_meshed_classify(mesh, body, donate_ct=donate_ct, slab=slab)
 
 
-def _make_meshed_classify(mesh, body, donate_ct: bool = True):
+def _make_meshed_classify(mesh, body, donate_ct: bool = True,
+                          slab: bool = False):
     """The shared shard_map/jit plumbing behind both meshed classify
     variants: spec construction, the per-(tensor-key-set, batch-kind) jit
-    cache, device-side wire unpack, and the counter psum."""
+    cache, device-side wire unpack, the counter psum, and (``slab``) the
+    per-chip pack of the results into one verdict slab segment."""
     import jax
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
+    from cilium_tpu.kernels.classify import OutSlab
+    from cilium_tpu.kernels.records import pack_out_jnp
 
     rule_sharded = mesh.shape["rules"] > 1
 
@@ -410,6 +429,10 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True):
             "insert_fail": jax.lax.psum(counters["insert_fail"], "flows"),
             "ct_evicted": jax.lax.psum(counters["ct_evicted"], "flows"),
         }
+        if slab:
+            # this chip's rows and its copy of the (now global) counters:
+            # one segment of the mesh's slab, under one layout for all
+            return OutSlab(*pack_out_jnp(out, counters)), new_ct
         return out, new_ct, counters
 
     verdict_spec = P(None, None, "rules", None) if rule_sharded else P()
@@ -427,6 +450,11 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True):
                  "rnat_src", "rnat_sport")}
     counters_spec = {"by_reason_dir": P(), "insert_fail": P(),
                      "ct_evicted": P()}
+    # the slab's words go out one contiguous segment a chip; the single
+    # P('flows') is a prefix of the whole OutSlab (its layout is static
+    # metadata of the output tree, as on one chip)
+    results_spec = ((P("flows"), ct_spec) if slab
+                    else (out_spec, ct_spec, counters_spec))
 
     def local_fn_packed(tensors, ct, wire, now, world_index):
         # device-side unpack of the local wire segment; the width dispatch
@@ -467,7 +495,7 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True):
             fn = jax.jit(shard_map(
                 body, mesh=mesh,
                 in_specs=(tensors_spec, ct_spec, bspec, P(), P()),
-                out_specs=(out_spec, ct_spec, counters_spec),
+                out_specs=results_spec,
                 check_vma=False,
             ), donate_argnums=(1,) if donate_ct else ())
             jits[key] = fn

@@ -362,7 +362,10 @@ class JITDatapath(DatapathBackend):
                 v4_only=self.config.v4_only,
                 donate_ct=self.config.donate_ct,
                 fused=self._fused,
-                fused_interpret=self._fused_interpret)
+                fused_interpret=self._fused_interpret,
+                # one packed verdict slab a batch, a segment a chip, as
+                # on one chip below (remesh builds its survivors alike)
+                slab=True)
             # per-survivor-set geometry cache: healing back onto a device
             # set the process already served reuses that set's mesh +
             # jitted classify — re-tracing on every down/up flap would
@@ -465,10 +468,9 @@ class JITDatapath(DatapathBackend):
             "upload_cache_misses": 0,
             "wire_flag_resets": 0,        # place() narrowed the wire format
             # device→host crossings: batches finalized through one packed
-            # verdict slab (the one-chip path — there ``readback_columns``
-            # must stay 0) vs one read per column (the mesh paths)
+            # verdict slab read in one transfer — every batch, on one chip
+            # and on a mesh (there one segment a chip, one sharded array)
             "readback_slab": 0,
-            "readback_columns": 0,
         }
         # live-patch attribution: how each place_patch applied (delta =
         # donated device scatter; full = whole-tensor re-upload) and how
@@ -975,7 +977,8 @@ class JITDatapath(DatapathBackend):
         device→host materialization — that is where the host actually
         waits on the device — and views the slab back into the same
         ``(out, counters)`` keys, dtypes and shapes a per-column read
-        gives (the mesh paths still read column by column). One crossing
+        gives. A mesh does the same with one segment of the slab a chip, in
+        one array sharded over 'flows' (``_read_slab``). One crossing
         each way per batch: the wire up, ``now``/``world_index`` riding
         the call's own argument transfer as numpy scalars, the slab down.
         Only the donated CT buffers need the lock — the slab is a fresh
@@ -1122,17 +1125,33 @@ class JITDatapath(DatapathBackend):
             self._path_dict_dev = dev
         return dev
 
-    def _read_columns(self, tracer, trace_id, out, counters):
-        """The meshed finalizers' device→host crossing: one read per out
-        column and counter, each of an array sharded over the mesh (the
-        one-chip path reads one slab). ``datapath.readback`` spans the
-        first ``np.asarray`` to the last, inside ``datapath.compute``."""
-        with tracer.span(trace_id, "datapath.readback",
-                         arrays=len(out) + len(counters),
-                         shards=self.n_flow_shards):
-            out_np = {k: np.asarray(v) for k, v in out.items()}
-            counters_np = {k: np.asarray(v) for k, v in counters.items()}
-        return out_np, counters_np
+    def _read_slab(self, tracer, trace_id, slab, shards, wire_key,
+                   wire_buf):
+        """The meshed finalizers' device→host crossing: the one verdict
+        slab of the batch, ``shards`` equal per-chip segments in one array
+        sharded over 'flows' whose copy back started at dispatch, read in
+        one ``np.asarray`` and viewed back into ``(out, counters)``.
+        ``shards`` is the width the batch was DISPATCHED with (a remesh
+        may have come since). ``datapath.readback`` spans that read,
+        inside ``datapath.compute``; the wire buffer is released only once
+        the slab is on the host (the device is then done with the batch),
+        and a failed materialization sheds it and is checked for a dead
+        chip's signature."""
+        try:
+            with tracer.span(trace_id, "datapath.compute",
+                             fused=int(self._fused)):
+                with tracer.span(trace_id, "datapath.readback",
+                                 arrays=1, shards=shards):
+                    words = np.asarray(slab.words)
+        except BaseException as e:
+            self._wire_buf_shed(wire_key)      # failed materialization
+            self._maybe_device_lost(e)
+            raise
+        if wire_key is not None:
+            self._wire_buf_release(wire_key, wire_buf)
+        with self._pack_lock:
+            self.pack_stats["readback_slab"] += 1
+        return unpack_out(words, slab.layout, shards)
 
     def _classify_async_sharded(self, placed, snap, batch, now,
                                 pre_steered=False):
@@ -1156,10 +1175,10 @@ class JITDatapath(DatapathBackend):
         import jax
         from cilium_tpu.parallel.mesh import steer_batch, unsteer_outputs
         tracer, trace_id = active_trace()
-        pre = pre_steered or self.n_flow_shards == 1
+        n = self.n_flow_shards      # the width this batch is dispatched at
+        pre = pre_steered or n == 1
         scatter = None
-        with tracer.span(trace_id, "datapath.pack",
-                         shards=self.n_flow_shards):
+        with tracer.span(trace_id, "datapath.pack", shards=n):
             b = self._columnar(batch)
             if not pre:
                 # steering must hash the post-DNAT tuple (service flows' CT
@@ -1168,12 +1187,12 @@ class JITDatapath(DatapathBackend):
                 lb = snap.lb if snap.lb.n_frontends else None
                 with tracer.span(trace_id, "datapath.steer"):
                     b, scatter, _per = steer_batch(
-                        b, self.n_flow_shards, lb=lb, round_to_pow2=True)
+                        b, n, lb=lb, round_to_pow2=True)
             n_rows = int(b["valid"].shape[0])
-            if n_rows % self.n_flow_shards:
+            if n_rows % n:
                 raise ValueError(
                     f"pre-steered batch rows ({n_rows}) must divide into "
-                    f"{self.n_flow_shards} flow shards")
+                    f"{n} flow shards")
             if not self.config.zero_copy_ingest:
                 # legacy dict dispatch (12 P('flows') column transfers);
                 # shard_map in_specs mirror the exact key-set, so staging
@@ -1195,7 +1214,7 @@ class JITDatapath(DatapathBackend):
                 nbytes = int(wire.nbytes)
         try:
             with tracer.span(trace_id, "datapath.transfer", bytes=nbytes,
-                             shards=self.n_flow_shards):
+                             shards=n):
                 FAULTS.fire("datapath.transfer")
                 FAULTS.fire("ct.insert")
                 self._fire_device_fault()
@@ -1208,29 +1227,21 @@ class JITDatapath(DatapathBackend):
                     dev_batch = jax.device_put(wire, self._batch_sharding)
                 with self._ct_lock:
                     self._check_placed(placed)
-                    out, new_ct, counters = self._classify(
+                    slab, new_ct = self._classify(
                         dict(placed), self._ct, dev_batch, np.uint32(now),
                         np.int32(snap.world_index))
                     self._ct = new_ct
+                # outside the CT lock (the slab is not donated): every
+                # chip's segment starts its way back behind the step
+                slab.words.copy_to_host_async()
         except BaseException as e:
             self._wire_buf_shed(wire_key)    # finalize will never run
             self._maybe_device_lost(e)       # dead-chip signature? reclassify
             raise
 
         def finalize():
-            try:
-                with tracer.span(trace_id, "datapath.compute",
-                                 fused=int(self._fused)):
-                    out_np, counters_np = self._read_columns(
-                        tracer, trace_id, out, counters)
-            except BaseException as e:
-                self._wire_buf_shed(wire_key)  # failed materialization
-                self._maybe_device_lost(e)
-                raise
-            if wire_key is not None:
-                self._wire_buf_release(wire_key, wire_buf)
-            with self._pack_lock:
-                self.pack_stats["readback_columns"] += 1
+            out_np, counters_np = self._read_slab(
+                tracer, trace_id, slab, n, wire_key, wire_buf)
             if scatter is not None:
                 out_np = unsteer_outputs(out_np, scatter)
             return out_np, counters_np
@@ -1306,29 +1317,21 @@ class JITDatapath(DatapathBackend):
                     dev_batch = jax.device_put(wire, self._batch_sharding)
                 with self._ct_lock:
                     self._check_placed(placed)
-                    out, new_ct, counters = self._classify(
+                    slab, new_ct = self._classify(
                         dict(placed), self._ct, dev_batch, np.uint32(now),
                         np.int32(snap.world_index))
                     self._ct = new_ct
+                # outside the CT lock (the slab is not donated): every
+                # chip's segment starts its way back behind the step
+                slab.words.copy_to_host_async()
         except BaseException as e:
             self._wire_buf_shed(wire_key)    # finalize will never run
             self._maybe_device_lost(e)       # dead-chip signature? reclassify
             raise
 
         def finalize():
-            try:
-                with tracer.span(trace_id, "datapath.compute",
-                                 fused=int(self._fused)):
-                    out_np, counters_np = self._read_columns(
-                        tracer, trace_id, out, counters)
-            except BaseException as e:
-                self._wire_buf_shed(wire_key)  # failed materialization
-                self._maybe_device_lost(e)
-                raise
-            if wire_key is not None:
-                self._wire_buf_release(wire_key, wire_buf)
-            with self._pack_lock:
-                self.pack_stats["readback_columns"] += 1
+            out_np, counters_np = self._read_slab(
+                tracer, trace_id, slab, n, wire_key, wire_buf)
             if orig_rows != rows:
                 # padded control-plane batch: outputs are already FIFO —
                 # dropping the invalid tail is the whole "un-steer"
@@ -1650,7 +1653,8 @@ class JITDatapath(DatapathBackend):
                             v4_only=self.config.v4_only,
                             donate_ct=self.config.donate_ct,
                             fused=self._fused,
-                            fused_interpret=self._fused_interpret))
+                            fused_interpret=self._fused_interpret,
+                            slab=True))
                 self._mesh_cache[key] = cached
             (self._mesh, self._ct_sharding, self._repl_sharding,
              self._batch_sharding, self._verdict_sharding,

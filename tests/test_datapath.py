@@ -346,7 +346,8 @@ class _StepRecorder:
 class _CountingWords:
     """Stands in for the slab's device vector: counts the read-back being
     started and the materializations (``np.asarray`` lands in
-    ``__array__``), optionally failing them."""
+    ``__array__``), optionally failing them (``fail``: True, or the
+    error's text)."""
 
     def __init__(self, words, fail=False):
         self.words, self.fail = words, fail
@@ -359,15 +360,46 @@ class _CountingWords:
     def __array__(self, dtype=None, copy=None):
         self.materialized += 1
         if self.fail:
-            raise RuntimeError("device error (test)")
+            raise RuntimeError("device error (test)" if self.fail is True
+                               else self.fail)
         return np.asarray(self.words)
 
 
-def slab_engine(ct_capacity=2048):
+def count_slabs(dp, fail=False):
+    """Put a ``_CountingWords`` in place of the words of every slab that
+    ``dp``'s step hands back; returns (the step's recorder, the stand-ins
+    so far, newest last)."""
+    import dataclasses
+    slabs = []
+
+    def wrap(res):
+        slab, new_ct = res
+        slabs.append(_CountingWords(slab.words, fail=fail))
+        return dataclasses.replace(slab, words=slabs[-1]), new_ct
+
+    dp._classify = _StepRecorder(dp._classify, wrap)
+    return dp._classify, slabs
+
+
+def same_columns(got, ref):
+    """``got`` (numpy, off the slab) is ``ref`` (the column form's arrays)
+    key for key, dtype for dtype, shape for shape, bit for bit."""
+    assert list(got) == list(ref)
+    for k in ref:
+        want = np.asarray(ref[k])
+        assert type(got[k]) is np.ndarray, k
+        assert got[k].dtype == want.dtype, k
+        assert got[k].shape == want.shape, k
+        assert got[k].tobytes() == want.tobytes(), k
+
+
+def slab_engine(ct_capacity=2048, **mesh):
     # donate_ct off: the CT state a step was handed stays readable, so the
-    # per-column step can be run again on exactly the same inputs
+    # per-column step can be run again on exactly the same inputs.
+    # ``mesh``: n_shards / rule_shards / rss_mode / zero_copy_ingest for
+    # the meshed cases (tests/test_mesh_slab.py)
     cfg = DaemonConfig(ct_capacity=ct_capacity, auto_regen=False,
-                       device="cpu", batch_size=32, donate_ct=False)
+                       device="cpu", batch_size=32, donate_ct=False, **mesh)
     eng = Engine(cfg, datapath=JITDatapath(cfg))
     eng.add_endpoint(["k8s:app=web"], ips=("192.168.1.10",), ep_id=1)
     eng.add_endpoint(["k8s:role=fe"], ips=("192.168.1.30",), ep_id=3)
@@ -442,14 +474,8 @@ class TestVerdictSlab:
             assert wire_arr.shape == (32, words)
             ref_out, _ct, ref_counters = columns(
                 tensors, ct, dev_batch, now_arg, wi)
-            for got, ref in ((out, ref_out), (counters, ref_counters)):
-                assert list(got) == list(ref)
-                for k in ref:
-                    want = np.asarray(ref[k])
-                    assert type(got[k]) is np.ndarray, k
-                    assert got[k].dtype == want.dtype, k
-                    assert got[k].shape == want.shape, k
-                    assert got[k].tobytes() == want.tobytes(), k
+            same_columns(out, ref_out)
+            same_columns(counters, ref_counters)
             np.testing.assert_array_equal(out["ct_state_pre"], out["status"])
             assert out["nat_dst"].shape == (32, 4)
         # the batch did what its case is there for
@@ -469,7 +495,7 @@ class TestVerdictSlab:
             assert out["redirect"].any() or (
                 out["reason"] == C.DropReason.POLICY_L7).any()
         assert dp.pack_stats["readback_slab"] == 2
-        assert dp.pack_stats["readback_columns"] == 0
+        assert "readback_columns" not in dp.pack_stats
         eng.stop()
 
     def test_pack_out_roundtrip_extremes(self):
@@ -538,17 +564,9 @@ class TestVerdictSlab:
         each started at dispatch and materialized once in finalize, no
         per-column path; ``now``/``world_index`` go up as numpy scalars
         and a new ``now`` is not a new program."""
-        import dataclasses
         eng = slab_engine()
         dp = eng.datapath
-        slabs = []
-
-        def wrap(res):
-            slab, new_ct = res
-            slabs.append(_CountingWords(slab.words))
-            return dataclasses.replace(slab, words=slabs[-1]), new_ct
-
-        rec = dp._classify = _StepRecorder(dp._classify, wrap)
+        rec, slabs = count_slabs(dp)
         act = eng.active
         n_batches, sizes = 5, []
         for i in range(n_batches):
@@ -567,7 +585,7 @@ class TestVerdictSlab:
             assert type(wi) is np.int32
             assert int(wi) == act.snapshot.world_index
         assert dp.pack_stats["readback_slab"] == n_batches
-        assert dp.pack_stats["readback_columns"] == 0
+        assert "readback_columns" not in dp.pack_stats
         assert dp._wire_out == 0
         assert len(dp._wire_pool[(32, 4)]) == 1   # released, and reused
         eng.stop()
@@ -576,16 +594,9 @@ class TestVerdictSlab:
         """The fault path keeps its contract: a slab that fails to
         materialize sheds the wire buffer — the in-flight count comes
         down, the buffer never returns to the pool."""
-        import dataclasses
         eng = slab_engine()
         dp = eng.datapath
-
-        def wrap(res):
-            slab, new_ct = res
-            return dataclasses.replace(
-                slab, words=_CountingWords(slab.words, fail=True)), new_ct
-
-        dp._classify = _StepRecorder(dp._classify, wrap)
+        count_slabs(dp, fail=True)
         act = eng.active
         fin = dp.classify_async(act.tensors, act.snapshot,
                                 slab_batch(eng, "v4", "valid", 42000), 3000)
@@ -595,4 +606,134 @@ class TestVerdictSlab:
         assert dp._wire_out == 0
         assert not dp._wire_pool.get((32, 4))
         assert dp.pack_stats["readback_slab"] == 0
+        eng.stop()
+
+    # -- the same on a mesh (ISSUE 30): one sharded slab a batch ----------
+    @pytest.mark.parametrize("rss", ["device", "host"])
+    def test_mesh_one_slab_a_batch(self, rss):
+        """N batches through a 4-wide mesh, either RSS mode: N slab
+        read-backs, each started at dispatch and materialized once in
+        finalize, under one ``datapath.readback`` span inside the batch's
+        ``datapath.compute``; the wire buffer comes back to its pool."""
+        import time
+        t0 = time.monotonic()       # the tracer is the process's
+        eng = slab_engine(n_shards=4, rss_mode=rss, trace_sample_rate=1.0)
+        dp = eng.datapath
+        rec, slabs = count_slabs(dp)
+        n_batches = 3
+        for i in range(n_batches):
+            out = eng.classify(slab_batch(eng, "v4", "valid", 43000),
+                               now=4000 + i)
+            assert (slabs[-1].started, slabs[-1].materialized) == (1, 1)
+            assert out["allow"].shape == (32,) and out["allow"].any()
+        for _t, _ct, _b, now, wi in rec.calls:
+            assert type(now) is np.uint32 and type(wi) is np.int32
+        assert dp.pack_stats["readback_slab"] == n_batches
+        assert dp._wire_out == 0
+        spans = [s for s in eng.tracer.spans(limit=1 << 12)
+                 if s["start_mono"] >= t0]
+        back = [s for s in spans if s["name"] == "datapath.readback"]
+        assert len(back) == n_batches
+        for s in back:
+            assert s["attrs"] == {"arrays": 1, "shards": 4}
+            assert any(o["name"] == "datapath.compute"
+                       and o["trace_id"] == s["trace_id"]
+                       and o["start_mono"] <= s["start_mono"]
+                       and s["duration_ms"] <= o["duration_ms"]
+                       for o in spans)
+        if rss == "device":
+            # arrival order ships as it is: pooled, released, reused
+            assert dp.pack_stats["pack_inplace"] == n_batches
+            assert len(dp._wire_pool[(32, 4)]) == 1
+        else:
+            # the synchronous entry steers with the allocating regroup
+            assert dp.pack_stats["pack_fallback_steered"] == n_batches
+        eng.stop()
+
+    @pytest.mark.parametrize("error,lost", [
+        ("device error (test)", None),
+        ("DEVICE_UNAVAILABLE: dev=2 (test)", 2)])
+    @pytest.mark.parametrize("rss", ["device", "host"])
+    def test_mesh_failed_materialization_sheds_and_is_triaged(
+            self, rss, error, lost):
+        """The meshed fault path keeps its contract under the slab: a
+        failed materialization sheds the wire buffer (in-flight count
+        down, buffer never re-pooled) and goes through
+        ``_maybe_device_lost``: a transient error is raised as it is, a
+        dead chip's signature latches the ordinal and raises DeviceLost."""
+        from cilium_tpu.parallel.mesh import steer_batch
+        from cilium_tpu.pipeline.guard import DeviceLost
+        eng = slab_engine(n_shards=4, rss_mode=rss)
+        dp = eng.datapath
+        count_slabs(dp, fail=error)
+        triaged = []
+        triage = dp._maybe_device_lost
+        dp._maybe_device_lost = lambda e: triaged.append(e) or triage(e)
+        act = eng.active
+        b = slab_batch(eng, "v4", "valid", 44000)
+        if rss == "host":       # as the staging ring delivers it
+            b, _scatter, _per = steer_batch(b, 4, per_shard=16)
+        key = (int(b["valid"].shape[0]), 4)
+        fin = dp.classify_async(act.tensors, act.snapshot, b, 5000,
+                                pre_steered=True)
+        assert dp._wire_out == 1
+        with pytest.raises(DeviceLost if lost is not None
+                           else RuntimeError) as raised:
+            fin()
+        assert len(triaged) == 1 and error in str(triaged[0])
+        if lost is not None:
+            assert raised.value.device == lost
+            assert dp.device_health[lost]["state"] == "dead"
+        else:
+            assert not isinstance(raised.value, DeviceLost)
+            assert not dp.device_health
+        assert dp._wire_out == 0
+        assert not dp._wire_pool.get(key)
+        assert dp.pack_stats["readback_slab"] == 0
+        eng.stop()
+
+    def test_mesh_unpacks_with_the_dispatch_time_shard_count(self):
+        """A remesh between a batch's dispatch and its finalize: the slab
+        is the OLD mesh's (four segments) and is unpacked as such, not
+        with the width serving now; the survivors' step is built in the
+        slab form too, and reads two segments."""
+        import time
+        t0 = time.monotonic()       # the tracer is the process's
+        eng = slab_engine(n_shards=4, rss_mode="device",
+                          trace_sample_rate=1.0)
+        dp = eng.datapath
+        b = slab_batch(eng, "v4", "valid", 45000)
+        want = eng.classify(dict(b), now=6000)      # opens the flows
+        act = eng.active
+        trace_id = eng.tracer.maybe_sample()
+        with eng.tracer.context(trace_id):
+            fin = dp.classify_async(act.tensors, act.snapshot, b, 6001)
+            res = eng._remesh_to([0, 1], reason="test")
+            assert (res["from"], res["to"]) == (4, 2)
+            assert dp.n_flow_shards == 2
+            out, counters = fin()
+        # the flows the first batch opened are established, their reply
+        # rows pass now, the denied stay denied: 32 rows in arrival order
+        assert out["allow"].shape == (32,)
+        denied = np.arange(32) % 7 == 3
+        assert not out["allow"][denied].any()
+        assert out["allow"][want["allow"]].all()
+        assert (out["status"][want["allow"]] == C.CTStatus.ESTABLISHED).all()
+        replies = out["allow"] & ~want["allow"]
+        assert replies.any()
+        assert (out["status"][replies] == C.CTStatus.REPLY).all()
+        assert int(counters["by_reason_dir"].sum()) == int(b["valid"].sum())
+        def back():
+            return [s["attrs"] for s in eng.tracer.spans(limit=1 << 12)
+                    if s["name"] == "datapath.readback"
+                    and s["start_mono"] >= t0]
+
+        assert back() == [{"arrays": 1, "shards": 4}] * 2
+        # the survivor mesh serves in the slab form as well
+        again = eng.classify(dict(b), now=6002)
+        assert again["allow"].shape == (32,)
+        assert again["allow"][want["allow"]].all()
+        assert not again["allow"][denied].any()
+        assert back()[2:] == [{"arrays": 1, "shards": 2}]
+        assert dp.pack_stats["readback_slab"] == 3
         eng.stop()

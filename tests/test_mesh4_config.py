@@ -13,7 +13,8 @@ documents spell out, built with numpy from the rule parameters).
     whole mesh, on the shard ``flow_shard_of`` names, and the shards' live
     counts sum to the reference's admitted flows;
 (c) the exchange's cumulative counters add ``exchange_bytes(rows, 4)`` a
-    batch, and each batch leaves one ``datapath.readback`` span;
+    batch, and each batch leaves one ``datapath.readback`` span (its one
+    sharded verdict slab, ``readback_slab``);
 (d) the configuration file is ``ct1m-50k``'s deployment but for the mesh,
     and the benchmark's byte function is the program's.
 """
@@ -192,7 +193,8 @@ def test_exchange_counters_and_one_readback_span_a_batch(mesh, seed, rows):
     back = [s for s in spans if s["name"] == "datapath.readback"]
     assert len(back) == 3
     for s in back:
-        assert s["attrs"] == {"arrays": 18, "shards": N_SHARDS}
+        # one sharded slab a batch, not a read per column
+        assert s["attrs"] == {"arrays": 1, "shards": N_SHARDS}
         # inside its batch's `datapath.compute`
         assert any(o["name"] == "datapath.compute"
                    and o["trace_id"] == s["trace_id"]
@@ -205,8 +207,11 @@ def test_exchange_counters_and_one_readback_span_a_batch(mesh, seed, rows):
     assert f"ciliumtpu_rss_exchange_batches_total " \
         f"{now['exchange_batches_total']}" in text
     ps = mesh.eng.datapath.pack_stats
-    assert ps["readback_columns"] == now["exchange_batches_total"]
-    assert ps["pack_fallback_steered"] == 0 and ps["readback_slab"] == 0
+    assert ps["readback_slab"] == now["exchange_batches_total"]
+    assert ps["pack_fallback_steered"] == 0
+    assert "readback_columns" not in ps
+    assert "ciliumtpu_datapath_readback_slab_total " \
+        f"{ps['readback_slab']}" in text
 
 
 # -- (d) the configuration file and the byte function -------------------------
