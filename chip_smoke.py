@@ -63,8 +63,9 @@ TWIN_STRIDE = 8                 # the oracle twin answers every 8th parity row
 
 @dataclasses.dataclass(frozen=True)
 class World:
-    """The deployment: BASELINE config 5, ``full`` preset (the documents
-    bench.py's ``_config5_world`` generates). Defaults are the real size;
+    """The deployment: BASELINE config 5 at its published size (the
+    documents ``benchmarks/worlds/`` builds for ``ct1m-50k``, in a copy of
+    this file's own: ROADMAP D12). Defaults are the real size;
     tests/test_chip_smoke.py shrinks it for the CPU."""
     n_ids: int = 2000
     n_rules: int = 50_000

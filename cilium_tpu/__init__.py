@@ -22,7 +22,7 @@ re-designed TPU-first:
                   sharding, rule-space row sharding (analog of per-CPU maps / RSS).
 - ``shim/``     — C++ AF_XDP front end + ctypes bindings (analog of ``bpf_xdp.c``
                   XDP hook, rebuilt as a userspace shim feeding the TPU).
-- ``cli/``      — inspect/trace/bench commands (analog of ``cilium-dbg``).
+- ``cli/``      — inspect/trace commands (analog of ``cilium-dbg``).
 """
 
 __version__ = "0.1.0"

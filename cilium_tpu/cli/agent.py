@@ -76,6 +76,18 @@ def cmd_agent_run(args) -> int:
         else:
             log.warning("checkpoint at %s unusable; cold start", state_dir)
     engine.regenerate(force=True)
+    # the handlers go in before the API socket answers: a client that sees
+    # healthz 200 may send SIGTERM at once, and the default action kills
+    # the agent without its final checkpoint
+    stop = threading.Event()
+
+    def _on_signal(signum, _frame):
+        log.info("signal %d: shutting down", signum)
+        stop.set()
+
+    signal.signal(signal.SIGTERM, _on_signal)
+    signal.signal(signal.SIGINT, _on_signal)
+
     engine.start_background()
     if config.api_socket:
         log.info("api listening on %s", config.api_socket)
@@ -87,15 +99,6 @@ def cmd_agent_run(args) -> int:
     if state_dir and args.checkpoint_interval_s > 0:
         engine.controllers.update("checkpoint", _checkpoint,
                                   interval=args.checkpoint_interval_s)
-
-    stop = threading.Event()
-
-    def _on_signal(signum, _frame):
-        log.info("signal %d: shutting down", signum)
-        stop.set()
-
-    signal.signal(signal.SIGTERM, _on_signal)
-    signal.signal(signal.SIGINT, _on_signal)
 
     log.info("agent up (revision %d, restored=%s, enforcement=%s)",
              engine.repo.revision, restored, engine.ctx.enforcement_mode)

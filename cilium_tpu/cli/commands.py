@@ -240,8 +240,7 @@ def register(sub: "argparse._SubParsersAction") -> None:
                    help="skip the LB axis (faster pre-merge check)")
     p.add_argument("--report", metavar="FILE",
                    help="write the sweep + HBM budget summary as JSON "
-                        "(embed into bench artifacts via --hbm-report so "
-                        "offline verification and the live ledger cite "
+                        "(offline verification and the live ledger cite "
                         "the same numbers)")
     p.set_defaults(func=_cmd_verify)
 

@@ -64,7 +64,7 @@ class PolicySnapshot:
         LB tensors are included only when a frontend exists: the classify
         kernel gates the whole LB stage (frontend hash probe + Maglev +
         rev-NAT gathers) on key presence, so a service-free snapshot pays
-        zero per-packet LB cost (round-2 bench regression: cfg5 carried the
+        zero per-packet LB cost (a round-2 regression: cfg5 carried the
         full LB stage with zero services).
 
         ``only`` restricts the dict to the named tensors. This matters on

@@ -109,7 +109,7 @@ _memory_stats = memory_stats           # pre-ISSUE-13 private name
 def budget_doc(reports: List[ComboReport],
                max_hbm_bytes: Optional[int] = None) -> Dict:
     """Summarize one verify sweep into the HBM budget report that
-    ``status_doc`` and bench-artifact provenance embed (ISSUE 13 satellite:
+    ``status_doc`` embeds (ISSUE 13 satellite:
     offline ``--max-hbm-bytes`` verification and the live ledger citing
     the same numbers). Pure function of the reports — reusable on a sweep
     loaded back from a ``cilium-tpu verify --report`` file."""

@@ -502,7 +502,7 @@ def make_classify_fn(probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
     (kernels/records.pack_out_jnp), so the host reads one batch's results
     back in one transfer; ``records.unpack_out(np.asarray(s.words),
     s.layout)`` is ``(out, counters)`` again, bit for bit. The one-chip
-    serving path's return form; the column form stays for tests/bench."""
+    serving path's return form; the column form stays for tests."""
     key = (probe_depth, v4_only, donate_ct, packed, lb_probe_depth,
            fused, fused_interpret, slab)
     with _FN_LOCK:

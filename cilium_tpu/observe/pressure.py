@@ -21,8 +21,8 @@ silently, wire pools and patch budgets reporting nothing. The ledger makes
   the per-subsystem gauge zoo for capacity questions. Pressure is
   occupancy/capacity unless the provider supplies the canonical fraction
   itself — the CT provider hands through the ``ct_occupancy`` gauge
-  verbatim, so the two surfaces can never disagree (the cfg6 bench gates
-  on exact equality).
+  verbatim, so the two surfaces can never disagree (tests/test_pressure.py
+  asserts exact equality).
 - **Time-to-exhaustion.** Per resource, a bounded window of (t, occupancy)
   samples yields a growth rate; ``eta_s = (capacity - occupancy) / rate``
   while the resource is growing. An ETA under ``eta_warn_s`` fires one
@@ -38,8 +38,8 @@ silently, wire pools and patch budgets reporting nothing. The ledger makes
 Consumers: ``Engine.health()`` folds pressured resources in as the
 ``RESOURCE_PRESSURE`` detail, the overload ladder takes ``max_pressure``
 (CT excluded — it is already the ladder's own signal) as its fourth latch,
-``GET /v1/resources`` + ``cilium-tpu top`` render the live table, and the
-cfg6 bench artifact carries per-resource high-water + the HBM ledger.
+``GET /v1/resources`` + ``cilium-tpu top`` render the live table with
+per-resource high-water and the HBM ledger.
 """
 
 from __future__ import annotations
@@ -167,8 +167,9 @@ class ResourceLedger:
     # -- sampling ------------------------------------------------------------
     def poll(self, now: Optional[float] = None) -> Dict:
         """One ledger sweep. ``now`` (seconds, any monotone clock) defaults
-        to ``time.monotonic()``; deterministic drivers (the cfg6 bench, the
-        pressure soak) pass their logical clock so ETA math is replayable.
+        to ``time.monotonic()``; deterministic drivers (the pressure soak
+        of tests/test_pressure.py) pass their logical clock so ETA math is
+        replayable.
         Returns the full report (the ``/v1/resources`` document)."""
         if now is None:
             now = time.monotonic()
@@ -228,8 +229,8 @@ class ResourceLedger:
             st.capacity = capacity
             st.occupancy = occupancy
             # the provider's canonical fraction wins (the CT provider hands
-            # the ct_occupancy gauge through VERBATIM — the bench gates on
-            # the two surfaces never disagreeing); otherwise derive
+            # the ct_occupancy gauge through VERBATIM — tests hold the two
+            # surfaces to never disagreeing); otherwise derive
             st.pressure = explicit_p if explicit_p is not None \
                 else occupancy / capacity if capacity > 0 else 0.0
             st.high_water = max(st.high_water, occupancy)

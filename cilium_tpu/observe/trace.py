@@ -38,27 +38,13 @@ _Span = Tuple[int, str, float, float, Optional[dict]]
 
 DEFAULT_CAPACITY = 4096
 
-#: Per-kernel attribution span names for the classify interior (ROADMAP
-#: item 2). A single jit cannot be split from the host, so the serving
-#: path's ``datapath.compute`` span carries a ``fused`` attr naming the
-#: executor (JITDatapath), while ``bench.py --kernels`` times each stage as
-#: its own jitted program under these span names — the artifact's
-#: per-kernel p50/p99 all flow through this tracer, same as the pipeline
-#: stage split. One name per fused kernel plus the whole interior.
-KERNEL_SPAN_LPM = "datapath.kernel.lpm"
-KERNEL_SPAN_CT_PROBE = "datapath.kernel.ct_probe"
-KERNEL_SPAN_POLICY_L7 = "datapath.kernel.policy_l7"
-KERNEL_SPAN_FULL = "datapath.kernel.full_step"
-KERNEL_SPANS = (KERNEL_SPAN_LPM, KERNEL_SPAN_CT_PROBE,
-                KERNEL_SPAN_POLICY_L7, KERNEL_SPAN_FULL)
-
 #: Live-state fast-path span names (ROADMAP item 3). PATCH_APPLY_SPAN
 #: wraps the device-side scatter-apply of a sparse policy delta
 #: (JITDatapath.place_patch — the "device-apply" half of a live rule
 #: update; the host compile half rides the existing engine.regen.patch
 #: span). CT_GC_SPAN wraps one overlapped chunk-sweep enqueue
-#: (JITDatapath.sweep_step). bench.py --update-storm reads both out of the
-#: tracer summary for the artifact's host/device latency split.
+#: (JITDatapath.sweep_step). Both show in the tracer summary beside the
+#: pipeline's stage spans.
 PATCH_APPLY_SPAN = "datapath.patch.apply"
 CT_GC_SPAN = "datapath.ct.gc"
 
@@ -293,7 +279,7 @@ class Tracer:
 
     def summary(self) -> Dict[str, Dict]:
         """Per-stage aggregate over the spans currently in the ring:
-        count + p50/p99/max/total (ms) — the bench/CLI surface."""
+        count + p50/p99/max/total (ms) — the CLI surface."""
         by_name: Dict[str, List[float]] = {}
         for _tid, nm, _t0, dur, _attrs in self._snapshot():
             by_name.setdefault(nm, []).append(dur)

@@ -347,7 +347,7 @@ def make_sharded_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
     over 'flows'. ``records.unpack_out(np.asarray(s.words), s.layout,
     shards=mesh.shape["flows"])`` is ``(out, counters)`` again, bit for
     bit. The serving path's return form (runtime/datapath.py); the column
-    form stays the default for tests and benches.
+    form stays the default for tests, chip_smoke.py and __graft_entry__.py.
     """
     from cilium_tpu.kernels.classify import classify_step
 

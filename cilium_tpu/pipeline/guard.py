@@ -289,8 +289,8 @@ class OverloadLadder:
     deliberately slow — a storm pausing for one scrape must not whiplash
     the feeder back into full admission.
 
-    Thread-safe; ``status()`` carries per-state dwell times (the cfg6
-    bench's ladder-residency surface) and the last observed inputs."""
+    Thread-safe; ``status()`` carries per-state dwell times (the
+    ladder-residency surface) and the last observed inputs."""
 
     #: bounded transition trail for status()/debug bundles
     MAX_TRANSITIONS = 32

@@ -420,8 +420,8 @@ class Pipeline:
         # rows; ingest steers rows into their segment, flush dispatches the
         # ONE steered shape [n_shards * seg_cap] every time (a single XLA
         # trace per wire format — sharded serving trades padded transfer
-        # bytes for zero recompile storms, exactly like the bench's
-        # uniform per-shard sizing). seg_cap carries `shard_headroom`x the
+        # bytes for zero recompile storms). seg_cap carries
+        # `shard_headroom`x the
         # even-split share so hash skew doesn't force tiny aggregates; a
         # submission more skewed than that is shed ("steer_overflow"),
         # never a worker-killing error.
@@ -450,7 +450,7 @@ class Pipeline:
             self._stage_rows = max_bucket
         self._shard_fill: List[int] = [0] * n_shards
         # lifetime per-shard ingest totals: the steering-balance surface
-        # (bench schema checks + operators read skew from here)
+        # (tests and operators read skew from here)
         self._shard_rows_total: List[int] = [0] * n_shards
         # the policy revision the staged bucket was steered under (-2 =
         # riders steered under different revisions): rides into
@@ -1678,8 +1678,8 @@ class Pipeline:
         pos = self._staged_rows
         with self.tracer.span(t.trace_id, "pipeline.microbatch", rows=m):
             # pipeline.stage_write: just the column writes into the pinned
-            # staging slot — the per-stage attribution point the ingest
-            # bench reads (microbatch additionally covers valid_idx/admin)
+            # staging slot — the per-stage attribution point
+            # (microbatch additionally covers valid_idx/admin)
             with self.tracer.span(t.trace_id, "pipeline.stage_write",
                                   rows=m, slot=self._stage_buf):
                 for k, col in buf.items():

@@ -61,8 +61,8 @@ class TenantQueues:
         self._lane_debt: Dict[int, float] = {}
         self._len = 0
         # lifetime service accounting (rows/batches the DRR actually
-        # handed to the dispatcher) — the bench's share-convergence gate
-        # reads these, so they must reflect pop order, not arrivals
+        # handed to the dispatcher) — tests/test_qos.py judges the DRR's
+        # shares on these, so they must reflect pop order, not arrivals
         self.admitted_rows: Dict[int, int] = {}
         self.admitted_batches: Dict[int, int] = {}
 

@@ -1,6 +1,6 @@
 """Multi-host serving harness: N engine processes over one clustermesh
 store (ISSUE 12 / ROADMAP item 3 — the "millions of users" horizontal
-axis). Used by ``bench.py --cluster N`` and the cluster chaos tests.
+axis). Used by the cluster chaos tests (tests/test_clustermesh.py).
 
 Each node is a real OS process (``multiprocessing`` *spawn* — a fresh
 interpreter per node, so jax state, the FAULTS singleton, identity
@@ -303,8 +303,8 @@ class ClusterSupervisor:
     def expected_remote(self, node: str,
                         exclude: Tuple[str, ...] = ()) -> Dict:
         """What ``node`` should see once converged: the union of every
-        OTHER live node's ledger (conflicting claims excluded — the bench
-        asserts those separately with the deterministic-winner rule)."""
+        OTHER live node's ledger (conflicting claims excluded — they are
+        judged separately, by the deterministic-winner rule)."""
         want: Dict[str, Tuple[str, ...]] = {}
         for peer, entries in self.ledger.items():
             if peer == node or peer in exclude:

@@ -505,7 +505,7 @@ class ClusterMesh:
     def remote_view(self) -> Dict[str, Dict]:
         """The ingested remote world, keyed by prefix — identity numbers
         are node-local, so cross-node convergence is judged on (peer,
-        labels), which this view carries (the bench/tests' convergence
+        labels), which this view carries (the tests' convergence
         probe). Reads GIL-atomic copies, same as :meth:`status`."""
         out: Dict[str, Dict] = {}
         for node, held in dict(self._ingested).items():

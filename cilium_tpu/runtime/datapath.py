@@ -379,8 +379,7 @@ class JITDatapath(DatapathBackend):
             from cilium_tpu.kernels.classify import make_classify_fn
             self._ct = {k: jnp.asarray(v) for k, v in ct_host.items()}
             # production single-chip path is transfer-bound: ship batches in
-            # the packed wire format (one contiguous buffer, not 12 arrays;
-            # round-2 fix that previously only bench.py used)
+            # the packed wire format (one contiguous buffer, not 12 arrays)
             self._classify = make_classify_fn(
                 probe_depth=self.config.probe_depth,
                 v4_only=self.config.v4_only,
@@ -557,8 +556,8 @@ class JITDatapath(DatapathBackend):
         cap = exchange_bytes(self.config.batch_size, self.n_flow_shards)
         with self._hbm_lock:
             # capacity tracks the largest bucket actually dispatched when
-            # a caller runs bigger-than-batch_size buckets (bench A/B
-            # shape-parity runs) — occupancy must never exceed capacity
+            # a caller runs bigger-than-batch_size buckets — occupancy
+            # must never exceed capacity
             return {"capacity": max(cap, self._exchange_peak_bytes),
                     "in_use": self._exchange_last_bytes,
                     "peak": self._exchange_peak_bytes,
@@ -841,8 +840,8 @@ class JITDatapath(DatapathBackend):
                     if scatter_failed:
                         # attribution: the healed patch COUNTS AS FULL —
                         # patch_delta must only ever mean "the donated
-                        # scatter actually ran" (the bench's delta-underuse
-                        # gate reads it as exactly that)
+                        # scatter actually ran" (tests/test_update_storm.py
+                        # reads it as exactly that)
                         self.patch_stats["patch_scatter_errors"] += 1
                         self.patch_stats["patch_full"] += 1
                         v = snap.tensors(only=frozenset(("verdict",)))
@@ -1038,9 +1037,9 @@ class JITDatapath(DatapathBackend):
 
         def finalize():
             # the ``fused`` tag attributes compute time to the executor
-            # that produced it (Pallas megakernels vs the jnp reference) —
-            # the per-kernel split itself lives in bench.py --kernels,
-            # since stages inside one jit are not separately timeable
+            # that produced it (Pallas megakernels vs the jnp reference);
+            # stages inside one jit are not separately timeable from the
+            # host, so there is no per-kernel span
             try:
                 with tracer.span(trace_id, "datapath.compute",
                                  fused=int(self._fused)):

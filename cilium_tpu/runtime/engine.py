@@ -993,7 +993,7 @@ class Engine:
 
     def remesh_step(self) -> Optional[Dict]:
         """One tick of the ``mesh-heal`` controller (directly callable
-        from the cfg10 bench/tests for deterministic driving).
+        from tests and chip_smoke.py for deterministic driving).
 
         DOWN: any latched-dead ordinal still in the serving set triggers
         a fenced re-mesh onto the survivors — the wedged in-flight window
@@ -1298,7 +1298,7 @@ class Engine:
 
     def overload_step(self) -> Optional[Dict]:
         """One tick of the overload-ladder controller (the ``overload``
-        controller body; directly callable from the cfg6 bench/tests for
+        controller body; directly callable from tests for
         deterministic logical-time driving). Folds queue occupancy,
         shed+admission-drop rate, and CT occupancy into the
         pipeline/guard.OverloadLadder state machine and propagates the
@@ -1443,7 +1443,7 @@ class Engine:
     def _res_ct(self) -> Dict:
         # the ct_occupancy gauge IS the canonical fraction: hand it
         # through verbatim so the resource row and the gauge can never
-        # disagree (the cfg6 bench gates on exact equality)
+        # disagree (tests/test_pressure.py asserts exact equality)
         occ = float(self.metrics.gauges.get("ct_occupancy", 0.0))
         cap = self.config.ct_capacity
         return {"ct_table": (cap, occ * cap, occ)}
@@ -1619,7 +1619,7 @@ class Engine:
 
     def resource_step(self, now: Optional[float] = None) -> Dict:
         """One ledger sweep (the ``resource-ledger`` controller body;
-        directly callable from benches/tests with a logical clock for
+        directly callable from tests with a logical clock for
         deterministic ETA math). Exports the labeled resource_* gauge
         families and fires forecast events; returns the full report."""
         FAULTS.fire("resource.poll")
@@ -1649,7 +1649,7 @@ class Engine:
 
     def note_verifier_budget(self, doc: Dict) -> None:
         """Attach an offline ``compile/verifier.budget_doc`` summary so
-        status/bench surfaces cite the same HBM numbers the ``verify
+        status surfaces cite the same HBM numbers the ``verify
         --max-hbm-bytes`` gate judged."""
         self._hbm_budget = doc
 
@@ -1657,8 +1657,8 @@ class Engine:
     def attach_mesh(self, store_dir: Optional[str] = None,
                     node_name: Optional[str] = None):
         """Create (or return) this engine's ClusterMesh WITHOUT starting the
-        sync controller — deterministic drivers (``bench.py --cluster``,
-        tests, chaos drills) tick ``mesh.step()`` themselves;
+        sync controller — deterministic drivers (tests, chaos drills)
+        tick ``mesh.step()`` themselves;
         ``start_background`` wires the controller on top. Arguments default
         to the config's ``cluster_store``/``node_name``."""
         with self._lock:

@@ -134,7 +134,7 @@ POINTS: Dict[str, str] = {
                    "serving mesh is swallowed (a dead chip cannot hurt a "
                    "mesh it is not in) — that is what lets degraded serving "
                    "continue while the fault stays armed, and what makes "
-                   "disarming it the bench's heal signal",
+                   "disarming it a drill's heal signal",
     "device.collective": "the host CT gather inside JITDatapath.remesh "
                          "(the salvage collective): a trip means the "
                          "surviving shards' tables could not be gathered — "

@@ -83,7 +83,7 @@ class Histogram:
         winning bucket). For observations past the last finite boundary the
         boundary itself is returned — a histogram cannot do better. An
         empty histogram reads 0.0 — this is a *display* surface (status
-        docs, bench artifacts), where "no observations yet" rendering as 0
+        docs), where "no observations yet" rendering as 0
         is the established convention; interval/delta consumers must use
         :func:`quantile_from` and test for :data:`EMPTY_QUANTILE`."""
         buckets, counts, _total, count = self.snapshot()

@@ -121,8 +121,8 @@ class EstablishedFingerprints:
 
 
 def shed_new_rows(b: Dict[str, np.ndarray]) -> int:
-    """The SHED-NEW harvest-time shed, shared by the feeder and the cfg6
-    bench's synthetic harvest: invalidate every valid row whose ``_prio``
+    """The SHED-NEW harvest-time shed (the feeder's, and a test's that
+    plays the feeder): invalidate every valid row whose ``_prio``
     class is worse than established — those frames get their drop verdict
     at apply time without EVER being submitted (rx-ring backpressure
     relief), while established-class rows ride on. Returns rows shed."""

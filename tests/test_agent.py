@@ -13,8 +13,18 @@ import pytest
 
 from cilium_tpu.runtime.api import UnixAPIClient
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-def _spawn_agent(tmp_path, extra=()):
+# Every wait of this file. The spawned interpreter imports jax and builds an
+# engine while the other xdist workers load the machine: alone it is up in
+# ~3 s, under 24 busy loops on 8 cores it took over a minute.
+SPAWN_WAIT_S = 240      # start-up, and exit after SIGTERM
+CLIENT_WAIT_S = 60      # one REST call
+
+
+def _spawn_agent(tmp_path, extra=(), healthz=True, poll_s=0.05):
+    """Start an agent and wait for its API socket, polled every ``poll_s``,
+    and (``healthz``) for the API to answer."""
     sock = str(tmp_path / "agent.sock")
     state = str(tmp_path / "state")
     cfg = {"ct_capacity": 1024, "api_socket": sock, "state_dir": state,
@@ -25,16 +35,18 @@ def _spawn_agent(tmp_path, extra=()):
     proc = subprocess.Popen(
         [sys.executable, "-m", "cilium_tpu.cli.main", "agent", "run",
          "--config", str(cfg_path), "--fake-datapath", *extra],
-        cwd="/root/repo", env=env,
+        cwd=REPO_ROOT, env=env,
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-    deadline = time.time() + 60
+    deadline = time.time() + SPAWN_WAIT_S
     while not os.path.exists(sock):
         if proc.poll() is not None:
             raise AssertionError(f"agent died: {proc.stderr.read()}")
         assert time.time() < deadline, "agent never came up"
-        time.sleep(0.05)
+        time.sleep(poll_s)
+    if not healthz:
+        return proc, sock, state
     # the socket file may exist before serve_forever runs; poll healthz
-    client = UnixAPIClient(sock, timeout=5)
+    client = UnixAPIClient(sock, timeout=CLIENT_WAIT_S)
     while True:
         try:
             code, _ = client.get("/v1/healthz")
@@ -51,7 +63,7 @@ class TestAgentProcess:
     def test_serve_policy_shutdown_restore(self, tmp_path):
         proc, sock, state = _spawn_agent(tmp_path)
         try:
-            client = UnixAPIClient(sock, timeout=10)
+            client = UnixAPIClient(sock, timeout=CLIENT_WAIT_S)
             code, _ = client.post("/v1/policy", [{
                 "endpointSelector": {"matchLabels": {"app": "web"}},
                 "ingress": [{"toPorts": [{"ports": [
@@ -61,7 +73,7 @@ class TestAgentProcess:
             assert st["rules"] == 1
         finally:
             proc.send_signal(signal.SIGTERM)
-            assert proc.wait(timeout=60) == 0, proc.stderr.read()
+            assert proc.wait(timeout=SPAWN_WAIT_S) == 0, proc.stderr.read()
         # clean shutdown: socket removed, checkpoint written
         assert not os.path.exists(sock)
         assert os.path.exists(os.path.join(state, "state.json"))
@@ -69,11 +81,25 @@ class TestAgentProcess:
         # restart restores the applied policy (upgrade-survival analog)
         proc2, sock2, _ = _spawn_agent(tmp_path)
         try:
-            code, st = UnixAPIClient(sock2, timeout=10).get("/v1/status")
+            code, st = UnixAPIClient(
+                sock2, timeout=CLIENT_WAIT_S).get("/v1/status")
             assert code == 200 and st["rules"] == 1, st
         finally:
             proc2.send_signal(signal.SIGTERM)
-            assert proc2.wait(timeout=60) == 0
+            assert proc2.wait(timeout=SPAWN_WAIT_S) == 0, proc2.stderr.read()
+
+    def test_sigterm_as_soon_as_the_socket_exists_still_checkpoints(
+            self, tmp_path):
+        """The agent takes SIGTERM gracefully from the moment a client can
+        see it: the handlers are installed before the API socket is bound,
+        so a signal that races start-up still ends in the final checkpoint
+        and exit 0, not in the default action's kill."""
+        proc, sock, state = _spawn_agent(tmp_path, healthz=False,
+                                         poll_s=0.001)
+        proc.send_signal(signal.SIGTERM)
+        assert proc.wait(timeout=SPAWN_WAIT_S) == 0, proc.stderr.read()
+        assert not os.path.exists(sock)
+        assert os.path.exists(os.path.join(state, "state.json"))
 
     def test_oneshot(self, tmp_path):
         sock = str(tmp_path / "a.sock")
@@ -83,8 +109,8 @@ class TestAgentProcess:
             [sys.executable, "-m", "cilium_tpu.cli.main", "agent", "run",
              "--api-socket", sock, "--state-dir", state,
              "--fake-datapath", "--oneshot"],
-            cwd="/root/repo", env=env, capture_output=True, text=True,
-            timeout=120)
+            cwd=REPO_ROOT, env=env, capture_output=True, text=True,
+            timeout=SPAWN_WAIT_S)
         assert out.returncode == 0, out.stderr
         assert os.path.exists(os.path.join(state, "state.json"))
         assert not os.path.exists(sock)

@@ -18,6 +18,8 @@ from cilium_tpu.utils import constants as C
 from cilium_tpu.utils.ip import parse_addr
 from oracle import PacketRecord
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture
 def live_engine(tmp_path):
@@ -146,7 +148,7 @@ class TestCLILive:
     def _run(self, argv):
         return subprocess.run(
             [sys.executable, "-m", "cilium_tpu.cli.main"] + argv,
-            capture_output=True, text=True, timeout=60, cwd="/root/repo",
+            capture_output=True, text=True, timeout=60, cwd=REPO_ROOT,
             env={**os.environ, "JAX_PLATFORMS": "cpu"})
 
     def test_cli_live_commands(self, live_engine):

@@ -8,7 +8,7 @@ fault drill (detection + health degradation + frozen bundle with the
 offending rows and revision), and fault tolerance (a wedged/crashing
 auditor never stalls serving). Integration tests run it against engines on
 both backends, including a sharded 8-shard pipeline; the ``slow``-marked
-soak (``make audit-smoke``) pushes 10k submissions with the auditor armed
+soak (`make chaos`) pushes 10k submissions with the auditor armed
 at sampling 1.0 and asserts zero mismatches, then arms ``audit.corrupt``
 and asserts the corruption is detected within the sampling window.
 
@@ -739,58 +739,7 @@ class TestDebugBundleSurfaces:
 
 
 # --------------------------------------------------------------------------- #
-# bench artifact provenance + compare gate
-# --------------------------------------------------------------------------- #
-class TestBenchCompare:
-    def test_provenance_fields(self):
-        import bench
-        p = bench._provenance(argv=["--ingest"])
-        assert set(p) >= {"git_rev", "jax_version", "config_hash",
-                          "generated_at"}
-        assert len(p["config_hash"]) == 12
-        # deterministic for identical config surface
-        assert p["config_hash"] == bench._provenance(
-            argv=["--ingest"])["config_hash"]
-        assert p["config_hash"] != bench._provenance(
-            argv=["--pipeline"])["config_hash"]
-
-    def test_compare_passes_within_noise(self, tmp_path):
-        import bench
-        old = {"value": 100000.0, "e2e_p99_ms": 20.0,
-               "stage_split": {"datapath.pack": {"p50_ms": 0.1}},
-               "provenance": {"git_rev": "abc123"}}
-        p = tmp_path / "old.json"
-        p.write_text(json.dumps(old))
-        new = {"value": 90000.0, "e2e_p99_ms": 25.0,
-               "stage_split": {"datapath.pack": {"p50_ms": 0.12}}}
-        cmp_ = bench._compare_artifacts(new, str(p), factor=1.75)
-        assert not cmp_["failed"]
-        assert cmp_["baseline_rev"] == "abc123"
-        assert cmp_["checked"]["value"]["ratio"] == 0.9
-
-    def test_compare_fails_on_regression(self, tmp_path):
-        import bench
-        old = {"value": 100000.0, "e2e_p99_ms": 20.0}
-        p = tmp_path / "old.json"
-        p.write_text(json.dumps(old))
-        slow = {"value": 40000.0, "e2e_p99_ms": 21.0}
-        cmp_ = bench._compare_artifacts(slow, str(p), factor=1.75)
-        assert cmp_["failed"] and "value" in cmp_["regressions"][0]
-        lat = {"value": 99000.0, "e2e_p99_ms": 60.0}
-        cmp_ = bench._compare_artifacts(lat, str(p), factor=1.75)
-        assert cmp_["failed"] and "e2e_p99_ms" in cmp_["regressions"][0]
-
-    def test_compare_env_override(self, tmp_path, monkeypatch):
-        import bench
-        old = {"value": 100000.0}
-        p = tmp_path / "old.json"
-        p.write_text(json.dumps(old))
-        assert bench._compare_artifacts(
-            {"value": 40000.0}, str(p), factor=3.0)["failed"] is False
-
-
-# --------------------------------------------------------------------------- #
-# slow: the audit-smoke soak (make audit-smoke)
+# slow: the audited soak (make chaos)
 # --------------------------------------------------------------------------- #
 @pytest.mark.slow
 class TestAuditSoak:

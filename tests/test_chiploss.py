@@ -1,8 +1,8 @@
 """Mesh self-healing tests (ISSUE 19): device-loss detection, the fenced
 re-mesh onto survivors, CT salvage (device gather → archive floor → cold)
 with the bounded established-fingerprint grace window, and hysteretic
-re-admission — the tier-1 subset behind ``make chiploss-smoke`` (the
-full-scale acceptance rides ``bench.py --chiploss``, cfg10).
+re-admission (the whole loss → degraded → heal cycles are the ``slow``
+cases, run by ``make chaos``).
 
 Layers covered here:
 
@@ -172,14 +172,26 @@ class TestCTArchive:
 # the engine protocol: loss -> fenced shrink -> degraded -> heal
 # --------------------------------------------------------------------------- #
 class TestEngineRemesh:
-    @pytest.mark.slow
-    def test_loss_remesh_degraded_then_heal(self):
-        eng = jit_pipeline_engine(4, remesh_heal_hysteresis_s=0.0)
+    @pytest.mark.parametrize("n", [2, pytest.param(4, marks=pytest.mark.slow)])
+    def test_loss_remesh_degraded_then_heal(self, n):
+        """A device dies under traffic: exactly one re-mesh onto the
+        survivors, the flows established before the loss keep their reply
+        verdicts through it (salvaged CT, or the grace window for the lost
+        shard's), exactly one re-mesh back at heal, full width again."""
+        eng = jit_pipeline_engine(n, remesh_heal_hysteresis_s=0.0)
         slot_of = eng.active.snapshot.ep_slot_of
+
+        def replies_allowed():
+            t = eng.submit(_replies(slot_of, 32, 0))
+            assert eng.drain(timeout=30)
+            return int(np.asarray(t.result(5)["allow"]).sum())
+
         try:
             t = eng.submit(_mk(slot_of, 32, 0))
             assert eng.drain(timeout=30)
             assert int(np.asarray(t.result(5)["allow"]).sum()) == 32
+            # replies ride CT and stamp the established-fingerprint filter
+            assert replies_allowed() == 32
             rev0 = eng.active.revision
 
             FAULTS.arm("device.fail", mode="fail", message="dev=1")
@@ -193,8 +205,8 @@ class TestEngineRemesh:
             queued = eng.submit(_mk(slot_of, 8, 2000))
 
             doc = eng.remesh_step()
-            assert doc["remesh"]["from"] == 4
-            assert doc["remesh"]["to"] == 3
+            assert doc["remesh"]["from"] == n
+            assert doc["remesh"]["to"] == n - 1
             assert doc["remesh"]["reason"] == "device-loss"
             assert eng.drain(timeout=30)
             # the wedged in-flight window is rejected attributably...
@@ -213,33 +225,40 @@ class TestEngineRemesh:
             assert h["devices"]["detail"] == C.DEVICE_LOST
             assert h["devices"]["dead"] == [1]
             width = eng._res_datapath()["mesh_width"]
-            assert width[0] == 4 and width[1] == 3
-            assert width[2] == pytest.approx(0.25)
+            assert width[0] == n and width[1] == n - 1
+            assert width[2] == pytest.approx(1 / n)
             mh = eng.datapath.mesh_health()
-            assert mh["live_ordinals"] == [0, 2, 3]
+            assert mh["live_ordinals"] == [0, 2, 3][:n - 1]
             assert mh["devices"][1]["state"] == "dead"
             # degraded serving with the fault STILL armed (the dead
             # chip cannot hurt a mesh it is no longer part of)
             t2 = eng.submit(_mk(slot_of, 16, 3000))
             assert eng.drain(timeout=30)
             assert int(np.asarray(t2.result(5)["allow"]).sum()) == 16
+            # established before the loss, still answered after it: the
+            # survivors' by salvaged CT, the lost shard's by the grace flip
+            assert replies_allowed() == 32
+            assert eng.metrics.counters.get(
+                "ct_salvage_grace_hits_total", 0) > 0
 
             # heal: disarm = the probe canary passes; hysteresis 0
             FAULTS.disarm("device.fail")
             doc = eng.remesh_step()
-            assert doc["remesh"]["from"] == 3
-            assert doc["remesh"]["to"] == 4
+            assert doc["remesh"]["from"] == n - 1
+            assert doc["remesh"]["to"] == n
             assert doc["remesh"]["reason"] == "heal"
             assert eng.drain(timeout=30)
             t3 = eng.submit(_mk(slot_of, 16, 4000))
             assert eng.drain(timeout=30)
             assert int(np.asarray(t3.result(5)["allow"]).sum()) == 16
+            assert replies_allowed() == 32
             assert eng.health()["state"] == C.HEALTH_OK
+            assert eng.datapath.mesh_health()["live"] == n
 
             ctr = eng.metrics.counters
             assert ctr['device_loss_total{device="1"}'] == 1
-            assert ctr['datapath_remesh_total{from="4",to="3"}'] == 1
-            assert ctr['datapath_remesh_total{from="3",to="4"}'] == 1
+            assert ctr[f'datapath_remesh_total{{from="{n}",to="{n - 1}"}}'] == 1
+            assert ctr[f'datapath_remesh_total{{from="{n - 1}",to="{n}"}}'] == 1
             assert ctr["pipeline_remesh_total"] == 2
             # each re-meshed generation restarted canary-first, and the
             # canary never leaked into submission accounting

@@ -626,7 +626,7 @@ class TestLagMetrics:
 @pytest.mark.slow
 class TestClusterSoak:
     """Satellite (CI wiring): the 2-proc partition/heal soak `make
-    cluster-smoke` runs — real spawned engine processes over one store,
+    chaos` runs — real spawned engine processes over one store,
     with `clustermesh.peer_read` and `clustermesh.store_list` faults
     armed through partition phases, gating on convergence-after-heal and
     zero parity mismatches."""

@@ -415,7 +415,7 @@ class TestEmergencyGC:
 
 
 # --------------------------------------------------------------------------- #
-# the slow flood soak (make ddos-smoke)
+# the slow flood soak (`make chaos`)
 # --------------------------------------------------------------------------- #
 @pytest.mark.slow
 class TestFloodSoak:

@@ -3,6 +3,7 @@ tests): the Engine must depend only on DatapathBackend, a fake must slot in
 exactly like pkg/datapath/fake, and control-plane fixtures replayed against
 the fake must produce the same verdicts the jit backend produces."""
 
+import os
 import subprocess
 import sys
 
@@ -16,6 +17,8 @@ from cilium_tpu.runtime.engine import Engine
 from cilium_tpu.utils import constants as C
 from oracle import PacketRecord
 from cilium_tpu.utils.ip import parse_addr
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 FIXTURE_RULES = [
     {
@@ -169,7 +172,7 @@ print("JAXFREE_OK")
 """
         proc = subprocess.run([sys.executable, "-c", code],
                               capture_output=True, text=True, timeout=120,
-                              cwd="/root/repo")
+                              cwd=REPO_ROOT)
         assert proc.returncode == 0, proc.stderr
         assert "JAXFREE_OK" in proc.stdout
 
@@ -641,13 +644,17 @@ class TestVerdictSlab:
                        and o["start_mono"] <= s["start_mono"]
                        and s["duration_ms"] <= o["duration_ms"]
                        for o in spans)
+        names = {s["name"] for s in spans}
         if rss == "device":
-            # arrival order ships as it is: pooled, released, reused
+            # arrival order ships as it is: pooled, released, reused,
+            # and no host steering is left to pay for
             assert dp.pack_stats["pack_inplace"] == n_batches
             assert len(dp._wire_pool[(32, 4)]) == 1
+            assert not names & {"pipeline.steer", "datapath.steer"}
         else:
             # the synchronous entry steers with the allocating regroup
             assert dp.pack_stats["pack_fallback_steered"] == n_batches
+            assert "datapath.steer" in names
         eng.stop()
 
     @pytest.mark.parametrize("error,lost", [

@@ -646,7 +646,25 @@ class TestChaosCLI:
         doc = json.loads(capsys.readouterr().out)
         assert "regen.compile" in doc
 
-    def test_chaos_scenario_fake_datapath(self, capsys):
+    @pytest.fixture
+    def stop_chaos_engines(self, monkeypatch):
+        """`faults chaos` is a one-shot command and leaves the engines it
+        builds to process exit. Here the process goes on: their pipeline
+        workers and watchdogs would run beside every later test of this
+        xdist worker, so the test stops them."""
+        made = []
+        init = Engine.__init__
+
+        def recording_init(eng, *args, **kwargs):
+            init(eng, *args, **kwargs)
+            made.append(eng)
+
+        monkeypatch.setattr(Engine, "__init__", recording_init)
+        yield
+        for eng in made:
+            eng.stop()
+
+    def test_chaos_scenario_fake_datapath(self, capsys, stop_chaos_engines):
         """Fast tier-1 subset of `make chaos`: the full scripted scenario
         on the oracle-backed fake datapath."""
         rc = cli_main(["faults", "chaos", "--datapath", "fake",
@@ -674,7 +692,7 @@ class TestChaosCLI:
         assert "probe closed breaker" in phases["breaker"]["detail"]
 
     @pytest.mark.slow
-    def test_chaos_scenario_jit_datapath(self, capsys):
+    def test_chaos_scenario_jit_datapath(self, capsys, stop_chaos_engines):
         """`make chaos` equivalent: the same scenario through the real
         compiled (jit) device path under JAX_PLATFORMS=cpu."""
         rc = cli_main(["faults", "chaos", "--datapath", "jit",

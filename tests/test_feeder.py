@@ -352,9 +352,14 @@ class TestZeroAllocSoak:
         datapath.py) shows no steady-state Python-heap growth — the wire
         rings, staging views, and upload cache make it allocation-free
         modulo transient temporaries the soak nets out to ~zero."""
+        from cilium_tpu.observe.trace import TRACER
         from cilium_tpu.runtime.datapath import JITDatapath
         from cilium_tpu.kernels.records import empty_batch
 
+        # tracemalloc counts every thread of the process, and the tracer is
+        # process-wide: armed by a neighbour's engine, its ring would keep
+        # this engine's spans and read as growth of the path under test
+        assert not TRACER.enabled, "the process-wide tracer was left armed"
         cfg = DaemonConfig(ct_capacity=4096, auto_regen=False,
                            batch_size=64, device="cpu",
                            pipeline_flush_ms=0.5,
@@ -393,8 +398,11 @@ class TestZeroAllocSoak:
         snap2 = tracemalloc.take_snapshot()
         tracemalloc.stop()
         flt = [tracemalloc.Filter(
-            True, f"*{os.sep}{name}") for name in
-            ("records.py", "scheduler.py", "datapath.py", "feeder.py")]
+            True, "*" + os.path.join(os.sep, "cilium_tpu", *name))
+            for name in (("kernels", "records.py"),
+                         ("pipeline", "scheduler.py"),
+                         ("runtime", "datapath.py"),
+                         ("shim", "feeder.py"))]
         diff = snap2.filter_traces(flt).compare_to(
             snap1.filter_traces(flt), "lineno")
         growth = sum(d.size_diff for d in diff)
@@ -412,7 +420,7 @@ class TestZeroAllocSoak:
 @pytest.mark.slow
 class TestFeederSoak:
     def test_soak_10k_frames_with_faults(self):
-        """`make ingest-smoke` soak: 10k frames through the mock rings
+        """`make chaos` soak: 10k frames through the mock rings
         with shim.rx_ring faults armed the whole run — every frame gets a
         verdict, forwarded frames leave in exact injection order, and the
         feeder/pipeline account for every batch.

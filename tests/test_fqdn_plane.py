@@ -337,10 +337,17 @@ FQDN_POLICY = [{
 }]
 
 
-def _engine():
-    from cilium_tpu.runtime.datapath import FakeDatapath
-    cfg = DaemonConfig(ct_capacity=4096, auto_regen=False)
-    eng = Engine(cfg, datapath=FakeDatapath(cfg))
+def _engine(jit_audited=False):
+    """The FQDN world on the oracle-backed datapath, or on the compiled one
+    with the parity auditor replaying every batch."""
+    from cilium_tpu.runtime.datapath import FakeDatapath, JITDatapath
+    audit = dict(audit_enabled=True, audit_sample_rate=1.0,
+                 audit_pool_batches=64) if jit_audited else {}
+    cfg = DaemonConfig(ct_capacity=4096, auto_regen=False, **audit)
+    eng = Engine(cfg, datapath=(JITDatapath if jit_audited
+                                else FakeDatapath)(cfg))
+    if jit_audited:
+        eng.auditor.configure(sample_rate=1.0)
     clock = {"t": 100}
     eng.ctx.fqdn_cache.clock = lambda: clock["t"]
     eng.add_endpoint(["k8s:app=web"], ips=("192.168.1.10",), ep_id=1)
@@ -407,13 +414,25 @@ class TestEngineIntegration:
         assert not bool(out["allow"][0])
         assert int(out["reason"][0]) == C.DropReason.POLICY
 
-    def test_churn_cycles_stay_incremental(self):
+    @pytest.mark.parametrize("jit_audited", [False, True],
+                             ids=["fake", "jit-audited"])
+    def test_churn_cycles_stay_incremental(self, jit_audited):
         """Steady learn/expire churn: zero full rebuilds after the seed,
-        every cycle equivalent (spot-checked by verdicts each round)."""
-        eng, clock = _engine()
+        every cycle equivalent (spot-checked by verdicts each round), and
+        the flow to a stable long-TTL name answered the same in every
+        cycle. On the compiled datapath the auditor at sampling 1.0 holds
+        every batch, retirement tombstones included, to the oracle (two
+        cycles there: every learn and every expiry grows a tensor and so
+        compiles the step anew, ~2.5 s each on the CPU)."""
+        eng, clock = _engine(jit_audited)
+        rounds = 2 if jit_audited else 4
+        eng.observe_dns("stable.svc.example.com", ["20.4.0.1"],
+                        ttl=1_000_000, now=clock["t"])
         eng.regenerate()
         fulls0 = eng.metrics.counters.get("regen_full_total", 0)
-        for r in range(4):
+        for r in range(rounds):
+            assert bool(_classify_dst(eng, "20.4.0.1",
+                                      now=clock["t"])["allow"][0])
             ip_new = f"20.3.{r}.1"
             eng.observe_dns(f"c{r}.svc.example.com", [ip_new], ttl=200,
                             now=clock["t"])
@@ -425,9 +444,21 @@ class TestEngineIntegration:
             eng.regenerate()
             assert not bool(_classify_dst(eng, ip_new,
                                           now=clock["t"])["allow"][0])
+        assert bool(_classify_dst(eng, "20.4.0.1",
+                                  now=clock["t"])["allow"][0])
         assert eng.metrics.counters.get("regen_full_total", 0) == fulls0
         assert eng.metrics.counters.get(
-            "fqdn_identities_retired_total", 0) == 4
+            "fqdn_identities_retired_total", 0) == rounds
+        if jit_audited:
+            for _ in range(50):
+                step = eng.audit_step(budget=128)
+                if not step or (not step.get("replayed")
+                                and not step.get("pending")):
+                    break
+            st = eng.auditor.stats()
+            assert st["checked_rows"] > 0, st
+            assert st["mismatched_rows"] == 0, st
+        eng.stop()
 
     def test_status_and_resources_surface(self):
         from cilium_tpu.runtime.api import status_doc

@@ -7,7 +7,7 @@ tests run tracing through the real Pipeline + Engine (spans appear per
 stage; verdicts stay bit-identical to the serial path with sampling at
 1.0 — the acceptance gate), exercise the REST routes, and pin the
 ``Engine._dirty`` Event semantics (a mark set mid-compile survives the
-regeneration). The ``slow``-marked soak (``make observe-smoke``) asserts
+regeneration). The ``slow``-marked soak (`make chaos`) asserts
 the 1/64-sampled pipeline costs <2% over tracing disabled.
 """
 

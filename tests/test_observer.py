@@ -9,7 +9,7 @@ Pinned here:
   every ring wraparound past a cursor is an explicit structured gap
   (acceptance criterion), including under a live writer race
 - relay fan-in: k-way merge ordering, node tags, per-source cursors/lag,
-  gap re-emission; the 4-engine fan-in phase `make observe-smoke` runs
+  gap re-emission; the 4-engine fan-in phase `make chaos` runs
 - per-rule hit/drop counters {rule=} with capped cardinality, scraped
   concurrently with a sharded soak (the satellite race test)
 """
@@ -674,7 +674,7 @@ class TestScrapeRaceRuleLabels:
 
 
 # --------------------------------------------------------------------------- #
-# slow soaks: the observe-smoke attestation + relay fan-in phase
+# slow soaks: the observer's overhead attestation + relay fan-in phase
 # --------------------------------------------------------------------------- #
 @pytest.mark.slow
 class TestObserverOverheadSoak:
@@ -714,9 +714,9 @@ class TestObserverOverheadSoak:
 
         # micro: append+incremental-poll vs append-only, same ring geometry
         # and per-batch row count as the pipeline soak. The follower polls
-        # once per 4 appended batches — the bench's 1ms wall cadence sees
-        # well over 4 batches per tick at soak throughput, so this is the
-        # conservative end of the realistic cadence range. The armed
+        # once per 4 appended batches — a follower on a 1ms wall cadence
+        # sees well over 4 batches per tick at soak throughput, so this is
+        # the conservative end of the realistic cadence range. The armed
         # filter is selective (the needle case a follow filter exists
         # for): one row per poll window matches and pays its rendering.
         log = FlowLog(capacity=eng.config.flowlog_capacity, mode="all")
@@ -780,9 +780,8 @@ class TestObserverOverheadSoak:
         # the gross bound is LOOSE by design: the oracle-backed fake
         # engine is GIL-bound pure Python, so a concurrent poll thread
         # costs wall-clock far beyond its measured CPU (scheduler ping-
-        # pong) — the precise 2% contract is the micro above, and the
-        # real-datapath fps gate lives in `bench.py --ingest --observer`
-        # (device compute releases the GIL there). This guards against
+        # pong) — the precise 2% contract is the micro above (on a real
+        # datapath device compute releases the GIL). This guards against
         # catastrophic regressions only (a lock held across the scan, a
         # render of unmatched rows).
         assert min(on) <= min(off) * 1.6, \
@@ -798,7 +797,7 @@ class _Sharded4(FakeDatapath):
 @pytest.mark.slow
 class TestRelayFanInPhase:
     def test_relay_follows_live_4shard_mesh_plus_peers(self):
-        """The observe-smoke fan-in phase: one 4-shard mesh engine under
+        """The relay fan-in phase: one 4-shard mesh engine under
         pipelined load + three plain engines classifying, all four rings
         fanned in by one live-polling relay. Every source's records are
         either merged (node-tagged, time-ordered per poll) or declared in

@@ -47,6 +47,21 @@ class TestSteering:
         np.testing.assert_array_equal(flow_shard_of(fwd, 4),
                                       flow_shard_of(rev, 4))
 
+    @pytest.mark.parametrize("n_shards", [2, 4, 8])
+    def test_uniform_flows_leave_no_shard_idle(self, world, n_shards):
+        """A steering fault that parks the work on one chip hides inside
+        every aggregate: over 64 distinct random flows a shard, no shard is
+        idle and none carries more than three times its fair share (capped
+        at 0.95 so that the check stays live on two shards)."""
+        ctx, snap = world
+        rng = random.Random(11)
+        packets = [random_packet(rng, []) for _ in range(64 * n_shards)]
+        batch = batch_from_records(packets, snap.ep_slot_of)
+        rows = np.bincount(flow_shard_of(batch, n_shards)[batch["valid"]],
+                           minlength=n_shards)
+        assert rows.min() > 0, rows
+        assert rows.max() / rows.sum() <= min(0.95, 3 / n_shards), rows
+
     def test_steer_roundtrip(self, world):
         ctx, snap = world
         rng = random.Random(4)

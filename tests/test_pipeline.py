@@ -11,7 +11,7 @@ Integration tests go through ``Engine.submit`` and pin pipeline verdicts
 bit-identical to the serial ``classify`` path on the same submissions —
 the serial path is already oracle-pinned (test_parity.py), so equality
 here extends the parity chain to the pipelined path. The ``slow``-marked
-soak (``make pipeline-smoke``) pushes 10k submissions through an engine
+soak (`make chaos`) pushes 10k submissions through an engine
 on FakeDatapath with ``pipeline.dispatch`` faults armed and asserts
 nothing is lost or reordered.
 """
@@ -422,7 +422,7 @@ class TestEnginePipelineParity:
 @pytest.mark.slow
 class TestPipelineSoak:
     def test_soak_10k_submissions_with_faults(self):
-        """`make pipeline-smoke` soak: 10k submissions through an engine on
+        """`make chaos` soak: 10k submissions through an engine on
         FakeDatapath with a 2% `pipeline.dispatch` fault storm armed the
         whole time — every ticket resolves, valid rows reach the datapath
         exactly once in submission order, nothing lost or reordered."""

@@ -10,7 +10,7 @@ ledger register/deregister under engine restart, trace-ring drop
 accounting, the pipeline's departed-shard gauge sweep, the verifier budget
 doc, and the JIT HBM ledger.
 
-Slow (make pressure-smoke): the cfg6-form storm soak — flood a tiny CT
+Slow (`make chaos`): the cfg6-form storm soak — flood a tiny CT
 through the live pipelined engine under the auditor, asserting the ledger's
 ct_table row tracks the ct_occupancy gauge bit-for-bit every tick and the
 time-to-exhaustion forecast fires before the ladder reaches SHED-NEW —
@@ -593,11 +593,13 @@ class TestJITHBMLedger:
 
 
 # --------------------------------------------------------------------------- #
-# slow: the cfg6-form pressure soak (make pressure-smoke)
+# slow: the cfg6-form pressure soak (`make chaos`)
 # --------------------------------------------------------------------------- #
-@pytest.mark.slow
 class TestPressureSoak:
-    def test_storm_ct_row_bit_identical_and_eta_before_shed_new(self):
+    @pytest.mark.parametrize("cap,rows", [
+        (1 << 8, 64), pytest.param(1 << 10, 256, marks=pytest.mark.slow)])
+    def test_storm_ct_row_bit_identical_and_eta_before_shed_new(self, cap,
+                                                                 rows):
         """The cfg6 acceptance, in-tree: a SYN flood saturates a tiny CT
         through the live pipelined engine (auditor at 1.0); every tick the
         ledger's ct_table row must equal the ct_occupancy gauge EXACTLY,
@@ -606,14 +608,13 @@ class TestPressureSoak:
         from cilium_tpu.pipeline.guard import OVERLOAD_SHED_NEW
         from cilium_tpu.runtime.datapath import JITDatapath
         rng = np.random.default_rng(7)
-        cap = 1 << 10
         cfg = DaemonConfig(
-            ct_capacity=cap, auto_regen=False, batch_size=256,
+            ct_capacity=cap, auto_regen=False, batch_size=rows,
             pipeline_flush_ms=0.5, pipeline_queue_batches=8,
             pipeline_block_timeout_s=0.05,
             audit_enabled=True, audit_sample_rate=1.0,
             audit_pool_batches=64, flowlog_mode="none",
-            ct_gc_chunk_rows=1 << 8,
+            ct_gc_chunk_rows=cap >> 2,
             ct_pressure_high=0.8, ct_pressure_low=0.5,
             overload_up_ticks=1, overload_down_ticks=4,
             overload_shed_rate_high=15.0, overload_shed_rate_low=2.0,
@@ -628,7 +629,7 @@ class TestPressureSoak:
                              {"port": "80", "protocol": "TCP"}]}]}]}])
         eng.regenerate()
 
-        def flood_batch(n=256):
+        def flood_batch(n=rows):
             from cilium_tpu.kernels.records import empty_batch
             b = empty_batch(n)
             b["valid"][:] = True
@@ -685,6 +686,7 @@ class TestPressureSoak:
         finally:
             eng.stop()
 
+    @pytest.mark.slow
     def test_8shard_audited_soak_scrape_race_with_restart(self):
         """The PR 7/11 house pattern extended to the {resource=} families:
         an 8-shard audited pipeline soak with concurrent render_metrics

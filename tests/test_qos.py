@@ -11,7 +11,7 @@ latency lane's immediate flush at the lane bucket, the ``qos.enqueue``
 fail-closed path, and engine parity with the auditor at sampling 1.0
 with QoS armed.
 
-Slow (make qos-smoke): the 8-shard audited soak with two concurrent
+Slow (`make chaos`): the 8-shard audited soak with two concurrent
 ``render_metrics`` scrapers and a mid-soak watchdog restart (the PR
 7/11/13 house race pattern, extended to the ``{tenant=}`` label
 families and the ``qos_tenant_queue_*`` resource rows).

@@ -458,7 +458,7 @@ class TestPipelineRSSValidation:
 
 
 # --------------------------------------------------------------------------- #
-# Slow soak (`make rss-smoke`): 10k skewed rows through the device mesh
+# Slow soak (`make chaos`): 10k skewed rows through the device mesh
 # --------------------------------------------------------------------------- #
 @pytest.mark.slow
 class TestDeviceRSSSoak:

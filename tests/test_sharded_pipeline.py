@@ -15,7 +15,7 @@ deadline-shed submission, CT continuity across drained phases (the
 direction-normalized steer must keep both directions of a flow on one
 shard) and a mid-soak ``place_patch``. A tracemalloc check pins the steered
 staging path allocation-free in steady state, and the slow soak
-(``make multichip-smoke``) pushes 10k frames through the mock-ring feeder
+(`make chaos`) pushes 10k frames through the mock-ring feeder
 into an 8-shard mesh with ``shim.rx_ring`` faults armed, asserting the
 steered path never fell back to an allocating pack
 (``datapath_pack_fallback_total{reason="steered"} == 0``).
@@ -647,7 +647,7 @@ class TestSteeredStagingAllocFree:
 
 
 # --------------------------------------------------------------------------- #
-# Slow soak (`make multichip-smoke`): the feeder → 8-shard mesh path under
+# Slow soak (`make chaos`): the feeder → 8-shard mesh path under
 # rx-ring faults
 # --------------------------------------------------------------------------- #
 @pytest.mark.slow

@@ -9,7 +9,8 @@ import subprocess
 import numpy as np
 import pytest
 
-SHIM_DIR = os.path.join(os.path.dirname(__file__), "..", "cilium_tpu", "shim")
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SHIM_DIR = os.path.join(REPO_ROOT, "cilium_tpu", "shim")
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -499,7 +500,7 @@ class TestTsan:
         import sys as _sys
         proc = subprocess.run([_sys.executable, "-c", code],
                               capture_output=True, text=True, timeout=120,
-                              cwd="/root/repo", env=env)
+                              cwd=REPO_ROOT, env=env)
         assert proc.returncode == 0, (proc.returncode, proc.stderr[-2000:])
         assert "TSAN_OK" in proc.stdout
         assert "WARNING: ThreadSanitizer" not in proc.stderr

@@ -694,13 +694,15 @@ class TestCTRestart:
 # the storm soak with the parity auditor at sampling 1.0
 # --------------------------------------------------------------------------- #
 class TestStormAudit:
-    @pytest.mark.slow
-    def test_policy_storm_audited_at_full_sampling(self):
+    @pytest.mark.parametrize("steps", [
+        18, pytest.param(60, marks=pytest.mark.slow)])
+    def test_policy_storm_audited_at_full_sampling(self, steps):
         """Pipelined traffic under continuous rule churn with the shadow
         auditor at sampling 1.0: zero parity mismatches, and the churn
         actually exercised the delta-patch path (no batch classified under
         a torn revision — the auditor replays each batch against the exact
-        revision it classified under)."""
+        revision it classified under), with no scatter healed by a full
+        upload on the way."""
         eng = jit_engine(audit_enabled=True, audit_sample_rate=1.0,
                          audit_pool_batches=64, audit_max_rows=512)
         eng.auditor.configure(sample_rate=1.0)
@@ -708,7 +710,8 @@ class TestStormAudit:
         slots = eng.active.snapshot.ep_slot_of
         now = 5000
         tickets = []
-        for step in range(60):
+        base = dict(eng.datapath.patch_stats)
+        for step in range(steps):
             if step % 3 == 0:
                 i, p = step % N_PEERS, (443, 8080)[step % 2]
                 label = f"k8s:warm=w{i}-{p}"
@@ -730,5 +733,7 @@ class TestStormAudit:
         st = eng.auditor.stats()
         assert st["checked_rows"] > 0, st
         assert st["mismatched_rows"] == 0, st
-        assert eng.datapath.patch_stats["patch_delta"] >= 1
+        ps = eng.datapath.patch_stats
+        assert ps["patch_delta"] - base["patch_delta"] >= 1, ps
+        assert ps["patch_scatter_errors"] == base["patch_scatter_errors"]
         eng.stop()

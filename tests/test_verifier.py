@@ -10,6 +10,8 @@ import pytest
 
 from cilium_tpu.compile.verifier import apply_budget, verify_configs
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture(scope="module")
 def sweep():
@@ -40,7 +42,7 @@ class TestVerifier:
         out = subprocess.run(
             [sys.executable, "-m", "cilium_tpu.cli.main", "verify",
              "--batch", "64", "--quick"],
-            capture_output=True, text=True, timeout=300, cwd="/root/repo",
+            capture_output=True, text=True, timeout=300, cwd=REPO_ROOT,
             env={**os.environ, "JAX_PLATFORMS": "cpu"})
         assert out.returncode == 0, out.stderr
         assert "combos verifier-accepted" in out.stdout
