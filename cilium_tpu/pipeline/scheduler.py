@@ -180,8 +180,8 @@ class Ticket:
     the serial classify path)."""
 
     __slots__ = ("seq", "n_rows", "n_valid", "submitted_mono", "trace_id",
-                 "deadline_mono", "ingest_mono", "tenant", "_event", "_out",
-                 "_exc")
+                 "deadline_mono", "ingest_mono", "tenant", "dispatched_mono",
+                 "waker", "_event", "_out", "_exc")
 
     def __init__(self, n_rows: int, n_valid: int):
         self.seq = -1                      # assigned at admission
@@ -198,6 +198,13 @@ class Ticket:
         # instant they build the batch (submitted_mono is then the truth)
         self.ingest_mono: Optional[float] = None
         self.deadline_mono: Optional[float] = None   # shed-after fence
+        # when the worker handed this submission's rows to the device
+        # (monotonic seconds; None while it waits in the queue or in a
+        # staged microbatch). A producer that paces itself on the worker
+        # (the shim feeder) reads it, and may leave an Event in ``waker``
+        # that is set at that moment and again when the ticket resolves
+        self.dispatched_mono: Optional[float] = None
+        self.waker: Optional[threading.Event] = None
         self._event = threading.Event()
         self._out: Optional[Dict[str, np.ndarray]] = None
         self._exc: Optional[BaseException] = None
@@ -221,10 +228,17 @@ class Ticket:
     def _resolve(self, out: Dict[str, np.ndarray]) -> None:
         self._out = out
         self._event.set()
+        self._wake()
 
     def _reject(self, exc: BaseException) -> None:
         self._exc = exc
         self._event.set()
+        self._wake()
+
+    def _wake(self) -> None:
+        w = self.waker
+        if w is not None:
+            w.set()
 
 
 def _batch_prio(batch: Dict[str, np.ndarray]) -> int:
@@ -2009,6 +2023,12 @@ class Pipeline:
         self.dispatched_batches += 1
         self._inflight.append(_Inflight(finalize, slices, t0, buf_idx))
         self._dispatching = []           # now visible in _inflight
+        t_dev = time.monotonic()
+        for sl in slices:
+            # the rows are the device's now: a producer pacing itself on
+            # the worker may harvest its next batch
+            sl.ticket.dispatched_mono = t_dev
+            sl.ticket._wake()
         self.metrics.set_gauge("pipeline_inflight", len(self._inflight))
         self._publish(gen)
         # keep at most ``inflight`` batches genuinely in flight; the ring

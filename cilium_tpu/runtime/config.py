@@ -98,8 +98,12 @@ class DaemonConfig:
     # in-place pack into preallocated wire rings + L7 path-dict upload
     # cache (JITDatapath); False restores per-batch allocation
     zero_copy_ingest: bool = True
-    ingest_pool_batches: int = 4        # feeder harvest buffers in flight
-    ingest_poll_budget: int = 256       # rx descriptors per afxdp_poll
+    # ceilings, not the pace: a harvest takes what the ring holds (up to
+    # shim/feeder.harvest_ceiling, from the ring's size and
+    # pipeline_inflight) and opens once the worker dispatched the one
+    # before (after a partial one: once its verdicts are back)
+    ingest_pool_batches: int = 4        # feeder harvest buffers, at most
+    ingest_poll_budget: int = 256       # rx descriptors per afxdp_poll round
     ingest_idle_sleep_s: float = 0.0005  # feeder park when rings are empty
     # --- ingestion pipeline (pipeline/scheduler.py) ---
     pipeline_queue_batches: int = 64    # bounded submission queue (batches)

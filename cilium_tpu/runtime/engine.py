@@ -573,20 +573,32 @@ class Engine:
                     # series — the cap is on strings, not snapshots)
                     self._rule_label_memo.clear()
                     self._rule_label_snap = snap
-                for u in range(uniq.size):
-                    c = int(uniq[u])
-                    label = self._rule_label_memo.get(c)
+                # summed by LABEL before the counters are touched: past the
+                # cap nearly every coordinate of a batch reads "other", and
+                # a counter bumped once a coordinate cost the pipeline's
+                # worker two microseconds a row (a 1,024-row batch of a
+                # 50k-rule world matches about as many cells)
+                memo = self._rule_label_memo
+                by_label: Dict[str, List[int]] = {}
+                for c, h, d in zip(uniq.tolist(), hits.tolist(),
+                                   drops.tolist()):
+                    label = memo.get(c)
                     if label is None:
                         label = self._resolve_rule_label(c, snap, cap)
-                        self._rule_label_memo[c] = label
-                    if hits[u]:
+                        memo[c] = label
+                    acc = by_label.get(label)
+                    if acc is None:
+                        by_label[label] = [h, d]
+                    else:
+                        acc[0] += h
+                        acc[1] += d
+                for label, (h, d) in by_label.items():
+                    if h:
                         self.metrics.inc_counter(
-                            f'policy_rule_hits_total{{rule="{label}"}}',
-                            int(hits[u]))
-                    if drops[u]:
+                            f'policy_rule_hits_total{{rule="{label}"}}', h)
+                    if d:
                         self.metrics.inc_counter(
-                            f'policy_rule_drops_total{{rule="{label}"}}',
-                            int(drops[u]))
+                            f'policy_rule_drops_total{{rule="{label}"}}', d)
         except Exception:   # noqa: BLE001
             log.exception("per-rule hit fold failed")
             self.metrics.inc_counter("rule_metrics_errors_total")
@@ -904,12 +916,20 @@ class Engine:
 
     # -- async shim ingestion (shim/feeder.py) ----------------------------------
     def start_feeder(self, shim):
-        """Attach an async shim→pipeline feeder: a harvest thread polls
-        ``shim`` on a budget, submits harvested batches into the ingestion
-        pipeline (reusable poll buffers, no per-poll allocation), and
-        applies verdicts FIFO as tickets resolve — replacing the
-        synchronous poll→classify→apply loop. Knobs: ``ingest_*`` in
-        DaemonConfig. Stopped (drained) by :meth:`stop`."""
+        """Attach an async shim→pipeline feeder: a harvest thread takes
+        what ``shim``'s ring holds — as many shim batches as are waiting,
+        up to a ceiling derived from the ring's size and
+        ``pipeline_inflight`` (``shim/feeder.harvest_ceiling``) — into one
+        reusable buffer, submits it as ONE bucket-shaped batch once the
+        worker has dispatched the harvest before (after a partial harvest:
+        once its verdicts are back), and applies verdicts FIFO as tickets
+        resolve — replacing the synchronous poll→classify→apply loop. ``ingest_pool_batches`` and
+        ``ingest_poll_budget`` are ceilings, not the pace. Every shape such
+        a submission can have (the feeder's ``buckets``: 256, 512 and 1,024
+        rows at the defaults on 4,096-frame rings) is compiled or loaded
+        here, before the first harvest, on rows that are all invalid and so
+        leave no conntrack state (:meth:`_warm_buckets`); attach the
+        shim's rings first. Stopped (drained) by :meth:`stop`."""
         with self._lock:
             if self._feeder is not None:
                 return self._feeder
@@ -930,11 +950,14 @@ class Engine:
                 self._dns_proxy = DNSProxy(
                     self.ctx.fqdn_cache, metrics=self.metrics,
                     min_ttl=cfg.fqdn_min_ttl, port=cfg.fqdn_proxy_port)
-            self._feeder = ShimFeeder(
+            feeder = ShimFeeder(
                 shim, self,
                 pool_batches=cfg.ingest_pool_batches,
                 poll_budget=cfg.ingest_poll_budget,
                 idle_sleep_s=cfg.ingest_idle_sleep_s,
+                inflight=cfg.pipeline_inflight,
+                min_bucket=min(cfg.pipeline_min_bucket, cfg.batch_size),
+                max_rows=cfg.batch_size,
                 slo_ms=cfg.slo_e2e_ms,
                 # steered mesh: harvest computes the flow-shard hash during
                 # ep-slot mapping (vectorized, shares flow_shard_of) so the
@@ -953,8 +976,33 @@ class Engine:
                 qos=self.qos,
                 # DNS plane armed: poll buffers grow the payload columns
                 # and the verdict-apply path taps the learning proxy
-                fqdn=self._dns_proxy).start()
-            return self._feeder
+                fqdn=self._dns_proxy)
+            self._warm_buckets(feeder.buckets)
+            self._feeder = feeder.start()
+            return feeder
+
+    def _warm_buckets(self, buckets) -> None:
+        """Compile or load the program of every dispatch shape in
+        ``buckets`` now, so that none compiles under traffic: one batch of
+        all-invalid rows a shape through the dispatch the pipeline's worker
+        makes (one chip and mesh alike), in the wire format the traffic so
+        far has settled on. Invalid rows open no flow, and the ``_canary``
+        marker keeps the batch out of every counter, log and observer. A
+        steered pipeline (host RSS) stages every submission and dispatches
+        at shapes of its own: nothing to warm here."""
+        if self._pipeline_sharded:
+            return
+        from cilium_tpu.kernels.records import empty_batch
+        min_bucket = min(self.config.pipeline_min_bucket,
+                         self.config.batch_size)
+        for rows in sorted({max(b, min_bucket) for b in buckets}):
+            batch = empty_batch(rows)
+            batch["_canary"] = np.ones(rows, dtype=np.uint8)
+            try:
+                self._pipeline_dispatch(batch, int(time.time()))()
+            except Exception:   # noqa: BLE001 — it compiles on first use
+                logging.getLogger("cilium_tpu.engine").exception(
+                    "warming the %d-row dispatch failed", rows)
 
     def feeder_stats(self) -> Optional[Dict]:
         fd = self._feeder

@@ -68,6 +68,9 @@ class ShimStats(ctypes.Structure):
 # oldest unverdicted batch at the same poll, keeping the verdict FIFO and
 # the Python count FIFO aligned
 MAX_UNVERDICTED_BATCHES = 64
+# must equal flowshim.cc kNumFrames: the umem frames and the entries of
+# each of the four rings that afxdp_bind sets up
+AFXDP_RING_FRAMES = 4096
 
 
 def _load_lib():
@@ -149,6 +152,11 @@ class FlowShim:
         self._pending_counts: list = []
         self._enforcing = False        # mirrors flowshim.cc Shim::enforcing
         self._rings_ready = False      # set by afxdp_bind/mock_rings_init
+        # frames the NIC can have handed over and not got back: the smaller
+        # of the rx ring and the umem (0 until rings exist). The feeder
+        # sizes a harvest from it (shim/feeder.harvest_ceiling)
+        self.ring_frames = 0
+        self.last_poll_rows = 0        # records in the newest polled batch
 
     def close(self):
         if self._handle:
@@ -177,34 +185,45 @@ class FlowShim:
         ("has_tokens", "u1"), ("method", "u1"), ("path_len", "<u2"),
         ("path", "u1", (C.L7_PATH_MAXLEN,)), ("pad", "u1", (4,))])
 
-    def make_poll_buffer(self) -> Dict[str, np.ndarray]:
+    def make_poll_buffer(self, rows: Optional[int] = None
+                         ) -> Dict[str, np.ndarray]:
         """A reusable ``poll_batch(out=...)`` buffer: the records layout
-        plus the shim-side ``_ep_raw``/``_frame_idx`` columns. The feeder
-        preallocates a pool of these so the hot harvest loop never builds
-        a fresh column dict per poll."""
-        b = empty_batch(self.batch_size)
-        b["_ep_raw"] = np.zeros((self.batch_size,), dtype=np.int64)
-        b["_frame_idx"] = np.zeros((self.batch_size,), dtype=np.int64)
+        plus the shim-side ``_ep_raw``/``_frame_idx`` columns, ``rows``
+        long (default one batch). The feeder preallocates a pool of
+        harvest-sized ones and polls into ``batch_size``-row views of
+        them, so the hot harvest loop never builds a fresh column dict."""
+        rows = self.batch_size if rows is None else rows
+        b = empty_batch(rows)
+        b["_ep_raw"] = np.zeros((rows,), dtype=np.int64)
+        b["_frame_idx"] = np.zeros((rows,), dtype=np.int64)
         return b
 
     def poll_batch(self, now_us: int = 0, force: bool = False,
                    out: Optional[Dict[str, np.ndarray]] = None
                    ) -> Optional[Dict[str, np.ndarray]]:
-        """Harvest a batch in the kernels/records layout (None if not ready).
-        Records for unknown endpoints (ep_id 0) stay invalid (fail closed).
+        """Harvest ONE batch of the batcher, at most ``batch_size`` records,
+        in the kernels/records layout (None if not ready: not full, not
+        timed out, not forced). Records for unknown endpoints (ep_id 0)
+        stay invalid (fail closed). Each batch polled is one entry of the
+        verdict FIFO: :meth:`apply_verdicts` answers them one by one, in
+        poll order. ``last_poll_rows`` holds its record count.
 
         ``out=`` reuses a caller-owned buffer from :meth:`make_poll_buffer`
-        instead of allocating: rows [:n] are overwritten, rows [n:] are
-        reset to the empty-batch defaults (``valid`` False, method ANY,
-        zeroed path) so a reused buffer is indistinguishable from a fresh
-        one. The caller must not hand the same buffer back before its
-        previous batch's consumer is done with it."""
+        instead of allocating — or any ``batch_size``-row view of a longer
+        one: the feeder's harvest polls several batches into consecutive
+        views of one buffer and submits them together. Rows [:n] are
+        overwritten, rows [n:batch_size] are reset to the empty-batch
+        defaults (``valid`` False, method ANY, zeroed path) so a reused
+        buffer is indistinguishable from a fresh one. The caller must not
+        hand the same rows back before their previous batch's consumer is
+        done with them."""
         FAULTS.fire("shim.rx_ring")
         n = self._lib.shim_poll_batch(self._handle, now_us, int(force),
                                       self._rec_buf, self._tok_buf)
         if n == 0:
             return None
         self._pending_counts.append(int(n))
+        self.last_poll_rows = int(n)
         if not self._enforcing \
                 and len(self._pending_counts) > MAX_UNVERDICTED_BATCHES:
             self._pending_counts.pop(0)   # C++ aged out the same batch
@@ -241,11 +260,14 @@ class FlowShim:
         return b
 
     def apply_verdicts(self, allow: np.ndarray) -> None:
-        """Enforce verdicts for the OLDEST unverdicted batch. ``allow`` may
-        cover any prefix of that batch's records (e.g. only the valid rows);
-        the remainder is dropped (fail closed) — the C++ side holds one
-        frame per emitted record, so the full count must always be consumed
-        or later verdicts would enforce on the wrong frames."""
+        """Enforce verdicts for the OLDEST unverdicted batch (one
+        :meth:`poll_batch` result; a harvest of several polls takes as many
+        calls, each with its own rows of the harvest's verdicts, in poll
+        order). ``allow`` may cover any prefix of that batch's records
+        (e.g. only the valid rows); the remainder is dropped (fail closed)
+        — the C++ side holds one frame per emitted record, so the full
+        count must always be consumed or later verdicts would enforce on
+        the wrong frames."""
         if not self._pending_counts:
             raise RuntimeError("apply_verdicts without a harvested batch")
         self._enforcing = True
@@ -292,6 +314,7 @@ class FlowShim:
         rc = self._lib.shim_afxdp_bind(self._handle, ifname.encode(), queue)
         if rc == 0:
             self._rings_ready = True
+            self.ring_frames = AFXDP_RING_FRAMES
         return rc
 
     @property
@@ -321,6 +344,7 @@ class FlowShim:
         if rc != 0:
             raise OSError(-rc, "shim_mock_rings_init failed")
         self._rings_ready = True
+        self.ring_frames = min(ring_size, n_frames)
 
     def mock_rx_inject(self, frame: bytes) -> int:
         """Act as the NIC: fill-ring frame ← frame bytes → rx descriptor."""

@@ -5,24 +5,44 @@ classify it with a blocking wait, apply verdicts, repeat — the device idles
 during every harvest and the host idles during every classify. The feeder
 replaces that loop with a harvest thread that
 
-- polls the :class:`~cilium_tpu.shim.bindings.FlowShim` on a budget
-  (AF_XDP rings and the heap-mocked rings drain through ``afxdp_poll``;
-  the plain mock batcher through ``poll_batch`` alone),
-- writes harvested columns straight into a small pool of reusable poll
-  buffers (``FlowShim.make_poll_buffer`` — no per-poll column dict),
+- harvests at the pipeline worker's pace. **A harvest takes what the ring
+  holds**: as many rounds of ``afxdp_poll`` + ``poll_batch`` as there are
+  frames waiting (AF_XDP rings and the heap-mocked rings drain through
+  ``afxdp_poll``; the plain mock batcher through ``poll_batch`` alone),
+  each round one shim batch, all into ONE reusable harvest buffer
+  (:class:`HarvestBuffer` — no per-poll column dict), up to
+  :func:`harvest_ceiling` rows. **A harvest waits for the worker**: none
+  opens while a submission of this feeder's is still undispatched (in the
+  pipeline's queue or a staged microbatch) — those frames wait in the
+  ring, where the next harvest takes them together, instead of in the
+  queue as a batch of their own. What comes after the dispatch follows
+  what the harvest found (``_held_back``): a full one means a backlog, and
+  the next opens at once, so that the worker dispatches it while the
+  device has the one before; a partial one means the ring ran dry, and the
+  next waits until the verdicts of everything out are back — a second
+  submission in flight would carry a handful of rows at a whole
+  dispatch's cost and put a dispatch and a finalize between every frame
+  and its verdict,
 - maps shim endpoint ids onto the active snapshot's slots (vectorized,
   lookup table cached per snapshot; unknown endpoints fail closed),
-- submits each buffer to the engine's ingestion pipeline and
-- applies verdicts **FIFO** as tickets resolve — the C++ shim holds one
+- makes ONE submission of the harvest to the engine's ingestion pipeline:
+  the view of the buffer's first rows at the smallest power-of-two bucket
+  (from ``min_bucket`` up) that holds them, so it dispatches ``direct`` —
+  rows a dispatch follow the load — and
+- applies verdicts **FIFO** as tickets resolve, one ``apply_verdicts`` per
+  shim batch the harvest took, in poll order — the C++ shim holds one
   FrameRef per emitted record, so verdict order must equal harvest order;
   a rejected/shed/timed-out ticket is applied as all-drop (fail closed)
-  rather than skipped, which would desync frames from verdicts.
+  for every shim batch of its harvest rather than skipped, which would
+  desync frames from verdicts.
 
-Buffer lifecycle: a poll buffer stays owned by the pipeline from submit
+Buffer lifecycle: a harvest buffer stays owned by the pipeline from submit
 until its ticket resolves (the scheduler stages from it asynchronously),
-so the pool bounds feeder in-flight batches; when every buffer is busy the
-feeder blocks on the oldest ticket — natural backpressure from the device
-straight back to the rx ring (frames simply wait in the ring).
+so the pool bounds feeder in-flight harvests; it is a ceiling the pacing
+keeps the feeder under (two dispatches in flight, one being finalized, one
+undispatched). When every buffer is busy all the same, the feeder blocks on
+the oldest ticket — backpressure from the device straight back to the rx
+ring (frames simply wait in the ring).
 
 Fault tolerance: the ``shim.rx_ring`` injection point fires inside both
 poll entry points; a trip is one failed poll — frames stay queued and
@@ -36,10 +56,11 @@ import logging
 import threading
 import time
 from collections import deque
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from cilium_tpu.kernels.records import reset_batch_rows
 from cilium_tpu.observe.trace import TRACER, Tracer
 from cilium_tpu.runtime.faults import FaultInjected
 from cilium_tpu.runtime.metrics import Metrics
@@ -169,17 +190,71 @@ def map_raw_slots(raw: np.ndarray, slot_of: Dict[int, int],
         dtype=np.int32, count=raw.shape[0])
 
 
+def harvest_ceiling(ring_frames: int, shim_batch: int, inflight: int,
+                    max_rows: int) -> int:
+    """Rows one harvest may take: the largest power-of-two number of shim
+    batches that leaves the NIC room while ``inflight`` dispatches are in
+    flight and one more harvest is being taken — a ``1 / (inflight + 2)``
+    share of the frames the NIC can have outstanding (``ring_frames``: the
+    smaller of rx ring and umem, the shim's to know) — never above the
+    pipeline's largest bucket ``max_rows`` and never under one shim batch.
+    4,096 frames, 256-row shim batches, two in flight: 1,024. Without rings
+    (the plain mock batcher) a harvest is one shim batch."""
+    polls = max(1, min(ring_frames // (inflight + 2), max_rows)
+                // shim_batch)
+    return shim_batch * (1 << (polls.bit_length() - 1))
+
+
+class HarvestBuffer(dict):
+    """One reusable harvest buffer: the column dict, ``harvest_ceiling``
+    rows long, with its views cut once. ``segments[k]`` is rows
+    [k·batch, (k+1)·batch): what the harvest's k-th poll writes.
+    ``views[rows]`` is the first ``rows`` rows for every bucket a
+    submission can have. ``view`` and ``counts`` describe the harvest the
+    buffer holds: the view submitted, and the records of each shim batch
+    polled into it, in poll order (all but the last are whole batches, so
+    batch k starts at row k·batch)."""
+
+    __slots__ = ("segments", "views", "view", "counts")
+
+    def cut(self, shim_batch: int, buckets: Tuple[int, ...]) -> None:
+        """(Re)cut the views — after the last optional column was added."""
+        rows = int(self["valid"].shape[0])
+        self.segments = [{k: v[i:i + shim_batch] for k, v in self.items()}
+                         for i in range(0, rows, shim_batch)]
+        self.views = {b: {k: v[:b] for k, v in self.items()}
+                      for b in buckets}
+        self.view = self.views[buckets[-1]]
+        self.counts: List[int] = []
+
+
 class ShimFeeder:
     """Harvest thread feeding one ``FlowShim`` into one engine's pipeline.
 
-    ``engine`` needs ``submit(batch, now=...) -> Ticket`` and
+    A harvest is every shim batch the ring and the batcher give up in one
+    go (up to ``harvest_rows``), in one buffer, as one submission shaped at
+    the smallest of ``buckets`` that holds it; and it opens only once the
+    worker has dispatched the harvest before — after a partial one, only
+    once its verdicts are back (module docstring, ``_held_back``).
+    ``pool_batches`` and ``poll_budget`` are ceilings, not the pace: the
+    buffers that may be out at once, and the rx descriptors one round
+    drains. ``inflight``, ``min_bucket`` and ``max_rows`` are the
+    pipeline's (``pipeline_inflight``, its smallest and largest dispatch
+    shapes); ``harvest_rows`` follows from them and the shim's rings as
+    they are when the feeder is built.
+
+    ``engine`` needs ``submit(batch, ingest_mono=...) -> Ticket`` and
     ``active.snapshot`` (slot mapping) — the real Engine, or any
-    duck-typed stand-in in tests."""
+    duck-typed stand-in in tests. A ticket without ``dispatched_mono``
+    (a stand-in's) never holds a harvest back."""
 
     def __init__(self, shim: FlowShim, engine, *,
                  pool_batches: int = 4,
                  poll_budget: int = 256,
                  idle_sleep_s: float = 0.0005,
+                 inflight: int = 2,
+                 min_bucket: int = 256,
+                 max_rows: Optional[int] = None,
                  n_shards: int = 1,
                  slo_ms: float = 0.0,
                  metrics: Optional[Metrics] = None,
@@ -188,14 +263,12 @@ class ShimFeeder:
                  qos=None,
                  fqdn=None,
                  name: str = "feeder"):
-        if not 1 <= pool_batches <= MAX_UNVERDICTED_BATCHES:
-            raise ValueError(
-                f"pool_batches must be in [1, {MAX_UNVERDICTED_BATCHES}] "
-                "(the shim ages out unverdicted batches past that)")
         if poll_budget < 1:
             raise ValueError("poll_budget must be >= 1")
         if n_shards < 1:
             raise ValueError("n_shards must be >= 1")
+        if inflight < 1 or min_bucket < 1:
+            raise ValueError("inflight and min_bucket must be >= 1")
         self.shim = shim
         self.engine = engine
         self.metrics = metrics if metrics is not None else Metrics()
@@ -204,6 +277,30 @@ class ShimFeeder:
         self._idle_sleep_s = idle_sleep_s
         self._n_shards = n_shards
         self._name = name
+        bs = shim.batch_size
+        #: rows one harvest may take, and one buffer holds
+        self.harvest_rows = harvest_ceiling(
+            int(getattr(shim, "ring_frames", 0)), bs, inflight,
+            max(bs, max_rows if max_rows is not None else bs))
+        polls = self.harvest_rows // bs
+        if not 1 <= pool_batches * polls <= MAX_UNVERDICTED_BATCHES:
+            raise ValueError(
+                f"pool_batches x polls a harvest ({pool_batches} x {polls}) "
+                f"must be in [1, {MAX_UNVERDICTED_BATCHES}] (the shim ages "
+                "out unverdicted batches past that)")
+        #: the row counts a submission can have, ascending: the powers of
+        #: two from the pipeline's smallest bucket (or one shim batch, if
+        #: that is larger) up to the ceiling. A buffer shorter than the
+        #: smallest bucket is submitted whole, and the pipeline stages it
+        lo = max(bs, min_bucket)
+        self.buckets: Tuple[int, ...] = tuple(
+            b for b in (lo << i for i in range(32))
+            if b <= self.harvest_rows and b % bs == 0) \
+            or (self.harvest_rows,)
+        self._by_bucket = {b: tuple(
+            f'feeder_{what}_total{{bucket="{b}"}}'
+            for what in ("harvests", "harvest_rows", "harvest_polls"))
+            for b in self.buckets}
         # guard-event sink (the flight recorder): SHED-NEW harvest drops
         # are narrated as kind="shed" reason="shed-new" events — the
         # relaxed spike class (observe/blackbox.RELAXED_SHED_REASONS).
@@ -214,46 +311,49 @@ class ShimFeeder:
         # FIFO head-of-line wait). slo_ms > 0 arms the burn counters.
         self._slo_s = slo_ms / 1e3 if slo_ms > 0 else 0.0
 
-        self._free: deque = deque(shim.make_poll_buffer()
-                                  for _ in range(pool_batches))
+        rows = self.harvest_rows
+        # (a stand-in shim's make_poll_buffer may take no length: it is
+        # only ever asked for its own batch)
+        self._free: deque = deque(
+            HarvestBuffer(shim.make_poll_buffer() if rows == bs
+                          else shim.make_poll_buffer(rows))
+            for _ in range(pool_batches))
         # overload-ladder level (set by the engine's overload controller);
         # >= SHED_NEW arms the harvest-time priority shed
         self._overload_level = 0
-        # priority classing: every poll buffer carries a ``_prio`` column
-        # (pipeline/guard.PRIO_*) the admission queue ranks batches by;
-        # the established-flow filter below feeds class 0
+        # priority classing: every harvest buffer carries a ``_prio``
+        # column (pipeline/guard.PRIO_*) the admission queue ranks batches
+        # by; the established-flow filter below feeds class 0. Like every
+        # optional column it is as long as the buffer, and a submission
+        # carries the view of its first rows
         from cilium_tpu.pipeline.guard import PRIO_NEW
         for buf in self._free:
-            buf["_prio"] = np.full((shim.batch_size,), PRIO_NEW,
-                                   dtype=np.int8)
+            buf["_prio"] = np.full((rows,), PRIO_NEW, dtype=np.int8)
         self._est = EstablishedFingerprints()
         # multi-tenant QoS (cilium_tpu/qos): with a TenantTable armed,
-        # every poll buffer carries a ``_tenant`` column stamped at
+        # every harvest buffer carries a ``_tenant`` column stamped at
         # harvest time from the endpoint→tenant LUT (same compiled-LUT
         # discipline as the ep-slot map below) — the admission queue's
         # weighted-fair scheduling and the per-tenant e2e/SLO families
-        # key on it. QoS off: no column, zero extra work per poll.
+        # key on it. QoS off: no column, zero extra work per harvest.
         self._qos = qos
         if qos is not None:
             for buf in self._free:
-                buf["_tenant"] = np.zeros((shim.batch_size,),
-                                          dtype=np.int32)
+                buf["_tenant"] = np.zeros((rows,), dtype=np.int32)
         # in-band DNS plane (cilium_tpu/fqdn): with a DNSProxy armed,
-        # every poll buffer carries the harvested DNS response payload
-        # (``_dns_payload`` [batch, W] uint8, ``_dns_len`` [batch] int32)
+        # every harvest buffer carries the harvested DNS response payload
+        # (``_dns_payload`` [rows, W] uint8, ``_dns_len`` [rows] int32)
         # the verdict-apply tap parses into the FQDN cache. The native
         # C++ shim has no payload channel and never writes either column
         # (the tap sees len==0 everywhere and no-ops); DNS-capable shim
         # stand-ins fill both during poll_batch. Proxy off: no columns,
-        # zero extra work per poll.
+        # zero extra work per harvest.
         self._fqdn = fqdn
         if fqdn is not None:
             w = int(getattr(fqdn, "payload_width", 512))
             for buf in self._free:
-                buf["_dns_payload"] = np.zeros((shim.batch_size, w),
-                                               dtype=np.uint8)
-                buf["_dns_len"] = np.zeros((shim.batch_size,),
-                                           dtype=np.int32)
+                buf["_dns_payload"] = np.zeros((rows, w), dtype=np.uint8)
+                buf["_dns_len"] = np.zeros((rows,), dtype=np.int32)
         if n_shards > 1:
             # software RSS (SURVEY §2), HOST steering mode only: harvest
             # pre-bins each record by the direction-normalized flow hash
@@ -268,11 +368,18 @@ class ShimFeeder:
             # binning policy revision above, so a bin hashed under a
             # superseded LB table is re-hashed at stage-write instead of
             # stranding a service flow's CT entry on the wrong shard —
-            # and rides the same reusable poll buffers.
+            # and rides the same reusable harvest buffers.
             for buf in self._free:
-                buf["_shard"] = np.zeros((shim.batch_size,), dtype=np.int64)
+                buf["_shard"] = np.zeros((rows,), dtype=np.int64)
+        for buf in self._free:
+            buf.cut(bs, self.buckets)
         self._pending: deque = deque()     # (ticket, buf) in harvest order
-        self._zeros = np.zeros((shim.batch_size,), dtype=bool)
+        self._zeros = np.zeros((bs,), dtype=bool)
+        # set by the pipeline when a ticket of ours is dispatched or
+        # resolves (Ticket.waker): what a held-back harvest sleeps on
+        self._wake = threading.Event()
+        self._held: Optional[str] = None    # what a harvest is held for
+        self._last_full = False             # the last harvest hit the ceiling
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._rings: Optional[bool] = None  # afxdp/mock rings attached?
@@ -280,8 +387,10 @@ class ShimFeeder:
         self._slot_lut = np.full((1,), -1, dtype=np.int32)
 
         # stats (single-writer: the feeder thread; read via stats())
-        self.harvested_batches = 0
+        self.harvested_batches = 0         # harvests, one submission each
+        self.harvested_polls = 0           # shim batches they took
         self.harvested_records = 0
+        self.deferred_harvests = 0         # held back for the worker
         self.applied_batches = 0
         self.rejected_batches = 0          # applied fail-closed
         self.harvest_faults = 0
@@ -306,6 +415,7 @@ class ShimFeeder:
         holds, then apply every pending verdict FIFO (fail closed on
         tickets that cannot resolve within ``timeout``)."""
         self._stop.set()
+        self._wake.set()         # a held-back harvest sleeps on this one
         t = self._thread
         if t is not None:
             t.join(timeout)
@@ -328,7 +438,10 @@ class ShimFeeder:
         e2e = self.metrics.histograms.get("ingest_e2e_latency_seconds")
         return {
             "harvested_batches": self.harvested_batches,
+            "harvested_polls": self.harvested_polls,
             "harvested_records": self.harvested_records,
+            "deferred_harvests": self.deferred_harvests,
+            "harvest_rows": self.harvest_rows,
             "applied_batches": self.applied_batches,
             "rejected_batches": self.rejected_batches,
             "harvest_faults": self.harvest_faults,
@@ -374,42 +487,63 @@ class ShimFeeder:
     def _step(self, force: bool) -> bool:
         """One harvest iteration. Returns True when any work happened."""
         progressed = self._apply_ready(block=False)
+        held = None if force else self._held_back()
+        if held is not None:
+            # frames wait in the ring, where this harvest will take them
+            # together, and not in the queue as a batch of their own
+            self._wait_for_worker(held)
+            return True
         buf = self._acquire_buffer()
         if buf is None:
             return progressed            # pool exhausted and head not done
+        # a harvest that was held back has coalesced in the ring for as
+        # long as the worker took: it leaves with what is there instead of
+        # waiting out the batcher's timeout on top
+        paced, self._held = self._held, None
         now_us = int(time.monotonic() * 1e6)
         if self._fqdn is not None:
             # reset_batch_rows zeroes only the TAIL of optional columns and
             # the native shim never writes them: a reused buffer would
-            # otherwise replay the PREVIOUS poll's DNS payload for head
+            # otherwise replay the PREVIOUS harvest's DNS payload for head
             # rows. len==0 makes stale payload bytes unreachable.
             buf["_dns_len"][:] = 0
         tid = self.tracer.maybe_sample()
+        t0 = time.monotonic()
         try:
-            with self.tracer.span(tid, "shim.harvest", force=force):
-                if self._rings_attached():
-                    rc = self.shim.afxdp_poll(self._poll_budget,
-                                              now_us=now_us)
-                    if rc < 0:
-                        log.debug("afxdp_poll -> %d", rc)
-                b = self.shim.poll_batch(now_us=now_us, force=force,
-                                         out=buf)
+            b = self._harvest(buf, now_us, force or paced is not None)
         except FaultInjected:
             # one failed poll: frames stay queued in the ring and drain on
             # the next poll — the supervised-degradation contract
-            self.harvest_faults += 1
-            self.metrics.inc_counter("feeder_harvest_faults_total")
-            self._free.append(buf)
-            return progressed
+            self._count_fault()
+            b = None
         except Exception:   # noqa: BLE001 — buffer must return to the pool
-            self._free.append(buf)
             self._count_error("poll failed")
-            return progressed
+            b = None
+        rows, polls = sum(buf.counts), len(buf.counts)
+        if tid is not None:
+            self.tracer.record(
+                tid, "shim.harvest", t0, time.monotonic() - t0,
+                {"force": force, "rows": rows, "polls": polls,
+                 "bucket": len(b["valid"]) if b else 0})
         if b is None:
             self._free.append(buf)
             return progressed
         self.harvested_batches += 1
-        self.metrics.inc_counter("feeder_harvest_batches_total")
+        self.harvested_polls += polls
+        self._last_full = rows >= self.harvest_rows
+        m = self.metrics
+        if paced is not None:
+            # frames were there when the worker let this harvest go
+            self.deferred_harvests += 1
+            m.inc_counter("feeder_harvest_deferred_total")
+            m.inc_counter(f'feeder_harvest_deferred_total{{for="{paced}"}}')
+        m.inc_counter("feeder_harvest_batches_total")
+        m.inc_counter("feeder_harvest_polls_total", polls)
+        # how often each dispatch shape engages, and how full it leaves
+        by_bucket = self._by_bucket[len(b["valid"])]
+        m.inc_counter(by_bucket[0])
+        m.inc_counter(by_bucket[1], rows)
+        m.inc_counter(by_bucket[2], polls)
         ticket = None
         try:
             n_valid = self._map_slots(b)
@@ -433,13 +567,15 @@ class ShimFeeder:
             # latency; monotonic — same clock as now_us above)
             if submit:
                 ticket = self.engine.submit(b, ingest_mono=now_us / 1e6)
+                if hasattr(ticket, "waker"):
+                    ticket.waker = self._wake
         except Exception as e:   # noqa: BLE001 — unavailable/closed/
             # regen-storm engine.active/... : the shim already holds this
-            # batch's FrameRefs, so a verdict MUST be consumed for it —
-            # but strictly AFTER the batches harvested before it
-            # (apply_verdicts always consumes the OLDEST batch). The
-            # rejection rides the pending queue as a ``None`` sentinel and
-            # is applied all-drop in FIFO position, never out of order.
+            # harvest's FrameRefs, so a verdict MUST be consumed for every
+            # shim batch of it — but strictly AFTER the batches harvested
+            # before it (apply_verdicts always consumes the OLDEST batch).
+            # The rejection rides the pending queue as a ``None`` sentinel
+            # and is applied all-drop in FIFO position, never out of order.
             self._submit_rejects += 1
             if self._submit_rejects <= 3 or self._submit_rejects % 100 == 0:
                 # throttled: a breaker-open storm rejects at harvest rate
@@ -449,6 +585,94 @@ class ShimFeeder:
         self._pending.append((ticket, buf, now_us / 1e6))
         self.metrics.set_gauge("feeder_pending", len(self._pending))
         return True
+
+    def _harvest(self, buf: HarvestBuffer, now_us: int,
+                 force: bool) -> Optional[Dict[str, np.ndarray]]:
+        """Take what the ring and the batcher hold into ``buf``: one round
+        of ``afxdp_poll`` + ``poll_batch`` per segment, until a round
+        leaves with less than a whole shim batch (the ring ran dry) or the
+        buffer is full. The first round follows the batcher's own rule
+        (full, timed out, or ``force``); once the harvest holds rows it
+        takes the batcher's tail with it. → the submission: the view of
+        the smallest bucket that holds the rows, its unpolled segments
+        reset; None when nothing was ready. ``buf.counts`` has each shim
+        batch's records. A poll that fails after the first round ends the
+        harvest there: the shim already holds the earlier rounds'
+        FrameRefs, so they leave as they are."""
+        shim, counts = self.shim, buf.counts
+        counts.clear()
+        rings = self._rings_attached()
+        for seg in buf.segments:
+            try:
+                if rings:
+                    rc = shim.afxdp_poll(self._poll_budget, now_us=now_us)
+                    if rc < 0:
+                        log.debug("afxdp_poll -> %d", rc)
+                got = shim.poll_batch(now_us=now_us,
+                                      force=force or bool(counts), out=seg)
+            except Exception as e:   # noqa: BLE001
+                if not counts:
+                    raise            # nothing taken yet: _step's to count
+                if isinstance(e, FaultInjected):
+                    self._count_fault()
+                else:
+                    self._count_error("poll failed mid-harvest")
+                break
+            if got is None:
+                break
+            counts.append(int(shim.last_poll_rows))
+            if counts[-1] < shim.batch_size:
+                break
+        if not counts:
+            return None
+        rows = sum(counts)
+        view = next((buf.views[b] for b in self.buckets if b >= rows),
+                    buf.view)
+        buf.view = view
+        polled = len(counts) * shim.batch_size
+        if polled < len(view["valid"]):
+            # segments this harvest did not reach still hold an older one
+            reset_batch_rows(view, polled, len(view["valid"]))
+        return view
+
+    def _held_back(self) -> Optional[str]:
+        """What the next harvest has to wait for, or None. ``"dispatch"``:
+        our newest submission is still undispatched (in the pipeline's
+        queue, or staged and waiting for its flush; only the newest can
+        be, since none is made while one is). Past that it depends on
+        what the last harvest found. A full one says the ring holds more
+        than a harvest can take: the next opens at once, so that the
+        worker dispatches it while the device has the one before. A
+        partial one says the ring ran dry, so nothing is gained by a
+        second submission in flight, and it costs a dispatch of its own:
+        the next waits for ``"verdicts"``, those of every harvest still
+        out, and takes what came in meanwhile in one piece."""
+        if not self._pending:
+            return None
+        t = self._pending[-1][0]
+        if t is not None and not t.done() \
+                and getattr(t, "dispatched_mono", 0.0) is None:
+            return "dispatch"
+        return None if self._last_full else "verdicts"
+
+    def _wait_for_worker(self, what: str) -> None:
+        """Sleep until the pipeline wakes us — our newest ticket dispatched,
+        or one of ours resolved, whose verdicts the next step applies at
+        once — or an idle-sleep has passed (the net under a wake-up that
+        ``stop`` or a stand-in's ticket never sends)."""
+        self._held = what
+        # clear, look again, then sleep: a wake-up sent after the clear is
+        # seen by the wait, one sent before it by the second look
+        self._wake.clear()
+        if self._stop.is_set() or self._held_back() != what \
+                or self._pending[0][0] is None \
+                or self._pending[0][0].done():
+            return
+        self._wake.wait(self._idle_sleep_s or 0.0005)
+
+    def _count_fault(self) -> None:
+        self.harvest_faults += 1
+        self.metrics.inc_counter("feeder_harvest_faults_total")
 
     def _acquire_buffer(self):
         if self._free:
@@ -590,18 +814,21 @@ class ShimFeeder:
 
     def _apply_one(self, ticket, buf, recycle: bool = True,
                    ingest_mono: Optional[float] = None) -> None:
-        """Apply one batch's verdicts (``ticket is None``: the rejected-
-        at-submit sentinel — all-drop, fail closed). ``recycle=False``
-        sheds the buffer instead of pooling it — for tickets that did NOT
-        resolve: the pipeline may still stage from the buffer later."""
+        """Apply one harvest's verdicts: one ``apply_verdicts`` per shim
+        batch it took, in poll order, each with its own rows
+        (``ticket is None``: the rejected-at-submit sentinel — all-drop for
+        every one of them, fail closed). ``recycle=False`` sheds the buffer
+        instead of pooling it — for tickets that did NOT resolve: the
+        pipeline may still stage from the buffer later."""
         rejected = True
-        allow = self._zeros
+        allow = None
+        view = buf.view
         if ticket is not None:
             try:
                 out = ticket.result(timeout=0)
-                allow = out["allow"]
+                allow = np.asarray(out["allow"])
                 rejected = False
-                self._note_established(buf, out)
+                self._note_established(view, out)
                 if self._fqdn is not None:
                     # in-band DNS learning tap: rows whose verdict carried
                     # the DNS L7 redirect get their response payload parsed
@@ -610,18 +837,22 @@ class ShimFeeder:
                     # proxy never raises and never touches ``allow``, so a
                     # broken parser can only lose learning, never the reply
                     # (the fail-open contract; fault point ``fqdn.parse``).
-                    self._fqdn.observe_batch(buf, out)
+                    self._fqdn.observe_batch(view, out)
             except Exception:   # noqa: BLE001 — drop/shed/unavailable
                 pass
-        try:
-            self.shim.apply_verdicts(allow)
-        except Exception:   # noqa: BLE001
-            log.exception("apply_verdicts failed; frame/verdict FIFO may "
-                          "be desynced")
+        bs = self.shim.batch_size
+        for k, n in enumerate(buf.counts):
+            try:
+                self.shim.apply_verdicts(
+                    self._zeros if allow is None
+                    else allow[k * bs:k * bs + n])
+            except Exception:   # noqa: BLE001
+                log.exception("apply_verdicts failed; frame/verdict FIFO "
+                              "may be desynced")
         if not rejected and ingest_mono is not None:
             # verdict-apply is the END of the serving path for this batch:
             # harvest stamp → here is the true ingest→verdict latency
-            self._observe_e2e(time.monotonic() - ingest_mono, buf)
+            self._observe_e2e(time.monotonic() - ingest_mono, view)
         if rejected:
             self.rejected_batches += 1
             self.metrics.inc_counter("feeder_rejected_batches_total")

@@ -467,3 +467,553 @@ class TestFeederSoak:
         assert stats["applied_batches"] == stats["harvested_batches"]
         assert feeder.stats()["pending"] == 0
         shim.close()
+
+
+# --------------------------------------------------------------------------- #
+# A harvest takes what the ring holds and waits for the worker (PR 32)
+# --------------------------------------------------------------------------- #
+def big_shim(batch_size=16, ring=256):
+    """16-row shim batches on a 256-frame ring: with two dispatches in
+    flight the ceiling is 256 / 4 = 64 rows, four polls a harvest."""
+    shim = FlowShim(batch_size=batch_size, timeout_us=100)
+    shim.register_endpoint("192.168.1.10", 1)
+    shim.mock_rings_init(ring_size=ring, frame_size=2048, n_frames=ring)
+    return shim
+
+
+def manual_feeder(shim, eng, **kw):
+    """A feeder whose thread is not started: the test calls ``_step``."""
+    from cilium_tpu.shim.feeder import ShimFeeder
+    kw.setdefault("min_bucket", 16)
+    kw.setdefault("max_rows", 64)
+    return ShimFeeder(shim, eng, metrics=eng.metrics, **kw)
+
+
+def step_until(fd, cond, force=False, deadline_s=10.0):
+    end = time.time() + deadline_s
+    while not cond():
+        fd._step(force=force)
+        if time.time() > end:
+            raise TimeoutError(f"feeder never got there: {fd.stats()}")
+
+
+def frames_of(n, first=0, allow=lambda i: True):
+    """Frame i is i bytes longer than the shortest and goes to the allowed
+    port, or to a denied one."""
+    return [build_frame("192.168.1.10", "10.1.2.3", 20000 + first + i,
+                        443 if allow(first + i) else 80,
+                        payload=b"h" * (first + i)) for i in range(n)]
+
+
+def tx_lens(shim):
+    return [ln for _a, ln in shim.mock_tx_drain(1024)]
+
+
+class _Submits:
+    """The engine, with every submission's row count written down and an
+    outage on request (``submit`` raises while ``down``)."""
+
+    def __init__(self, eng):
+        self.eng, self.rows, self.down = eng, [], False
+        self.metrics = eng.metrics
+
+    @property
+    def active(self):
+        return self.eng.active
+
+    def submit(self, batch, ingest_mono=None):
+        if self.down:
+            raise RuntimeError("pipeline unavailable (test)")
+        self.rows.append((len(batch["valid"]), int(batch["valid"].sum())))
+        return self.eng.submit(batch, ingest_mono=ingest_mono)
+
+
+@pytest.mark.parametrize("ring,batch,inflight,max_rows,want", [
+    (4096, 256, 2, 8192, 1024),     # every cell of the benchmark
+    (4096, 256, 2, 512, 512),       # never above the largest bucket
+    (4096, 256, 6, 8192, 512),      # more in flight, less a harvest
+    (1024, 256, 2, 8192, 256),
+    (256, 16, 2, 64, 64),
+    (64, 16, 2, 64, 16),
+    (100, 256, 2, 8192, 256),       # never under one shim batch
+    (0, 16, 2, 64, 16),             # no rings: one shim batch
+    (4096, 24, 2, 8192, 768),       # whole shim batches, a power of two
+])
+def test_harvest_ceiling(ring, batch, inflight, max_rows, want):
+    from cilium_tpu.shim.feeder import harvest_ceiling
+    assert harvest_ceiling(ring, batch, inflight, max_rows) == want
+
+
+class TestHarvestTakesWhatTheRingHolds:
+    @pytest.mark.parametrize("polls", [1, 2, 4])
+    def test_kth_verdict_is_kth_accepted_frame(self, polls):
+        """One harvest of 1, 2 and 4 shim polls (the last one partial):
+        one submission, one ``apply_verdicts`` a shim batch, and the
+        frames forwarded are the allowed ones in ring order."""
+        eng = fake_engine(pipeline_min_bucket=16)
+        shim = big_shim()
+        fd = manual_feeder(shim, eng)
+        assert fd.harvest_rows == 64 and fd.buckets == (16, 32, 64)
+        n = 16 * polls - 5
+        allow = lambda i: i % 3 != 1
+        for f in frames_of(n, allow=allow):
+            assert shim.mock_rx_inject(f) == 0
+        step_until(fd, lambda: fd.harvested_batches == 1)
+        (_t, buf, _m), = fd._pending
+        assert buf.counts == [16] * (polls - 1) + [11]
+        assert len(buf.view["valid"]) == 16 * polls and polls in (1, 2, 4)
+        assert shim.stats()["batches_emitted"] == polls
+        step_until(fd, lambda: fd.applied_batches == 1)
+        st = fd.stats()
+        assert (st["harvested_batches"], st["harvested_polls"],
+                st["harvested_records"]) == (1, polls, n)
+        assert tx_lens(shim) == [BASE_LEN + i for i in range(n) if allow(i)]
+        got = shim.stats()
+        assert got["verdict_passes"] + got["verdict_drops"] == n
+        assert not shim._pending_counts
+        c = eng.metrics.counters
+        b = 16 * polls
+        assert c[f'feeder_harvests_total{{bucket="{b}"}}'] == 1
+        assert c[f'feeder_harvest_rows_total{{bucket="{b}"}}'] == n
+        assert c[f'feeder_harvest_polls_total{{bucket="{b}"}}'] == polls
+        eng.stop()
+        shim.close()
+
+    @pytest.mark.parametrize("rows,bucket", [
+        (1, 16), (16, 16), (17, 32), (33, 64), (64, 64)])
+    def test_partial_harvest_submits_the_smallest_bucket(self, rows, bucket):
+        """The submission is the view of the buffer's first rows at the
+        smallest bucket that holds the harvest, and what the harvest did
+        not write there is invalid, whatever the buffer held before."""
+        eng = fake_engine(pipeline_min_bucket=16)
+        sub = _Submits(eng)
+        shim = big_shim()
+        fd = manual_feeder(shim, sub)
+        for buf in fd._free:
+            for k, col in buf.items():
+                col[:] = True if col.dtype == bool else 7
+        for f in frames_of(rows):
+            assert shim.mock_rx_inject(f) == 0
+        step_until(fd, lambda: fd.applied_batches == 1, force=True)
+        assert sub.rows == [(bucket, rows)]
+        reasons = eng.pipeline_stats()["flush_reasons"]
+        assert reasons.pop("direct") == 1 and not any(reasons.values())
+        assert tx_lens(shim) == [BASE_LEN + i for i in range(rows)]
+        eng.stop()
+        shim.close()
+
+    def test_rejected_harvest_drops_every_shim_batch_in_fifo_position(self):
+        """A submission the pipeline refuses still owes the shim one
+        verdict batch per poll of its harvest, all-drop, after the harvest
+        before it and before the one behind it."""
+        eng = fake_engine(pipeline_min_bucket=16)
+        sub = _Submits(eng)
+        shim = big_shim()
+        fd = manual_feeder(shim, sub)
+        sizes, first = (20, 40, 30), 0        # 2, 3 and 2 polls
+        for k, n in enumerate(sizes):
+            for f in frames_of(n, first=first):
+                assert shim.mock_rx_inject(f) == 0
+            sub.down = k == 1
+            step_until(fd, lambda: fd.harvested_batches == k + 1,
+                       force=True)
+            ticket, buf, _m = fd._pending[-1]
+            assert len(buf.counts) == (2, 3, 2)[k] and sum(buf.counts) == n
+            assert (ticket is None) == sub.down
+            first += n
+        step_until(fd, lambda: fd.applied_batches == 3)
+        assert tx_lens(shim) == [BASE_LEN + i for i in range(20)] \
+            + [BASE_LEN + i for i in range(60, 90)]
+        st = shim.stats()
+        assert (st["verdict_passes"], st["verdict_drops"]) == (50, 40)
+        assert fd.stats()["rejected_batches"] == 1
+        assert not shim._pending_counts
+        eng.stop()
+        shim.close()
+
+    @pytest.mark.parametrize("n", [11, 50, 200])
+    def test_stop_drains_multi_poll_buffers(self, n):
+        """stop() takes what ring and batcher still hold, several polls a
+        buffer, and applies every verdict: no stranded FrameRefs."""
+        eng = fake_engine(pipeline_min_bucket=16)
+        shim = big_shim()
+        feeder = eng.start_feeder(shim)
+        assert feeder.harvest_rows == 64
+        for f in frames_of(n):
+            assert shim.mock_rx_inject(f) == 0
+        eng.stop()
+        st = shim.stats()
+        assert st["verdict_passes"] == n and st["verdict_drops"] == 0
+        assert tx_lens(shim) == [BASE_LEN + i for i in range(n)]
+        assert not shim._pending_counts
+        fs = feeder.stats()
+        assert fs["applied_batches"] == fs["harvested_batches"]
+        assert fs["harvested_records"] == n and fs["pending"] == 0
+        shim.close()
+
+    @pytest.mark.parametrize("extras", [
+        {}, {"n_shards": 4}, {"qos": True}, {"fqdn": True},
+        {"n_shards": 4, "qos": True, "fqdn": True}])
+    def test_optional_columns_have_the_buffers_length(self, extras):
+        """Host-RSS, QoS and DNS columns are as long as the buffer, and a
+        submission carries the view of their first rows."""
+        class _Qos:
+            def map_tenants(self, raw):
+                return np.asarray(raw) % 3
+
+            def name_of(self, t):
+                return f"t{t}"
+
+        class _Dns:
+            payload_width = 64
+
+            def observe_batch(self, buf, out):
+                assert len(buf["_dns_len"]) == len(out["allow"])
+
+        eng = fake_engine(pipeline_min_bucket=16)
+        sub = _Submits(eng)
+        shim = big_shim()
+        kw = dict(n_shards=extras.get("n_shards", 1),
+                  qos=_Qos() if extras.get("qos") else None,
+                  fqdn=_Dns() if extras.get("fqdn") else None)
+        fd = manual_feeder(shim, sub, **kw)
+        want = {"_prio"} | ({"_shard"} if "n_shards" in extras else set()) \
+            | ({"_tenant"} if "qos" in extras else set()) \
+            | ({"_dns_payload", "_dns_len"} if "fqdn" in extras else set())
+        for buf in fd._free:
+            assert want <= set(buf)
+            assert {k for k in buf if k.startswith("_")} \
+                == want | {"_ep_raw", "_frame_idx"}
+            assert all(len(col) == fd.harvest_rows == 64
+                       for col in buf.values())
+            for b, view in buf.views.items():
+                assert set(view) == set(buf)
+                assert all(len(col) == b for col in view.values())
+                assert all(np.shares_memory(view[k], buf[k]) for k in buf)
+        for f in frames_of(20):
+            assert shim.mock_rx_inject(f) == 0
+        step_until(fd, lambda: fd.harvested_batches == 1, force=True)
+        (_t, buf, _m), = fd._pending
+        assert all(len(col) == 32 for col in buf.view.values())
+        if "n_shards" in extras:
+            assert (buf.view["_shard"][:20] != 0).all()     # pre-binned
+        if "qos" in extras:
+            assert (buf.view["_tenant"][:20] == 1).all()    # ep 1 % 3
+        step_until(fd, lambda: fd.applied_batches == 1)
+        assert shim.stats()["verdict_passes"] == 20
+        eng.stop()
+        shim.close()
+
+
+class _SlowEngine:
+    """A stand-in whose worker takes its time: a submission stays
+    undispatched until ``serve`` gets to it."""
+
+    def __init__(self, eng):
+        self.eng = eng
+        self.metrics = eng.metrics
+        self.tickets = []
+        self.most_undispatched = 0
+
+    @property
+    def active(self):
+        return self.eng.active
+
+    def submit(self, batch, ingest_mono=None):
+        from cilium_tpu.pipeline.scheduler import Ticket
+        t = Ticket(len(batch["valid"]), int(batch["valid"].sum()))
+        self.tickets.append(t)
+        self.most_undispatched = max(
+            self.most_undispatched,
+            sum(1 for x in self.tickets
+                if x.dispatched_mono is None and not x.done()))
+        return t
+
+    def serve(self, stop, pace_s=0.004):
+        served = 0
+        while not stop.is_set() or served < len(self.tickets):
+            if served == len(self.tickets):
+                time.sleep(0.0005)
+                continue
+            t = self.tickets[served]
+            time.sleep(pace_s)               # the queue
+            t.dispatched_mono = time.monotonic()
+            t._wake()
+            time.sleep(pace_s)               # the device
+            t._resolve({"allow": np.ones(t.n_rows, bool),
+                        "status": np.zeros(t.n_rows, np.int32)})
+            served += 1
+
+
+class TestHarvestWaitsForTheWorker:
+    def test_at_most_one_submission_undispatched(self):
+        """With a slow worker the feeder never has two submissions
+        waiting: frames wait in the ring and leave together, several polls
+        a harvest, and the deferrals are counted."""
+        import threading
+        eng = fake_engine()
+        slow = _SlowEngine(eng)
+        shim = big_shim()
+        fd = manual_feeder(shim, slow).start()
+        stop = threading.Event()
+        worker = threading.Thread(target=slow.serve, args=(stop,))
+        worker.start()
+        n = 600
+        try:
+            drained = []
+            inject_all(shim, frames_of(n), drain_to=drained)
+            wait_verdicts(shim, n, drain_to=drained)
+        finally:
+            fd.stop()
+            stop.set()
+            worker.join(10)
+        eng.stop()
+        drained.extend(shim.mock_tx_drain(1024))
+        assert [ln for _a, ln in drained] == [BASE_LEN + i for i in range(n)]
+        st = fd.stats()
+        assert slow.most_undispatched == 1
+        assert st["deferred_harvests"] >= 1
+        assert eng.metrics.counters["feeder_harvest_deferred_total"] \
+            == st["deferred_harvests"] <= st["harvested_batches"]
+        assert st["harvested_polls"] > st["harvested_batches"]
+        assert st["harvested_records"] == n
+        assert st["pending"] == 0 and not shim._pending_counts
+        shim.close()
+
+    @pytest.mark.parametrize("first,regime", [(20, "verdicts"),
+                                              (64, "dispatch")])
+    def test_what_a_harvest_waits_for_follows_the_one_before(self, first,
+                                                             regime):
+        """Undispatched, the newest submission holds the next harvest back
+        whatever its size. Dispatched, a full one (the ring held more than
+        a harvest takes) lets the next go at once, so that host and device
+        work together; a partial one (the ring ran dry) holds it until its
+        verdicts are back, and the frames that came meanwhile leave in one
+        piece. Each is counted by what it waited for."""
+        from cilium_tpu.pipeline.scheduler import Ticket
+
+        class _Hand:
+            """Tickets the test dispatches and resolves by hand."""
+            active = None
+            tickets = []
+
+            def submit(self, batch, ingest_mono=None):
+                self.tickets.append(
+                    Ticket(len(batch["valid"]), int(batch["valid"].sum())))
+                return self.tickets[-1]
+
+        eng = fake_engine()
+        hand = _Hand()
+        hand.active, hand.metrics, hand.tickets = eng.active, eng.metrics, []
+        shim = big_shim()
+        fd = manual_feeder(shim, hand)
+        for f in frames_of(first):
+            assert shim.mock_rx_inject(f) == 0
+        step_until(fd, lambda: fd.harvested_batches == 1)
+        assert sum(fd._pending[-1][1].counts) == first
+        for f in frames_of(10, first=first):      # these come meanwhile
+            assert shim.mock_rx_inject(f) == 0
+        t1 = hand.tickets[0]
+        assert fd._held_back() == "dispatch"
+        for _ in range(5):
+            fd._step(force=False)
+        assert fd.harvested_batches == 1          # held back
+        t1.dispatched_mono = time.monotonic()
+        t1._wake()
+        if regime == "dispatch":
+            assert fd._held_back() is None
+            step_until(fd, lambda: fd.harvested_batches == 2)
+            assert not t1.done()                  # two of ours in flight
+        else:
+            assert fd._held_back() == "verdicts"
+            for _ in range(5):
+                fd._step(force=False)
+            assert fd.harvested_batches == 1      # still held back
+        t1._resolve({"allow": np.ones(t1.n_rows, bool),
+                     "status": np.zeros(t1.n_rows, np.int32)})
+        step_until(fd, lambda: fd.harvested_batches == 2
+                   and fd.applied_batches >= 1)
+        c = eng.metrics.counters
+        assert c[f'feeder_harvest_deferred_total{{for="{regime}"}}'] == 1
+        assert c["feeder_harvest_deferred_total"] == 1 \
+            == fd.stats()["deferred_harvests"]
+        for t in hand.tickets[1:]:
+            t._resolve({"allow": np.ones(t.n_rows, bool),
+                        "status": np.zeros(t.n_rows, np.int32)})
+        step_until(fd, lambda: not fd._pending, force=True)
+        assert shim.stats()["verdict_passes"] == first + 10
+        assert tx_lens(shim) == [BASE_LEN + i for i in range(first + 10)]
+        eng.stop()
+        shim.close()
+
+    def test_a_harvest_that_finds_nothing_waiting_is_not_a_deferral(self):
+        """Held back or not, a harvest counts as deferred only if frames
+        were there when the worker let it go."""
+        eng = fake_engine(pipeline_min_bucket=16)
+        shim = big_shim()
+        fd = manual_feeder(shim, eng)
+        for k in range(5):
+            for f in frames_of(10, first=10 * k):
+                assert shim.mock_rx_inject(f) == 0
+            step_until(fd, lambda: fd.harvested_batches == k + 1)
+            # held for this one's verdicts, with an empty ring behind it
+            step_until(fd, lambda: fd.applied_batches == k + 1)
+            fd._step(force=False)                 # let go: nothing to take
+        assert fd.stats()["deferred_harvests"] == 0
+        assert "feeder_harvest_deferred_total" not in eng.metrics.counters
+        eng.stop()
+        shim.close()
+
+    def test_pacing_signals_under_a_short_switch_interval(self):
+        """Feeder, pipeline worker and NIC side racing with the interpreter
+        switching every 10 µs: the dispatched stamp and the wake-up are
+        read and written across threads, and a lost one would strand a
+        harvest (frames without a verdict) or reorder them."""
+        import sys
+        eng = fake_engine(pipeline_min_bucket=16)
+        shim = big_shim()
+        feeder = eng.start_feeder(shim)
+        n, drained = 1500, []          # frame i is 54 + i bytes of 2,048
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            inject_all(shim, frames_of(n), drain_to=drained,
+                       deadline_s=60.0)
+            wait_verdicts(shim, n, deadline_s=60.0, drain_to=drained)
+        finally:
+            sys.setswitchinterval(old)
+            st = feeder.stats()
+            eng.stop()
+        drained.extend(shim.mock_tx_drain(1024))
+        assert [ln for _a, ln in drained] == [BASE_LEN + i for i in range(n)]
+        assert st["harvested_records"] == n and st["rejected_batches"] == 0
+        assert st["harvested_polls"] >= st["harvested_batches"]
+        assert not shim._pending_counts
+        shim.close()
+
+    def test_pipeline_marks_a_ticket_dispatched_and_wakes_its_producer(self):
+        """The signal the feeder reads: ``dispatched_mono`` is set when the
+        worker has handed the rows to the device, and the ticket's waker
+        fires then and again when it resolves."""
+        import threading
+        eng = fake_engine()
+        from cilium_tpu.kernels.records import empty_batch
+        batch = empty_batch(64)
+        batch["valid"][:3] = True
+        batch["ep_slot"][:] = 0
+        t = eng.submit(batch)
+        t.waker = threading.Event()
+        t.result(timeout=10)
+        assert t.dispatched_mono is not None
+        assert t.submitted_mono <= t.dispatched_mono <= time.monotonic()
+        assert t.waker.is_set()
+        none = empty_batch(64)                  # nothing valid: no dispatch
+        t0 = eng.submit(none)
+        t0.result(timeout=10)
+        assert t0.dispatched_mono is None and t0.done()
+        eng.stop()
+
+
+# --------------------------------------------------------------------------- #
+# Every shape the feeder can dispatch is warm before start_feeder returns
+# --------------------------------------------------------------------------- #
+class _Compiles:
+    """Every XLA backend compile (or cache load) of this process."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.n = 0
+
+    def __call__(self, event, _secs, **_kw):
+        self.n += event == self.EVENT
+
+    def __enter__(self):
+        import jax.monitoring as mon
+        mon.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc):
+        import jax.monitoring as mon
+        mon.unregister_event_duration_listener(self)
+
+
+@pytest.mark.parametrize("mesh", [
+    {}, {"n_shards": 4, "rss_mode": "device"}],
+    ids=["one-device", "device-rss-4"])
+def test_no_harvest_size_compiles_after_start_feeder(mesh):
+    """The jit-audited engine (parity auditor on every batch) on one device
+    and on four virtual ones under device RSS: ``start_feeder`` compiles
+    the ladder 16, 32, 64 on rows that open no flow; after it a harvest of
+    any size from 1 to the ceiling compiles nothing, and every verdict is
+    the oracle's."""
+    from benchmarks.worlds import podrules
+    from cilium_tpu.runtime.datapath import JITDatapath
+    cfg = DaemonConfig(ct_capacity=8192, auto_regen=False, device="cpu",
+                       batch_size=64, pipeline_min_bucket=16,
+                       flowlog_mode="none", audit_enabled=True,
+                       audit_sample_rate=1.0, audit_pool_batches=64, **mesh)
+    eng = Engine(cfg, datapath=JITDatapath(cfg))
+    eng.auditor.configure(sample_rate=1.0)
+    world = podrules.build({"builder": "podrules", "n_ids": 16,
+                            "n_rules": 64, "port_span": 32})
+    world.load(eng)
+    eng.regenerate()
+    shim = FlowShim(batch_size=16, timeout_us=100)
+    world.register(shim)
+    shim.mock_rings_init(ring_size=256, frame_size=2048, n_frames=256)
+    try:
+        from benchmarks.frames import columns_of, frames_of as wire_frames
+        rng = np.random.default_rng(3200000101)
+        ceiling = 64
+        n_frames = ceiling * (ceiling + 1) // 2
+        flows = world.allowed_flows(rng, 64 + n_frames, 1, 2)
+        flows["sport"] = (20000 + np.arange(64 + n_frames)).astype(np.int32)
+        table, lens = wire_frames(flows, world.ep_v4, world.ep_v6_words)
+        # some live state first: the warm-up must leave it as it is
+        slot = eng.active.snapshot.ep_slot_of[world.ep_id]
+        opened = columns_of({k: v[:64] for k, v in flows.items()},
+                            world.ep_v4, world.ep_v6_words, slot)
+        assert eng.submit(opened).result(timeout=120)["allow"].all()
+        live = eng.ct_stats()["live"]
+        assert live == 64
+        with _Compiles() as compiles:
+            feeder = eng.start_feeder(shim)
+            warmed = compiles.n
+            feeder.stop()          # the thread's; the test steps from here
+            assert feeder.buckets == (16, 32, 64)
+            assert warmed >= 2     # 64 rows was compiled by the submit
+            assert eng.ct_stats()["live"] == live
+            sent = 64              # the flows opened above
+            for rows in range(1, ceiling + 1):
+                for i in range(sent, sent + rows):
+                    frame = table[i, :lens[i]].tobytes()
+                    assert shim.mock_rx_inject(frame) == 0
+                h = feeder.harvested_batches
+                step_until(feeder, lambda: feeder.harvested_batches == h + 1,
+                           force=True)
+                assert sum(feeder._pending[-1][1].counts) == rows
+                step_until(feeder, lambda: not feeder._pending, force=True)
+                shim.mock_tx_drain(1024)
+                sent += rows
+            assert compiles.n == warmed, "a harvest compiled under traffic"
+        st = shim.stats()
+        # a flow whose probe window is full is refused a slot, and counted
+        full = int(eng.metrics.insert_fail)
+        assert full <= 8
+        assert (st["verdict_passes"], st["verdict_drops"]) \
+            == (n_frames - full, full)
+        assert eng.ct_stats()["live"] == 64 + n_frames - full
+        assert eng.drain(timeout=60)
+        for _ in range(200):
+            step = eng.audit_step(budget=128)
+            if not step or (not step.get("replayed")
+                            and not step.get("pending")):
+                break
+        audit = eng.auditor.stats()
+        assert audit["checked_rows"] > 0, audit
+        assert audit["mismatched_rows"] == 0, audit
+        assert feeder.stats()["harvested_batches"] == ceiling
+    finally:
+        eng.stop()
+        shim.close()
