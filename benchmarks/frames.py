@@ -10,10 +10,25 @@ A set of flows is a dict of equal-length arrays:
     proto   [n] int32       6 TCP, 17 UDP
     is_v6   [n] bool
 
-Every flow is ingress to the one local endpoint; its two addresses (v4 and
-v6) come from the world. TCP segments carry ACK and no payload, UDP
-datagrams no payload: the smallest frames, where per-packet cost is all
-there is.
+and, where the world states them, any of:
+
+    egress       [n] bool        the flow leaves the local endpoint
+    payload      [n, k] uint8    bytes after the TCP header, and
+    payload_len  [n] int32       how many of the k each flow carries
+    http_method  [n] int32       what the shim's request-line tokenizer
+    http_path    [n, 64] uint8   makes of that payload (columns only)
+
+``src`` is the **peer's** address. The one local endpoint's two addresses
+(v4 and v6) come from the world. A flow is ingress to it, peer → endpoint,
+unless ``egress`` says it leaves: then the frame carries the endpoint's
+address as its source and the peer's as its destination, which is how the
+shim tells the direction (``flowshim.cc``: source match → egress). ``sport``
+and ``dport`` are the frame's own either way.
+
+Without ``payload`` TCP segments carry ACK and nothing else, UDP datagrams
+nothing: the smallest frames, where per-packet cost is all there is, in a
+table of ``FRAME_STRIDE`` bytes a row. With it a row is as long as the
+payload column's width needs.
 """
 
 from __future__ import annotations
@@ -25,8 +40,9 @@ import numpy as np
 PROTO_TCP = 6
 PROTO_UDP = 17
 TCP_ACK = 0x10
+DIR_EGRESS = 0                  # cilium_tpu/utils/constants.py
 DIR_INGRESS = 1
-FRAME_STRIDE = 80               # 74 bytes is the longest frame built here
+FRAME_STRIDE = 80               # 74 bytes is the longest frame with no payload
 
 Flows = Dict[str, np.ndarray]
 
@@ -56,16 +72,50 @@ def _be(words: np.ndarray, n: int) -> np.ndarray:
         .reshape(n, -1)
 
 
+def _addresses(flows: Flows, ep_v4: int, ep_v6_words) -> Tuple[np.ndarray,
+                                                               np.ndarray]:
+    """→ (source, destination) [n, 4] words of each flow's frame: peer →
+    endpoint, or endpoint → peer where the flow set says ``egress``."""
+    n = flows["sport"].shape[0]
+    peer = flows["src"]
+    ep = np.where(flows["is_v6"].astype(bool)[:, None],
+                  np.asarray(ep_v6_words, np.uint32)[None, :],
+                  v4_words(np.full((n,), ep_v4, np.uint32)))
+    if "egress" not in flows:
+        return peer, ep
+    out = flows["egress"].astype(bool)[:, None]
+    return np.where(out, ep, peer), np.where(out, peer, ep)
+
+
+def _payload_len(flows: Flows) -> np.ndarray:
+    """[n] int64 payload bytes of each frame (TCP segments only)."""
+    n = flows["sport"].shape[0]
+    if "payload" not in flows:
+        return np.zeros((n,), np.int64)
+    plen = flows["payload_len"].astype(np.int64)
+    if ((plen < 0) | (plen > flows["payload"].shape[1])).any():
+        raise ValueError("payload_len outside the payload column")
+    if (plen[flows["proto"] != PROTO_TCP] != 0).any():
+        raise ValueError("only a TCP segment carries a payload here")
+    return plen
+
+
 def frames_of(flows: Flows, ep_v4: int, ep_v6_words) -> Tuple[np.ndarray,
                                                                np.ndarray]:
-    """→ (table [n, FRAME_STRIDE] uint8, length [n] uint16): Ethernet II,
-    IPv4 (20-byte header) or IPv6 (40), then TCP (20) or UDP (8)."""
+    """→ (table [n, stride] uint8, length [n] uint16): Ethernet II, IPv4
+    (20-byte header) or IPv6 (40), then TCP (20, and the payload where the
+    flow set has one) or UDP (8). The stride is ``FRAME_STRIDE``, or the
+    next multiple of 16 that holds the payload column's width."""
     n = flows["sport"].shape[0]
     v6 = flows["is_v6"].astype(bool)
     udp = flows["proto"] == PROTO_UDP
-    tab = np.zeros((n, FRAME_STRIDE), dtype=np.uint8)
+    src, dst = _addresses(flows, ep_v4, ep_v6_words)
+    plen = _payload_len(flows)
+    width = flows["payload"].shape[1] if "payload" in flows else 0
+    stride = max(FRAME_STRIDE, -(-(74 + width) // 16) * 16)
+    tab = np.zeros((n, stride), dtype=np.uint8)
     tab[:, :12] = np.frombuffer(_ETH, dtype=np.uint8)
-    l4_len = np.where(udp, 8, 20).astype(np.int64)
+    l4_len = np.where(udp, 8, 20).astype(np.int64) + plen
     l3_len = np.where(v6, 40, 20).astype(np.int64)
     length = (14 + l3_len + l4_len).astype(np.uint16)
     sp = flows["sport"].astype(">u2").view(np.uint8).reshape(n, 2)
@@ -80,8 +130,8 @@ def frames_of(flows: Flows, ep_v4: int, ep_v6_words) -> Tuple[np.ndarray,
             .reshape(m, 2)
         tab[i4, 22] = 64                                   # ttl
         tab[i4, 23] = flows["proto"][i4]
-        tab[i4, 26:30] = _be(flows["src"][i4, 3:4], m)
-        tab[i4, 30:34] = _be(np.full((m, 1), ep_v4, np.uint32), m)
+        tab[i4, 26:30] = _be(src[i4, 3:4], m)
+        tab[i4, 30:34] = _be(dst[i4, 3:4], m)
     i6 = np.nonzero(v6)[0]
     if i6.size:
         m = i6.size
@@ -91,9 +141,8 @@ def frames_of(flows: Flows, ep_v4: int, ep_v6_words) -> Tuple[np.ndarray,
             .reshape(m, 2)
         tab[i6, 20] = flows["proto"][i6]                   # next header
         tab[i6, 21] = 64                                   # hop limit
-        tab[i6, 22:38] = _be(flows["src"][i6], m)
-        tab[i6, 38:54] = _be(np.tile(np.asarray(ep_v6_words, np.uint32),
-                                     (m, 1)), m)
+        tab[i6, 22:38] = _be(src[i6], m)
+        tab[i6, 38:54] = _be(dst[i6], m)
     l4 = 14 + l3_len
     rows = np.arange(n)
     for j in range(2):
@@ -106,6 +155,11 @@ def frames_of(flows: Flows, ep_v4: int, ep_v6_words) -> Tuple[np.ndarray,
     tab[t, l4[t] + 15] = 0xFF
     u = np.nonzero(udp)[0]
     tab[u, l4[u] + 5] = 8                                  # udp length
+    if width:
+        keep = np.arange(width)[None, :] < plen[:, None]
+        for family, at in ((~v6, 54), (v6, 74)):           # past the TCP header
+            r = np.nonzero(family & (plen > 0))[0]
+            tab[r, at:at + width] = np.where(keep[r], flows["payload"][r], 0)
     return tab, length
 
 
@@ -115,18 +169,18 @@ def columns_of(flows: Flows, ep_v4: int, ep_v6_words,
     shim would parse the frames above into)."""
     from cilium_tpu.kernels.records import empty_batch
     n = flows["sport"].shape[0]
-    v6 = flows["is_v6"].astype(bool)
     b = empty_batch(n)
-    b["src"][:] = flows["src"]
-    b["dst"][:] = np.where(v6[:, None],
-                           np.asarray(ep_v6_words, np.uint32)[None, :],
-                           v4_words(np.full((n,), ep_v4, np.uint32)))
+    b["src"][:], b["dst"][:] = _addresses(flows, ep_v4, ep_v6_words)
     b["sport"][:] = flows["sport"]
     b["dport"][:] = flows["dport"]
     b["proto"][:] = flows["proto"]
     b["tcp_flags"][:] = np.where(flows["proto"] == PROTO_TCP, TCP_ACK, 0)
-    b["is_v6"][:] = v6
-    b["direction"][:] = DIR_INGRESS
+    b["is_v6"][:] = flows["is_v6"].astype(bool)
+    b["direction"][:] = np.where(flows["egress"], DIR_EGRESS, DIR_INGRESS) \
+        if "egress" in flows else DIR_INGRESS
+    for k in ("http_method", "http_path"):
+        if k in flows:
+            b[k][:] = flows[k]
     b["ep_slot"][:] = ep_slot
     b["valid"][:] = True
     return b

@@ -1,7 +1,9 @@
-"""Mesh: self time of the span ``datapath.readback`` (the meshed
-finalizers' device→host reads of a batch's verdict columns and counters,
-first ``np.asarray`` to last) per batch, over the window. None where the
-program has no such span (one chip reads a slab; a program before PR 29)."""
+"""Mesh: self time of the span ``datapath.readback`` per batch, over the
+window: the meshed finalizers' one ``np.asarray`` of a batch's verdict slab
+(four per-chip segments in one sharded array, since PR 30). Since PR 32 the
+eager finalize enters that read while the chips still work, so the span
+holds the wait for the chips as well as the copy (PERF.md §3). None where
+the program has no such span (one chip; a program before PR 29)."""
 
 
 def read(run):

@@ -27,6 +27,12 @@ if REPO not in sys.path:
 DATA = os.path.join(HERE, "data")
 
 
+def tiny_config(name):
+    """A test-size configuration of ``data/configs``."""
+    with open(os.path.join(DATA, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
 @pytest.fixture(scope="session")
 def tiny_manifest():
     with open(os.path.join(DATA, "BENCHMARK.json")) as f:

@@ -7,7 +7,9 @@ at every point where the shim's counters stood still, the frames passed
 are exactly the plain reference's count of admitted frames among the first
 K accepted. In the open-loop cells harvests are a frame or two, so the
 points are nearly as many as the frames. One chip, and a 4-wide virtual
-mesh (host steering, un-steer on finalize).
+mesh (host steering, un-steer on finalize). ``tiny-cidrsvc`` is the world
+whose flows leave the endpoint: egress frames, prefixes of mixed length,
+services.
 """
 
 import json
@@ -45,12 +47,13 @@ def runs(tiny_manifest):
             ("tiny-dual.steady80", 4000000007, False),
             ("tiny-pods-mesh4.saturate", 31, False),
             ("tiny-pods.steady80", 77, True),
+            ("tiny-cidrsvc.saturate", 3000000019, False),
         )}
 
 
 @pytest.mark.parametrize("name", [
     "tiny-pods.saturate", "tiny-dual.steady80", "tiny-pods-mesh4.saturate",
-    "tiny-pods.steady80"])
+    "tiny-pods.steady80", "tiny-cidrsvc.saturate"])
 def test_result_line_and_fifo(runs, name):
     cell, r = runs[name]
     assert CONTRACT_KEYS <= set(r)
@@ -129,14 +132,16 @@ def test_wrong_table_comes_out_not_correct(runs):
     """The control: the reference with one exercised rule taken out fails
     the comparisons the sound reference passes."""
     for name in ("tiny-pods.saturate", "tiny-dual.steady80",
-                 "tiny-pods-mesh4.saturate"):
+                 "tiny-pods-mesh4.saturate", "tiny-cidrsvc.saturate"):
         c = runs[name][1]["control"]
         assert c["caught"] is True, (name, c)
         assert c["frames_on_it"] >= 16
         assert c["prefix_excess"] > 0 and c["passed_gap"] > 0
 
 
-def test_broken_timed_path_comes_out_not_correct(tiny_manifest):
+@pytest.mark.parametrize("name,seed", [("tiny-dual.steady80", 5),
+                                       ("tiny-cidrsvc.saturate", 8)])
+def test_broken_timed_path_comes_out_not_correct(tiny_manifest, name, seed):
     """A verdict altered where it is produced: the shim applies one wrong
     verdict in every 40th batch. The rest of the run is untouched."""
     def break_path(eng, shim):
@@ -150,8 +155,7 @@ def test_broken_timed_path_comes_out_not_correct(tiny_manifest):
             sound(allow)
         shim.apply_verdicts = apply
 
-    _cell, r = run(tiny_manifest, "tiny-dual.steady80", 5,
-                   break_path=break_path)
+    _cell, r = run(tiny_manifest, name, seed, break_path=break_path)
     n = numbers(r)
     assert not r["correct"]
     assert n["prefix_excess"]["value"] > 0 and not n["prefix_excess"]["ok"]

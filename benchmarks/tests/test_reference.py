@@ -6,7 +6,8 @@ import pytest
 
 from benchmarks import reference as ref
 from benchmarks.laws import flowmix
-from benchmarks.worlds import groupports, podrules
+from benchmarks.tests.conftest import tiny_config
+from benchmarks.worlds import cidrsvc, groupports, podrules
 
 LAW = {"live_share": 0.9, "zipf_s": 1.0, "new_allowed": 0.78,
        "new_denied": 0.18, "new_unknown": 0.04}
@@ -17,7 +18,8 @@ WORLDS = [
 ]
 
 
-@pytest.mark.parametrize("mod,params", WORLDS)
+@pytest.mark.parametrize("mod,params", WORLDS + [
+    (cidrsvc, tiny_config("tiny-cidrsvc")["world"])])
 def test_law_and_reference_agree_on_kinds(mod, params):
     w = mod.build(params)
     mix = flowmix.generate(LAW, w, np.random.default_rng(3), 1000, 20000)
