@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from cilium_tpu.compile.ct_layout import PROBE_DEPTH
+from cilium_tpu.compile.lpm import PFX_LEN_MASK
 from cilium_tpu.kernels import conntrack as ctk
 from cilium_tpu.kernels.l7 import l7_match_batch
 from cilium_tpu.kernels.lb import lb_step
@@ -43,6 +44,21 @@ from cilium_tpu.kernels.policy import policy_lookup_batch
 from cilium_tpu.utils import constants as C
 
 N_REASON_BINS = C.DROP_REASON_BINS   # counter-tensor geometry (one source)
+
+#: the two pre-CT kernels' names in every program's op metadata, so that a
+#: profiler trace's device time can be read by kernel (the trace's readers
+#: look for these strings; a program loaded from a compile cache written
+#: before the scopes existed carries none, the cache key leaves metadata out)
+SCOPE_LB = "lb.step"
+SCOPE_LPM = "lpm.walk"
+#: ... and of the three counters of that stage, so that what they cost the
+#: device can be read the same way
+SCOPE_TALLY = "pre_ct.tally"
+
+#: every key of the step's ``counters`` group (the meshed step psums and
+#: specs them by this list)
+COUNTER_KEYS = ("by_reason_dir", "insert_fail", "ct_evicted",
+                "lb_translated", "lb_no_backend", "lpm_rows")
 
 
 def compose_verdict(decision, enforced, cell_redirect, l7_fail,
@@ -133,6 +149,24 @@ def classify_interior_core(tensors, ep_slot, direction, id_idx, proto,
     return allow, reason, status, redirect, matched_rule
 
 
+def tally_pre_ct(valid0, translated, no_backend, pfx_meta):
+    """Rows the two pre-CT kernels answered, once a batch: ``lb_translated``
+    (DNAT'd to a backend), ``lb_no_backend`` (a frontend with none:
+    NO_SERVICE) and ``lpm_rows`` [C.LPM_PLEN_BINS], the rows valid at ingest
+    by the length of the prefix their walk matched (``C.LPM_MISS_BIN``: none
+    held the address, the world fallback)."""
+    with jax.named_scope(SCOPE_TALLY):
+        plen_bin = jnp.where(pfx_meta < 0, C.LPM_MISS_BIN,
+                             pfx_meta & PFX_LEN_MASK)
+        scat = jnp.where(valid0, plen_bin, C.LPM_PLEN_BINS)
+        return {
+            "lb_translated": translated.sum().astype(jnp.uint32),
+            "lb_no_backend": no_backend.sum().astype(jnp.uint32),
+            "lpm_rows": jnp.zeros((C.LPM_PLEN_BINS,), dtype=jnp.uint32).at[
+                scat].add(jnp.uint32(1), mode="drop"),
+        }
+
+
 def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
                     rule_axis=None, lb_probe_depth: int = 8, plan=None,
                     fused_interpret: bool = False,
@@ -148,7 +182,8 @@ def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
       ``svc`` / ``rev_nat`` / ``no_backend`` — the LB columns,
       ``id_idx`` / ``remote_identity`` / ``lpm_prefix`` — the LPM result
       + provenance (masked by the ORIGINAL valid, like classify_step),
-      ``fwd_keys`` / ``rev_keys`` — the post-DNAT CT key pair.
+      ``fwd_keys`` / ``rev_keys`` — the post-DNAT CT key pair,
+      ``tally`` — :func:`tally_pre_ct`'s three counters of this stage.
 
     ``split_interior=True`` additionally runs :func:`interior_pre_core`
     (ladder + L7, no compose) and adds ``decision``/``enforced``/
@@ -163,8 +198,9 @@ def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
     # tuple, exactly like the upstream from-container path.
     has_lb = "lb_tab_keys" in tensors
     if has_lb:
-        new_dst, new_dport, rev_nat, no_backend = lb_step(
-            tensors, batch, probe_depth=lb_probe_depth)
+        with jax.named_scope(SCOPE_LB):
+            new_dst, new_dport, rev_nat, no_backend = lb_step(
+                tensors, batch, probe_depth=lb_probe_depth)
         svc = rev_nat > 0
         batch = dict(batch)
         batch["dst"] = new_dst
@@ -182,16 +218,17 @@ def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
     # ((slot << 8) | plen, -1 on miss) in the same register chain
     remote_words = jnp.where((direction == C.DIR_EGRESS)[:, None],
                              batch["dst"], batch["src"])
-    if plan is not None and plan.lpm:
-        from cilium_tpu.kernels import fused as fk
-        id_idx, pfx_meta = fk.lpm_lookup_fused(
-            tensors["lpm_v4"], tensors["lpm_v6"], remote_words,
-            batch["is_v6"], world_index, v4_only=v4_only,
-            interpret=fused_interpret)
-    else:
-        id_idx, pfx_meta = lpm_lookup_prov_batch(
-            tensors["lpm_v4"], tensors["lpm_v6"], remote_words,
-            batch["is_v6"], default_index=world_index, v4_only=v4_only)
+    with jax.named_scope(SCOPE_LPM):
+        if plan is not None and plan.lpm:
+            from cilium_tpu.kernels import fused as fk
+            id_idx, pfx_meta = fk.lpm_lookup_fused(
+                tensors["lpm_v4"], tensors["lpm_v6"], remote_words,
+                batch["is_v6"], world_index, v4_only=v4_only,
+                interpret=fused_interpret)
+        else:
+            id_idx, pfx_meta = lpm_lookup_prov_batch(
+                tensors["lpm_v4"], tensors["lpm_v6"], remote_words,
+                batch["is_v6"], default_index=world_index, v4_only=v4_only)
     remote_identity = tensors["identity_ids"][id_idx].astype(jnp.uint32)
     # provenance masking follows the same truth the columns they explain
     # use: lpm_prefix for every row that was valid at ingest (NO_SERVICE
@@ -208,6 +245,7 @@ def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
         "no_backend": no_backend, "id_idx": id_idx,
         "remote_identity": remote_identity, "lpm_prefix": lpm_prefix,
         "fwd_keys": fwd_keys, "rev_keys": rev_keys,
+        "tally": tally_pre_ct(valid0, svc & valid, no_backend, pfx_meta),
     }
     if split_interior:
         decision, enforced, cell_redirect, l7_fail, mrule = \
@@ -310,7 +348,7 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
     rnat_sport [N] int32 (reply un-DNAT).
     counters: by_reason_dir [COUNTER_CELLS] uint32 (reasons x directions),
     insert_fail uint32 scalar, ct_evicted uint32 scalar (live entries
-    tail-evicted by saturated inserts).
+    tail-evicted by saturated inserts), and :func:`tally_pre_ct`'s three.
 
     ``fused=True`` routes the interior through the Pallas kernels of
     kernels/fused.py where each stage's static geometry permits
@@ -396,6 +434,7 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
         "by_reason_dir": by_reason_dir,
         "insert_fail": ct_full.sum().astype(jnp.uint32),
         "ct_evicted": n_evicted,
+        **pre["tally"],
     }
     remote_identity = pre["remote_identity"]
     lpm_prefix = pre["lpm_prefix"]

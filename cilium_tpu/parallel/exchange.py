@@ -324,6 +324,7 @@ def classify_step_exchange(tensors, ct, batch, now, world_index=0, *,
         # steered layout's per-chip sums produce
         "insert_fail": insert_fail,
         "ct_evicted": n_evicted,
+        **pre["tally"],
     }
     out = {
         "allow": allow,

@@ -414,7 +414,7 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True,
     import jax
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    from cilium_tpu.kernels.classify import OutSlab
+    from cilium_tpu.kernels.classify import COUNTER_KEYS, OutSlab
     from cilium_tpu.kernels.records import pack_out_jnp
 
     rule_sharded = mesh.shape["rules"] > 1
@@ -424,11 +424,8 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True,
         # counters are global: reduce over 'flows' only — along 'rules' the
         # batch is replicated and every shard computes identical counts
         # (summing there would multiply by the rules-axis size)
-        counters = {
-            "by_reason_dir": jax.lax.psum(counters["by_reason_dir"], "flows"),
-            "insert_fail": jax.lax.psum(counters["insert_fail"], "flows"),
-            "ct_evicted": jax.lax.psum(counters["ct_evicted"], "flows"),
-        }
+        counters = {k: jax.lax.psum(counters[k], "flows")
+                    for k in COUNTER_KEYS}
         if slab:
             # this chip's rows and its copy of the (now global) counters:
             # one segment of the mesh's slab, under one layout for all
@@ -448,8 +445,7 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True,
                  "redirect", "matched_rule", "lpm_prefix", "ct_state_pre",
                  "svc", "nat_dst", "nat_dport", "rnat",
                  "rnat_src", "rnat_sport")}
-    counters_spec = {"by_reason_dir": P(), "insert_fail": P(),
-                     "ct_evicted": P()}
+    counters_spec = {k: P() for k in COUNTER_KEYS}
     # the slab's words go out one contiguous segment a chip; the single
     # P('flows') is a prefix of the whole OutSlab (its layout is static
     # metadata of the output tree, as on one chip)

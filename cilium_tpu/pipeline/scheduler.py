@@ -1056,6 +1056,9 @@ class Pipeline:
             "flush_reasons": flush_reasons,
             "fill_rows": fill_rows,
             "bucket_rows": bucket_rows,
+            # rows whose verdicts are back (every finalize folds its batch
+            # into the shared registry), by what LB and LPM made of them
+            "verdict_rows": self.metrics.verdict_rows(),
             "shed_total": shed_total,
             "shed_reasons": shed_reasons,
             "unavailable_total": unavailable,

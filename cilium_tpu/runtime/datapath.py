@@ -33,6 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
+from cilium_tpu.compile.lpm import PFX_LEN_MASK
 from cilium_tpu.compile.snapshot import PolicySnapshot
 from cilium_tpu.kernels.records import unpack_out
 from cilium_tpu.observe.trace import (CT_GC_SPAN, PATCH_APPLY_SPAN,
@@ -1806,6 +1807,16 @@ class FakeDatapath(DatapathBackend):
                 self._ct_table.insert_fail - fail0)
             counters["ct_evicted"] = np.uint32(
                 self._ct_table.evicted - evicted0)
+            # the pre-CT kernels' rows, as kernels/classify.tally_pre_ct
+            # counts them, from the oracle's own columns
+            valid0 = np.asarray(batch["valid"], bool)
+            meta = out["lpm_prefix"][valid0]
+            counters["lb_translated"] = np.uint32(out["svc"].sum())
+            counters["lb_no_backend"] = np.uint32(
+                (out["reason"][valid0] == int(C.DropReason.NO_SERVICE)).sum())
+            counters["lpm_rows"] = np.bincount(
+                np.where(meta < 0, C.LPM_MISS_BIN, meta & PFX_LEN_MASK),
+                minlength=C.LPM_PLEN_BINS).astype(np.uint32)
             return out, counters
 
     def sweep(self, now: int) -> int:

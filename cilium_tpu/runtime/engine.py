@@ -469,6 +469,18 @@ class Engine:
         self.metrics.set_gauge("policy_image_bytes", snap.nbytes)
         self.metrics.set_gauge("engine_degraded", 0)
         self.metrics.set_gauge("regen_consecutive_failures", 0)
+        # what the LPM walk and the LB step were placed with (the resource
+        # ledger's ``hbm`` row holds the tries' bytes only in total)
+        lpm, lb = snap.lpm, snap.lb
+        self.metrics.set_gauges({
+            'lpm_trie_nodes{family="v4"}': lpm.v4_nodes.shape[0],
+            'lpm_trie_nodes{family="v6"}': lpm.v6_nodes.shape[0],
+            'lpm_trie_bytes{family="v4"}': lpm.v4_nodes.nbytes,
+            'lpm_trie_bytes{family="v6"}': lpm.v6_nodes.nbytes,
+            "lpm_prefixes": len(lpm.prefixes),
+            "lb_frontends": lb.n_frontends,
+            "lb_backends": len(lb.backends),
+        })
         # flight recorder: the revision trail is what makes a frozen bundle
         # attributable ("which policy world were these verdicts from")
         self.blackbox.record_event("regen", revision=snap.revision,

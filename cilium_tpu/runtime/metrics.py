@@ -143,6 +143,12 @@ class Metrics:
         self.by_reason_dir = np.zeros((C.COUNTER_CELLS,), dtype=np.uint64)
         self.insert_fail = 0
         self.ct_evicted = 0
+        # the pre-CT kernels' rows (kernels/classify.tally_pre_ct): DNAT'd
+        # to a backend, sent to a frontend with none, and walked, by the
+        # matched prefix length (last bin: no prefix, the world fallback)
+        self.lb_translated = 0
+        self.lb_no_backend = 0
+        self.lpm_rows = np.zeros((C.LPM_PLEN_BINS,), dtype=np.uint64)
         self.packets_total = 0
         self.batches_total = 0
         self.spans: Dict[str, SpanStat] = {}
@@ -179,8 +185,23 @@ class Metrics:
             # optional: legacy counter dicts (older backends, tests)
             # predate the insert-when-full eviction accounting
             self.ct_evicted += int(counters.get("ct_evicted", 0))
+            if "lpm_rows" in counters:
+                self.lb_translated += int(counters["lb_translated"])
+                self.lb_no_backend += int(counters["lb_no_backend"])
+                self.lpm_rows += np.asarray(counters["lpm_rows"])
             self.packets_total += n_valid
             self.batches_total += 1
+
+    def verdict_rows(self) -> Dict[str, int]:
+        """Rows verdicted so far, and how many of them each pre-CT kernel
+        answered which way, read at one instant (a delta of two reads is
+        exact: every batch folds all of them under the one lock)."""
+        with self._lock:
+            return {"total": self.packets_total,
+                    "lb_translated": self.lb_translated,
+                    "lb_no_backend": self.lb_no_backend,
+                    "lpm_walked": int(self.lpm_rows.sum()),
+                    "lpm_missed": int(self.lpm_rows[C.LPM_MISS_BIN])}
 
     def set_gauge(self, name: str, value: float) -> None:
         with self._lock:
@@ -234,6 +255,17 @@ class Metrics:
             lines.append(f"ciliumtpu_ct_insert_fail_total {self.insert_fail}")
             lines.append("# TYPE ciliumtpu_ct_evicted_total counter")
             lines.append(f"ciliumtpu_ct_evicted_total {self.ct_evicted}")
+            lines.append("# TYPE ciliumtpu_lb_translated_rows_total counter")
+            lines.append(
+                f"ciliumtpu_lb_translated_rows_total {self.lb_translated}")
+            lines.append("# TYPE ciliumtpu_lb_no_backend_rows_total counter")
+            lines.append(
+                f"ciliumtpu_lb_no_backend_rows_total {self.lb_no_backend}")
+            lines.append("# TYPE ciliumtpu_lpm_rows_total counter")
+            for b in np.nonzero(self.lpm_rows)[0]:
+                plen = "miss" if b == C.LPM_MISS_BIN else int(b)
+                lines.append(f'ciliumtpu_lpm_rows_total{{plen="{plen}"}} '
+                             f'{int(self.lpm_rows[b])}')
             lines.append("# TYPE ciliumtpu_packets_total counter")
             lines.append(f"ciliumtpu_packets_total {self.packets_total}")
             lines.append("# TYPE ciliumtpu_batches_total counter")

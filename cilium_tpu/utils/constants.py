@@ -147,6 +147,11 @@ class DropReason(enum.IntEnum):
 # aggregates the same shape on the host). Reason ids are an 8-bit field.
 DROP_REASON_BINS = 256
 COUNTER_CELLS = DROP_REASON_BINS * N_DIRECTIONS
+# ... and of the LPM walk's rows-by-matched-length counter: a bin for each
+# prefix length 0..128, then the miss bin (no prefix held the address: the
+# world fallback)
+LPM_PLEN_BINS = 130
+LPM_MISS_BIN = LPM_PLEN_BINS - 1
 
 if int(max(DropReason)) >= DROP_REASON_BINS:
     raise AssertionError(
