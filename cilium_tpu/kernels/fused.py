@@ -97,6 +97,8 @@ def _nbytes(a) -> int:
 #:   lpm:    ValueError: Shape mismatch in input, indices and output
 #:   ct:     NotImplementedError: Only 2D gather is supported
 #:   policy: NotImplementedError: Only 2D gather is supported
+#: The rank of a body's gathers does not change the refusal
+#: (kernels/policy.py).
 #: Until a body is rewritten (ROADMAP S7/D3 decide repair or deletion) the
 #: kernels run only under the Pallas interpreter, i.e. ``fused_kernels="on"``
 #: off-TPU — the CI configuration.

@@ -4,10 +4,16 @@ compile time by compile/policy_image.py).
 
 ``policy_core`` is the *fusable core*: pure jnp over the snapshot's tensor
 dict, shared verbatim by the XLA reference and the fused Pallas verdict
-kernel (kernels/fused.py). Every gather is explicitly clipped then
-flattened to a single-axis take — the clip reproduces jax's out-of-bounds
-clamp semantics exactly (so garbage rows cannot diverge between the two
-executors) and the flat form is the one gather shape Mosaic lowers.
+kernel (kernels/fused.py). Every row-derived index is explicitly clipped —
+the clip reproduces jax's out-of-bounds clamp semantics exactly (so garbage
+rows cannot diverge between the two executors) — and every table is
+gathered from in the shape it is placed in. A flattened take (``v.reshape(-1)[…]``)
+is not free on the TPU: the image's last dimension is no multiple of the
+128-lane tile, so XLA runs the reshape as a physical copy of the whole
+image in every batch (the chip's trace: 1.58 ms of every dispatch for
+``ct1m-50k``'s 100 MB, PERF.md §6 PR 35), and it buys nothing: Mosaic
+lowers none of the fused bodies, flat or not (kernels/fused.py:
+``TPU_COMPILED_STAGES``).
 
 Besides the cell, the lookup emits ``matched_rule``: the (id_class,
 port_class) coordinate of the resolved verdict cell, packed
@@ -35,17 +41,15 @@ def policy_core(tensors, ep_slot, direction, id_index, proto, dport):
     id_cls = tensors["id_class_of"][jnp.clip(id_index, 0, n_ids - 1)]
     fam = tensors["proto_family"][jnp.clip(proto, 0, 255)]
     n_ports = tensors["port_class"].shape[1]
-    pcls = tensors["port_class"].reshape(-1)[
-        fam * n_ports + jnp.clip(dport, 0, n_ports - 1)]
+    pcls = tensors["port_class"][fam, jnp.clip(dport, 0, n_ports - 1)]
     v = tensors["verdict"]
     n_eps, _, n_rows, n_cols = v.shape
     ep = jnp.clip(ep_slot, 0, n_eps - 1)
     d = jnp.clip(direction, 0, 1)
     cls = jnp.clip(id_cls, 0, n_rows - 1)
     pc = jnp.clip(pcls, 0, n_cols - 1)
-    cell = v.reshape(-1)[((ep * 2 + d) * n_rows + cls) * n_cols
-                         + pc].astype(jnp.int32)
-    enforced = tensors["enforced"].reshape(-1)[ep * 2 + d].astype(bool)
+    cell = v[ep, d, cls, pc].astype(jnp.int32)
+    enforced = tensors["enforced"][ep, d].astype(bool)
     decision = cell & C.VERDICT_DECISION_MASK
     l7_id = cell >> C.VERDICT_L7_SHIFT
     matched_rule = (id_cls * n_cols + pcls).astype(jnp.int32)

@@ -20,7 +20,11 @@ list and the rule parameters).
     datapath's program for this world; ``tiny-pods`` has no service, so
     its program has no LB step to name (``kernels/classify.py``:
     ``has_lb``) and carries ``lpm.walk`` alone, and both once a service is
-    upserted beside it;
+    upserted beside it; and in a program whose world has a policy image
+    the ladder's three placed tables (``verdict``, ``port_class``,
+    ``enforced``) reach their gather as the parameters they are placed
+    as (``kernels/policy.py``; PR 35: the flat take cost the chip a
+    copy of the whole image in every batch);
 (e) the counters ``ciliumtpu_lb_translated_rows_total``,
     ``ciliumtpu_lb_no_backend_rows_total`` and
     ``ciliumtpu_lpm_rows_total{plen}`` add up to the rows submitted, bin
@@ -34,6 +38,7 @@ list and the rule parameters).
 import copy
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -357,6 +362,26 @@ def test_the_programs_carry_the_kernels_names(programs, deployment, scope,
         # a gather under it: the walk's node reads, the LB table's probes
         assert any(f"/{scope}/" in line and "gather" in line
                    for line in text.splitlines())
+
+
+@pytest.mark.parametrize("table", ["verdict", "port_class", "enforced"])
+def test_the_ladder_gathers_from_the_placed_table_itself(programs, table):
+    """Every use of the table's parameter in ``@main`` is a
+    ``stablehlo.gather`` with the parameter as its operand: no reshape,
+    transpose, copy or call stands between the placed table and its
+    gather."""
+    main = programs["tiny-pods"].split("func.func public @main", 1)[1]
+    head, body = main.split("\n", 1)
+    body = body.split("func.func", 1)[0]
+    arg, = re.findall(
+        r"(%arg\d+): tensor<[^>]*> loc\(\"tensors\['" + table + r"'\]\"\)",
+        head)
+    uses = [line.strip() for line in body.splitlines()
+            if re.search(re.escape(arg) + r"\b", line)]
+    assert uses, table
+    for line in uses:
+        assert re.search(r'= "stablehlo\.gather"\(' + re.escape(arg) + ",",
+                         line), (table, line[:200])
 
 
 # -- (e) ---------------------------------------------------------------------
