@@ -385,6 +385,7 @@ class Traffic:
     table: np.ndarray               # [n_flows, stride] one frame a flow
     lens: np.ndarray
     want_flow: np.ndarray           # the plain reference's answer per flow
+    reason_flow: np.ndarray         # and its reason for a refusal of each
 
 
 def make_traffic(cell: Cell, world, rng, n_frames: int) -> Traffic:
@@ -399,7 +400,7 @@ def make_traffic(cell: Cell, world, rng, n_frames: int) -> Traffic:
     say("setup", traffic_s=round(time.monotonic() - t0, 2),
         flows=int(want.shape[0]), schedule=n_frames)
     return Traffic(mix["flows"], mix["n_live"], mix["sched_flow"], table,
-                   lens, want)
+                   lens, want, ref.refusal_reasons(world, mix["flows"]))
 
 
 def open_live_set(sv: Served, tr: Traffic, numbers: List[Dict]
@@ -576,6 +577,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         result["also"] = read_metrics(run, not traced)
         if not traced:
             result["window_prefixes"] = window_prefixes(run)
+        if "refused_for" in run.info:
+            result["refused_for"] = run.info["refused_for"]
         result["control"] = run.info.get("control")
         result["latency_samples"] = run.info.get("latency_samples")
         result["nic"] = {
@@ -644,8 +647,18 @@ def check(sv: Served, tr: Traffic, run: Run, before: Dict,
     N.append(C("passed_gap", abs(admitted - ct_full - passed_end), 0))
     N.append(C("reason_ok_gap",
                abs(int(reasons[ref.REASON_OK]) - passed_end), 0))
-    N.append(C("reason_policy_gap",
-               abs(int(reasons[ref.REASON_POLICY]) - (n_acc - admitted)), 0))
+    # a refusal's reason is the world's to state: REASON_POLICY's total
+    # always, every other reason's where the world states it of some flow
+    why_refused = tr.reason_flow[flow_of][~allow_acc]
+    refused_for = {}
+    for reason, name in ref.REFUSAL_GAPS.items():
+        if reason == ref.REASON_POLICY or (tr.reason_flow == reason).any():
+            refused_for[name] = int((why_refused == reason).sum())
+            N.append(C(name, abs(int(reasons[reason]) - refused_for[name]),
+                       0))
+    if len(refused_for) > 1:
+        # the frames each of those gaps is taken over
+        run.info["refused_for"] = refused_for
     N.append(C("reason_ct_full_gap",
                abs(int(reasons[ref.REASON_CT_FULL]) - ct_full), 0))
     N.append(C("ct_full_share", ct_full / max(1, admitted),

@@ -27,6 +27,13 @@ Three comparisons, each number printed beside its limit:
    or admitted flow must be ESTABLISHED, a refused one must have left no
    state and read NEW again).
 
+Why a frame is refused is the world's to state (``refusal_reasons``): its
+``reasons(flows)`` gives, for each flow, the drop reason the rule documents
+give a frame of it that the table does not admit; a world without the
+method means ``REASON_POLICY`` for every flow. The end totals are held
+reason by reason (``REFUSAL_GAPS`` names each reason's number) and the
+probe's rows each against its own flow's.
+
 The control is the same comparison with the reference handed a wrong
 table: one rule that the run's traffic exercised, and that alone admits
 its cell, is taken out. It has to come out as not correct.
@@ -39,8 +46,13 @@ from typing import Dict, List, Optional
 import numpy as np
 
 REASON_OK = 0
-REASON_POLICY = 130
+REASON_POLICY = 130        # no rule admits the frame
 REASON_CT_FULL = 137
+REASON_POLICY_L7 = 180     # the port's HTTP rules refuse the request
+#: the reasons a world may state for a refusal, and the compared number
+#: that holds each one's end total
+REFUSAL_GAPS = {REASON_POLICY: "reason_policy_gap",
+                REASON_POLICY_L7: "reason_policy_l7_gap"}
 STATUS_NEW = 0
 STATUS_ESTABLISHED = 1
 PROTO_TCP = 6
@@ -57,6 +69,21 @@ def expected_allow(world, flows, table: Optional[np.ndarray] = None
         table, _ = world.table()
     cell = world.cells(flows)
     return np.where(cell >= 0, table[np.maximum(cell, 0)], False)
+
+
+def refusal_reasons(world, flows) -> np.ndarray:
+    """[n_flows] int64: the drop reason the rule documents give a frame of
+    each flow if the table does not admit it."""
+    n = flows["sport"].shape[0]
+    if not hasattr(world, "reasons"):
+        return np.full((n,), REASON_POLICY, np.int64)
+    reasons = np.asarray(world.reasons(flows)).astype(np.int64)
+    known = np.isin(reasons, list(REFUSAL_GAPS))
+    if reasons.shape != (n,) or not known.all():
+        raise ValueError(f"world.reasons: one of {sorted(REFUSAL_GAPS)} a "
+                         f"flow, not {np.unique(reasons[~known]).tolist()} "
+                         f"in shape {reasons.shape}")
+    return reasons
 
 
 def wrong_table(world, flows, frames_per_flow: np.ndarray, rng,
@@ -118,8 +145,8 @@ def probe_check(world, flows, kind_live: np.ndarray, sent: np.ndarray,
     refused_now = np.asarray(out["ct_full"]).astype(bool)
     judged = ~refused_now
     bad_allow = judged & (allow != want)
-    bad_reason = judged & (reason != np.where(want, REASON_OK,
-                                              REASON_POLICY))
+    bad_reason = judged & (reason != np.where(
+        want, REASON_OK, refusal_reasons(world, flows)))
     tcp = flows["proto"] == PROTO_TCP
     # a denied flow must have left no state behind
     bad_state = judged & ~want & (status != STATUS_NEW)

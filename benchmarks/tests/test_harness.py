@@ -9,7 +9,8 @@ K accepted. In the open-loop cells harvests are a frame or two, so the
 points are nearly as many as the frames. One chip, and a 4-wide virtual
 mesh (host steering, un-steer on finalize). ``tiny-cidrsvc`` is the world
 whose flows leave the endpoint: egress frames, prefixes of mixed length,
-services.
+services. ``tiny-l7`` is the world whose refusals have two reasons: HTTP
+rule sets a port, a request line in every frame.
 """
 
 import json
@@ -18,7 +19,7 @@ import time
 import numpy as np
 import pytest
 
-from benchmarks import harness
+from benchmarks import harness, reference as ref
 from benchmarks.tests import stops
 from benchmarks.tests.conftest import DATA
 
@@ -48,12 +49,13 @@ def runs(tiny_manifest):
             ("tiny-pods-mesh4.saturate", 31, False),
             ("tiny-pods.steady80", 77, True),
             ("tiny-cidrsvc.saturate", 3000000019, False),
+            ("tiny-l7.saturate", 3000000021, False),
         )}
 
 
 @pytest.mark.parametrize("name", [
     "tiny-pods.saturate", "tiny-dual.steady80", "tiny-pods-mesh4.saturate",
-    "tiny-pods.steady80", "tiny-cidrsvc.saturate"])
+    "tiny-pods.steady80", "tiny-cidrsvc.saturate", "tiny-l7.saturate"])
 def test_result_line_and_fifo(runs, name):
     cell, r = runs[name]
     assert CONTRACT_KEYS <= set(r)
@@ -132,15 +134,128 @@ def test_wrong_table_comes_out_not_correct(runs):
     """The control: the reference with one exercised rule taken out fails
     the comparisons the sound reference passes."""
     for name in ("tiny-pods.saturate", "tiny-dual.steady80",
-                 "tiny-pods-mesh4.saturate", "tiny-cidrsvc.saturate"):
+                 "tiny-pods-mesh4.saturate", "tiny-cidrsvc.saturate",
+                 "tiny-l7.saturate"):
         c = runs[name][1]["control"]
         assert c["caught"] is True, (name, c)
         assert c["frames_on_it"] >= 16
         assert c["prefix_excess"] > 0 and c["passed_gap"] > 0
 
 
+#: the compared numbers of a run at the parent (66381af), in the result
+#: line's order: name, how it is held, and the limit where it is the same
+#: in every run
+PARENTS_NUMBERS = [
+    ("fill_table_gap", "max", 0), ("fill_denied", "max", 0),
+    ("unverdicted", "eq", 0), ("log_overflow", "max", 0),
+    ("stable_points", "min", 16), ("prefix_excess", "max", 0),
+    ("passed_gap", "max", 0), ("reason_ok_gap", "max", 0),
+    ("reason_policy_gap", "max", 0), ("reason_ct_full_gap", "max", 0),
+    ("ct_full_share", "max", 0.01), ("pipeline_faults", "max", 0),
+    ("feeder_faults", "max", 0), ("probe_rows", "min", 64),
+    ("probe_mismatched", "max", 0), ("probe_refused_now", "max", None),
+    ("probe_reopened", "max", None)]
+
+
+@pytest.mark.parametrize("name", [
+    "tiny-pods.saturate", "tiny-dual.steady80", "tiny-cidrsvc.saturate",
+    "tiny-pods-mesh4.saturate"])
+def test_a_world_that_states_no_reasons_has_the_parents_result_line(
+        runs, name):
+    """Names, order, how held and limits of ``numbers`` as the parent
+    printed them, and no key the parent's line lacked."""
+    _cell, r = runs[name]
+    got = [(n["name"], n["how"], n["limit"]) for n in r["numbers"]]
+    assert [g[:2] for g in got] == [p[:2] for p in PARENTS_NUMBERS]
+    assert all(g[2] == p[2] for g, p in zip(got, PARENTS_NUMBERS)
+               if p[2] is not None)
+    assert list(r)[-1] == "numbers" and "refused_for" not in r
+    assert set(r) == CONTRACT_KEYS | {
+        "compiles", "also", "window_prefixes", "control", "latency_samples",
+        "nic", "numbers"}
+
+
+def test_a_world_that_states_two_reasons_holds_each_total(runs):
+    """``tiny-l7``: refused frames of both reasons in the window, each
+    reason's end total exact, and the probe met refused rows of both."""
+    _cell, r = runs["tiny-l7.saturate"]
+    n = numbers(r)
+    got = [x["name"] for x in r["numbers"]]
+    at = got.index("reason_policy_gap")
+    assert got[at + 1] == "reason_policy_l7_gap"
+    assert got[:at + 1] + got[at + 2:] == [p[0] for p in PARENTS_NUMBERS]
+    assert n["reason_policy_gap"]["value"] == 0 \
+        and n["reason_policy_l7_gap"]["value"] == 0
+    assert n["reason_policy_l7_gap"]["limit"] == 0
+    over = r["refused_for"]
+    assert set(over) == {"reason_policy_gap", "reason_policy_l7_gap"}
+    assert over["reason_policy_gap"] > 100
+    assert over["reason_policy_l7_gap"] > 100
+    assert list(r)[-1] == "numbers"
+
+
+def swap_counted(monkeypatch, said, instead):
+    """The program's verdicts-by-reason with every ``said`` counted as
+    ``instead``: what a program does that drops the right frames and books
+    them under the other reason."""
+    sound = harness.reason_counts
+
+    def counts(eng):
+        c = sound(eng).copy()
+        c[instead] += c[said]
+        c[said] = 0
+        return c
+    monkeypatch.setattr(harness, "reason_counts", counts)
+
+
+def swap_answered(said, instead):
+    """``break_path``: every row ``Engine.submit`` answers the probe with
+    ``said`` reads ``instead`` (the feeder's submissions, which name their
+    ingest time, and so the rings' verdicts are untouched)."""
+    def break_path(eng, shim):
+        sound = eng.submit
+
+        class Swapped:
+            def __init__(self, ticket):
+                self.ticket = ticket
+
+            def result(self, timeout=None):
+                out = dict(self.ticket.result(timeout=timeout))
+                reason = np.array(out["reason"])
+                out["reason"] = np.where(reason == said, instead, reason)
+                return out
+        eng.submit = lambda batch, **kw: sound(batch, **kw) if kw \
+            else Swapped(sound(batch))
+    return break_path
+
+
+@pytest.mark.parametrize("said,instead", [(180, 130), (130, 180)])
+def test_a_reason_swapped_either_way_comes_out_not_correct(
+        tiny_manifest, monkeypatch, said, instead):
+    """Where the answer is produced (the probe's rows) and where it is
+    counted (the end totals): the right frames refused under the other
+    reason is not correct."""
+    _cell, r = run(tiny_manifest, "tiny-l7.saturate", 12 + said,
+                   break_path=swap_answered(said, instead))
+    n = numbers(r)
+    assert not r["correct"]
+    assert n["probe_mismatched"]["value"] > 0
+    assert [x["name"] for x in r["numbers"] if not x["ok"]] \
+        == ["probe_mismatched"]
+    swap_counted(monkeypatch, said, instead)
+    _cell, r = run(tiny_manifest, "tiny-l7.saturate", 14 + said)
+    n = numbers(r)
+    assert not r["correct"]
+    gap = r["refused_for"][ref.REFUSAL_GAPS[said]]
+    assert gap > 0
+    assert n["reason_policy_gap"]["value"] == gap \
+        == n["reason_policy_l7_gap"]["value"]
+    assert n["passed_gap"]["value"] == 0 and n["probe_mismatched"]["ok"]
+
+
 @pytest.mark.parametrize("name,seed", [("tiny-dual.steady80", 5),
-                                       ("tiny-cidrsvc.saturate", 8)])
+                                       ("tiny-cidrsvc.saturate", 8),
+                                       ("tiny-l7.saturate", 9)])
 def test_broken_timed_path_comes_out_not_correct(tiny_manifest, name, seed):
     """A verdict altered where it is produced: the shim applies one wrong
     verdict in every 40th batch. The rest of the run is untouched."""

@@ -5,9 +5,10 @@ import numpy as np
 import pytest
 
 from benchmarks import reference as ref
+from benchmarks.frames import concat
 from benchmarks.laws import flowmix
 from benchmarks.tests.conftest import tiny_config
-from benchmarks.worlds import cidrsvc, groupports, podrules
+from benchmarks.worlds import cidrsvc, groupports, httprules, podrules
 
 LAW = {"live_share": 0.9, "zipf_s": 1.0, "new_allowed": 0.78,
        "new_denied": 0.18, "new_unknown": 0.04}
@@ -19,7 +20,8 @@ WORLDS = [
 
 
 @pytest.mark.parametrize("mod,params", WORLDS + [
-    (cidrsvc, tiny_config("tiny-cidrsvc")["world"])])
+    (cidrsvc, tiny_config("tiny-cidrsvc")["world"]),
+    (httprules, tiny_config("tiny-l7")["world"])])
 def test_law_and_reference_agree_on_kinds(mod, params):
     w = mod.build(params)
     mix = flowmix.generate(LAW, w, np.random.default_rng(3), 1000, 20000)
@@ -135,6 +137,68 @@ def test_wrong_table_drops_one_exercised_rule():
     none, _ = ref.wrong_table(w, flows, np.zeros((300,)),
                               np.random.default_rng(2))
     assert none is None
+
+
+def sound_answers(w, flows):
+    """What a sound program answers the probe for ``flows`` it has never
+    seen: the reference's verdict, the world's reason, no state left by a
+    refused flow."""
+    want = ref.expected_allow(w, flows)
+    return {"allow": want,
+            "reason": np.where(want, ref.REASON_OK,
+                               ref.refusal_reasons(w, flows)),
+            "status": np.where(want, ref.STATUS_ESTABLISHED, ref.STATUS_NEW),
+            "ct_full": np.zeros(want.shape, bool)}
+
+
+def probe(w, flows, out):
+    n = flows["sport"].shape[0]
+    return ref.probe_check(w, flows, np.zeros((n,), bool),
+                           np.ones((n,), bool), np.zeros((n,), bool), out)
+
+
+@pytest.mark.parametrize("said,instead", [(180, 130), (130, 180)])
+def test_a_refusal_under_the_other_reason_is_a_mismatch(said, instead):
+    """The world says which reason a refused frame is dropped with: an
+    answer that refuses the right frames under the other one (a matcher
+    folded into the port lookup; every refusal counted as the request's)
+    fails the probe, row for row."""
+    w = httprules.build(tiny_config("tiny-l7")["world"])
+    rng = np.random.default_rng(7)
+    flows = concat([w.allowed_flows(rng, 200, 20000, 40000),
+                            w.denied_flows(rng, 300, 20000, 40000)])
+    stated = ref.refusal_reasons(w, flows)
+    refused = ~ref.expected_allow(w, flows)
+    assert (refused & (stated == 130)).sum() >= 30
+    assert (refused & (stated == 180)).sum() >= 30
+    out = sound_answers(w, flows)
+    assert probe(w, flows, out)["probe_mismatched"] == 0
+    swapped = dict(out, reason=np.where(out["reason"] == said, instead,
+                                        out["reason"]))
+    assert probe(w, flows, swapped)["probe_mismatched"] \
+        == int((refused & (stated == said)).sum())
+    assert not ref.verdict([ref.compare(
+        "probe_mismatched", probe(w, flows, swapped)["probe_mismatched"],
+        0)])
+
+
+def test_a_world_without_reasons_means_130_and_a_strange_one_is_refused():
+    w = podrules.build(WORLDS[0][1])
+    flows = w.denied_flows(np.random.default_rng(0), 50, 20000, 40000)
+    assert not hasattr(w, "reasons")
+    assert (ref.refusal_reasons(w, flows) == ref.REASON_POLICY).all()
+    out = sound_answers(w, flows)
+    assert probe(w, flows, out)["probe_mismatched"] == 0
+    l7 = dict(out, reason=np.full((50,), ref.REASON_POLICY_L7))
+    assert probe(w, flows, l7)["probe_mismatched"] == 50
+
+    class Strange:
+        def reasons(self, flows):
+            return np.full(flows["sport"].shape, 131)
+    with pytest.raises(ValueError):
+        ref.refusal_reasons(Strange(), flows)
+    assert set(ref.REFUSAL_GAPS) == {ref.REASON_POLICY,
+                                     ref.REASON_POLICY_L7}
 
 
 def test_compare():
