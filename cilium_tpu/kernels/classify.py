@@ -27,6 +27,7 @@ deterministic-winner semantics live in kernels/conntrack.py either way.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 from typing import Any
@@ -54,11 +55,15 @@ SCOPE_LPM = "lpm.walk"
 #: ... and of the three counters of that stage, so that what they cost the
 #: device can be read the same way
 SCOPE_TALLY = "pre_ct.tally"
+#: the L7 lane's match (``kernels/records.py`` names the path dictionary's
+#: unpacking ``l7.unpack``), in a program whose snapshot holds an L7 set
+SCOPE_L7 = "l7.match"
 
 #: every key of the step's ``counters`` group (the meshed step psums and
 #: specs them by this list)
 COUNTER_KEYS = ("by_reason_dir", "insert_fail", "ct_evicted",
-                "lb_translated", "lb_no_backend", "lpm_rows")
+                "lb_translated", "lb_no_backend", "lpm_rows",
+                "l7_checked", "l7_refused")
 
 
 def compose_verdict(decision, enforced, cell_redirect, l7_fail,
@@ -111,13 +116,37 @@ def interior_pre_core(tensors, ep_slot, direction, id_idx, proto,
     # tokens — new and established flows alike (the per-request proxy
     # semantics; CT entries carry no L7 state, so policy swaps need no
     # remap)
-    has_tokens = (http_method != C.HTTP_METHOD_ANY) \
-        | (http_path != 0).any(axis=-1)
+    has_tokens = has_l7_tokens(http_method, http_path)
     cell_redirect = decision == C.VERDICT_REDIRECT
     set_to_check = jnp.where(cell_redirect, l7_cell, 0)
-    l7_ok = l7_match_batch(tensors, set_to_check, http_method, http_path)
+    # set 0 is "none": a snapshot with no L7 set has a one-row rule tensor
+    # and nothing to name (as a program with no frontend has no lb.step)
+    with jax.named_scope(SCOPE_L7) if tensors["l7_methods"].shape[0] > 1 \
+            else contextlib.nullcontext():
+        l7_ok = l7_match_batch(tensors, set_to_check, http_method, http_path)
     l7_fail = has_tokens & (set_to_check > 0) & ~l7_ok
     return decision, enforced, cell_redirect, l7_fail, mrule
+
+
+def has_l7_tokens(http_method, http_path):
+    """[N] bool: the row carries a request (a method or a path byte)."""
+    return (http_method != C.HTTP_METHOD_ANY) | (http_path != 0).any(axis=-1)
+
+
+def tally_l7(http_method, http_path, valid, redirect, reason):
+    """Rows the L7 lane judged, once a batch, from the composed verdict's
+    own columns (so the fused interior, which hands back nothing else,
+    counts the same): ``l7_checked``, the valid rows that carry a request
+    and whose cell redirects to a rule set (``redirect`` is ``valid &
+    cell_redirect``, and a REDIRECT cell always names a set: ids are
+    1-based), and ``l7_refused``, those of them the set refused
+    (``l7_fail`` is the one way to reason POLICY_L7)."""
+    return {
+        "l7_checked": (redirect & has_l7_tokens(http_method, http_path)
+                       ).sum().astype(jnp.uint32),
+        "l7_refused": (valid & (reason == int(C.DropReason.POLICY_L7))
+                       ).sum().astype(jnp.uint32),
+    }
 
 
 def classify_interior_core(tensors, ep_slot, direction, id_idx, proto,
@@ -348,7 +377,8 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
     rnat_sport [N] int32 (reply un-DNAT).
     counters: by_reason_dir [COUNTER_CELLS] uint32 (reasons x directions),
     insert_fail uint32 scalar, ct_evicted uint32 scalar (live entries
-    tail-evicted by saturated inserts), and :func:`tally_pre_ct`'s three.
+    tail-evicted by saturated inserts), :func:`tally_pre_ct`'s three and
+    :func:`tally_l7`'s two.
 
     ``fused=True`` routes the interior through the Pallas kernels of
     kernels/fused.py where each stage's static geometry permits
@@ -411,6 +441,8 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
             pre["l7_fail"], est, reply, valid)
         matched_rule = jnp.where(valid & pre["enforced"], pre["mrule"],
                                  jnp.int32(-1)).astype(jnp.int32)
+    l7_tally = tally_l7(batch["http_method"], batch["http_path"], valid,
+                        redirect, reason)
     reason = jnp.where(no_backend, int(C.DropReason.NO_SERVICE), reason)
 
     # 6 + 6b-read. CT insert-when-full + aggregate apply + the batch-start
@@ -435,6 +467,7 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
         "insert_fail": ct_full.sum().astype(jnp.uint32),
         "ct_evicted": n_evicted,
         **pre["tally"],
+        **l7_tally,
     }
     remote_identity = pre["remote_identity"]
     lpm_prefix = pre["lpm_prefix"]

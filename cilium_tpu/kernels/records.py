@@ -316,17 +316,26 @@ def pack_batch_l7dict(b: BatchArrays, path_words: Optional[int] = None,
     return wire, dict_words
 
 
+#: the path dictionary's unpacking in a program's op metadata, so that a
+#: profiler trace's device time can be read for the L7 lane (the match is
+#: ``l7.match``, kernels/classify.py); every dictionary wire's path arm
+#: (l7-dict, address-dict) goes through the one function
+SCOPE_L7_UNPACK = "l7.unpack"
+
+
 def _unpack_dict_paths_jnp(dict_words, idx):
+    import jax
     import jax.numpy as jnp
-    words = dict_words[idx]                                # [N, P]
-    n = words.shape[0]
-    path = jnp.stack([(words >> 24) & 0xFF, (words >> 16) & 0xFF,
-                      (words >> 8) & 0xFF, words & 0xFF],
-                     axis=-1).reshape(n, -1).astype(jnp.uint8)
-    pad = C.L7_PATH_MAXLEN - path.shape[1]
-    if pad > 0:
-        path = jnp.pad(path, ((0, 0), (0, pad)))
-    return path
+    with jax.named_scope(SCOPE_L7_UNPACK):
+        words = dict_words[idx]                            # [N, P]
+        n = words.shape[0]
+        path = jnp.stack([(words >> 24) & 0xFF, (words >> 16) & 0xFF,
+                          (words >> 8) & 0xFF, words & 0xFF],
+                         axis=-1).reshape(n, -1).astype(jnp.uint8)
+        pad = C.L7_PATH_MAXLEN - path.shape[1]
+        if pad > 0:
+            path = jnp.pad(path, ((0, 0), (0, pad)))
+        return path
 
 
 def unpack_batch_l7dict_jnp(wire, dict_words):

@@ -47,6 +47,10 @@ DEFAULT_CAPACITY = 4096
 #: pipeline's stage spans.
 PATCH_APPLY_SPAN = "datapath.patch.apply"
 CT_GC_SPAN = "datapath.ct.gc"
+#: inside ``datapath.pack``, a batch on the L7 path-dictionary wire: the
+#: dictionary's build (JITDatapath._pack_wire; attrs rows, distinct,
+#: dict_rows, bytes)
+L7_DICT_SPAN = "datapath.pack.l7dict"
 
 
 class _NullSpan:

@@ -55,7 +55,7 @@ from cilium_tpu.compile.ct_layout import PROBE_DEPTH
 from cilium_tpu.kernels import conntrack as ctk
 from cilium_tpu.kernels.classify import (classify_pre_ct, compose_verdict,
                                          ct_update_stage, resolve_rev_nat,
-                                         tally_by_reason_dir)
+                                         tally_by_reason_dir, tally_l7)
 from cilium_tpu.kernels.hashing import hash_words_jnp
 from cilium_tpu.utils import constants as C
 
@@ -310,6 +310,8 @@ def classify_step_exchange(tensors, ct, batch, now, world_index=0, *,
         pre["l7_fail"], est, reply, valid)
     matched_rule = jnp.where(valid & pre["enforced"], pre["mrule"],
                              jnp.int32(-1)).astype(jnp.int32)
+    l7_tally = tally_l7(b["http_method"], b["http_path"], valid, redirect,
+                        reason)
     reason = jnp.where(no_backend, int(C.DropReason.NO_SERVICE), reason)
     allow = allow & ~ct_full
     reason = jnp.where(ct_full, int(C.DropReason.CT_FULL), reason)
@@ -325,6 +327,7 @@ def classify_step_exchange(tensors, ct, batch, now, world_index=0, *,
         "insert_fail": insert_fail,
         "ct_evicted": n_evicted,
         **pre["tally"],
+        **l7_tally,
     }
     out = {
         "allow": allow,

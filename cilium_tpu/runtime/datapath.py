@@ -36,7 +36,8 @@ from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
 from cilium_tpu.compile.lpm import PFX_LEN_MASK
 from cilium_tpu.compile.snapshot import PolicySnapshot
 from cilium_tpu.kernels.records import unpack_out
-from cilium_tpu.observe.trace import (CT_GC_SPAN, PATCH_APPLY_SPAN,
+from cilium_tpu.observe.trace import (CT_GC_SPAN, L7_DICT_SPAN,
+                                      PATCH_APPLY_SPAN,
                                       active as active_trace)
 from cilium_tpu.pipeline.guard import DeviceLost
 from cilium_tpu.runtime.config import DaemonConfig
@@ -426,6 +427,11 @@ class JITDatapath(DatapathBackend):
         self._wire_wide = False        # v6 or >14-bit ep_slot seen
         self._l7_path_words = 1
         self._l7_dict_rows = 1
+        # what the L7 path-dictionary wire carried so far (pack lock):
+        # distinct paths over every batch's dictionary, and the bytes of
+        # the dictionaries that went up (a content-cache hit uploads none)
+        self.l7_stats: Dict[str, int] = {"dict_paths": 0,
+                                         "dict_upload_bytes": 0}
         # zero-copy staging: a checkout/return pool of wire buffers the
         # pack kernels fill in place, keyed by (rows, words). A buffer may
         # be aliased by the backend until its batch finalizes
@@ -940,12 +946,26 @@ class JITDatapath(DatapathBackend):
                     f"pack_fallback_{fallback_reason}"] += 1
         try:
             if use_l7:
+                t0 = time.monotonic()
                 wire, path_dict = pack_batch_l7dict(
                     b, path_words=l7_path_words, min_rows=l7_min_rows,
                     force_full=use_wide, out=wire_buf)
+                took = time.monotonic() - t0
+                # either wire variant keeps a row's dictionary index in the
+                # low half of its last word, and np.unique numbers from 0
+                distinct = int((wire[:, -1] & 0xFFFF).max(initial=0)) + 1 \
+                    if n_rows else 0
                 with self._pack_lock:       # dict geometry stays grow-only
                     self._l7_dict_rows = max(self._l7_dict_rows,
                                              path_dict.shape[0])
+                    self.l7_stats["dict_paths"] += distinct
+                # the dictionary's build (one sort of the batch's paths)
+                # and the wire's columns, inside the caller's datapath.pack
+                tracer, trace_id = active_trace()
+                tracer.record(trace_id, L7_DICT_SPAN, t0, took, {
+                    "rows": n_rows, "distinct": distinct,
+                    "dict_rows": int(path_dict.shape[0]),
+                    "bytes": int(path_dict.nbytes)})
             elif not use_wide:
                 wire = pack_batch_v4(b, out=wire_buf)
             else:
@@ -1094,6 +1114,13 @@ class JITDatapath(DatapathBackend):
         with self._pack_lock:
             self._wire_out = max(0, self._wire_out - 1)
 
+    def l7_wire_stats(self) -> Dict[str, int]:
+        """The L7 dictionary wire's two totals and its grow-only geometry
+        (what the serving programs are traced at), read at one instant."""
+        with self._pack_lock:
+            return dict(self.l7_stats, path_words=self._l7_path_words,
+                        dict_rows=self._l7_dict_rows)
+
     def _upload_path_dict(self, path_dict: np.ndarray):
         """Device copy of the L7 path dict, cached by content: serving
         traffic repeats a small stable path set, so in steady state the
@@ -1119,6 +1146,7 @@ class JITDatapath(DatapathBackend):
             dev = self._jnp.asarray(path_dict)
         with self._pack_lock:
             self.pack_stats["upload_cache_misses"] += 1
+            self.l7_stats["dict_upload_bytes"] += int(path_dict.nbytes)
             # the dict is a fresh np.unique product (never pool-aliased):
             # safe to retain as the comparison baseline without a copy
             self._path_dict_host = path_dict
@@ -1817,6 +1845,14 @@ class FakeDatapath(DatapathBackend):
             counters["lpm_rows"] = np.bincount(
                 np.where(meta < 0, C.LPM_MISS_BIN, meta & PFX_LEN_MASK),
                 minlength=C.LPM_PLEN_BINS).astype(np.uint32)
+            # ... and the L7 lane's, as kernels/classify.tally_l7 does
+            tokens = (np.asarray(batch["http_method"])
+                      != C.HTTP_METHOD_ANY) \
+                | np.asarray(batch["http_path"]).any(axis=-1)
+            counters["l7_checked"] = np.uint32(
+                (out["redirect"] & tokens & valid0).sum())
+            counters["l7_refused"] = np.uint32(
+                (out["reason"][valid0] == int(C.DropReason.POLICY_L7)).sum())
             return out, counters
 
     def sweep(self, now: int) -> int:

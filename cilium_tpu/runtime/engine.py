@@ -2106,6 +2106,19 @@ class Engine:
                     if d:
                         self.metrics.inc_counter(f"rss_{k}", d)
                         self._pack_stats_seen[f"rss:{k}"] = ex[k]
+        # the L7 path-dictionary wire: distinct paths and uploaded bytes
+        # as counters (same delta-fold), its grow-only geometry as gauges
+        l7 = getattr(self.datapath, "l7_wire_stats", None)
+        if l7 is not None:
+            l7 = l7()
+            with self._pack_fold_lock:
+                for k in ("dict_paths", "dict_upload_bytes"):
+                    d = l7[k] - self._pack_stats_seen.get(f"l7:{k}", 0)
+                    if d:
+                        self.metrics.inc_counter(f"l7_{k}_total", d)
+                        self._pack_stats_seen[f"l7:{k}"] = l7[k]
+            self.metrics.set_gauges({"l7_path_words": l7["path_words"],
+                                     "l7_dict_rows": l7["dict_rows"]})
         # make_classify_fn memo cache (kernels/classify): size gauge +
         # eviction counter, folded only when the jax-backed module is
         # actually loaded — a fake-datapath engine must stay jax-free

@@ -149,6 +149,10 @@ class Metrics:
         self.lb_translated = 0
         self.lb_no_backend = 0
         self.lpm_rows = np.zeros((C.LPM_PLEN_BINS,), dtype=np.uint64)
+        # the L7 lane's rows (kernels/classify.tally_l7): requests held to
+        # their cell's rule set, and those of them the set refused
+        self.l7_checked = 0
+        self.l7_refused = 0
         self.packets_total = 0
         self.batches_total = 0
         self.spans: Dict[str, SpanStat] = {}
@@ -189,19 +193,25 @@ class Metrics:
                 self.lb_translated += int(counters["lb_translated"])
                 self.lb_no_backend += int(counters["lb_no_backend"])
                 self.lpm_rows += np.asarray(counters["lpm_rows"])
+            if "l7_checked" in counters:
+                self.l7_checked += int(counters["l7_checked"])
+                self.l7_refused += int(counters["l7_refused"])
             self.packets_total += n_valid
             self.batches_total += 1
 
     def verdict_rows(self) -> Dict[str, int]:
         """Rows verdicted so far, and how many of them each pre-CT kernel
-        answered which way, read at one instant (a delta of two reads is
-        exact: every batch folds all of them under the one lock)."""
+        and the L7 lane answered which way, read at one instant (a delta
+        of two reads is exact: every batch folds all of them under the one
+        lock)."""
         with self._lock:
             return {"total": self.packets_total,
                     "lb_translated": self.lb_translated,
                     "lb_no_backend": self.lb_no_backend,
                     "lpm_walked": int(self.lpm_rows.sum()),
-                    "lpm_missed": int(self.lpm_rows[C.LPM_MISS_BIN])}
+                    "lpm_missed": int(self.lpm_rows[C.LPM_MISS_BIN]),
+                    "l7_checked": self.l7_checked,
+                    "l7_refused": self.l7_refused}
 
     def set_gauge(self, name: str, value: float) -> None:
         with self._lock:
@@ -266,6 +276,12 @@ class Metrics:
                 plen = "miss" if b == C.LPM_MISS_BIN else int(b)
                 lines.append(f'ciliumtpu_lpm_rows_total{{plen="{plen}"}} '
                              f'{int(self.lpm_rows[b])}')
+            lines.append("# TYPE ciliumtpu_l7_checked_rows_total counter")
+            lines.append(
+                f"ciliumtpu_l7_checked_rows_total {self.l7_checked}")
+            lines.append("# TYPE ciliumtpu_l7_refused_rows_total counter")
+            lines.append(
+                f"ciliumtpu_l7_refused_rows_total {self.l7_refused}")
             lines.append("# TYPE ciliumtpu_packets_total counter")
             lines.append(f"ciliumtpu_packets_total {self.packets_total}")
             lines.append("# TYPE ciliumtpu_batches_total counter")

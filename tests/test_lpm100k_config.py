@@ -287,7 +287,9 @@ def test_rows_agree_with_the_programs_oracle(served, seed):
     assert c["rows"]["fake"] == {
         "total": BUCKET, "lpm_walked": BUCKET, "lb_no_backend": 0,
         "lb_translated": int((cell >= served.world.ipcache.addr.size).sum()),
-        "lpm_missed": int((cell < 0).sum())}
+        "lpm_missed": int((cell < 0).sum()),
+        # no document of this world has rules.http: the L7 lane checks none
+        "l7_checked": 0, "l7_refused": 0}
 
 
 # -- (d) ---------------------------------------------------------------------
