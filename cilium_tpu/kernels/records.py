@@ -263,9 +263,21 @@ def _pad_dict_rows(count: int, min_rows: int) -> int:
 def _pack_path_dict(paths: np.ndarray, path_words: Optional[int],
                     min_rows: int = 1) -> Tuple[np.ndarray, np.ndarray]:
     """[N, 64] uint8 → (dict_words [U_pow2, P] uint32, index [N] int64).
+    The distinct rows in ascending byte order, the index dense from 0.
     ``min_rows`` floors the padded row count (callers pin it grow-only so
     serving doesn't retrace when per-batch path diversity fluctuates)."""
-    uniq, idx = np.unique(paths, axis=0, return_inverse=True)
+    n, width = paths.shape
+    # a row is ONE 64-byte item to the sort: memcmp order over the item is
+    # the lexicographic order of its unsigned bytes, so dictionary and
+    # index are what np.unique(axis=0) gives, which sorts a structured view
+    # of 64 one-byte fields, field by field, at ~20x the time. The view
+    # needs whole rows in memory: a column view of a wider harvest buffer
+    # is copied (64 KB), never reinterpreted
+    rows = np.ascontiguousarray(paths, dtype=np.uint8)
+    items, idx = np.unique(
+        rows.view(np.dtype((np.void, width))).reshape(n),
+        return_inverse=True)
+    uniq = items.view(np.uint8).reshape(-1, width)
     if uniq.shape[0] > 65536:
         raise ValueError("path dictionary overflow (>64k unique paths)")
     if path_words is None:
@@ -273,12 +285,10 @@ def _pack_path_dict(paths: np.ndarray, path_words: Optional[int],
     path_words = min(path_words, C.L7_PATH_MAXLEN // 4)
     if uniq[:, 4 * path_words:].any():
         raise ValueError(f"path_words={path_words} truncates a path")
-    u_pad = _pad_dict_rows(uniq.shape[0], min_rows)
-    p = np.zeros((u_pad, 4 * path_words), dtype=np.uint32)
-    p[:uniq.shape[0]] = uniq[:, :4 * path_words]
-    p = p.reshape(u_pad, path_words, 4)
-    words = ((p[:, :, 0] << 24) | (p[:, :, 1] << 16)
-             | (p[:, :, 2] << 8) | p[:, :, 3])
+    words = np.zeros((_pad_dict_rows(uniq.shape[0], min_rows), path_words),
+                     dtype=np.uint32)
+    # b0<<24 | b1<<16 | b2<<8 | b3 is the big-endian uint32 of the four
+    words[:uniq.shape[0]] = uniq.view(">u4")[:, :path_words]
     return words, idx
 
 
@@ -291,8 +301,8 @@ def pack_batch_l7dict(b: BatchArrays, path_words: Optional[int] = None,
     (``force_full`` pins the full wire so serving paths don't flap formats
     batch-to-batch). ``out=`` fills a caller-owned wire buffer in place
     (its width must match the variant this batch selects; the path dict is
-    always fresh — ``np.unique`` allocates regardless, and the upload layer
-    dedups re-transfers by content instead)."""
+    a fresh array every batch, and the upload layer dedups re-transfers by
+    content instead)."""
     dict_words, idx = _pack_path_dict(b["http_path"], path_words, min_rows)
     n = b["valid"].shape[0]
     if not force_full and not b["is_v6"].any() \

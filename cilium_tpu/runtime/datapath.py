@@ -952,15 +952,16 @@ class JITDatapath(DatapathBackend):
                     force_full=use_wide, out=wire_buf)
                 took = time.monotonic() - t0
                 # either wire variant keeps a row's dictionary index in the
-                # low half of its last word, and np.unique numbers from 0
+                # low half of its last word, and the index is dense from 0
                 distinct = int((wire[:, -1] & 0xFFFF).max(initial=0)) + 1 \
                     if n_rows else 0
                 with self._pack_lock:       # dict geometry stays grow-only
                     self._l7_dict_rows = max(self._l7_dict_rows,
                                              path_dict.shape[0])
                     self.l7_stats["dict_paths"] += distinct
-                # the dictionary's build (one sort of the batch's paths)
-                # and the wire's columns, inside the caller's datapath.pack
+                # the dictionary's build (one sort of the batch's paths as
+                # 64-byte items) and the wire's columns, inside the caller's
+                # datapath.pack
                 tracer, trace_id = active_trace()
                 tracer.record(trace_id, L7_DICT_SPAN, t0, took, {
                     "rows": n_rows, "distinct": distinct,
@@ -1147,7 +1148,7 @@ class JITDatapath(DatapathBackend):
         with self._pack_lock:
             self.pack_stats["upload_cache_misses"] += 1
             self.l7_stats["dict_upload_bytes"] += int(path_dict.nbytes)
-            # the dict is a fresh np.unique product (never pool-aliased):
+            # the dict is a fresh array every batch (never pool-aliased):
             # safe to retain as the comparison baseline without a copy
             self._path_dict_host = path_dict
             self._path_dict_dev = dev

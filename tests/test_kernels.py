@@ -15,9 +15,13 @@ from cilium_tpu.kernels.l7 import l7_match_batch
 from cilium_tpu.kernels.lpm import lpm_lookup_batch
 from cilium_tpu.kernels.records import (PACK4_L7_WORDS, PACK4_WORDS,
                                         PACK_L7DICT_WORDS, PACK_WORDS,
-                                        ct_key_words, empty_batch,
-                                        pack_batch, pack_batch_l7dict,
-                                        pack_batch_v4)
+                                        _pack_path_dict, _pad_dict_rows,
+                                        _path_words_of, ct_key_words,
+                                        empty_batch, pack_batch,
+                                        pack_batch_addrdict,
+                                        pack_batch_l7dict, pack_batch_v4,
+                                        unpack_batch_addrdict_jnp,
+                                        unpack_batch_l7dict_jnp)
 from cilium_tpu.model.rules import HTTPRule
 from cilium_tpu.utils import constants as C
 from cilium_tpu.utils.ip import parse_addr
@@ -273,3 +277,176 @@ class TestPackOutVariants:
             pack_batch_v4(b, out=np.zeros((8, PACK_WORDS), np.uint32))
         with pytest.raises(ValueError):
             pack_batch_v4(b, out=np.zeros((8, PACK4_WORDS), np.int32))
+
+
+def _path_dict_by_fields(paths, path_words, min_rows=1):
+    """The plain reference: ``_pack_path_dict`` as it stood until PR 38,
+    a sort of the rows as 64 one-byte fields and the words by shifts."""
+    uniq, idx = np.unique(paths, axis=0, return_inverse=True)
+    if uniq.shape[0] > 65536:
+        raise ValueError("path dictionary overflow (>64k unique paths)")
+    if path_words is None:
+        path_words = _path_words_of(uniq)
+    path_words = min(path_words, C.L7_PATH_MAXLEN // 4)
+    if uniq[:, 4 * path_words:].any():
+        raise ValueError(f"path_words={path_words} truncates a path")
+    u_pad = _pad_dict_rows(uniq.shape[0], min_rows)
+    p = np.zeros((u_pad, 4 * path_words), dtype=np.uint32)
+    p[:uniq.shape[0]] = uniq[:, :4 * path_words]
+    p = p.reshape(u_pad, path_words, 4)
+    words = ((p[:, :, 0] << 24) | (p[:, :, 1] << 16)
+             | (p[:, :, 2] << 8) | p[:, :, 3])
+    return words, idx.reshape(-1)
+
+
+def _text_paths(n, pool, seed):
+    rng = np.random.default_rng(seed)
+    table = np.zeros((pool, C.L7_PATH_MAXLEN), dtype=np.uint8)
+    for i in range(pool):
+        p = f"/api/v{i % 200}/item/{i * 7919}".encode()
+        table[i, :len(p)] = np.frombuffer(p, np.uint8)
+    w = 1.0 / np.arange(1, pool + 1)
+    return table[rng.choice(pool, n, p=w / w.sum())]
+
+
+def _all_equal():
+    return np.tile(_text_paths(1, 1, 0), (1024, 1))
+
+
+def _all_distinct():
+    rng = np.random.default_rng(1)
+    rows = rng.integers(0, 256, (1024, C.L7_PATH_MAXLEN), dtype=np.uint8)
+    rows[:, :2] = np.arange(1024, dtype=np.uint16).view(
+        np.uint8).reshape(1024, 2)
+    return rows[rng.permutation(1024)]
+
+
+def _differ_in(byte):
+    rng = np.random.default_rng(2 + byte)
+    rows = np.full((256, C.L7_PATH_MAXLEN), ord("a"), dtype=np.uint8)
+    rows[:, byte] = rng.integers(0, 256, 256)
+    return rows
+
+
+def _high_bytes():
+    """0x7f / 0x80 / 0xff at the first, a middle and the last byte: a
+    signed compare puts 0x80 and 0xff before 0x7f."""
+    rows = np.full((27, C.L7_PATH_MAXLEN), 0x80, dtype=np.uint8)
+    for k, at in enumerate((0, 31, 63)):
+        rows[:, at] = np.array([0x7F, 0x80, 0xFF], np.uint8)[
+            (np.arange(27) // 3 ** k) % 3]
+    return rows[np.random.default_rng(3).permutation(27)]
+
+
+def _zeros_among():
+    rows = _text_paths(64, 40, 4)
+    rows[::5] = 0
+    return rows
+
+
+def _column_view():
+    """``http_path`` as a column of a wider row buffer (a harvest buffer's
+    layout): rows 208 bytes apart, so no 64-byte item view of it exists."""
+    buf = np.random.default_rng(5).integers(
+        0, 256, (96, 208), dtype=np.uint8)
+    buf[:, 100:164] = _text_paths(96, 30, 5)
+    view = buf[:, 100:164]
+    assert not view.flags["C_CONTIGUOUS"]
+    return view
+
+
+PATH_SETS = {
+    "all-equal": _all_equal,
+    "1024-distinct": _all_distinct,
+    "differ-in-byte-63": lambda: _differ_in(63),
+    "differ-in-byte-0": lambda: _differ_in(0),
+    "bytes-from-0x80": _high_bytes,
+    "zeros-among": _zeros_among,
+    "column-view": _column_view,
+    "zipf-1024": lambda: _text_paths(1024, 1500, 6),
+    "one-row": lambda: _text_paths(1, 3, 7),
+    "no-row": lambda: np.zeros((0, C.L7_PATH_MAXLEN), dtype=np.uint8),
+}
+
+
+class TestPathDict:
+    """``_pack_path_dict`` dedups a batch's rows as 64-byte items; the
+    field-by-field ``np.unique(axis=0)`` it replaced is the reference, and
+    dictionary words and index have to be the reference's bit for bit."""
+
+    @pytest.mark.parametrize("path_words", [None, 16])
+    @pytest.mark.parametrize("min_rows", [1, 2, 4096])
+    @pytest.mark.parametrize("name", PATH_SETS)
+    def test_words_and_index_are_the_field_sorts(self, name, min_rows,
+                                                 path_words):
+        paths = PATH_SETS[name]()
+        keep = paths.copy()
+        want_words, want_idx = _path_dict_by_fields(
+            paths, path_words, min_rows)
+        words, idx = _pack_path_dict(paths, path_words, min_rows)
+        assert words.dtype == np.uint32 and words.flags["C_CONTIGUOUS"]
+        np.testing.assert_array_equal(words, want_words)
+        np.testing.assert_array_equal(idx, want_idx)
+        np.testing.assert_array_equal(paths, keep)     # input untouched
+        distinct = len({r.tobytes() for r in paths})
+        assert words.shape[0] == _pad_dict_rows(distinct, min_rows)
+        if len(paths):
+            # dense from 0: _pack_wire reads distinct as max index + 1
+            assert int(idx.max()) + 1 == distinct
+            assert np.unique(idx).size == distinct
+
+    @pytest.mark.parametrize("name,path_words", [
+        ("differ-in-byte-63", 8), ("differ-in-byte-63", 15),
+        ("zipf-1024", 1), ("column-view", 2), ("bytes-from-0x80", 4)])
+    def test_too_few_path_words_raise(self, name, path_words):
+        paths = PATH_SETS[name]()
+        for fn in (_path_dict_by_fields, _pack_path_dict):
+            with pytest.raises(ValueError, match="truncates a path"):
+                fn(paths, path_words)
+
+    def test_short_paths_take_the_given_words(self):
+        paths = np.zeros((8, C.L7_PATH_MAXLEN), dtype=np.uint8)
+        paths[:, :3] = np.frombuffer(b"/ab", np.uint8)
+        paths[3:, 3] = ord("c")
+        for pw in (None, 1, 2, 16, 64):
+            words, idx = _pack_path_dict(paths, pw)
+            want_words, want_idx = _path_dict_by_fields(paths, pw)
+            np.testing.assert_array_equal(words, want_words)
+            np.testing.assert_array_equal(idx, want_idx)
+            assert words.shape == (2, 1 if pw is None else min(pw, 16))
+        assert words[0, 0] == 0x2F616200 and words[1, 0] == 0x2F616263
+
+    @staticmethod
+    def _batch_of(paths, seed=0):
+        n = paths.shape[0]
+        b = TestPackOutVariants._batch(n, seed=seed)
+        b["http_path"] = paths
+        b["http_method"][:] = np.random.default_rng(seed).integers(0, 9, n)
+        return b
+
+    @pytest.mark.parametrize("wire", ["l7dict-compact", "l7dict-full",
+                                      "addrdict"])
+    @pytest.mark.parametrize("name", PATH_SETS)
+    def test_every_wire_hands_the_device_the_rows_bytes(self, name, wire):
+        paths = PATH_SETS[name]()
+        b = self._batch_of(paths)
+        if wire == "addrdict":
+            parts = pack_batch_addrdict(b, l7=True)
+            assert len(parts) == 3
+            unpack = unpack_batch_addrdict_jnp
+        else:
+            full = wire == "l7dict-full"
+            parts = pack_batch_l7dict(b, force_full=full)
+            assert parts[0].shape[1] == (PACK_L7DICT_WORDS if full
+                                         else PACK4_L7_WORDS)
+            unpack = unpack_batch_l7dict_jnp
+        assert parts[0].shape[0] == len(paths)
+        if not len(paths):
+            # no bucket is empty, and the device's unpack takes no 0-row
+            # wire: the host's side alone, one all-zero dictionary row
+            assert parts[-1].shape[0] == 1 and not parts[-1].any()
+            return
+        got = unpack(*map(jnp.asarray, parts))
+        np.testing.assert_array_equal(np.asarray(got["http_path"]), paths)
+        np.testing.assert_array_equal(np.asarray(got["http_method"]),
+                                      b["http_method"])

@@ -379,6 +379,28 @@ def test_the_span_states_each_batchs_dictionary(served, seed):
         rows_before = a["dict_rows"]
 
 
+@pytest.mark.parametrize("seed", SEEDS)
+def test_the_span_counts_the_references_distinct_requests(served, seed):
+    """``distinct`` against the request lines themselves: each flow's path
+    read back from its frame's payload and cut to 64 bytes, counted as
+    Python ``bytes`` the way the plain reference holds a path; no sort of
+    the program's, no numpy ``unique``."""
+    from benchmarks.worlds.httprules import PATH_CUT
+    c = served.case(seed)
+    flows = c["flows"]
+    lines = [bytes(p[:n]) for p, n in zip(flows["payload"],
+                                          flows["payload_len"])]
+    paths = [line.split(b" ")[1][:PATH_CUT] for line in lines]
+    assert len(paths) == N_FLOWS and len(set(paths)) > 150
+    want = [len(set(paths[i:i + BUCKET]))
+            for i in range(0, N_FLOWS, BUCKET)] * 2
+    assert [s["attrs"]["distinct"] for s in c["spans"]] == want
+    # ... which are the paths the columns state, as the reference reads them
+    stated = np.ascontiguousarray(flows["http_path"]).view(
+        f"S{PATH_CUT}").reshape(-1)
+    assert [p.rstrip(b"\0") for p in paths] == stated.tolist()
+
+
 def test_the_counters_and_gauges_are_rendered(served):
     for seed in SEEDS:
         served.case(seed)
