@@ -960,19 +960,27 @@ def _cmd_trace(args) -> int:
     if st.get("spans_dropped_total"):
         print(f"** {st['spans_dropped_total']} spans lost to ring "
               f"wraparound ({st.get('ring_wraps', 0)} full wraps) — the "
-              "summary below covers only the surviving tail **")
+              "percentiles below cover only the surviving tail; the "
+              "columns under 'since start' cover every span **")
     summary = doc.get("summary", {})
     if summary:
-        print(f"{'stage':<24} {'count':>7} {'p50 ms':>10} {'p99 ms':>10} "
-              f"{'max ms':>10}")
+        print(f"{'stage':<28} {'thread':<20} {'count':>7} {'p50 ms':>10} "
+              f"{'p99 ms':>10} {'max ms':>10} | since start: "
+              f"{'count':>8} {'wall ms':>12}")
         for name, s in summary.items():
-            print(f"{name:<24} {s['count']:>7} {s['p50_ms']:>10.3f} "
-                  f"{s['p99_ms']:>10.3f} {s['max_ms']:>10.3f}")
+            a = s.get("since_start", {})
+            print(f"{name:<28} {s.get('thread', ''):<20} {s['count']:>7} "
+                  f"{s['p50_ms']:>10.3f} {s['p99_ms']:>10.3f} "
+                  f"{s['max_ms']:>10.3f} | "
+                  f"{'':<13}{a.get('count', 0):>8} "
+                  f"{a.get('total_ms', 0.0):>12.3f}")
     if args.spans:
         for sp in doc.get("spans", []):
             attrs = sp.get("attrs")
-            print(f"  trace={sp['trace_id']:<8} {sp['name']:<24} "
+            print(f"  trace={sp['trace_id']:<8} {sp['name']:<28} "
                   f"{sp['duration_ms']:.3f}ms"
+                  + f" {sp.get('kind', '')} on {sp.get('thread', '?')}"
+                  + (f" in {sp['parent']}" if sp.get("parent") else "")
                   + (f" {attrs}" if attrs else ""))
     return 0
 

@@ -18,6 +18,27 @@ the hot path. So:
   caller) enters ``context(trace_id)``; downstream layers (the datapath's
   pack/transfer/compute split) attach spans to whatever trace is current
   without any signature changes across the DatapathBackend boundary.
+- **A span says whose time it was.** Beside name, start and duration it
+  carries the recording thread's name, the name of the span that was open
+  on that thread when it opened (``parent``: self time is a span's
+  duration less its children's) and its ``kind``: ``work``, or ``wait``
+  for an interval in which the thread sleeps or that is recorded after
+  the fact. All of it is taken on the sampled path only.
+- **CPU time is the thread's, not the span's.** What a whole thread
+  burns is :func:`thread_cpu_s`, the thread's own CPU clock read from
+  whoever asks (``Pipeline.stats()``, ``ShimFeeder.stats()``), never on
+  the hot path. No span reads a CPU clock: under gVisor, where the
+  benchmark's machine runs the process, one ``time.thread_time()`` is a
+  6 µs system call (0.3 µs on plain Linux), the clock advances in 10 ms
+  ticks, and over millisecond spans that follow a sleep, wall less CPU
+  read 0.4 of the wall with nobody else wanting the interpreter lock
+  (``benchmarks/tests/host_facts.py --clock``): it is the host's time to
+  put the thread back on a core, not the lock's wait, so a span's CPU
+  time would say nothing there.
+- **Totals outlive the ring.** ``record`` adds (count, wall) to a dict by
+  name under the lock it already holds; the ring's wrap does not touch
+  it, so a reader takes a window's sums from ``totals()`` at its two ends
+  and the ring need not hold the run.
 
 One process-wide instance (``TRACER``) mirrors the ``FAULTS`` singleton so
 instrumentation points need no plumbing; independent ``Tracer`` objects
@@ -33,8 +54,20 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-#: span tuple layout: (trace_id, name, t0_monotonic, duration_s, attrs|None)
-_Span = Tuple[int, str, float, float, Optional[dict]]
+#: span tuple layout: (trace_id, name, t0_monotonic, duration_s, attrs|None,
+#: thread, parent|None, kind)
+_Span = Tuple[int, str, float, float, Optional[dict], str, Optional[str],
+              str]
+
+#: a span's ``kind``: the thread ran (or wanted to) all through it ...
+WORK = "work"
+#: ... or it slept in it (a device wait, a held-back harvest), or the
+#: interval was recorded after the fact from two stamps (a queue wait)
+WAIT = "wait"
+
+#: ``trace_id`` of a submission whose producer drew no sampling decision:
+#: the pipeline draws it. (None is a decision: drawn and not sampled.)
+UNDECIDED = -1
 
 DEFAULT_CAPACITY = 4096
 
@@ -69,29 +102,49 @@ _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("_tracer", "_tid", "_name", "_attrs", "_t0")
+    """One sampled span: pushes its name on the thread's stack of open
+    spans, which is what a span opened inside it records as its
+    ``parent``."""
 
-    def __init__(self, tracer: "Tracer", tid: int, name: str, attrs):
+    __slots__ = ("_tracer", "_tid", "_name", "_attrs", "_kind", "_stack",
+                 "_parent", "_t0")
+
+    def __init__(self, tracer: "Tracer", tid: int, name: str, attrs,
+                 kind: str):
         self._tracer = tracer
         self._tid = tid
         self._name = name
         self._attrs = attrs
+        self._kind = kind
 
     def __enter__(self):
+        stack = self._stack = _open_spans()
+        self._parent = stack[-1] if stack else None
+        stack.append(self._name)
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.record(self._tid, self._name, self._t0,
-                            time.monotonic() - self._t0, self._attrs)
+        dur = time.monotonic() - self._t0
+        self._stack.pop()
+        self._tracer._put(self._tid, self._name, self._t0, dur,
+                          self._attrs, self._parent, self._kind)
         return False
 
 
 #: thread-local trace context: (tracer, trace_id) of the innermost
-#: ``Tracer.context`` block. Module-level (not per-Tracer) so downstream
+#: ``Tracer.context`` block, and ``stack``, the names of the sampled spans
+#: open on the thread. Module-level (not per-Tracer) so downstream
 #: layers attach spans to whichever tracer set the context — a Pipeline
 #: constructed with an injected test tracer still gets its datapath spans.
 _ACTIVE = threading.local()
+
+
+def _open_spans() -> List[str]:
+    stack = getattr(_ACTIVE, "stack", None)
+    if stack is None:
+        stack = _ACTIVE.stack = []
+    return stack
 
 
 def active() -> Tuple["Tracer", Optional[int]]:
@@ -99,6 +152,18 @@ def active() -> Tuple["Tracer", Optional[int]]:
     current trace context, or (TRACER, None) when none is set."""
     entry = getattr(_ACTIVE, "entry", None)
     return entry if entry is not None else (TRACER, None)
+
+
+def thread_cpu_s(thread: Optional[threading.Thread]) -> Optional[float]:
+    """CPU seconds ``thread`` has burnt since it started, by its own CPU
+    clock read from the calling thread. None where it does not run or the
+    platform has no such clock."""
+    if thread is None or not thread.is_alive():
+        return None
+    try:
+        return time.clock_gettime(time.pthread_getcpuclockid(thread.ident))
+    except (OSError, AttributeError):
+        return None
 
 
 class _TraceCtx:
@@ -137,6 +202,9 @@ class Tracer:
         # counts; wraps counts full ring cycles.
         self.spans_dropped_total = 0
         self.ring_wraps = 0
+        # name -> [count, wall_s] of every span recorded since the start
+        # (or reset()); the ring's wrap does not touch it
+        self._totals: Dict[str, List[float]] = {}
         self.configure(sample_rate=sample_rate, capacity=capacity)
 
     # -- configuration -------------------------------------------------------
@@ -179,6 +247,7 @@ class Tracer:
             self.forced_total = 0
             self.spans_dropped_total = 0
             self.ring_wraps = 0
+            self._totals = {}
             self._events = itertools.count()
             self._trace_ids = itertools.count(1)
 
@@ -211,17 +280,32 @@ class Tracer:
             self.forced_total += 1
         return next(self._trace_ids)
 
-    def span(self, trace_id: Optional[int], name: str, **attrs):
+    def span(self, trace_id: Optional[int], name: str, kind: str = WORK,
+             **attrs):
         """Context manager recording one span when ``trace_id`` is not None
-        (the no-op path allocates nothing)."""
+        (the no-op path allocates nothing, reads no clock and touches no
+        thread-local)."""
         if trace_id is None:
             return _NULL_SPAN
-        return _SpanCtx(self, trace_id, name, attrs or None)
+        return _SpanCtx(self, trace_id, name, attrs or None, kind)
 
     def record(self, trace_id: Optional[int], name: str, t0: float,
-               duration_s: float, attrs: Optional[dict] = None) -> None:
+               duration_s: float, attrs: Optional[dict] = None,
+               kind: str = WORK) -> None:
+        """One span recorded after the fact, from stamps the caller took:
+        its thread is the calling one and its parent the span open on it
+        now."""
         if trace_id is None:
             return
+        stack = getattr(_ACTIVE, "stack", None)
+        self._put(trace_id, name, t0, duration_s, attrs,
+                  stack[-1] if stack else None, kind)
+
+    def _put(self, trace_id: int, name: str, t0: float, duration_s: float,
+             attrs: Optional[dict], parent: Optional[str],
+             kind: str) -> None:
+        span = (trace_id, name, t0, duration_s, attrs,
+                threading.current_thread().name, parent, kind)
         with self._lock:
             ring = self._ring
             overwrote = ring[self._widx] is not None
@@ -232,13 +316,18 @@ class Tracer:
                 # summary/export — count it so /v1/trace can say how much
                 # of the story the ring no longer holds
                 self.spans_dropped_total += 1
-            ring[self._widx] = (trace_id, name, t0, duration_s, attrs)
+            ring[self._widx] = span
             self._widx = (self._widx + 1) % len(ring)
             # a wrap is a completed cycle of LOSS, so the initial free
             # fill doesn't count — keeps drops == wraps * capacity (+
             # the partial cycle) mutually consistent
             if self._widx == 0 and overwrote:
                 self.ring_wraps += 1
+            tot = self._totals.get(name)
+            if tot is None:
+                tot = self._totals[name] = [0, 0.0]
+            tot[0] += 1
+            tot[1] += duration_s
 
     def event(self, name: str, **attrs) -> Optional[int]:
         """Record a zero-duration decision event (always, when enabled)."""
@@ -269,33 +358,53 @@ class Tracer:
     def spans(self, limit: int = 100, name: Optional[str] = None,
               trace_id: Optional[int] = None) -> List[Dict]:
         out = []
-        for tid, nm, t0, dur, attrs in self._snapshot():
+        for tid, nm, t0, dur, attrs, thread, parent, kind \
+                in self._snapshot():
             if name is not None and nm != name:
                 continue
             if trace_id is not None and tid != trace_id:
                 continue
             d = {"trace_id": tid, "name": nm, "start_mono": round(t0, 6),
-                 "duration_ms": round(dur * 1e3, 6)}
+                 "duration_ms": round(dur * 1e3, 6), "thread": thread,
+                 "kind": kind}
+            if parent is not None:
+                d["parent"] = parent
             if attrs:
                 d["attrs"] = attrs
             out.append(d)
         return out[-limit:]
 
+    def totals(self) -> Dict[str, List[float]]:
+        """name → ``[count, wall_s]`` over every span recorded since the
+        start (or ``reset()``), whatever the ring still holds."""
+        with self._lock:
+            return {nm: list(t) for nm, t in self._totals.items()}
+
     def summary(self) -> Dict[str, Dict]:
-        """Per-stage aggregate over the spans currently in the ring:
-        count + p50/p99/max/total (ms) — the CLI surface."""
+        """Per-stage aggregate — the CLI surface. Over the spans currently
+        in the ring: count, p50/p99/max/total (ms) and the thread that
+        recorded the newest; ``since_start``: count and wall (ms) of every
+        span of the name, wrapped out of the ring or not."""
         by_name: Dict[str, List[float]] = {}
-        for _tid, nm, _t0, dur, _attrs in self._snapshot():
+        thread_of: Dict[str, str] = {}
+        for _tid, nm, _t0, dur, _attrs, thread, _parent, _kind \
+                in self._snapshot():
             by_name.setdefault(nm, []).append(dur)
+            thread_of[nm] = thread
+        totals = self.totals()
         out = {}
         for nm in sorted(by_name):
             v = np.asarray(by_name[nm], dtype=np.float64) * 1e3
+            n, wall = totals.get(nm, (0, 0.0))
             out[nm] = {
                 "count": int(v.size),
                 "p50_ms": round(float(np.percentile(v, 50)), 4),
                 "p99_ms": round(float(np.percentile(v, 99)), 4),
                 "max_ms": round(float(v.max()), 4),
                 "total_ms": round(float(v.sum()), 4),
+                "thread": thread_of[nm],
+                "since_start": {"count": int(n),
+                                "total_ms": round(wall * 1e3, 4)},
             }
         return out
 

@@ -90,7 +90,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from cilium_tpu.kernels.records import empty_batch, reset_batch_rows
-from cilium_tpu.observe.trace import TRACER, Tracer
+from cilium_tpu.observe.trace import (TRACER, UNDECIDED, WAIT, Tracer,
+                                      thread_cpu_s)
 from cilium_tpu.parallel.mesh import steer_rows
 from cilium_tpu.pipeline.guard import (OVERLOAD_OVERLOAD, OVERLOAD_PRESSURE,
                                        PIPELINE_STATES, PRIO_NEW,
@@ -610,7 +611,8 @@ class Pipeline:
                now: Optional[int] = None,
                timeout: Optional[float] = None,
                deadline_ms: Optional[float] = None,
-               ingest_mono: Optional[float] = None) -> Ticket:
+               ingest_mono: Optional[float] = None,
+               trace_id: Optional[int] = UNDECIDED) -> Ticket:
         """Admit one batch (records layout, ``valid``-masked). Returns a
         :class:`Ticket` immediately; with ``admission="drop"`` (or a blocked
         admission that times out) the ticket comes back already rejected
@@ -622,6 +624,10 @@ class Pipeline:
         :class:`PipelineDeadlineExceeded` instead of burning device time.
         Raises :class:`PipelineUnavailable` (fail fast, no queueing) while
         the circuit breaker is open or the pipeline is hard-failed.
+        ``trace_id``: a producer that has drawn its own sampling decision
+        (the feeder, once a harvest) hands it in, an id or None for "not
+        sampled", and the submission's spans join that trace; left
+        ``UNDECIDED`` the decision is drawn here.
 
         The caller must not mutate ``batch`` until the ticket resolves (the
         staging copy happens on the worker; a direct-dispatch batch is read
@@ -652,8 +658,10 @@ class Pipeline:
         if dl is not None:
             ticket.deadline_mono = ticket.submitted_mono + dl
         # the sampling decision is made once per submission and rides the
-        # ticket; unsampled submissions pay exactly one counter draw here
-        ticket.trace_id = self.tracer.maybe_sample()
+        # ticket; unsampled submissions pay exactly one counter draw, here
+        # or at the producer that handed its own in
+        ticket.trace_id = self.tracer.maybe_sample() \
+            if trace_id == UNDECIDED else trace_id
         deadline = time.monotonic() + (
             self._block_timeout_s if timeout is None else timeout)
         prio = _batch_prio(batch)
@@ -1059,6 +1067,13 @@ class Pipeline:
             # rows whose verdicts are back (every finalize folds its batch
             # into the shared registry), by what LB and LPM made of them
             "verdict_rows": self.metrics.verdict_rows(),
+            # name -> [count, wall_s] of every span recorded since the
+            # tracer's start (None with tracing off), and the CPU seconds
+            # the worker's thread has burnt (its own clock, read from
+            # here): what a reader takes at a window's two ends
+            "span_totals": self.tracer.totals()
+            if self.tracer.enabled else None,
+            "thread_cpu_s": thread_cpu_s(self._worker),
             "shed_total": shed_total,
             "shed_reasons": shed_reasons,
             "unavailable_total": unavailable,
@@ -1654,7 +1669,7 @@ class Pipeline:
             self.metrics.histogram("pipeline_queue_wait_seconds").observe(
                 wait)
             self.tracer.record(t.trace_id, "pipeline.admission",
-                               t.submitted_mono, wait)
+                               t.submitted_mono, wait, kind=WAIT)
             self._settle([(t, _zero_out(t.n_rows), None)])
             return
         # latency lane: a lane-tagged tenant's submission never waits out
@@ -1944,7 +1959,7 @@ class Pipeline:
                 lw.observe(t0 - sl.ticket.submitted_mono)
             self.tracer.record(sl.ticket.trace_id, "pipeline.admission",
                                sl.ticket.submitted_mono,
-                               t0 - sl.ticket.submitted_mono)
+                               t0 - sl.ticket.submitted_mono, kind=WAIT)
         # the batch-level spans ride the first sampled rider's trace; the
         # trace context makes the datapath's pack/transfer/compute split
         # attach to the same trace id across the backend boundary
@@ -2078,6 +2093,15 @@ class Pipeline:
         self.breaker.record_success()
         self.metrics.histogram("pipeline_batch_latency_seconds").observe(
             time.monotonic() - inf.t_dispatch)
+        with self.tracer.span(tid, "pipeline.settle"):
+            self._settle_finalized(inf, out, gen)
+        self._finalizing = None          # settled above
+
+    def _settle_finalized(self, inf: _Inflight, out: Dict[str, np.ndarray],
+                          gen: int) -> None:
+        """Hand a finalized batch's verdicts to its tickets: each slice's
+        rows back in its submission's geometry, the staging buffer
+        recycled, the stats published, the tickets resolved."""
         outcomes = []
         for sl in inf.slices:
             if sl.valid_idx is None:        # direct: geometry already matches
@@ -2102,7 +2126,6 @@ class Pipeline:
         self.metrics.set_gauge("pipeline_inflight", len(self._inflight))
         self._publish(gen)
         self._settle(outcomes)
-        self._finalizing = None          # settled above
 
     # -- small helpers ---------------------------------------------------------
     def _publish(self, gen: int) -> None:

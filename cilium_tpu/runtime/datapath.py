@@ -37,7 +37,7 @@ from cilium_tpu.compile.lpm import PFX_LEN_MASK
 from cilium_tpu.compile.snapshot import PolicySnapshot
 from cilium_tpu.kernels.records import unpack_out
 from cilium_tpu.observe.trace import (CT_GC_SPAN, L7_DICT_SPAN,
-                                      PATCH_APPLY_SPAN,
+                                      PATCH_APPLY_SPAN, WAIT,
                                       active as active_trace)
 from cilium_tpu.pipeline.guard import DeviceLost
 from cilium_tpu.runtime.config import DaemonConfig
@@ -1063,7 +1063,7 @@ class JITDatapath(DatapathBackend):
             # stages inside one jit are not separately timeable from the
             # host, so there is no per-kernel span
             try:
-                with tracer.span(trace_id, "datapath.compute",
+                with tracer.span(trace_id, "datapath.compute", WAIT,
                                  fused=int(self._fused)):
                     words = np.asarray(slab.words)
             except BaseException:
@@ -1071,15 +1071,16 @@ class JITDatapath(DatapathBackend):
                 # shed the checkout count, the buffer goes to the GC
                 self._wire_buf_shed(wire_key)
                 raise
-            if wire_key is not None:
-                # the device is provably done with this batch (the slab is
-                # materialized): the wire buffer is safe to reuse now —
-                # and ONLY now (a dispatch that never finalizes simply
-                # sheds its buffer to the GC)
-                self._wire_buf_release(wire_key, wire_buf)
-            with self._pack_lock:
-                self.pack_stats["readback_slab"] += 1
-            return unpack_out(words, slab.layout)
+            with tracer.span(trace_id, "datapath.unpack"):
+                if wire_key is not None:
+                    # the device is provably done with this batch (the
+                    # slab is materialized): the wire buffer is safe to
+                    # reuse now — and ONLY now (a dispatch that never
+                    # finalizes simply sheds its buffer to the GC)
+                    self._wire_buf_release(wire_key, wire_buf)
+                with self._pack_lock:
+                    self.pack_stats["readback_slab"] += 1
+                return unpack_out(words, slab.layout)
         return finalize
 
     def _wire_buf(self, rows: int, words: int) -> Optional[np.ndarray]:
@@ -1167,20 +1168,21 @@ class JITDatapath(DatapathBackend):
         and a failed materialization sheds it and is checked for a dead
         chip's signature."""
         try:
-            with tracer.span(trace_id, "datapath.compute",
+            with tracer.span(trace_id, "datapath.compute", WAIT,
                              fused=int(self._fused)):
-                with tracer.span(trace_id, "datapath.readback",
+                with tracer.span(trace_id, "datapath.readback", WAIT,
                                  arrays=1, shards=shards):
                     words = np.asarray(slab.words)
         except BaseException as e:
             self._wire_buf_shed(wire_key)      # failed materialization
             self._maybe_device_lost(e)
             raise
-        if wire_key is not None:
-            self._wire_buf_release(wire_key, wire_buf)
-        with self._pack_lock:
-            self.pack_stats["readback_slab"] += 1
-        return unpack_out(words, slab.layout, shards)
+        with tracer.span(trace_id, "datapath.unpack"):
+            if wire_key is not None:
+                self._wire_buf_release(wire_key, wire_buf)
+            with self._pack_lock:
+                self.pack_stats["readback_slab"] += 1
+            return unpack_out(words, slab.layout, shards)
 
     def _classify_async_sharded(self, placed, snap, batch, now,
                                 pre_steered=False):

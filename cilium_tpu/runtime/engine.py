@@ -37,7 +37,8 @@ from cilium_tpu.observe.audit import ShadowAuditor
 from cilium_tpu.observe.blackbox import FlightRecorder
 from cilium_tpu.observe.flowmetrics import FlowMetrics
 from cilium_tpu.observe.pressure import LADDER_EXCLUDE, ResourceLedger
-from cilium_tpu.observe.trace import TRACER
+from cilium_tpu.observe.trace import (TRACER, UNDECIDED,
+                                      active as active_trace)
 from cilium_tpu.policy.repository import PolicyContext, Repository
 from cilium_tpu.policy.selectorcache import SelectorCache
 from cilium_tpu.runtime.config import DaemonConfig
@@ -797,7 +798,8 @@ class Engine:
     def submit(self, batch: Dict[str, np.ndarray],
                now: Optional[int] = None,
                deadline_ms: Optional[float] = None,
-               ingest_mono: Optional[float] = None):
+               ingest_mono: Optional[float] = None,
+               trace_id: Optional[int] = UNDECIDED):
         """Admit one batch into the ingestion pipeline; returns a Ticket
         whose ``result()`` is bit-identical to what :meth:`classify` would
         return for the same batch in the same order. ``deadline_ms``
@@ -805,13 +807,17 @@ class Engine:
         submission the worker cannot serve in time is shed with
         ``PipelineDeadlineExceeded``. ``ingest_mono`` (monotonic seconds)
         is the producer's harvest stamp — it rides the ticket so
-        verdict-apply can compute true ingest→verdict latency. Raises
+        verdict-apply can compute true ingest→verdict latency, and
+        ``trace_id`` its sampling decision where it drew one (the feeder:
+        one draw a harvest, None for "not sampled"; left ``UNDECIDED`` the
+        pipeline draws). Raises
         ``PipelineUnavailable`` while the dispatch circuit breaker is open
         or after the pipeline hard-failed (watchdog restart budget
         exhausted)."""
         return self.start_pipeline().submit(batch, now=now,
                                             deadline_ms=deadline_ms,
-                                            ingest_mono=ingest_mono)
+                                            ingest_mono=ingest_mono,
+                                            trace_id=trace_id)
 
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Wait until every pipeline submission so far has resolved."""
@@ -875,22 +881,21 @@ class Engine:
                 batch["ep_slot"][good] = slots[good]
                 batch["valid"] &= ~(has & (slots < 0))
             try:
-                with self.metrics.span("pipeline_dispatch").timer():
-                    # a sharded pipeline's staging ring delivers rows already
-                    # grouped into per-shard segments: the datapath packs
-                    # them in place and ships each chip its own segment —
-                    # verdicts come back in the steered geometry, un-steered
-                    # per-ticket by the pipeline's finalize gather. The kwarg
-                    # rides only on sharded engines so duck-typed 4-arg
-                    # backends stay compatible.
-                    if self._pipeline_sharded:
-                        fin = self.datapath.classify_async(
-                            active.tensors, active.snapshot, batch, now,
-                            pre_steered=steer_rev is not None
-                            and steer_rev == active.revision)
-                    else:
-                        fin = self.datapath.classify_async(
-                            active.tensors, active.snapshot, batch, now)
+                # a sharded pipeline's staging ring delivers rows already
+                # grouped into per-shard segments: the datapath packs
+                # them in place and ships each chip its own segment —
+                # verdicts come back in the steered geometry, un-steered
+                # per-ticket by the pipeline's finalize gather. The kwarg
+                # rides only on sharded engines so duck-typed 4-arg
+                # backends stay compatible.
+                if self._pipeline_sharded:
+                    fin = self.datapath.classify_async(
+                        active.tensors, active.snapshot, batch, now,
+                        pre_steered=steer_rev is not None
+                        and steer_rev == active.revision)
+                else:
+                    fin = self.datapath.classify_async(
+                        active.tensors, active.snapshot, batch, now)
                 break
             except StalePlacement:
                 # a live delta patch donated the captured handle's buffers
@@ -909,21 +914,30 @@ class Engine:
                 # surface (no metrics, flow log, observers, or grace-
                 # window learning)
                 return out
-            n_valid = int(np.asarray(batch["valid"]).sum())
-            self.metrics.add_batch(counters, n_valid)
-            self.flowlog.append_batch(batch, out, now,
-                                      active.snapshot.ep_ids)
-            self.flowmetrics.add_batch(batch, out, now)
-            # the finalize capture hook: batch/out are still the live
-            # (un-recycled) staging views here — the audit copy happens
-            # before the scheduler recycles the buffer
-            self._observe_batch(batch, out, active.snapshot, now, n_valid,
-                                steered=self._pipeline_sharded)
-            # CT-salvage grace window (ISSUE 19): strictly AFTER the
-            # audit capture — the auditor judges the datapath's raw
-            # verdict (oracle parity must stay exact), while the APPLIED
-            # verdict rides the bounded established-fingerprint grace
-            return self._ct_salvage_apply(batch, out)
+            # the worker's accounting of the batch, after the device's
+            # answer is on the host: the trace is the pipeline worker's
+            # (its finalize set the context), as the datapath's spans are
+            tracer, tid = active_trace()
+            with tracer.span(tid, "engine.account"):
+                n_valid = int(np.asarray(batch["valid"]).sum())
+                self.metrics.add_batch(counters, n_valid)
+                self.flowlog.append_batch(batch, out, now,
+                                          active.snapshot.ep_ids)
+                with tracer.span(tid, "engine.account.flowmetrics"):
+                    self.flowmetrics.add_batch(batch, out, now)
+                # the finalize capture hook: batch/out are still the live
+                # (un-recycled) staging views here — the audit copy happens
+                # before the scheduler recycles the buffer
+                with tracer.span(tid, "engine.account.observe"):
+                    self._observe_batch(batch, out, active.snapshot, now,
+                                        n_valid,
+                                        steered=self._pipeline_sharded)
+                # CT-salvage grace window (ISSUE 19): strictly AFTER the
+                # audit capture — the auditor judges the datapath's raw
+                # verdict (oracle parity must stay exact), while the
+                # APPLIED verdict rides the bounded established-fingerprint
+                # grace
+                return self._ct_salvage_apply(batch, out)
         return finalize
 
     # -- async shim ingestion (shim/feeder.py) ----------------------------------

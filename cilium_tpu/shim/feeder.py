@@ -61,7 +61,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from cilium_tpu.kernels.records import reset_batch_rows
-from cilium_tpu.observe.trace import TRACER, Tracer
+from cilium_tpu.observe.trace import TRACER, WAIT, Tracer, thread_cpu_s
 from cilium_tpu.runtime.faults import FaultInjected
 from cilium_tpu.runtime.metrics import Metrics
 from cilium_tpu.shim.bindings import MAX_UNVERDICTED_BATCHES, FlowShim
@@ -210,12 +210,13 @@ class HarvestBuffer(dict):
     rows long, with its views cut once. ``segments[k]`` is rows
     [k·batch, (k+1)·batch): what the harvest's k-th poll writes.
     ``views[rows]`` is the first ``rows`` rows for every bucket a
-    submission can have. ``view`` and ``counts`` describe the harvest the
-    buffer holds: the view submitted, and the records of each shim batch
-    polled into it, in poll order (all but the last are whole batches, so
-    batch k starts at row k·batch)."""
+    submission can have. ``view``, ``counts`` and ``trace_id`` describe the
+    harvest the buffer holds: the view submitted, the records of each shim
+    batch polled into it, in poll order (all but the last are whole
+    batches, so batch k starts at row k·batch), and its trace id (None:
+    unsampled)."""
 
-    __slots__ = ("segments", "views", "view", "counts")
+    __slots__ = ("segments", "views", "view", "counts", "trace_id")
 
     def cut(self, shim_batch: int, buckets: Tuple[int, ...]) -> None:
         """(Re)cut the views — after the last optional column was added."""
@@ -226,6 +227,7 @@ class HarvestBuffer(dict):
                       for b in buckets}
         self.view = self.views[buckets[-1]]
         self.counts: List[int] = []
+        self.trace_id: Optional[int] = None
 
 
 class ShimFeeder:
@@ -243,7 +245,8 @@ class ShimFeeder:
     shapes); ``harvest_rows`` follows from them and the shim's rings as
     they are when the feeder is built.
 
-    ``engine`` needs ``submit(batch, ingest_mono=...) -> Ticket`` and
+    ``engine`` needs ``submit(batch, ingest_mono=..., trace_id=...) ->
+    Ticket`` (the harvest's stamp and its sampling decision) and
     ``active.snapshot`` (slot mapping) — the real Engine, or any
     duck-typed stand-in in tests. A ticket without ``dispatched_mono``
     (a stand-in's) never holds a harvest back."""
@@ -379,6 +382,7 @@ class ShimFeeder:
         # resolves (Ticket.waker): what a held-back harvest sleeps on
         self._wake = threading.Event()
         self._held: Optional[str] = None    # what a harvest is held for
+        self._hold: Optional[Tuple[int, float]] = None  # its trace, since
         self._last_full = False             # the last harvest hit the ceiling
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -456,6 +460,12 @@ class ShimFeeder:
             "slo_burns": self.slo_burns,
             "e2e_p50_ms": round(e2e.quantile(0.5) * 1e3, 3) if e2e else 0.0,
             "e2e_p99_ms": round(e2e.quantile(0.99) * 1e3, 3) if e2e else 0.0,
+            # name -> [count, wall_s] of every span recorded since the
+            # tracer's start (None with tracing off), and the CPU seconds
+            # this thread has burnt (its own clock, read from here)
+            "span_totals": self.tracer.totals()
+            if self.tracer.enabled else None,
+            "thread_cpu_s": thread_cpu_s(t),
         }
 
     # -- harvest loop ---------------------------------------------------------
@@ -500,6 +510,11 @@ class ShimFeeder:
         # long as the worker took: it leaves with what is there instead of
         # waiting out the batcher's timeout on top
         paced, self._held = self._held, None
+        if paced is not None and self._hold is not None:
+            tid, t0 = self._hold
+            self.tracer.record(tid, "feeder.wait", t0,
+                               time.monotonic() - t0, {"for": paced},
+                               kind=WAIT)
         now_us = int(time.monotonic() * 1e6)
         if self._fqdn is not None:
             # reset_batch_rows zeroes only the TAIL of optional columns and
@@ -507,7 +522,9 @@ class ShimFeeder:
             # otherwise replay the PREVIOUS harvest's DNS payload for head
             # rows. len==0 makes stale payload bytes unreachable.
             buf["_dns_len"][:] = 0
-        tid = self.tracer.maybe_sample()
+        # one sampling decision a harvest: the id rides the submission into
+        # the pipeline, so the worker's spans of it join this trace
+        tid = buf.trace_id = self.tracer.maybe_sample()
         t0 = time.monotonic()
         try:
             b = self._harvest(buf, now_us, force or paced is not None)
@@ -546,27 +563,31 @@ class ShimFeeder:
         m.inc_counter(by_bucket[2], polls)
         ticket = None
         try:
-            n_valid = self._map_slots(b)
-            self.harvested_records += n_valid
-            self.metrics.inc_counter("feeder_harvest_records_total",
-                                     n_valid)
-            from cilium_tpu.pipeline.guard import OVERLOAD_SHED_NEW
-            submit = True
-            if self._overload_level >= OVERLOAD_SHED_NEW:
-                # the ladder's terminal rung: only established-class rows
-                # are submitted; everything else gets its drop verdict at
-                # apply time without touching the pipeline — the rx ring's
-                # real backpressure relief. A batch shed whole rides the
-                # pending queue as the all-drop sentinel (FIFO-safe).
-                if self._shed_new(b) and not bool(b["valid"].any()):
-                    submit = False
-                    self.prio_shed_batches += 1
-                    self.metrics.inc_counter(
-                        "feeder_prio_shed_batches_total")
+            with self.tracer.span(tid, "feeder.map"):
+                n_valid = self._map_slots(b)
+                self.harvested_records += n_valid
+                self.metrics.inc_counter("feeder_harvest_records_total",
+                                         n_valid)
+                from cilium_tpu.pipeline.guard import OVERLOAD_SHED_NEW
+                submit = True
+                if self._overload_level >= OVERLOAD_SHED_NEW:
+                    # the ladder's terminal rung: only established-class
+                    # rows are submitted; everything else gets its drop
+                    # verdict at apply time without touching the pipeline
+                    # — the rx ring's real backpressure relief. A batch
+                    # shed whole rides the pending queue as the all-drop
+                    # sentinel (FIFO-safe).
+                    if self._shed_new(b) and not bool(b["valid"].any()):
+                        submit = False
+                        self.prio_shed_batches += 1
+                        self.metrics.inc_counter(
+                            "feeder_prio_shed_batches_total")
             # the harvest stamp rides the ticket (true ingest→verdict
             # latency; monotonic — same clock as now_us above)
             if submit:
-                ticket = self.engine.submit(b, ingest_mono=now_us / 1e6)
+                with self.tracer.span(tid, "feeder.submit"):
+                    ticket = self.engine.submit(
+                        b, ingest_mono=now_us / 1e6, trace_id=tid)
                 if hasattr(ticket, "waker"):
                     ticket.waker = self._wake
         except Exception as e:   # noqa: BLE001 — unavailable/closed/
@@ -660,6 +681,12 @@ class ShimFeeder:
         or one of ours resolved, whose verdicts the next step applies at
         once — or an idle-sleep has passed (the net under a wake-up that
         ``stop`` or a stand-in's ticket never sends)."""
+        if self._held is None:
+            # a hold begins. It is many short sleeps with the head's apply
+            # between them, and one span: recorded when the harvest is
+            # let go, under the trace of the harvest it waited for
+            tid = self._pending[-1][1].trace_id
+            self._hold = None if tid is None else (tid, time.monotonic())
         self._held = what
         # clear, look again, then sleep: a wake-up sent after the clear is
         # seen by the wait, one sent before it by the second look
@@ -820,15 +847,35 @@ class ShimFeeder:
         every one of them, fail closed). ``recycle=False`` sheds the buffer
         instead of pooling it — for tickets that did NOT resolve: the
         pipeline may still stage from the buffer later."""
+        tracer, tid = self.tracer, buf.trace_id
+        with tracer.span(tid, "feeder.apply", rows=sum(buf.counts),
+                         polls=len(buf.counts)):
+            lat_s = self._apply_verdicts(ticket, buf, ingest_mono)
+        if lat_s is not None:
+            # harvest stamp -> verdicts applied, exactly what the e2e
+            # histogram observed: a wait as the frames see it, whose parts
+            # are the harvest's other spans
+            tracer.record(tid, "feeder.roundtrip", ingest_mono, lat_s,
+                          kind=WAIT)
+        if recycle:
+            self._free.append(buf)
+
+    def _apply_verdicts(self, ticket, buf,
+                        ingest_mono: Optional[float]) -> Optional[float]:
+        """:meth:`_apply_one`'s work. → the harvest → apply latency it
+        observed, None for a rejected batch or one without a stamp."""
+        tid = buf.trace_id
         rejected = True
         allow = None
+        lat_s = None
         view = buf.view
         if ticket is not None:
             try:
                 out = ticket.result(timeout=0)
                 allow = np.asarray(out["allow"])
                 rejected = False
-                self._note_established(view, out)
+                with self.tracer.span(tid, "feeder.apply.note"):
+                    self._note_established(view, out)
                 if self._fqdn is not None:
                     # in-band DNS learning tap: rows whose verdict carried
                     # the DNS L7 redirect get their response payload parsed
@@ -852,14 +899,14 @@ class ShimFeeder:
         if not rejected and ingest_mono is not None:
             # verdict-apply is the END of the serving path for this batch:
             # harvest stamp → here is the true ingest→verdict latency
-            self._observe_e2e(time.monotonic() - ingest_mono, view)
+            lat_s = time.monotonic() - ingest_mono
+            self._observe_e2e(lat_s, view)
         if rejected:
             self.rejected_batches += 1
             self.metrics.inc_counter("feeder_rejected_batches_total")
         self.applied_batches += 1
         self.metrics.inc_counter("feeder_applied_batches_total")
-        if recycle:
-            self._free.append(buf)
+        return lat_s
 
     def _observe_e2e(self, lat_s: float, buf: Dict[str, np.ndarray]) -> None:
         """One applied batch's ingest→verdict latency into the e2e SLO

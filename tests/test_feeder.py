@@ -521,11 +521,12 @@ class _Submits:
     def active(self):
         return self.eng.active
 
-    def submit(self, batch, ingest_mono=None):
+    def submit(self, batch, ingest_mono=None, trace_id=None):
         if self.down:
             raise RuntimeError("pipeline unavailable (test)")
         self.rows.append((len(batch["valid"]), int(batch["valid"].sum())))
-        return self.eng.submit(batch, ingest_mono=ingest_mono)
+        return self.eng.submit(batch, ingest_mono=ingest_mono,
+                               trace_id=trace_id)
 
 
 @pytest.mark.parametrize("ring,batch,inflight,max_rows,want", [
@@ -719,7 +720,7 @@ class _SlowEngine:
     def active(self):
         return self.eng.active
 
-    def submit(self, batch, ingest_mono=None):
+    def submit(self, batch, ingest_mono=None, trace_id=None):
         from cilium_tpu.pipeline.scheduler import Ticket
         t = Ticket(len(batch["valid"]), int(batch["valid"].sum()))
         self.tickets.append(t)
@@ -797,7 +798,7 @@ class TestHarvestWaitsForTheWorker:
             active = None
             tickets = []
 
-            def submit(self, batch, ingest_mono=None):
+            def submit(self, batch, ingest_mono=None, trace_id=None):
                 self.tickets.append(
                     Ticket(len(batch["valid"]), int(batch["valid"].sum())))
                 return self.tickets[-1]

@@ -138,7 +138,12 @@ def test_the_cell_and_its_mix_in_the_manifest():
     assert reads == {"verdicts_per_s", "setup_s", "feeder.rows_per_harvest",
                      "pipeline.fill_ratio", "datapath.host_us_per_batch",
                      "kernels.device_ns_per_row",
-                     "startup.compiles_in_window", *L7_METRICS}
+                     "startup.compiles_in_window", *L7_METRICS,
+                     # every saturate cell's, from the host's spans and
+                     # thread clocks (PR 39)
+                     "pipeline.finalize_own_us_per_batch",
+                     "feeder.apply_us_per_batch", "feeder.map_us_per_batch",
+                     "host.cpu_us_per_row"}
     for name in L7_METRICS:
         m = next(m for m in manifest["per_layer"] if m["name"] == name)
         assert m["workloads"] == [CELL] and m["moves"] == "verdicts_per_s"

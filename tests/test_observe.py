@@ -11,6 +11,7 @@ regeneration). The ``slow``-marked soak (`make chaos`) asserts
 the 1/64-sampled pipeline costs <2% over tracing disabled.
 """
 
+import os
 import threading
 import time
 
@@ -23,8 +24,11 @@ from cilium_tpu.observe.trace import TRACER, Tracer
 from cilium_tpu.runtime.metrics import Metrics, quantile_from
 from tests.test_pipeline import (EchoDispatch, POLICY, _assert_parity,
                                  fake_engine, mk_chunks, pkt, sub_batch)
+from tests.test_feeder import (big_shim, frames_of, manual_feeder,
+                               step_until)
 from cilium_tpu.kernels.records import batch_from_records
 from cilium_tpu.pipeline import Pipeline
+from cilium_tpu.shim.bindings import LIB_PATH
 
 
 @pytest.fixture(autouse=True)
@@ -235,6 +239,325 @@ class TestPipelineTracing:
                 "pipeline.finalize", "engine.classify"} <= names
         pipe.stop()
         ser.stop()
+
+
+# --------------------------------------------------------------------------- #
+# One harvest, one trace: spans carry thread, parent, CPU and kind (PR 39)
+# --------------------------------------------------------------------------- #
+def _spin(seconds):
+    end = time.monotonic() + seconds
+    while time.monotonic() < end:
+        pass
+
+
+class TestSpanFields:
+    def test_two_draws_a_harvest_starve_the_second_and_one_does_not(self):
+        """At 1/64 a harvest that draws for itself and again for its
+        submission, both from the one counter, samples the harvest 313
+        times in 10,000 and the submission never (every even divisor does
+        it). One draw a harvest, handed on: both halves, every time."""
+        t = Tracer(sample_rate=1 / 64, capacity=8)
+        harvest = submission = 0
+        for _ in range(10_000):
+            harvest += t.maybe_sample() is not None
+            submission += t.maybe_sample() is not None
+        assert (harvest, submission) == (313, 0)
+
+        tr = Tracer(sample_rate=1 / 64, capacity=4096)
+        pl = Pipeline(EchoDispatch(), min_bucket=4, max_bucket=16,
+                      flush_ms=1.0, queue_batches=64, tracer=tr)
+        try:
+            sampled = 0
+            for i in range(1_000):
+                tid = tr.maybe_sample()              # the feeder's draw
+                ticket = pl.submit(sub_batch(16, start=i), trace_id=tid)
+                assert ticket.trace_id == tid        # None stays None
+                sampled += tid is not None
+                if i % 32 == 31:
+                    assert pl.drain(timeout=10)
+            assert pl.drain(timeout=10)
+            assert sampled == 16 == tr.sampled_total     # 1,000 / 64, up
+            s = tr.summary()
+            assert s["pipeline.admission"]["count"] == 16
+            assert s["pipeline.dispatch"]["count"] == 16
+            assert s["pipeline.settle"]["count"] == 16
+            # a caller that drew nothing leaves the draw to the pipeline
+            assert pl.submit(sub_batch(16, start=0)).trace_id is None
+            assert tr.sampled_total == 16
+            for _ in range(64 * 16 - 1_001):         # up to draw 1,024
+                tr.maybe_sample()
+            assert pl.submit(sub_batch(16, start=0)).trace_id is not None
+            assert pl.drain(timeout=10)
+        finally:
+            pl.close(timeout=5)
+
+    def test_parent_and_thread_of_nested_spans_on_two_threads_at_once(self):
+        t = Tracer(sample_rate=1.0, capacity=64)
+        inside = threading.Barrier(2, timeout=10)
+
+        def work(outer, inner):
+            tid = t.maybe_sample()
+            with t.span(tid, outer):
+                with t.span(tid, inner, kind="wait"):
+                    inside.wait()        # both inner spans open at once
+                    inside.wait()
+                # recorded after the fact: its parent is the span open now
+                t.record(tid, inner + ".late", time.monotonic(), 0.0)
+
+        threads = [threading.Thread(target=work, args=a, name=n)
+                   for n, a in (("feeder-x", ("shim.a", "shim.a.b")),
+                                ("worker-x", ("pipeline.a", "pipeline.a.b")))]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(10)
+            assert not th.is_alive()
+        got = {s["name"]: s for s in t.spans()}
+        for thread, outer in (("feeder-x", "shim.a"),
+                              ("worker-x", "pipeline.a")):
+            assert got[outer]["thread"] == thread
+            assert "parent" not in got[outer]
+            assert got[outer]["kind"] == "work"
+            assert got[outer + ".b"]["parent"] == outer
+            assert got[outer + ".b"]["thread"] == thread
+            assert got[outer + ".b"]["kind"] == "wait"
+            assert got[outer + ".b.late"]["parent"] == outer
+        assert t.summary()["shim.a"]["thread"] == "feeder-x"
+        # the stacks are empty again: the next span has no parent
+        with t.span(t.maybe_sample(), "after"):
+            pass
+        assert "parent" not in t.spans(name="after")[0]
+
+    def test_cpu_time_is_the_threads_own_clock_read_from_another(self):
+        """``thread_cpu_s``: what a thread burnt, read from the caller's
+        thread: a spin burns its wall time, a sleep nothing; a thread that
+        has gone, or none, reads None."""
+        from cilium_tpu.observe.trace import thread_cpu_s
+        go, done = threading.Event(), threading.Event()
+
+        def work(fn):
+            go.wait(10)
+            fn(0.2)
+            done.set()
+            go.clear()
+            go.wait(10)                  # stay alive to be read
+
+        for fn, lo, hi in ((_spin, 0.1, 0.25), (time.sleep, 0.0, 0.02)):
+            done.clear()
+            th = threading.Thread(target=work, args=(fn,))
+            th.start()
+            c0 = thread_cpu_s(th)
+            go.set()
+            assert done.wait(10)
+            burnt = thread_cpu_s(th) - c0
+            go.set()
+            th.join(10)
+            assert not th.is_alive()
+            assert lo <= burnt <= hi, (fn, burnt)
+            assert thread_cpu_s(th) is None          # it has gone
+        assert thread_cpu_s(None) is None
+        assert thread_cpu_s(threading.Thread(target=_spin)) is None
+        me = thread_cpu_s(threading.current_thread())
+        assert me == pytest.approx(time.thread_time(), abs=0.05)
+
+    def test_totals_outlive_the_ring(self):
+        """The ring wraps three times; the totals hold every span."""
+        t = Tracer(sample_rate=1.0, capacity=8)
+        want = {}
+        for i in range(8 * 4 + 3):
+            name = f"s{i % 3}"
+            dur = 0.001 * (i + 1)
+            t.record(i + 1, name, float(i), dur)
+            w = want.setdefault(name, [0, 0.0])
+            w[0] += 1
+            w[1] += dur
+        assert t.ring_wraps == 3 and len(t.spans(limit=100)) == 8
+        got = t.totals()
+        assert set(got) == set(want)
+        for name, (n, wall) in want.items():
+            assert got[name] == [n, pytest.approx(wall)]
+        assert t.summary()["s0"]["since_start"] == {
+            "count": want["s0"][0],
+            "total_ms": pytest.approx(want["s0"][1] * 1e3, abs=1e-3)}
+        assert t.summary()["s0"]["count"] < want["s0"][0]     # the ring's
+        got["s0"][0] = -1                       # a copy, not the tracer's
+        assert t.totals()["s0"][0] == want["s0"][0]
+        t.reset()
+        assert t.totals() == {} and t.summary() == {}
+
+
+HARVEST_SPANS = {
+    "shim.harvest", "feeder.map", "feeder.submit", "pipeline.admission",
+    "pipeline.dispatch", "datapath.pack", "pipeline.finalize",
+    "datapath.unpack", "engine.account", "pipeline.settle", "feeder.apply",
+    "feeder.roundtrip"}
+
+
+@pytest.mark.skipif(not os.path.exists(LIB_PATH),
+                    reason="libflowshim.so not built")
+class TestHarvestTrace:
+    def test_a_sampled_harvest_holds_its_workers_spans_at_1_64(
+            self, monkeypatch):
+        """A feeder over the mock rings, the pipeline and the jitted
+        datapath at 1/64: one draw a harvest; every sampled harvest's
+        trace id holds the spans of both threads, an unsampled one none."""
+        from cilium_tpu.runtime.config import DaemonConfig
+        from cilium_tpu.runtime.datapath import JITDatapath
+        from cilium_tpu.runtime.engine import Engine
+        from tests.test_feeder import POLICY as EGRESS
+        cfg = DaemonConfig(ct_capacity=4096, auto_regen=False, device="cpu",
+                           batch_size=64, pipeline_min_bucket=16,
+                           pipeline_flush_ms=1.0, flowlog_mode="none",
+                           trace_sample_rate=1 / 64, trace_capacity=4096)
+        eng = Engine(cfg, datapath=JITDatapath(cfg))
+        eng.add_endpoint(["k8s:app=web"], ips=("192.168.1.10",), ep_id=1)
+        eng.apply_policy(EGRESS)
+        eng.regenerate()
+        assert eng.tracer is TRACER
+        TRACER.reset()
+        draws = []
+        drawn = TRACER.maybe_sample
+        monkeypatch.setattr(
+            TRACER, "maybe_sample",
+            lambda: draws.append(drawn()) or draws[-1])
+        shim = big_shim()
+        fd = manual_feeder(shim, eng, tracer=TRACER)
+        n = 130
+        try:
+            for k in range(n):
+                for f in frames_of(10, first=10 * k):
+                    assert shim.mock_rx_inject(f) == 0
+                # applies harvest k-1 (it is done), then takes harvest k
+                fd._step(force=True)
+                assert fd.harvested_batches == k + 1
+                fd._pending[-1][0].result(timeout=120)
+                shim.mock_tx_drain(64)
+            step_until(fd, lambda: not fd._pending, force=True)
+            st_feeder, st_pipeline = fd.stats(), eng.pipeline_stats()
+            assert st_feeder["span_totals"]["shim.harvest"][0] >= 3
+            assert st_pipeline["span_totals"] == st_feeder["span_totals"]
+        finally:
+            eng.stop()
+            shim.close()
+        assert fd.applied_batches == n and fd.rejected_batches == 0
+        # one draw a harvest (and the last, empty-handed step's)
+        assert len(draws) == n + 1
+        sampled = [d for d in draws[:n] if d is not None]
+        assert len(sampled) == 3                     # harvests 0, 64, 128
+        by_trace = {}
+        for s in TRACER.spans(limit=4096):
+            by_trace.setdefault(s["trace_id"], set()).add(s["name"])
+        for tid in sampled:
+            assert HARVEST_SPANS <= by_trace[tid], \
+                HARVEST_SPANS - by_trace[tid]
+        # nothing of an unsampled harvest: the only other trace is the
+        # regeneration's, which is always traced
+        for tid, names in by_trace.items():
+            if tid not in draws:
+                assert all(nm.startswith("engine.regen") for nm in names), \
+                    names
+        threads = {s["name"]: s["thread"] for s in TRACER.spans(limit=4096)}
+        assert threads["pipeline.settle"].endswith("-worker")
+        assert threads["engine.account"] == threads["datapath.unpack"] \
+            == threads["pipeline.settle"]
+        assert threads["feeder.apply"] == threads["shim.harvest"] \
+            != threads["pipeline.settle"]
+        kinds = {s["name"]: s["kind"] for s in TRACER.spans(limit=4096)}
+        assert {n for n, k in kinds.items() if k == "wait"} == {
+            "pipeline.admission", "datapath.compute", "feeder.roundtrip"}
+        parents = {s["name"]: s.get("parent")
+                   for s in TRACER.spans(limit=4096)}
+        assert parents["engine.account"] == "pipeline.finalize"
+        assert parents["engine.account.observe"] == "engine.account"
+        assert parents["datapath.unpack"] == "pipeline.finalize"
+        assert parents["feeder.apply.note"] == "feeder.apply"
+        assert parents["pipeline.settle"] is None
+        assert parents["feeder.roundtrip"] is None
+        # each thread's CPU clock, read from here
+        assert st_feeder["thread_cpu_s"] is None        # not started
+        assert st_pipeline["thread_cpu_s"] > 0
+
+    def test_a_held_back_harvest_records_one_wait(self):
+        """The feeder's own thread at 1.0: a harvest held back for the
+        worker is one ``feeder.wait`` span however often it slept, a wait
+        on the feeder's thread, under the trace of the harvest it waited
+        for."""
+        from tests.test_feeder import (fake_engine as feeder_engine,
+                                       inject_all, wait_verdicts)
+        eng = feeder_engine(pipeline_min_bucket=16, trace_sample_rate=1.0,
+                            trace_capacity=1 << 15)
+        TRACER.reset()
+        shim = big_shim()
+        feeder = eng.start_feeder(shim)
+        try:
+            inject_all(shim, frames_of(600), deadline_s=60.0)
+            wait_verdicts(shim, 600, deadline_s=60.0)
+        finally:
+            st = feeder.stats()
+            eng.stop()
+            shim.close()
+        assert st["thread_cpu_s"] > 0          # the feeder's own clock
+        waits = TRACER.spans(limit=1 << 15, name="feeder.wait")
+        harvests = {s["trace_id"] for s in TRACER.spans(
+            limit=1 << 15, name="shim.harvest") if s["attrs"]["rows"]}
+        assert waits and len(waits) <= st["harvested_batches"]
+        assert st["span_totals"]["feeder.wait"][0] == len(waits)
+        for s in waits:
+            assert s["kind"] == "wait" and "parent" not in s
+            assert s["attrs"]["for"] in ("dispatch", "verdicts")
+            assert s["thread"].endswith("-harvest")
+            assert s["trace_id"] in harvests
+        assert len({s["trace_id"] for s in waits}) == len(waits)
+
+    def test_tracing_off_reads_no_cpu_clock_and_allocates_nothing(
+            self, monkeypatch):
+        """With the rate 0 the feeder's step, the pipeline and the
+        engine's finalize make no ``time.thread_time`` call and leave no
+        allocation of ``observe/trace.py``'s behind, over 1,000 steps."""
+        import gc
+        import tracemalloc
+        from cilium_tpu.observe import trace as trace_mod
+        from tests.test_feeder import fake_engine as feeder_engine
+        assert not TRACER.enabled
+        calls = []
+
+        def no_cpu_clock():
+            calls.append(1)
+            raise AssertionError("time.thread_time read with tracing off")
+        eng = feeder_engine(pipeline_min_bucket=16)
+        shim = big_shim()
+        fd = manual_feeder(shim, eng, tracer=TRACER)
+
+        def run(steps, first):
+            for k in range(steps):
+                for f in frames_of(4, first=(first + k) % 1000):
+                    assert shim.mock_rx_inject(f) == 0
+                fd._step(force=True)
+                fd._pending[-1][0].result(timeout=30)
+                shim.mock_tx_drain(64)
+            step_until(fd, lambda: not fd._pending, force=True)
+
+        try:
+            run(50, 0)                                # warm every path
+            monkeypatch.setattr(time, "thread_time", no_cpu_clock)
+            gc.collect()
+            tracemalloc.start()
+            snap1 = tracemalloc.take_snapshot()
+            run(1000, 50)
+            gc.collect()
+            snap2 = tracemalloc.take_snapshot()
+            tracemalloc.stop()
+        finally:
+            monkeypatch.undo()
+            eng.stop()
+            shim.close()
+        assert fd.applied_batches >= 1050 and not calls
+        flt = [tracemalloc.Filter(True, trace_mod.__file__)]
+        diff = snap2.filter_traces(flt).compare_to(
+            snap1.filter_traces(flt), "lineno")
+        assert sum(d.size_diff for d in diff) == 0, diff[:5]
+        assert TRACER.spans() == [] and TRACER.totals() == {}
+        assert fd.stats()["span_totals"] is None
 
 
 class TestFlowMetrics:
