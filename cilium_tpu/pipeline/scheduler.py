@@ -328,25 +328,36 @@ class _StageBuf:
     the steered layout the mesh wants. ``dirty`` tracks each segment's
     content high-water mark across reuses — flush restores empty-batch
     defaults only on [fill, dirty), not the whole tail, so segment resets
-    stay proportional to actual traffic."""
+    stay proportional to actual traffic.
 
-    __slots__ = ("cols", "dirty", "_views")
+    Beside the record's columns a slot has two optional shim-side ones.
+    ``_ep_raw``: the raw endpoint ids that let the dispatch-time slot
+    re-mapping survive coalescing; 0 is "no raw id", so a rider without
+    the column stages as 0. ``_fp``: the flow fingerprints a feeder hashed
+    at harvest (shim/feeder.py), for the engine's salvage filter to read
+    instead of hashing again. There every value is a real hash, so absence
+    cannot be a value: ``fp_whole`` says whether every rider staged since
+    the slot was opened brought the column, and the view handed to dispatch
+    holds ``_fp`` only then."""
+
+    __slots__ = ("cols", "dirty", "fp_whole", "_views")
 
     def __init__(self, rows: int, n_shards: int = 1):
         self.cols = empty_batch(rows)
-        # shim-fed submissions carry raw endpoint ids so the dispatch-time
-        # slot re-mapping survives coalescing; rows from producers without
-        # the column stage as 0 (= "no raw id", left untouched downstream)
         self.cols["_ep_raw"] = np.zeros((rows,), dtype=np.int64)
+        self.cols["_fp"] = np.zeros((rows,), dtype=np.uint32)
         self.dirty: Optional[List[int]] = [0] * n_shards \
             if n_shards > 1 else None
-        self._views: Dict[int, Dict[str, np.ndarray]] = {}
+        self.fp_whole = True
+        self._views: Dict[Tuple[int, bool], Dict[str, np.ndarray]] = {}
 
     def view(self, bucket: int) -> Dict[str, np.ndarray]:
-        v = self._views.get(bucket)
+        fp = self.fp_whole
+        v = self._views.get((bucket, fp))
         if v is None:
-            v = {k: col[:bucket] for k, col in self.cols.items()}
-            self._views[bucket] = v
+            v = {k: col[:bucket] for k, col in self.cols.items()
+                 if fp or k != "_fp"}
+            self._views[(bucket, fp)] = v
         return v
 
 
@@ -1706,7 +1717,10 @@ class Pipeline:
             self._stage_deadline = t.submitted_mono + self._flush_s
             self._stage_now = None
         valid_idx = np.nonzero(np.asarray(sub.batch["valid"]))[0]
-        buf = self._buffers[self._stage_buf].cols
+        stage = self._buffers[self._stage_buf]
+        if "_fp" not in sub.batch:
+            stage.fp_whole = False       # the bucket is hashed downstream
+        buf = stage.cols
         pos = self._staged_rows
         with self.tracer.span(t.trace_id, "pipeline.microbatch", rows=m):
             # pipeline.stage_write: just the column writes into the pinned
@@ -1717,7 +1731,8 @@ class Pipeline:
                 for k, col in buf.items():
                     if k.startswith("_"):
                         # optional shim-side column: absent in non-shim
-                        # submissions → 0 ("no raw id")
+                        # submissions → 0 ("no raw id"; for ``_fp``, whose
+                        # 0 is a hash like any other, ``fp_whole`` above)
                         src = sub.batch.get(k)
                         if src is None:
                             col[pos:pos + m] = 0
@@ -1806,6 +1821,8 @@ class Pipeline:
         elif self._stage_steer_rev != rev:
             self._stage_steer_rev = -2       # mixed: dispatch must re-steer
         stage = self._buffers[self._stage_buf]
+        if "_fp" not in sub.batch:
+            stage.fp_whole = False       # the bucket is hashed downstream
         buf = stage.cols
         fills = self._shard_fill
         with self.tracer.span(t.trace_id, "pipeline.microbatch", rows=m):
@@ -2168,6 +2185,7 @@ class Pipeline:
             self._check_gen(gen)
             self._finalize_oldest(gen)
         idx = self._free_bufs.pop()
+        self._buffers[idx].fp_whole = True     # no rider yet
         # staging-ring occupancy: free slots left after this acquire (0 =
         # every slot staged or in flight — the host is the bottleneck)
         self.metrics.set_gauge("pipeline_staging_free", len(self._free_bufs))

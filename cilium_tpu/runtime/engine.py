@@ -212,8 +212,9 @@ class Engine:
         # mesh self-healing (ISSUE 19): device loss → fenced re-mesh onto
         # survivors → CT salvage → hysteretic re-admission. The grace-
         # window fingerprint filter shares the feeder's exact established-
-        # flow update/lookup discipline (shim/feeder.py) but is engine-
-        # owned: the window must work for direct submit() producers too.
+        # flow update/lookup discipline (shim/feeder.py), and the hashes a
+        # feeder's batch carries, but is engine-owned: the window must
+        # work for direct submit() producers too.
         from cilium_tpu.shim.feeder import EstablishedFingerprints
         self._remesh_lock = threading.Lock()
         self._remesh_last: Optional[Dict] = None
@@ -828,7 +829,13 @@ class Engine:
 
     def pipeline_stats(self) -> Optional[Dict]:
         pl = self._pipeline
-        return pl.stats() if pl is not None else None
+        if pl is None:
+            return None
+        st = pl.stats()
+        # rows the worker ran through flow_hashes for the salvage filter:
+        # none where every finalized batch carried its ``_fp``
+        st["verdict_rows"]["flow_hash_rows"] = self._salvage_fp.hashed_rows
+        return st
 
     def _pipeline_shard_of(self, batch: Dict[str, np.ndarray]) -> np.ndarray:
         """Per-row flow-shard ids for the sharded staging ring: the
@@ -1204,8 +1211,13 @@ class Engine:
         is open, denied rows whose fingerprint was stamped established
         pre-loss flip to allow — established flows ride over the lost
         shard's CT while forward packets cold-learn entries on the
-        survivor mesh. Counted ``ct_salvage_grace_hits_total``; never
-        raises; copies on flip (the auditor holds the raw arrays)."""
+        survivor mesh. A feeder's batch carries the fingerprints its
+        harvest hashed (``_fp``: a ``direct`` dispatch is the feeder's own
+        view, a staged one holds the column when every rider brought it),
+        and both the stamp and the lookup read them; any other batch is
+        hashed here (``EstablishedFingerprints``). Counted
+        ``ct_salvage_grace_hits_total``; never raises; copies on flip (the
+        auditor holds the raw arrays)."""
         try:
             self._salvage_fp.note(batch, out)
             if time.monotonic() >= self._salvage_until:

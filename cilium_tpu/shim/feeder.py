@@ -107,6 +107,15 @@ class EstablishedFingerprints:
       collision admits one flow for a bounded window, which is exactly
       the documented grace contract, never a policy bypass outside it.
 
+    They share one hash too. A batch that carries a ``_fp`` column (the
+    feeder's harvest buffers: :func:`flow_hashes` of the view, written once
+    a harvest by ``ShimFeeder._map_slots``, row-aligned through staging)
+    is looked up and stamped from it; a batch without one (``Engine.submit``
+    producers, batches built by hand) is hashed here. Slot and stamp have
+    the one definition either way, so a table fed carried hashes is bit for
+    bit the table fed by hashing. ``hashed_rows`` counts the rows this
+    table's owner ran through :func:`flow_hashes`, in either place.
+
     ``note`` never raises (both call sites are verdict hot paths)."""
 
     def __init__(self, slots: int = EST_FILTER_SLOTS):
@@ -114,6 +123,13 @@ class EstablishedFingerprints:
             raise ValueError("fingerprint slots must be a power of two")
         self._tab = np.zeros((slots,), dtype=np.uint32)
         self._mask = np.uint32(slots - 1)
+        self.hashed_rows = 0     # single writer: the owner's thread
+
+    def hash_rows(self, b: Dict[str, np.ndarray]) -> np.ndarray:
+        """:func:`flow_hashes` of ``b``'s rows, counted."""
+        h = flow_hashes(b)
+        self.hashed_rows += len(h)
+        return h
 
     def note(self, buf: Dict[str, np.ndarray],
              out: Dict[str, np.ndarray]) -> None:
@@ -126,10 +142,14 @@ class EstablishedFingerprints:
                  & np.asarray(buf["valid"]))
             if not m.any():
                 return
-            cols = {k: np.asarray(buf[k])[m]
-                    for k in ("src", "dst", "sport", "dport", "proto",
-                              "direction")}
-            h = flow_hashes(cols)
+            h = buf.get("_fp")
+            if h is not None:
+                h = np.asarray(h)[m]
+            else:
+                h = self.hash_rows(
+                    {k: np.asarray(buf[k])[m]
+                     for k in ("src", "dst", "sport", "dport", "proto",
+                               "direction")})
             self._tab[h & self._mask] = h | np.uint32(1)
         except Exception:   # noqa: BLE001 — heuristic, never load-bearing
             log.exception("established-fingerprint update failed")
@@ -137,7 +157,8 @@ class EstablishedFingerprints:
     def hits(self, buf: Dict[str, np.ndarray]) -> np.ndarray:
         """[N] bool: rows whose direction-normalized fingerprint is
         stamped. Row-aligned with ``buf``; validity is the caller's mask."""
-        h = flow_hashes(buf)
+        h = buf.get("_fp")
+        h = self.hash_rows(buf) if h is None else np.asarray(h)
         return self._tab[h & self._mask] == (h | np.uint32(1))
 
 
@@ -214,7 +235,14 @@ class HarvestBuffer(dict):
     harvest the buffer holds: the view submitted, the records of each shim
     batch polled into it, in poll order (all but the last are whole
     batches, so batch k starts at row k·batch), and its trace id (None:
-    unsampled)."""
+    unsampled).
+
+    Its columns: what the shim's ``make_poll_buffer`` gives (the record's
+    fields, ``_ep_raw``, ``_frame_idx``), and the feeder's own, each as
+    long as the buffer: ``_prio`` and ``_fp`` always (the row's priority
+    class and its flow fingerprint, both written by ``_map_slots``),
+    ``_tenant``, ``_dns_payload`` + ``_dns_len`` and ``_shard`` where QoS,
+    the DNS proxy and host RSS are armed."""
 
     __slots__ = ("segments", "views", "view", "counts", "trace_id")
 
@@ -332,6 +360,11 @@ class ShimFeeder:
         from cilium_tpu.pipeline.guard import PRIO_NEW
         for buf in self._free:
             buf["_prio"] = np.full((rows,), PRIO_NEW, dtype=np.int8)
+            # the row's flow fingerprint (flow_hashes), hashed once a
+            # harvest for the classing lookup and carried for every later
+            # ``note``: ours at apply, the engine's salvage filter's at
+            # finalize (EstablishedFingerprints)
+            buf["_fp"] = np.zeros((rows,), dtype=np.uint32)
         self._est = EstablishedFingerprints()
         # multi-tenant QoS (cilium_tpu/qos): with a TenantTable armed,
         # every harvest buffer carries a ``_tenant`` column stamped at
@@ -453,6 +486,9 @@ class ShimFeeder:
             "overload_level": self._overload_level,
             "prio_shed_rows": self.prio_shed_rows,
             "prio_shed_batches": self.prio_shed_batches,
+            # rows this thread ran through flow_hashes: one a harvested row
+            # where every batch carries its ``_fp``
+            "flow_hash_rows": self._est.hashed_rows,
             "alive": bool(t is not None and t.is_alive()),
             "pending": len(self._pending),
             "pool_free": len(self._free),
@@ -757,6 +793,12 @@ class ShimFeeder:
             # SHED-NEW harvest shed keys on
             from cilium_tpu.pipeline.guard import (PRIO_ESTABLISHED,
                                                    PRIO_NEW, PRIO_UNKNOWN)
+            if "_fp" in b:
+                # the harvest's one hash: the lookup below reads it, and
+                # so does every ``note`` of these rows, here and on the
+                # worker (nothing between harvest and apply writes the
+                # tuple columns)
+                b["_fp"][:] = self._est.hash_rows(b)
             hit = self._est.hits(b)
             pr = np.where(hit, PRIO_ESTABLISHED, PRIO_NEW).astype(np.int8)
             pr[unknown] = PRIO_UNKNOWN
@@ -812,7 +854,9 @@ class ShimFeeder:
         observed allowed-ESTABLISHED/REPLY stamp their fingerprint, so the
         NEXT harvest ranks them class 0 (EstablishedFingerprints — shared
         with the engine's CT-salvage grace window, which needs the exact
-        same update/lookup discipline). Never raises."""
+        same update/lookup discipline). The view carries the hashes its
+        harvest computed (``_fp``), so this is a mask and a scatter. Never
+        raises."""
         self._est.note(buf, out)
 
     # -- verdict application (FIFO) -------------------------------------------

@@ -130,6 +130,204 @@ class TestEstablishedFingerprints:
         with pytest.raises(ValueError):
             EstablishedFingerprints(slots=48)
 
+    def _tuples(self, family, direction, n=96, seed=7):
+        """``n`` rows of distinct flows: v4, v6 or every third row v6, in
+        the forward orientation (``"fwd"``), the reverse (``"rev"``) or
+        row by row alternating (``"both"``); every fifth row invalid."""
+        rng = np.random.default_rng(seed)
+        b = self._buf(n)
+        v6 = {"v4": np.zeros(n, bool), "v6": np.ones(n, bool),
+              "mixed": np.arange(n) % 3 == 0}[family]
+        words = rng.integers(1, 1 << 32, size=(2, n, 4), dtype=np.uint64)
+        for col, w in zip(("src", "dst"), words):
+            b[col][v6] = w[v6].astype(np.uint32)
+        b["proto"][::4] = 17
+        rev = {"fwd": np.zeros(n, bool), "rev": np.ones(n, bool),
+               "both": np.arange(n) % 2 == 1}[direction]
+        for x, y in (("src", "dst"), ("sport", "dport")):
+            fwd = b[x].copy()
+            b[x][rev], b[y][rev] = b[y][rev], fwd[rev]
+        b["direction"][rev] = 1
+        b["valid"][::5] = False
+        return b
+
+    @pytest.mark.parametrize("direction", ["fwd", "rev", "both"])
+    @pytest.mark.parametrize("family", ["v4", "v6", "mixed"])
+    def test_carried_hashes_give_the_same_table(self, family, direction):
+        """A table fed ``note`` and asked ``hits`` with the batch's ``_fp``
+        column is, array for array, the table fed by hashing: over invalid
+        rows, denied rows, rows not yet established, a second batch that
+        overwrites slots of the first, and the replies' orientation."""
+        from cilium_tpu.shim.feeder import flow_hashes
+        n = 96
+        est = np.full(n, int(C.CTStatus.ESTABLISHED), np.int32)
+        est[1::7] = int(C.CTStatus.NEW)
+        est[2::7] = int(C.CTStatus.REPLY)
+        allow = np.ones(n, bool)
+        allow[3::6] = False
+        out = {"allow": allow, "status": est}
+        hashed = EstablishedFingerprints(slots=1 << 6)    # slots collide
+        carried = EstablishedFingerprints(slots=1 << 6)
+        seen = []
+        for seed in (7, 8):
+            b = self._tuples(family, direction, n, seed)
+            with_fp = dict(b, _fp=flow_hashes(b))
+            hashed.note(b, out)
+            carried.note(with_fp, out)
+            np.testing.assert_array_equal(carried._tab, hashed._tab)
+            seen.append((b, with_fp))
+        assert hashed._tab.any()
+        for b, with_fp in seen:
+            np.testing.assert_array_equal(carried.hits(with_fp),
+                                          hashed.hits(b))
+            # the other orientation of the same flows reads the same slots
+            back = {**b, "src": b["dst"], "dst": b["src"],
+                    "sport": b["dport"], "dport": b["sport"],
+                    "direction": 1 - b["direction"]}
+            np.testing.assert_array_equal(
+                carried.hits(dict(back, _fp=flow_hashes(back))),
+                hashed.hits(b))
+        m = out["allow"] & (est != int(C.CTStatus.NEW)) & seen[1][0]["valid"]
+        hit = hashed.hits(seen[1][0])
+        assert hit[m].any() and not hit[~m].any()   # (a slot's last writer)
+        # hashing is counted where it is done, carried hashes are not
+        assert carried.hashed_rows == 0
+        assert hashed.hashed_rows == 2 * int(m.sum()) + 5 * n
+
+    def test_a_carried_zero_is_a_hash_and_not_an_absence(self):
+        """``_fp`` holds no "absent" value: a batch that carries the
+        column stamps what it says, 0 included, so only a batch WITHOUT
+        the column may be handed in for rows that were never hashed."""
+        fp = EstablishedFingerprints(slots=1 << 4)
+        b = self._buf(2)
+        out = {"allow": np.ones(2, bool),
+               "status": np.full(2, int(C.CTStatus.ESTABLISHED), np.int32)}
+        fp.note(dict(b, _fp=np.zeros(2, np.uint32)), out)
+        assert fp._tab[0] == 1 and not fp._tab[1:].any()
+        assert not fp.hits(b).any()
+
+
+# --------------------------------------------------------------------------- #
+# the salvage filter reads the hash the batch carries (PR 41)
+# --------------------------------------------------------------------------- #
+class TestSalvageReadsTheCarriedHash:
+    """``Engine._ct_salvage_apply`` over batches that carry ``_fp`` and
+    over the same batches without it: the same rows flip while the grace
+    window is open, the same table is left behind, and only a batch
+    without the column is hashed on the worker."""
+
+    N = 24
+
+    def _engine(self, how):
+        if how == "sharded":
+            # tiny-pods-mesh4.json's size: four flow shards, host RSS, so
+            # every submission is steered into per-shard segments
+            return jit_pipeline_engine(4, batch_size=1024,
+                                       ct_capacity=65536,
+                                       pipeline_flush_ms=5000.0)
+        from tests.test_sharded_pipeline import fake_serial_engine
+        return fake_serial_engine(batch_size=64, pipeline_min_bucket=16,
+                                  pipeline_flush_ms=5000.0)
+
+    def _phase(self, slot_of, how, replies, start, n):
+        """``n`` flows' packets as the variant submits them: one
+        bucket-shaped batch (``direct``), or chunks of ten rows with an
+        invalid tail, which stage and coalesce."""
+        mk = _replies if replies else _mk
+        if how == "direct":
+            b = mk(slot_of, n, start)
+            pad = {k: np.zeros((32 - n,) + v.shape[1:], v.dtype)
+                   for k, v in b.items()}
+            return [{k: np.concatenate([b[k], pad[k]]) for k in b}]
+        chunks = []
+        for i in range(0, n, 10):
+            m = min(10, n - i)
+            recs = [pkt(f"10.0.2.{((start + i + j) % 200) + 1}",
+                        "192.168.1.10", 443, 52000 + start + i + j,
+                        flags=C.TCP_ACK, direction=C.DIR_INGRESS)
+                    if replies else
+                    pkt("192.168.1.10", f"10.0.2.{((start + i + j) % 200) + 1}",
+                        52000 + start + i + j, 443) for j in range(m)]
+            chunks.append(batch_from_records(recs, slot_of, pad_to=m + 2))
+        return chunks
+
+    def _serve(self, eng, how, carry):
+        """Warm the filter, lose the conntrack state (its entries expire),
+        open the window, and ask again for the flows stamped and for as
+        many never seen. ``carry(i)``: whether the i-th submission brings
+        its ``_fp``. → every submission's answers."""
+        from cilium_tpu.shim.feeder import flow_hashes
+        slot_of = eng.active.snapshot.ep_slot_of
+        n, outs, k = self.N, [], 0
+        t0 = 1_000_000
+        for now, replies, start, rows, grace in (
+                (t0, False, 0, n, False), (t0 + 1, True, 0, n, False),
+                (t0 + 10 ** 7, True, 0, n, True),
+                (t0 + 10 ** 7, True, 7000, n, True)):
+            if grace:
+                eng._salvage_until = time.monotonic() + 600
+            tickets = []
+            for b in self._phase(slot_of, how, replies, start, rows):
+                if carry(k):
+                    b = dict(b, _fp=flow_hashes(b))
+                tickets.append((eng.submit(b, now=now), b))
+                k += 1
+            assert eng.drain(timeout=60)
+            outs.append([(b["valid"].copy(),
+                          {key: np.asarray(t.result(5)[key]).copy()
+                           for key in ("allow", "reason", "status")})
+                         for t, b in tickets])
+        return outs
+
+    @pytest.mark.parametrize("how,carry", [
+        ("direct", "all"), ("staged", "all"), ("sharded", "all"),
+        ("staged", "mixed")])
+    def test_the_same_rows_flip_with_and_without_the_column(self, how,
+                                                            carry):
+        carries = {"all": lambda i: True, "mixed": lambda i: i % 2 == 0}
+        got, tabs, engines = [], [], []
+        try:
+            for fn in (carries[carry], lambda i: False):
+                eng = self._engine(how)
+                engines.append(eng)
+                got.append(self._serve(eng, how, fn))
+                tabs.append(eng._salvage_fp._tab.copy())
+            with_fp, without = engines
+            for a, b in zip(*got):
+                for (va, oa), (vb, ob) in zip(a, b):
+                    np.testing.assert_array_equal(va, vb)
+                    for key in oa:
+                        np.testing.assert_array_equal(oa[key], ob[key])
+            n = self.N
+            warm, stamped, fresh = got[0][1], got[0][2], got[0][3]
+            for v, o in warm:
+                assert (o["status"][v] == int(C.CTStatus.REPLY)).all()
+            # the flows stamped before the loss ride the window, and flows
+            # the filter never saw stay refused
+            assert sum(int(o["allow"][v].sum()) for v, o in stamped) == n
+            assert sum(int(o["allow"].sum()) for v, o in fresh) == 0
+            for eng in engines:
+                assert eng.metrics.counters[
+                    "ct_salvage_grace_hits_total"] == n
+            np.testing.assert_array_equal(tabs[0], tabs[1])
+            assert tabs[0].any() and tabs[0][0] == 0
+            reasons = with_fp.pipeline_stats()["flush_reasons"]
+            if how == "direct":
+                assert reasons["direct"] == 4
+            else:                     # one coalesced bucket a phase
+                assert reasons["direct"] == 0
+                assert sum(reasons.values()) == 4
+            hashed = [e.pipeline_stats()["verdict_rows"]["flow_hash_rows"]
+                      for e in engines]
+            # a bucket whose riders all brought the column is never hashed
+            # on the worker; one rider without it and the bucket is hashed
+            # as before, whole: "absent" is never read as the hash 0
+            assert hashed[1] > 0
+            assert hashed[0] == (0 if carry == "all" else hashed[1])
+        finally:
+            for eng in engines:
+                eng.stop()
+
 
 # --------------------------------------------------------------------------- #
 # CT archive helpers

@@ -678,7 +678,8 @@ class TestHarvestTakesWhatTheRingHolds:
                   qos=_Qos() if extras.get("qos") else None,
                   fqdn=_Dns() if extras.get("fqdn") else None)
         fd = manual_feeder(shim, sub, **kw)
-        want = {"_prio"} | ({"_shard"} if "n_shards" in extras else set()) \
+        want = {"_prio", "_fp"} \
+            | ({"_shard"} if "n_shards" in extras else set()) \
             | ({"_tenant"} if "qos" in extras else set()) \
             | ({"_dns_payload", "_dns_len"} if "fqdn" in extras else set())
         for buf in fd._free:
@@ -696,12 +697,70 @@ class TestHarvestTakesWhatTheRingHolds:
         step_until(fd, lambda: fd.harvested_batches == 1, force=True)
         (_t, buf, _m), = fd._pending
         assert all(len(col) == 32 for col in buf.view.values())
+        from cilium_tpu.shim.feeder import flow_hashes
+        assert buf.view["_fp"].dtype == np.uint32           # the harvest's
+        np.testing.assert_array_equal(buf.view["_fp"],      # hash, tail too
+                                      flow_hashes(buf.view))
         if "n_shards" in extras:
             assert (buf.view["_shard"][:20] != 0).all()     # pre-binned
         if "qos" in extras:
             assert (buf.view["_tenant"][:20] == 1).all()    # ep 1 % 3
         step_until(fd, lambda: fd.applied_batches == 1)
         assert shim.stats()["verdict_passes"] == 20
+        eng.stop()
+        shim.close()
+
+
+    @pytest.mark.parametrize("min_bucket,reason", [
+        (16, "direct"), (64, "deadline")])
+    def test_a_row_is_hashed_once_a_harvest(self, monkeypatch, min_bucket,
+                                            reason):
+        """Harvest → apply rounds over the same flows, new and then
+        established, dispatched ``direct`` and staged: ``flow_hashes`` runs
+        once a harvest, on the harvest's view, and never for the feeder's
+        ``note`` or the worker's; ``flow_hash_rows`` says the same on both
+        threads; and both fingerprint tables are the tables that hashing
+        every ``note`` would have built."""
+        from cilium_tpu.shim import feeder as feeder_mod
+        from cilium_tpu.shim.feeder import EstablishedFingerprints
+        plain_hashes = feeder_mod.flow_hashes
+        eng = fake_engine(pipeline_min_bucket=min_bucket)
+        shim = big_shim()
+        fd = manual_feeder(shim, eng)
+        calls, notes = [], []
+
+        def counted(b):
+            calls.append(len(b["valid"]))
+            return plain_hashes(b)
+
+        def note(view, out, plain=fd._note_established):
+            notes.append(({k: v.copy() for k, v in view.items()
+                           if k != "_fp"},
+                          {k: np.asarray(out[k]).copy()
+                           for k in ("allow", "status")}))
+            plain(view, out)
+        monkeypatch.setattr(feeder_mod, "flow_hashes", counted)
+        monkeypatch.setattr(fd, "_note_established", note)
+        rounds, n = 4, 20
+        for r in range(rounds):
+            for f in frames_of(n, allow=lambda i: i % 4 != 3):
+                assert shim.mock_rx_inject(f) == 0
+            step_until(fd, lambda: fd.applied_batches == r + 1, force=True)
+            tx_lens(shim)
+        assert calls == [32] * rounds
+        assert fd.stats()["flow_hash_rows"] == 32 * rounds
+        ps = eng.pipeline_stats()
+        assert ps["verdict_rows"]["flow_hash_rows"] == 0
+        assert ps["verdict_rows"]["total"] == n * rounds
+        assert ps["flush_reasons"][reason] == rounds
+        # later rounds found the flows established and stamped them
+        monkeypatch.setattr(feeder_mod, "flow_hashes", plain_hashes)
+        want = EstablishedFingerprints()
+        for view, out in notes:
+            want.note(view, out)
+        assert want.hashed_rows == 15 * (rounds - 1)
+        np.testing.assert_array_equal(fd._est._tab, want._tab)
+        np.testing.assert_array_equal(eng._salvage_fp._tab, want._tab)
         eng.stop()
         shim.close()
 

@@ -143,7 +143,9 @@ def test_the_cell_and_its_mix_in_the_manifest():
                      # thread clocks (PR 39)
                      "pipeline.finalize_own_us_per_batch",
                      "feeder.apply_us_per_batch", "feeder.map_us_per_batch",
-                     "host.cpu_us_per_row"}
+                     "host.cpu_us_per_row",
+                     # and how often a row is hashed (PR 41)
+                     "host.flow_hashes_per_row"}
     for name in L7_METRICS:
         m = next(m for m in manifest["per_layer"] if m["name"] == name)
         assert m["workloads"] == [CELL] and m["moves"] == "verdicts_per_s"
