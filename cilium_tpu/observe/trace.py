@@ -97,6 +97,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -123,6 +126,13 @@ class _SpanCtx:
         stack.append(self._name)
         self._t0 = time.monotonic()
         return self
+
+    def set(self, **attrs):
+        """Attributes known only once the span's work is under way."""
+        if self._attrs is None:
+            self._attrs = attrs
+        else:
+            self._attrs.update(attrs)
 
     def __exit__(self, *exc):
         dur = time.monotonic() - self._t0

@@ -477,7 +477,17 @@ class JITDatapath(DatapathBackend):
             # verdict slab read in one transfer — every batch, on one chip
             # and on a mesh (there one segment a chip, one sharded array)
             "readback_slab": 0,
+            # bytes put on the device for the wire (and the path dictionary
+            # where one went up), and what the same valid rows would ship
+            # each on its own class's layout (_pack_wire)
+            "wire_bytes": 0,
+            "wire_bytes_needed": 0,
         }
+        # valid rows through _pack_wire by what their OWN class needs of
+        # the wire (the choice itself is batch-wide and sticky), and those
+        # that leave the endpoint (pack lock)
+        self.wire_rows: Dict[str, int] = {"wide_needed": 0, "l7_needed": 0,
+                                          "egress": 0}
         # live-patch attribution: how each place_patch applied (delta =
         # donated device scatter; full = whole-tensor re-upload) and how
         # often the StalePlacement fence actually fired (a caller captured
@@ -888,12 +898,15 @@ class JITDatapath(DatapathBackend):
                    "is_v6", "ep_slot", "direction", "http_method",
                    "http_path", "valid")
 
-    def _pack_wire(self, b, snap, pooled: bool, fallback_reason: str):
+    def _pack_wire(self, b, snap, pooled: bool, fallback_reason: str,
+                   span=None):
         """The shared zero-copy pack: widen-then-choose the sticky wire
         format under the pack lock, check out a pooled wire buffer, pack in
-        place. Returns (wire, path_dict_or_None, wire_key, wire_buf) —
-        wire_key is None when the pack allocated (the buffer then just
-        sheds to the GC instead of returning to the pool).
+        place. Returns (wire, path_dict_or_None, wire_key, wire_buf,
+        dict_needed) — wire_key is None when the pack allocated (the buffer
+        then just sheds to the GC instead of returning to the pool);
+        dict_needed is for ``_upload_path_dict``: the bytes of the
+        dictionary that are paths of rows that carry a request.
 
         The lock covers only widen-then-choose + the pool checkout (a
         concurrent place() reset can only land before or after this batch's
@@ -909,18 +922,51 @@ class JITDatapath(DatapathBackend):
         ``pooled=False`` skips the pool entirely and counts the batch under
         ``pack_fallback_{fallback_reason}`` — for paths whose zero-copy
         chain already broke upstream (the allocating steer of an un-steered
-        sharded batch)."""
+        sharded batch).
+
+        The reductions that choose the wire also count what each valid row
+        needs of it by its own class (``wire_rows``, ``wire_bytes`` /
+        ``wire_bytes_needed`` of ``pack_stats``, and ``wire_words`` /
+        ``rows_wide`` / ``rows_l7`` on ``span``, the caller's
+        ``datapath.pack``): a per-row mask is built only for a class the
+        batch holds."""
         from cilium_tpu.kernels.records import (
             PACK4_EP_SLOT_MAX, _path_words_of, pack_batch, pack_batch_l7dict,
             pack_batch_v4, wire_words_for)
-        batch_l7 = bool(
-            (b["http_method"] != C.HTTP_METHOD_ANY).any()
-            or b["http_path"].any())
-        batch_wide = bool(
-            b["is_v6"].any()
-            or int(b["ep_slot"].max(initial=0)) > PACK4_EP_SLOT_MAX)
-        path_dict = None
-        n_rows = int(b["valid"].shape[0])
+        valid = b["valid"]
+        has_method = b["http_method"] != C.HTTP_METHOD_ANY
+        batch_l7 = bool(has_method.any() or b["http_path"].any())
+        slot_wide = int(b["ep_slot"].max(initial=0)) > PACK4_EP_SLOT_MAX
+        batch_wide = bool(b["is_v6"].any() or slot_wide)
+        n_valid = int(np.count_nonzero(valid))
+        n_egress = int(np.count_nonzero(
+            valid & (b["direction"] == C.DIR_EGRESS)))
+        rows_wide = rows_l7 = rows_both = 0
+        if batch_wide:
+            wide = valid & b["is_v6"]
+            if slot_wide:
+                wide |= valid & (b["ep_slot"] > PACK4_EP_SLOT_MAX)
+            rows_wide = int(np.count_nonzero(wide))
+        if batch_l7 and snap.l7.n_sets > 0:
+            # a row carries a request by its method or, where the
+            # tokenizer knew none, by a path byte (has_l7_tokens). The
+            # rows without a method are swept as one block: only where one
+            # of them holds a path does the batch pay a reduction a row
+            carries = valid & has_method
+            bare = valid & ~has_method
+            if bare.any() and b["http_path"].compress(bare, axis=0).any():
+                carries |= bare & b["http_path"].any(axis=1)
+            rows_l7 = int(np.count_nonzero(carries))
+            if rows_wide:
+                rows_both = int(np.count_nonzero(carries & wide))
+        needed = 4 * (
+            (n_valid - rows_wide - rows_l7 + rows_both)
+            * wire_words_for(False, False)
+            + (rows_wide - rows_both) * wire_words_for(False, True)
+            + (rows_l7 - rows_both) * wire_words_for(True, False)
+            + rows_both * wire_words_for(True, True))
+        path_dict, dict_needed = None, 0
+        n_rows = int(valid.shape[0])
         zero_copy = self.config.zero_copy_ingest and pooled
         with self._pack_lock:
             if snap.l7.n_sets > 0:
@@ -935,6 +981,11 @@ class JITDatapath(DatapathBackend):
                 l7_path_words = self._l7_path_words
                 l7_min_rows = self._l7_dict_rows
             words = wire_words_for(use_l7, use_wide)
+            self.wire_rows["wide_needed"] += rows_wide
+            self.wire_rows["l7_needed"] += rows_l7
+            self.wire_rows["egress"] += n_egress
+            self.pack_stats["wire_bytes"] += 4 * n_rows * words
+            self.pack_stats["wire_bytes_needed"] += needed
             wire_buf = self._wire_buf(n_rows, words) if zero_copy else None
             wire_key = (n_rows, words) if wire_buf is not None else None
             if wire_buf is not None:
@@ -944,6 +995,8 @@ class JITDatapath(DatapathBackend):
             else:
                 self.pack_stats[
                     f"pack_fallback_{fallback_reason}"] += 1
+        if span is not None:
+            span.set(wire_words=words, rows_wide=rows_wide, rows_l7=rows_l7)
         try:
             if use_l7:
                 t0 = time.monotonic()
@@ -955,6 +1008,12 @@ class JITDatapath(DatapathBackend):
                 # low half of its last word, and the index is dense from 0
                 distinct = int((wire[:, -1] & 0xFFFF).max(initial=0)) + 1 \
                     if n_rows else 0
+                # what the rows that carry a request put in the dictionary:
+                # its paths that are not empty. The rows stand in ascending
+                # byte order, so only the first can be the empty one (the
+                # other rows'), and past ``distinct`` all is padding
+                dict_needed = 4 * int(path_dict.shape[1]) * max(
+                    0, distinct - (0 if path_dict[0].any() else 1))
                 with self._pack_lock:       # dict geometry stays grow-only
                     self._l7_dict_rows = max(self._l7_dict_rows,
                                              path_dict.shape[0])
@@ -976,7 +1035,7 @@ class JITDatapath(DatapathBackend):
             # that dies here never reaches a finalize to release it
             self._wire_buf_shed(wire_key)
             raise
-        return wire, path_dict, wire_key, wire_buf
+        return wire, path_dict, wire_key, wire_buf, dict_needed
 
     @staticmethod
     def _columnar(batch):
@@ -1019,10 +1078,11 @@ class JITDatapath(DatapathBackend):
         # caller's current trace context (pipeline worker or
         # Engine.classify), whichever tracer instance set it
         tracer, trace_id = active_trace()
-        with tracer.span(trace_id, "datapath.pack"):
+        with tracer.span(trace_id, "datapath.pack") as pack_span:
             b = self._columnar(batch)
-            wire, path_dict, wire_key, wire_buf = self._pack_wire(
-                b, snap, pooled=True, fallback_reason="shape")
+            wire, path_dict, wire_key, wire_buf, dict_needed = \
+                self._pack_wire(b, snap, pooled=True,
+                                fallback_reason="shape", span=pack_span)
         try:
             with tracer.span(trace_id, "datapath.transfer",
                              bytes=int(wire.nbytes)):
@@ -1034,7 +1094,8 @@ class JITDatapath(DatapathBackend):
                 FAULTS.fire("ct.insert")
                 if path_dict is not None:
                     dev_batch = (jnp.asarray(wire),
-                                 self._upload_path_dict(path_dict))
+                                 self._upload_path_dict(
+                                     path_dict, dict_needed))
                 else:
                     dev_batch = jnp.asarray(wire)
                 with self._ct_lock:
@@ -1123,7 +1184,12 @@ class JITDatapath(DatapathBackend):
             return dict(self.l7_stats, path_words=self._l7_path_words,
                         dict_rows=self._l7_dict_rows)
 
-    def _upload_path_dict(self, path_dict: np.ndarray):
+    def wire_stats(self) -> Tuple[Dict[str, int], Dict[str, int]]:
+        """(``wire_rows``, ``pack_stats``) read at one instant."""
+        with self._pack_lock:
+            return dict(self.wire_rows), dict(self.pack_stats)
+
+    def _upload_path_dict(self, path_dict: np.ndarray, needed: int):
         """Device copy of the L7 path dict, cached by content: serving
         traffic repeats a small stable path set, so in steady state the
         dict (host-compared — same cost as one pack column) is uploaded
@@ -1149,6 +1215,10 @@ class JITDatapath(DatapathBackend):
         with self._pack_lock:
             self.pack_stats["upload_cache_misses"] += 1
             self.l7_stats["dict_upload_bytes"] += int(path_dict.nbytes)
+            # the second upload is the wire's too; ``needed`` of it is
+            # the paths of the rows that carry a request (_pack_wire)
+            self.pack_stats["wire_bytes"] += int(path_dict.nbytes)
+            self.pack_stats["wire_bytes_needed"] += needed
             # the dict is a fresh array every batch (never pool-aliased):
             # safe to retain as the comparison baseline without a copy
             self._path_dict_host = path_dict
@@ -1209,7 +1279,7 @@ class JITDatapath(DatapathBackend):
         n = self.n_flow_shards      # the width this batch is dispatched at
         pre = pre_steered or n == 1
         scatter = None
-        with tracer.span(trace_id, "datapath.pack", shards=n):
+        with tracer.span(trace_id, "datapath.pack", shards=n) as pack_span:
             b = self._columnar(batch)
             if not pre:
                 # steering must hash the post-DNAT tuple (service flows' CT
@@ -1239,9 +1309,11 @@ class JITDatapath(DatapathBackend):
                 # attribution: a pre-steered batch that still allocates can
                 # only do so for a pool-unfriendly shape; only the
                 # allocating-regroup path above earns the "steered" label
-                wire, path_dict, wire_key, wire_buf = self._pack_wire(
-                    b, snap, pooled=pre,
-                    fallback_reason="shape" if pre else "steered")
+                wire, path_dict, wire_key, wire_buf, dict_needed = \
+                    self._pack_wire(
+                        b, snap, pooled=pre,
+                        fallback_reason="shape" if pre else "steered",
+                        span=pack_span)
                 nbytes = int(wire.nbytes)
         try:
             with tracer.span(trace_id, "datapath.transfer", bytes=nbytes,
@@ -1253,7 +1325,8 @@ class JITDatapath(DatapathBackend):
                     dev_batch = dict_batch   # the jit shards the columns
                 elif path_dict is not None:
                     dev_batch = (jax.device_put(wire, self._batch_sharding),
-                                 self._upload_path_dict(path_dict))
+                                 self._upload_path_dict(
+                                     path_dict, dict_needed))
                 else:
                     dev_batch = jax.device_put(wire, self._batch_sharding)
                 with self._ct_lock:
@@ -1299,7 +1372,7 @@ class JITDatapath(DatapathBackend):
         tracer, trace_id = active_trace()
         n = self.n_flow_shards
         with tracer.span(trace_id, "datapath.pack",
-                         shards=n, rss="device"):
+                         shards=n, rss="device") as pack_span:
             b = self._columnar(batch)
             orig_rows = int(b["valid"].shape[0])
             rows = orig_rows
@@ -1320,8 +1393,9 @@ class JITDatapath(DatapathBackend):
                 nbytes = sum(v.nbytes for v in dict_batch.values())
             else:
                 dict_batch = None
-                wire, path_dict, wire_key, wire_buf = self._pack_wire(
-                    b, snap, pooled=True, fallback_reason="shape")
+                wire, path_dict, wire_key, wire_buf, dict_needed = \
+                    self._pack_wire(b, snap, pooled=True,
+                                    fallback_reason="shape", span=pack_span)
                 nbytes = int(wire.nbytes)
         # exchange-buffer accounting (the HBM ledger's ``exchange`` group):
         # per-mesh bytes the ring materializes for this bucket shape
@@ -1343,7 +1417,8 @@ class JITDatapath(DatapathBackend):
                     dev_batch = dict_batch   # the jit shards the columns
                 elif path_dict is not None:
                     dev_batch = (jax.device_put(wire, self._batch_sharding),
-                                 self._upload_path_dict(path_dict))
+                                 self._upload_path_dict(
+                                     path_dict, dict_needed))
                 else:
                     dev_batch = jax.device_put(wire, self._batch_sharding)
                 with self._ct_lock:

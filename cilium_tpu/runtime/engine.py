@@ -835,6 +835,12 @@ class Engine:
         # rows the worker ran through flow_hashes for the salvage filter:
         # none where every finalized batch carried its ``_fp``
         st["verdict_rows"]["flow_hash_rows"] = self._salvage_fp.hashed_rows
+        # rows by the wire their own class needs and the bytes the wire
+        # took, counted where the datapath packs (a jitted one)
+        wire_stats = getattr(self.datapath, "wire_stats", None)
+        if wire_stats is not None:
+            rows, st["pack_stats"] = wire_stats()
+            st["verdict_rows"].update(rows)
         return st
 
     def _pipeline_shard_of(self, batch: Dict[str, np.ndarray]) -> np.ndarray:

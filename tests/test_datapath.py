@@ -744,3 +744,149 @@ class TestVerdictSlab:
         assert back()[2:] == [{"arrays": 1, "shards": 2}]
         assert dp.pack_stats["readback_slab"] == 3
         eng.stop()
+
+
+class TestWireCounters:
+    """PR 42: the reductions that choose the batch-wide wire also count
+    what each valid row needs of it by its own class, and what went up.
+    One batch of known make-up on each of the four wires; every expected
+    number is written out by hand below."""
+
+    #: 16 rows, 12 valid: (v6, carries a request, leaves the endpoint),
+    #: four of each make-up the case asks for, then four invalid rows
+    #: (which read as v4, no request, direction 0 = egress: the defaults)
+    PLAIN, V6, REQUEST, BOTH = (0, 0), (1, 0), (0, 1), (1, 1)
+    PATHS = (b"/api/one", b"/api/two")             # 8 bytes: 2 path words
+    CASES = {
+        # wire: (make-up of the 12 valid rows, words a row, expected
+        #        wide_needed, l7_needed, needed words of the valid rows,
+        #        dictionary bytes up, dictionary bytes needed)
+        "narrow": ([PLAIN] * 12, 4, 0, 0, 12 * 4, 0, 0),
+        "wide": ([PLAIN] * 8 + [V6] * 4, 11, 4, 0, 8 * 4 + 4 * 11, 0, 0),
+        # 3 distinct paths (the empty one is the other rows') pad to 4
+        # dictionary rows of 2 words; the requests' own are 2 of them
+        "l7": ([PLAIN] * 8 + [REQUEST] * 4, 5, 0, 4, 8 * 4 + 4 * 5,
+               4 * 2 * 4, 2 * 2 * 4),
+        "full": ([PLAIN] * 4 + [V6] * 2 + [REQUEST] * 4 + [BOTH] * 2, 12,
+                 4, 6, 4 * 4 + 2 * 11 + 4 * 5 + 2 * 12,
+                 4 * 2 * 4, 2 * 2 * 4),
+    }
+
+    def _engine(self, **more):
+        cfg = DaemonConfig(ct_capacity=2048, auto_regen=False, device="cpu",
+                           batch_size=32, **more)
+        eng = Engine(cfg, datapath=JITDatapath(cfg))
+        eng.add_endpoint(["k8s:app=web"], ips=("192.168.1.10", "fd00::10"),
+                         ep_id=1)
+        eng.apply_policy(TestWireFlagReset.L7_POLICY)
+        eng.regenerate()
+        return eng
+
+    def _batch(self, eng, makeup):
+        from cilium_tpu.kernels.records import empty_batch
+        b = empty_batch(16)
+        n = len(makeup)
+        b["valid"][:n] = True
+        b["ep_slot"][:] = eng.active.snapshot.ep_slot_of[1]
+        b["proto"][:] = C.PROTO_TCP
+        b["tcp_flags"][:] = C.TCP_SYN
+        b["sport"][:] = 40000 + np.arange(16)
+        b["dport"][:] = 80
+        b["src"][:, 2], b["dst"][:, 2] = 0xFFFF, 0xFFFF
+        b["src"][:, 3] = 0x0B000001 + np.arange(16)
+        b["dst"][:, 3] = 0xC0A8010A
+        # every third valid row leaves the endpoint
+        b["direction"][:n] = np.where(np.arange(n) % 3 == 0, C.DIR_EGRESS,
+                                      C.DIR_INGRESS)
+        for i, (v6, request) in enumerate(makeup):
+            b["is_v6"][i] = bool(v6)
+            if request:
+                b["http_method"][i] = C.HTTP_METHOD_IDS["GET"]
+                b["http_path"][i, :8] = np.frombuffer(self.PATHS[i % 2],
+                                                      np.uint8)
+        return b
+
+    @pytest.mark.parametrize("wire", sorted(CASES))
+    def test_a_batch_of_known_make_up_counts_exactly(self, wire):
+        makeup, words, wide, l7, needed_words, dict_up, dict_needed = \
+            self.CASES[wire]
+        eng = self._engine()
+        try:
+            dp = eng.datapath
+            rows0, pack0 = dp.wire_stats()
+            assert rows0 == {"wide_needed": 0, "l7_needed": 0, "egress": 0}
+            assert pack0["wire_bytes"] == pack0["wire_bytes_needed"] == 0
+            eng.classify(self._batch(eng, makeup), now=100)
+            rows, pack = dp.wire_stats()
+            assert (dp._wire_wide, dp._wire_l7) == (wide > 0, l7 > 0)
+            assert rows == {"wide_needed": wide, "l7_needed": l7,
+                            "egress": 4}
+            # all 16 rows ride the batch's one layout, padding too
+            assert pack["wire_bytes"] == 16 * words * 4 + dict_up
+            assert pack["wire_bytes_needed"] == needed_words * 4 \
+                + dict_needed
+            # the choice is kept: plain rows now pay the widened wire,
+            # and need what they always needed
+            eng.classify(self._batch(eng, self.CASES["narrow"][0]), now=101)
+            rows2, pack2 = dp.wire_stats()
+            assert rows2 == {"wide_needed": wide, "l7_needed": l7,
+                             "egress": 8}
+            # the dictionary of a batch without a request is the empty
+            # path alone: one row goes up where the first had four
+            # (_l7_dict_rows only grows), none of it needed
+            again_up = dict_up if l7 else 0
+            assert pack2["wire_bytes"] - pack["wire_bytes"] \
+                == 16 * words * 4 + again_up
+            assert pack2["wire_bytes_needed"] - pack["wire_bytes_needed"] \
+                == 12 * 4 * 4
+        finally:
+            eng.stop()
+
+    def test_a_path_without_a_method_is_a_request_too(self):
+        """``has_l7_tokens``: a method or a path byte. Two of the four
+        requests lose their method (a request line whose method the
+        tokenizer does not know) and still count."""
+        eng = self._engine()
+        try:
+            b = self._batch(eng, self.CASES["l7"][0])
+            b["http_method"][8:10] = C.HTTP_METHOD_ANY
+            eng.classify(b, now=100)
+            rows, pack = eng.datapath.wire_stats()
+            assert rows["l7_needed"] == 4
+            assert pack["wire_bytes_needed"] == self.CASES["l7"][4] * 4 \
+                + self.CASES["l7"][6]
+        finally:
+            eng.stop()
+
+    def test_the_counts_reach_pipeline_stats_and_the_pack_span(self):
+        eng = self._engine(trace_sample_rate=1.0)
+        try:
+            def packs():
+                # the tracer is process-wide: earlier cases' spans too
+                return [s.get("attrs") for s in eng.tracer.spans(
+                    limit=1 << 16, name="datapath.pack")]
+            before = len(packs())
+            b = self._batch(eng, self.CASES["full"][0])
+            eng.submit(b, now=100).result(timeout=120)
+            eng.drain(timeout=60)
+            st = eng.pipeline_stats()
+            rows, pack = st["verdict_rows"], st["pack_stats"]
+            assert (rows["wide_needed"], rows["l7_needed"], rows["egress"],
+                    rows["total"]) == (4, 6, 4, 12)
+            assert pack["wire_bytes"] > pack["wire_bytes_needed"] > 0
+            assert packs()[before:] == [
+                {"wire_words": 12, "rows_wide": 4, "rows_l7": 6}]
+        finally:
+            eng.stop()
+
+    def test_a_fake_datapath_states_no_wire(self):
+        eng = fixture_engine(FakeDatapath(DaemonConfig(ct_capacity=2048)))
+        try:
+            eng.regenerate()
+            b = batch_from_records(TRAFFIC, eng.active.snapshot.ep_slot_of)
+            eng.submit(b, now=100).result(timeout=60)
+            st = eng.pipeline_stats()
+            assert "pack_stats" not in st
+            assert "wide_needed" not in st["verdict_rows"]
+        finally:
+            eng.stop()

