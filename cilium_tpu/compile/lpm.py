@@ -6,7 +6,7 @@ family (mirroring upstream's separate v4/v6 maps), stride 8 bits, so an IPv4
 lookup is 4 dependent gathers and IPv6 is 16 — cost independent of prefix
 count (SURVEY.md §5: "LPM over 100k prefixes as multi-level stride tables").
 
-Node layout: ``nodes[n, 256, 3] int32`` —
+Node layout, host form: ``nodes[n, 256, 3] int32`` —
   ``nodes[x, b, 0]`` = child node index, or -1 (no child);
   ``nodes[x, b, 1]`` = identity *index* decided at this byte, or -1 (inherit
   the best match seen so far along the path);
@@ -15,6 +15,18 @@ Node layout: ``nodes[n, 256, 3] int32`` —
   snapshot's canonical prefixes in sorted order (``LPMTables.prefixes``), so
   a verdict can name the exact ipcache entry that won the walk — the
   match-provenance column the observer/flowlog surfaces (ISSUE 11).
+Placed form: the same entries as one 2-D table ``[n * 256, 3]``, entry
+``x * 256 + b`` (``LPMTables.v4_placed`` / ``v6_placed``: a view of the host
+form, nothing is copied). It is what ``PolicySnapshot.tensors()`` hands out
+as ``lpm_v4`` / ``lpm_v6`` and so what the device holds; the host form stays
+what ``lpm_lookup_host``, ``nbytes`` and the gauges read. Why two: a TPU
+lays a 32-bit ``[rows, 3]`` table out in tiles of 4 x 128 with the rows
+minor, which is the form a gather of whole entries reads, so the walk
+(kernels/lpm.py) reads the trie where it lies; a 3-D ``[n, 256, 3]`` array
+it lays out plane-major, and the compiled program then re-laid the whole
+trie before the first level of every batch (445 us for 122 MB beside 48 us
+of walking: ledger, PR 42 and 43). The tiles pad an entry's three words to
+four, so a TPU holds 4/3 of ``nbytes``.
 A sentinel "dead" node of all -1 lets the fixed-depth device loop run to full
 depth without data-dependent control flow: after a path ends, the gather
 chain idles in the dead node. Misses resolve to ``default_index``
@@ -63,6 +75,16 @@ class LPMTables:
     # same slot ids the device trie carries in its provenance plane
     prefixes: Tuple[str, ...] = ()
     pfx_slot_of: Dict[str, int] = field(default_factory=dict)
+
+    @property
+    def v4_placed(self) -> np.ndarray:
+        """[n4 * 256, 3] int32: the placed form, a view of ``v4_nodes``."""
+        return self.v4_nodes.reshape(-1, 3)
+
+    @property
+    def v6_placed(self) -> np.ndarray:
+        """[n6 * 256, 3] int32: the placed form, a view of ``v6_nodes``."""
+        return self.v6_nodes.reshape(-1, 3)
 
     @property
     def nbytes(self) -> int:

@@ -84,8 +84,8 @@ class PolicySnapshot:
         for name, arr in (
                 ("id_class_of", self.id_classes.class_of),
                 ("identity_ids", self.id_classes.identity_ids),
-                ("lpm_v4", self.lpm.v4_nodes),
-                ("lpm_v6", self.lpm.v6_nodes),
+                ("lpm_v4", self.lpm.v4_placed),
+                ("lpm_v6", self.lpm.v6_placed),
                 ("port_class", self.port_classes.table),
                 ("proto_family", self.proto_family_table),
                 ("l7_methods", self.l7.methods),

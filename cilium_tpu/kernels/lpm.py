@@ -16,10 +16,19 @@ the winning prefix, so the two can never disagree. ``lpm_walk_core`` /
 ``lpm_lookup_batch`` keep the index-only contract for callers that do not
 need provenance.
 
-The per-level gather is flattened to a single-axis ``take`` (node*256+byte)
-so the Mosaic lowering sees one supported gather per level instead of a 3-D
-fancy index; in-range indices make it bit-identical to the 3-D form (node is
-always a real node or the dead sentinel, byte is masked to 0..255).
+The tries arrive in their placed form, one 2-D table ``[n * 256, 3]`` a
+family (compile/lpm.py), and a level takes its entry with a single-axis
+``take`` (node * 256 + byte) from the placed table itself: no reshape stands
+between the parameter and its gather. A TPU lays that table out as a gather
+of whole entries reads it (tiles of 4 x 128, rows minor), so the walk reads
+the trie where it lies; handed ``[n, 256, 3]`` and flattening it here, the
+compiled program re-laid the whole trie into that form before the first
+level of every batch (tests/test_tpu_compile.py holds the compiled program
+to the form). A gather of one word a plane from a plane-major table copies
+nothing either and costs three to five times the walking (11-15 us a gather
+of 1,024 words whatever the table's size). In-range indices make the take
+bit-identical to a 3-D index ``nodes[node, byte]`` (node is always a real
+node or the dead sentinel, byte is masked to 0..255).
 """
 
 from __future__ import annotations
@@ -29,16 +38,14 @@ import jax.numpy as jnp
 from cilium_tpu.compile.lpm import V4_LEVELS, V6_LEVELS
 
 
-def _walk(nodes, addr_words, byte_index, levels, default_index):
-    """nodes [n,256,3] int32; addr_words [N,4] uint32; byte_index(l) gives the
+def _walk(flat, addr_words, byte_index, levels, default_index):
+    """flat [n*256,3] int32; addr_words [N,4] uint32; byte_index(l) gives the
     byte position 0..15 in the 16-byte address for level l. ``node``,
     ``best`` and ``best_meta`` live in registers across the whole chain —
     nothing but the node-triple gather touches memory per level. Returns
     (best identity index [N], best packed provenance [N], -1 on miss)."""
-    n_nodes = nodes.shape[0]
-    dead = n_nodes - 1
+    dead = flat.shape[0] // 256 - 1
     n = addr_words.shape[0]
-    flat = nodes.reshape(-1, 3)
     node = jnp.zeros((n,), dtype=jnp.int32)
     # default_index may be a traced scalar (snapshot-dependent) — broadcast,
     # don't bake

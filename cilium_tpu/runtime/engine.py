@@ -472,7 +472,10 @@ class Engine:
         self.metrics.set_gauge("engine_degraded", 0)
         self.metrics.set_gauge("regen_consecutive_failures", 0)
         # what the LPM walk and the LB step were placed with (the resource
-        # ledger's ``hbm`` row holds the tries' bytes only in total)
+        # ledger's ``hbm`` row holds the tries' bytes only in total). The
+        # bytes are the host tables' own, 12 an entry; a TPU tiles the
+        # placed ``[n * 256, 3]`` form 4 x 128 and holds 16 an entry, 4/3
+        # of the gauge (compile/lpm.py)
         lpm, lb = snap.lpm, snap.lb
         self.metrics.set_gauges({
             'lpm_trie_nodes{family="v4"}': lpm.v4_nodes.shape[0],

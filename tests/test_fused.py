@@ -126,7 +126,7 @@ def _lpm_parity(entries, probes, default_index=0):
     addr = np.stack([np.frombuffer(a, dtype=">u4").astype(np.uint32)
                      for a, _ in probes])
     is_v6 = np.asarray([v6 for _, v6 in probes])
-    v4n, v6n = jnp.asarray(tables.v4_nodes), jnp.asarray(tables.v6_nodes)
+    v4n, v6n = jnp.asarray(tables.v4_placed), jnp.asarray(tables.v6_placed)
     got_jnp, got_jnp_meta = lpm_lookup_prov_batch(
         v4n, v6n, jnp.asarray(addr), jnp.asarray(is_v6), default_index)
     got_fused, got_fused_meta = fk.lpm_lookup_fused(

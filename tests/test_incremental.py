@@ -372,6 +372,62 @@ class TestEngineIncremental:
         rendered = eng_inc.metrics.render_prometheus()
         assert "regen_incremental_total" in rendered
 
+    @pytest.mark.parametrize("mesh", [False, True],
+                             ids=["one-chip", "mesh"])
+    def test_ipcache_delta_reships_the_placed_tries(self, mesh):
+        """Full build and incremental patch alike place both tries in the
+        placed form (``[n * 256, 3]``, a view of the host form; what the
+        walk gathers from: kernels/lpm.py), one chip and mesh alike, and
+        a delta that leaves the node counts as they were compiles nothing
+        anew (counted on one chip, where the step is one jitted
+        function)."""
+        shards = dict(n_shards=2, rule_shards=2) if mesh else {}
+        eng = self._world_engine(JITDatapath(DaemonConfig(
+            ct_capacity=2048, auto_regen=False, **shards)))
+        try:
+            step = eng.datapath._classify
+            before = eng.active
+            batch = self._traffic(before.snapshot.ep_slot_of)
+            eng.classify(dict(batch), now=1000)
+            programs = None if mesh else step._cache_size()
+            patched = eng.metrics.counters.get("regen_incremental_total", 0)
+            # a second address of peer p0, in the node its first one made:
+            # a value more, no node more
+            eng.ctx.ipcache.upsert("172.16.0.6/32",
+                                   eng.endpoints[10].identity_id)
+            eng.regenerate()
+            after = eng.active
+            assert eng.metrics.counters["regen_incremental_total"] \
+                == patched + 1
+            assert after.snapshot.lpm.v4_nodes.shape \
+                == before.snapshot.lpm.v4_nodes.shape
+            for compiled in (before, after):
+                lpm, host = compiled.snapshot.lpm, compiled.snapshot.tensors()
+                for name, nodes in (("lpm_v4", lpm.v4_nodes),
+                                    ("lpm_v6", lpm.v6_nodes)):
+                    assert host[name].shape == (nodes.shape[0] * 256, 3)
+                    assert np.shares_memory(host[name], nodes)
+                    dev = compiled.tensors[name]
+                    assert dev.shape == host[name].shape
+                    np.testing.assert_array_equal(np.asarray(dev),
+                                                  host[name])
+            for name in ("lpm_v4", "lpm_v6"):        # re-shipped whole
+                assert after.tensors[name] is not before.tensors[name]
+            assert after.tensors["port_class"] is before.tensors["port_class"]
+            # as many rows as the first batch: the same bucket
+            pkts = [_mk_pkt(src, "192.168.1.10", 30000 + i, 80, 1,
+                            C.DIR_INGRESS)
+                    for i in range(6)
+                    for src in ("172.16.0.6", "172.16.0.5", "172.16.0.7")]
+            out = eng.classify(batch_from_records(
+                pkts, after.snapshot.ep_slot_of), now=1050)
+            ids = np.asarray(out["remote_identity"])[:3]
+            assert ids[0] == ids[1] == eng.endpoints[10].identity_id
+            assert ids[2] == C.IDENTITY_WORLD
+            assert mesh or step._cache_size() == programs
+        finally:
+            eng.stop()
+
     def test_incremental_sharded_backend(self):
         """place_patch through the meshed backend: device-side row updates
         on a sharded verdict tensor."""

@@ -8,11 +8,12 @@ import jax.numpy as jnp
 
 from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
 from cilium_tpu.compile.l7 import L7SetInterner, build_l7_tensors, l7_match_host
-from cilium_tpu.compile.lpm import build_lpm, lpm_lookup_host
+from cilium_tpu.compile.lpm import (build_lpm, lpm_lookup_host,
+                                    lpm_lookup_host_prov, pack_pfx)
 from cilium_tpu.kernels import conntrack as ctk
 from cilium_tpu.kernels.hashing import hash_words_jnp, hash_words_np
 from cilium_tpu.kernels.l7 import l7_match_batch
-from cilium_tpu.kernels.lpm import lpm_lookup_batch
+from cilium_tpu.kernels.lpm import lpm_lookup_batch, lpm_lookup_prov_batch
 from cilium_tpu.kernels.records import (PACK4_L7_WORDS, PACK4_WORDS,
                                         PACK_L7DICT_WORDS, PACK_WORDS,
                                         _pack_path_dict, _pad_dict_rows,
@@ -25,6 +26,7 @@ from cilium_tpu.kernels.records import (PACK4_L7_WORDS, PACK4_WORDS,
 from cilium_tpu.model.rules import HTTPRule
 from cilium_tpu.utils import constants as C
 from cilium_tpu.utils.ip import parse_addr
+from tests.test_fused import _fuzz_addresses, _random_prefix_set
 
 
 class TestHash:
@@ -60,10 +62,144 @@ class TestLPMKernel:
             is_v6[i] = v6
             want.append(lpm_lookup_host(tables, a16, v6))
         got = np.asarray(lpm_lookup_batch(
-            jnp.asarray(tables.v4_nodes), jnp.asarray(tables.v6_nodes),
+            jnp.asarray(tables.v4_placed), jnp.asarray(tables.v6_placed),
             jnp.asarray(addr_words), jnp.asarray(is_v6),
             default_index=index[C.IDENTITY_WORLD]))
         np.testing.assert_array_equal(got, np.asarray(want))
+
+
+#: the tries the placed form is held over (PR 44): ipcache → the node
+#: counts it has to build, dead sentinel included. 2 and 11 are what
+#: ``ct1m-50k`` and ``l7-http`` place; the last is of the kind
+#: ``lpm100k-zipf`` and ``node-mixed`` place, a hundredth the size.
+PLACED_TRIES = {
+    # a /0 sits in every cell of the root; the v6 trie holds nothing
+    "two-nodes": ({"0.0.0.0/0": 600}, 2, 2),
+    "eleven-nodes": ({"10.1.2.3/32": 100, "10.1.9.9/32": 200,
+                      "10.2.0.1/32": 300, "172.16.0.5/32": 400,
+                      "10.0.0.0/8": 500, "10.1.0.0/16": 600,
+                      "10.16.0.0/12": 700, "2001:db8::/32": 800,
+                      "2001:db8:0:8::/61": 900}, 11, 9),
+    "thousands": (_random_prefix_set(np.random.default_rng(44), 3000, 500),
+                  None, None),
+}
+
+
+def _build(entries):
+    """→ (tables, identity → index) with world as the default."""
+    ids = sorted(set(entries.values()) | {C.IDENTITY_WORLD})
+    index = {v: i for i, v in enumerate(ids)}
+    return build_lpm(entries, index,
+                     default_index=index[C.IDENTITY_WORLD]), index
+
+
+def _walk_placed(tables, addrs, **kw):
+    """The device walk over the placed form for ``addrs`` [(a16, is_v6)]
+    → (identity index, provenance) as numpy."""
+    got = lpm_lookup_prov_batch(
+        jnp.asarray(tables.v4_placed), jnp.asarray(tables.v6_placed),
+        jnp.asarray(np.stack([np.frombuffer(a16, dtype=">u4")
+                              .astype(np.uint32) for a16, _ in addrs])),
+        jnp.asarray([v6 for _, v6 in addrs]),
+        default_index=tables.default_index, **kw)
+    return np.asarray(got[0]), np.asarray(got[1])
+
+
+def _walk_3d(nodes, data, default_index):
+    """The walk by a 3-D index ``nodes[node, b]`` over the host form, a
+    whole batch at a time: what the placed form's take has to equal bit
+    for bit. ``data`` [N, levels] uint8."""
+    dead = nodes.shape[0] - 1
+    node = np.zeros(len(data), np.int64)
+    best = np.full(len(data), default_index, np.int32)
+    meta = np.full(len(data), -1, np.int32)
+    for level in range(data.shape[1]):
+        child, value, m = np.moveaxis(nodes[node, data[:, level]], 1, 0)
+        best = np.where(value >= 0, value, best)
+        meta = np.where(value >= 0, m, meta)
+        node = np.where(child >= 0, child, dead)
+    return best, meta
+
+
+class TestLPMPlacedForm:
+    """The walk takes its entries from the placed form ``[n * 256, 3]``
+    (compile/lpm.py) and gives what the host mirror and a 3-D index over
+    the host form ``[n, 256, 3]`` give: identity index and provenance, both
+    families in one batch, hits, misses and paths that leave the trie."""
+
+    @pytest.fixture(scope="class", params=sorted(PLACED_TRIES))
+    def world(self, request):
+        entries, n4, n6 = PLACED_TRIES[request.param]
+        tables, _ = _build(entries)
+        if n4 is None:
+            assert tables.v4_nodes.shape[0] > 2000 \
+                and tables.v6_nodes.shape[0] > 2000
+        else:
+            assert (tables.v4_nodes.shape[0],
+                    tables.v6_nodes.shape[0]) == (n4, n6)
+        # half inside the set's prefixes with random host bits (hits, and
+        # paths that leave the trie below a shorter match: the chain idles
+        # in the dead sentinel), half random addresses of both families
+        addrs = _fuzz_addresses(np.random.default_rng(len(entries)),
+                                entries, 384)
+        return tables, addrs
+
+    def test_placed_is_a_view_of_the_host_form(self, world):
+        tables, _ = world
+        for placed, nodes in ((tables.v4_placed, tables.v4_nodes),
+                              (tables.v6_placed, tables.v6_nodes)):
+            assert placed.shape == (nodes.shape[0] * 256, 3)
+            assert placed.dtype == np.int32
+            assert np.shares_memory(placed, nodes)
+            x = nodes.shape[0] - 1
+            np.testing.assert_array_equal(placed[x * 256 + 7], nodes[x, 7])
+            np.testing.assert_array_equal(placed[255], nodes[0, 255])
+
+    @pytest.mark.parametrize("v4_only", [False, True])
+    def test_walk_equals_host_mirror_and_3d_index(self, world, v4_only):
+        tables, addrs = world
+        world_index = tables.default_index
+        raw = np.stack([np.frombuffer(a16, np.uint8) for a16, _ in addrs])
+        is_v6 = np.asarray([v6 for _, v6 in addrs])
+        assert is_v6.any() and not is_v6.all()      # a mixed-family batch
+        r4, m4 = _walk_3d(tables.v4_nodes, raw[:, 12:], world_index)
+        r6, m6 = _walk_3d(tables.v6_nodes, raw, world_index)
+        mirror = [lpm_lookup_host_prov(tables, a16, v6) for a16, v6 in addrs]
+        sel_r, sel_m = np.where(is_v6, r6, r4), np.where(is_v6, m6, m4)
+        np.testing.assert_array_equal(sel_r, [w[0] for w in mirror])
+        np.testing.assert_array_equal(sel_m, [w[1] for w in mirror])
+        assert (sel_m >= 0).any() and (sel_m < 0).any()   # hits and misses
+        got_index, got_prov = _walk_placed(tables, addrs, v4_only=v4_only)
+        # ``v4_only`` walks every row's last four bytes through the v4 trie
+        want_r, want_m = (r4, m4) if v4_only else (sel_r, sel_m)
+        np.testing.assert_array_equal(got_index, want_r)
+        np.testing.assert_array_equal(got_prov, want_m)
+
+    def test_named_probes(self):
+        """By hand, over the eleven-node tries: the prefix that has to win
+        (None: a miss, ``default_index`` and provenance -1)."""
+        entries = PLACED_TRIES["eleven-nodes"][0]
+        probes = [("10.1.2.3", "10.1.2.3/32"), ("10.1.2.4", "10.1.0.0/16"),
+                  ("10.1.3.4", "10.1.0.0/16"), ("10.2.0.2", "10.0.0.0/8"),
+                  ("10.16.0.1", "10.16.0.0/12"),
+                  ("10.31.255.255", "10.16.0.0/12"),
+                  ("10.32.0.0", "10.0.0.0/8"), ("172.16.0.6", None),
+                  ("9.9.9.9", None), ("2001:db8:0:f::1", "2001:db8:0:8::/61"),
+                  ("2001:db8:0:10::1", "2001:db8::/32"),
+                  ("2001:db9::1", None), ("fe80::1", None)]
+        tables, index = _build(entries)
+        want = []
+        for _, prefix in probes:
+            if prefix is None:
+                want.append((tables.default_index, -1))
+                continue
+            plen = int(prefix.rsplit("/", 1)[1])
+            want.append((index[entries[prefix]],
+                         pack_pfx(tables.pfx_slot_of[prefix],
+                                  plen if ":" in prefix else plen + 96)))
+        got_index, got_prov = _walk_placed(
+            tables, [parse_addr(a) for a, _ in probes])
+        assert list(zip(got_index.tolist(), got_prov.tolist())) == want
 
 
 class TestL7Kernel:

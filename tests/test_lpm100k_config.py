@@ -24,7 +24,9 @@ list and the rule parameters).
     the ladder's three placed tables (``verdict``, ``port_class``,
     ``enforced``) reach their gather as the parameters they are placed
     as (``kernels/policy.py``; PR 35: the flat take cost the chip a
-    copy of the whole image in every batch);
+    copy of the whole image in every batch), and so do the two tries,
+    placed as one table of entries each (``kernels/lpm.py``; PR 44: the
+    reshape in the walk cost the chip a copy of the whole trie);
 (e) the counters ``ciliumtpu_lb_translated_rows_total``,
     ``ciliumtpu_lb_no_backend_rows_total`` and
     ``ciliumtpu_lpm_rows_total{plen}`` add up to the rows submitted, bin
@@ -366,24 +368,50 @@ def test_the_programs_carry_the_kernels_names(programs, deployment, scope,
                    for line in text.splitlines())
 
 
+def uses_of_parameter(text, table):
+    """The name of the parameter ``tensors[table]`` is placed as, and every
+    line of ``@main`` that names it."""
+    main = text.split("func.func public @main", 1)[1]
+    head, body = main.split("\n", 1)
+    body = body.split("func.func", 1)[0]
+    arg, = re.findall(
+        r"(%arg\d+): tensor<[^>]*> loc\(\"tensors\['" + table + r"'\]\"\)",
+        head)
+    return arg, [line.strip() for line in body.splitlines()
+                 if re.search(re.escape(arg) + r"\b", line)]
+
+
+def is_a_gather_of(arg, line):
+    return re.search(r'= "stablehlo\.gather"\(' + re.escape(arg) + ",", line)
+
+
 @pytest.mark.parametrize("table", ["verdict", "port_class", "enforced"])
 def test_the_ladder_gathers_from_the_placed_table_itself(programs, table):
     """Every use of the table's parameter in ``@main`` is a
     ``stablehlo.gather`` with the parameter as its operand: no reshape,
     transpose, copy or call stands between the placed table and its
     gather."""
-    main = programs["tiny-pods"].split("func.func public @main", 1)[1]
-    head, body = main.split("\n", 1)
-    body = body.split("func.func", 1)[0]
-    arg, = re.findall(
-        r"(%arg\d+): tensor<[^>]*> loc\(\"tensors\['" + table + r"'\]\"\)",
-        head)
-    uses = [line.strip() for line in body.splitlines()
-            if re.search(re.escape(arg) + r"\b", line)]
+    arg, uses = uses_of_parameter(programs["tiny-pods"], table)
     assert uses, table
     for line in uses:
-        assert re.search(r'= "stablehlo\.gather"\(' + re.escape(arg) + ",",
-                         line), (table, line[:200])
+        assert is_a_gather_of(arg, line), (table, line[:200])
+
+
+@pytest.mark.parametrize("deployment", ["lpm100k-zipf", "tiny-pods"])
+@pytest.mark.parametrize("trie,levels", [("lpm_v4", 4), ("lpm_v6", 16)])
+def test_the_walk_gathers_from_the_placed_trie_itself(programs, deployment,
+                                                      trie, levels):
+    """The same for the two tries (PR 44): every use of a placed trie in
+    ``@main`` is a gather of whole entries ``[1, 3]`` from the 2-D
+    parameter, one a level. A reshape between the placed trie and its read
+    is what made the TPU's compiler re-lay the whole table in every
+    batch."""
+    arg, uses = uses_of_parameter(programs[deployment], trie)
+    assert len(uses) == levels, (trie, len(uses))
+    for line in uses:
+        assert is_a_gather_of(arg, line), (trie, line[:200])
+        assert "slice_sizes = array<i64: 1, 3>" in line \
+            and re.search(r"\(tensor<\d+x3xi32>,", line), line[:400]
 
 
 # -- (e) ---------------------------------------------------------------------
