@@ -26,18 +26,19 @@ is the TPU-friendly equivalent and is what the oracle specifies.)
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass, field
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
 from cilium_tpu.kernels.hashing import hash_words_np
 from cilium_tpu.model.services import Backend, Frontend, Service
-from cilium_tpu.utils.ip import addr_to_words, parse_addr
+from cilium_tpu.utils.ip import parse_addr
 
 FE_KEY_WORDS = 6          # addr[4], port, proto
 LB_PROBE_DEPTH = 8
-MAGLEV_M_DEFAULT = 251    # prime; production-sized tables use 16381
+MAGLEV_M_DEFAULT = 251    # prime; upstream's default 16381 runs in the
+#                           benchmark's svc10k-maglev (10,000 rows of it)
 
 
 @dataclass(frozen=True)
@@ -71,10 +72,21 @@ class LBTables:
     frontends: Tuple[Frontend, ...]
     fe_names: Tuple[str, ...]   # "namespace/name" per frontend
     backends: Tuple[Backend, ...]
+    # Maglev row r was populated from backends[row_base[r]:row_base[r+1]]
+    # (its entries are those indices): what a later build reuses a row by
+    row_base: np.ndarray = field(
+        default_factory=lambda: np.zeros((1,), dtype=np.int64))
+    rows_built: int = 0         # rows this build populated, not reused
 
     @property
     def n_frontends(self) -> int:
         return len(self.frontends)
+
+    @property
+    def n_services(self) -> int:
+        """Maglev rows populated for a service (the table has one row of
+        -1 where there is none)."""
+        return len(self.row_base) - 1
 
     def tensors(self) -> Dict[str, np.ndarray]:
         return {
@@ -100,62 +112,206 @@ def _is_prime(n: int) -> bool:
     return True
 
 
-def _fe_key_words(addr16: bytes, port: int, proto: int) -> np.ndarray:
-    w = addr_to_words(addr16)
-    return np.array([w[0], w[1], w[2], w[3], port, proto], dtype=np.uint32)
+def _name_hashes(names: Sequence[str], suffix: str) -> np.ndarray:
+    """[n] uint32: ``hash_words_np(_str_hash_words(name + suffix))`` of every
+    name, the names of one padded length hashed together."""
+    data = [(name + suffix).encode() for name in names]
+    words = np.array([-(-len(d) // 4) for d in data], dtype=np.int64)
+    out = np.zeros((len(data),), dtype=np.uint32)
+    for w in np.unique(words).tolist():
+        idx = np.nonzero(words == w)[0]
+        buf = b"".join(data[i].ljust(4 * w, b"\x00") for i in idx.tolist())
+        out[idx] = hash_words_np(np.frombuffer(buf, dtype="<u4")
+                                 .reshape(idx.size, w))
+    return out
 
 
-def _str_hash_words(s: str) -> np.ndarray:
-    data = s.encode()
-    data += b"\x00" * (-len(data) % 4)
-    return np.frombuffer(data, dtype="<u4").astype(np.uint32)
+def _permutations(backends: Sequence[Backend], m: int):
+    """→ (offset, skip) [n] int64 of each backend's permutation of [0, M),
+    from its name's two hashes."""
+    names = [f"{b.addr}:{b.port}" for b in backends]
+    offsets = _name_hashes(names, "#o").astype(np.int64) % m
+    skips = _name_hashes(names, "#s").astype(np.int64) % (m - 1) + 1
+    return offsets, skips
+
+
+def _maglev_rows_py(offsets, skips, weights, row_start, m: int) -> np.ndarray:
+    """The population without the native library: the backends of a row take
+    turns claiming their next unclaimed permutation slot, over Python ints."""
+    out = np.full((len(row_start) - 1, m), -1, dtype=np.int32)
+    for r in range(len(row_start) - 1):
+        b0, b1 = int(row_start[r]), int(row_start[r + 1])
+        if b0 == b1:
+            continue
+        row = [-1] * m
+        at = offsets[b0:b1].tolist()
+        skip = skips[b0:b1].tolist()
+        turns = [i for i, w in enumerate(weights[b0:b1].tolist())
+                 for _ in range(w)]
+        filled = 0
+        while filled < m:
+            for i in turns:
+                c = at[i]
+                while row[c] >= 0:
+                    c = (c + skip[i]) % m
+                row[c] = i
+                at[i] = (c + skip[i]) % m
+                filled += 1
+                if filled == m:
+                    break
+        out[r] = row
+    return out
+
+
+def _native_fill():
+    """``shim_maglev_fill`` of the shim's library, or None where the library
+    is not built (or was built before it had one)."""
+    import ctypes
+    try:
+        from cilium_tpu.shim.bindings import _load_lib
+        fill = _load_lib().shim_maglev_fill
+    except (OSError, AttributeError):
+        return None
+    p64, p32 = ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int32)
+    fill.restype = None
+    fill.argtypes = [p64, p64, p32, p64, ctypes.c_uint32, ctypes.c_uint32,
+                     p32, ctypes.c_uint32]
+    return lambda off, skip, w, start, rows, m, out, threads: fill(
+        off.ctypes.data_as(p64), skip.ctypes.data_as(p64),
+        w.ctypes.data_as(p32), start.ctypes.data_as(p64), rows, m,
+        out.ctypes.data_as(p32), threads)
+
+
+MAGLEV_FILL_THREADS = 8
+
+
+def maglev_rows(rows: Sequence[Sequence[Backend]], m: int,
+                native: bool = True,
+                out: Optional[np.ndarray] = None) -> np.ndarray:
+    """Standard Maglev population (the upstream pkg/loadbalancer algorithm
+    shape) of one row a backend list → ``[len(rows), M]`` int32 of row-local
+    backend indices (-1 throughout for an empty list): each backend gets a
+    permutation of [0, M) from (offset, skip) derived from its name hash;
+    backends take turns claiming their next unclaimed slot, weighted
+    backends take ``weight`` consecutive turns. No Python step a slot: the
+    turns run in the shim's library (``shim_maglev_fill``), over Python ints
+    where it is not built; both give the one table
+    (tests/test_maglev_build.py holds them to the loop this replaced).
+    ``out``: a C-contiguous ``[len(rows), M]`` int32 array to populate."""
+    if not _is_prime(m):
+        raise ValueError(f"maglev M must be prime, got {m}")
+    flat = [b for row in rows for b in row]
+    row_start = np.concatenate([[0], np.cumsum(
+        [len(row) for row in rows], dtype=np.int64)]).astype(np.int64)
+    offsets, skips = _permutations(flat, m)
+    weights = np.array([b.weight for b in flat], dtype=np.int32)
+    fill = _native_fill() if native else None
+    if out is None:
+        out = np.empty((len(rows), m), dtype=np.int32)
+    if fill is None:
+        out[:] = _maglev_rows_py(offsets, skips, weights, row_start, m)
+        return out
+    fill(np.ascontiguousarray(offsets), np.ascontiguousarray(skips), weights,
+         row_start, len(rows), m, out, MAGLEV_FILL_THREADS)
+    return out
 
 
 def maglev_table(backends: Sequence[Backend], m: int) -> np.ndarray:
-    """Standard Maglev population (the upstream pkg/loadbalancer algorithm
-    shape): each backend gets a permutation of [0, M) from (offset, skip)
-    derived from its name hash; backends take turns claiming their next
-    unclaimed slot, weighted backends take ``weight`` consecutive turns."""
-    if not _is_prime(m):
-        raise ValueError(f"maglev M must be prime, got {m}")
-    n = len(backends)
-    if n == 0:
-        return np.full((m,), -1, dtype=np.int32)
-    offsets = np.empty(n, dtype=np.int64)
-    skips = np.empty(n, dtype=np.int64)
-    for i, b in enumerate(backends):
-        name = f"{b.addr}:{b.port}"
-        h1 = int(hash_words_np(_str_hash_words(name + "#o"))[()])
-        h2 = int(hash_words_np(_str_hash_words(name + "#s"))[()])
-        offsets[i] = h1 % m
-        skips[i] = h2 % (m - 1) + 1
-    table = np.full((m,), -1, dtype=np.int32)
-    next_idx = np.zeros(n, dtype=np.int64)
-    filled = 0
-    while filled < m:
-        for i, b in enumerate(backends):
-            for _ in range(b.weight):
-                # claim the backend's next unclaimed permutation slot
-                while True:
-                    c = (offsets[i] + next_idx[i] * skips[i]) % m
-                    next_idx[i] += 1
-                    if table[c] < 0:
-                        table[c] = i
-                        filled += 1
-                        break
-                if filled == m:
-                    return table
-    return table
+    """One service's row: ``maglev_rows`` of one list."""
+    return maglev_rows([backends], m)[0]
 
 
-def build_lb(registry_or_services,
-             cfg: Optional[LBConfig] = None) -> LBTables:
+def _addr_words(addrs: Sequence[str]) -> np.ndarray:
+    """[n, 4] uint32 device words of each address literal."""
+    packed = b"".join(parse_addr(a)[0] for a in addrs)
+    return np.frombuffer(packed, dtype=">u4").reshape(len(addrs), 4) \
+        .astype(np.uint32)
+
+
+def _fill_frontend_table(fe_keys: np.ndarray, probe_depth: int):
+    """The open-addressed frontend table: key i sits in the first slot of
+    its probe window that no key before it took, the capacity doubling until
+    every key fits. The keys are hashed once and placed a pass at a time: a
+    pass gives every key still unplaced the first free slot of its window
+    (the lowest key where several want one), and keeps a placement only
+    where no unplaced key before it has that slot in its window, which is
+    when inserting the keys one after the other gives the same."""
+    F = fe_keys.shape[0]
+    hashes = hash_words_np(fe_keys).astype(np.int64) if F else \
+        np.zeros((0,), np.int64)
+    cap = 8
+    while cap < 2 * max(F, 1):
+        cap *= 2
+    while True:
+        slot_of = _place_keys(hashes & (cap - 1), cap, probe_depth)
+        if slot_of is not None:
+            break
+        cap *= 2
+    tab_keys = np.zeros((cap, FE_KEY_WORDS), dtype=np.uint32)
+    tab_val = np.full((cap,), -1, dtype=np.int32)
+    tab_keys[slot_of] = fe_keys
+    tab_val[slot_of] = np.arange(F, dtype=np.int32)
+    return tab_keys, tab_val
+
+
+def _place_keys(base: np.ndarray, cap: int, depth: int
+                ) -> Optional[np.ndarray]:
+    """→ [F] the slot sequential insertion gives each key, or None where
+    some key's window is full."""
+    F = base.shape[0]
+    slot_of = np.full((F,), -1, dtype=np.int64)
+    taken = np.zeros((cap,), dtype=bool)
+    todo = np.arange(F, dtype=np.int64)
+    window = np.arange(depth, dtype=np.int64)
+    never = np.int64(F)
+    while todo.size:
+        slots = (base[todo, None] + window) & (cap - 1)       # [n, depth]
+        free = ~taken[slots]
+        if not free.any(axis=1).all():
+            # a window full of keys placed for good: each of them came
+            # before this key (one after it is final only once this key is)
+            return None
+        want = slots[np.arange(todo.size), free.argmax(axis=1)]
+        lowest = np.full((cap,), never)
+        np.minimum.at(lowest, want, todo)
+        won = lowest[want] == todo
+        # a placement is final when no unsettled key before it could still
+        # come to its slot; the unsettled are the losers, and then every
+        # winner that one of the unsettled before it could displace
+        unsettled = ~won
+        while True:
+            reach = np.full((cap,), never)
+            np.minimum.at(reach, slots[unsettled].ravel(),
+                          np.repeat(todo[unsettled], depth))
+            more = won & ~unsettled & (reach[want] < todo)
+            if not more.any():
+                break
+            unsettled |= more
+        final = ~unsettled          # the lowest unplaced key always is
+        slot_of[todo[final]] = want[final]
+        taken[want[final]] = True
+        todo = todo[~final]
+    return slot_of
+
+
+def _row_keys(lb: "LBTables"):
+    """What each Maglev row of ``lb`` was populated from → its index."""
+    return {lb.backends[lb.row_base[r]:lb.row_base[r + 1]]: r
+            for r in range(len(lb.row_base) - 1)}
+
+
+def build_lb(registry_or_services, cfg: Optional[LBConfig] = None,
+             prev: Optional[LBTables] = None) -> LBTables:
     """Compile LB state. Deterministic given the service set
     (services/frontends iterated in sorted registry order).
 
     Accepts a ServiceRegistry (preferred: its stable rev-NAT id allocator is
     used) or a plain Service sequence (ids fall back to positional — only
-    safe when the service set never changes, e.g. one-shot tests)."""
+    safe when the service set never changes, e.g. one-shot tests).
+
+    ``prev`` is an earlier build's result: a service's Maglev row is taken
+    from it while its backends, their weights and M stand, so a build after
+    a policy-only change populates none (``rows_built``)."""
     cfg = cfg or LBConfig()
     if hasattr(registry_or_services, "all"):
         services: Sequence[Service] = registry_or_services.all()
@@ -165,96 +321,88 @@ def build_lb(registry_or_services,
         _pos = {}
         rnat_id_of = lambda fe: _pos.setdefault(  # noqa: E731
             (fe.addr, fe.port, fe.proto), len(_pos))
-    frontends: List[Frontend] = []
-    fe_names: List[str] = []
-    fe_service: List[int] = []
-    fe_rnat_ids: List[int] = []
-    maglev_rows: List[np.ndarray] = []
-    all_backends: List[Backend] = []
+    services = [svc for svc in services if svc.frontends]
+    frontends = [fe for svc in services for fe in svc.frontends]
+    fe_names = [f"{svc.namespace}/{svc.name}" for svc in services
+                for _fe in svc.frontends]
+    fe_service = np.repeat(np.arange(len(services), dtype=np.int32),
+                           [len(svc.frontends) for svc in services])
+    fe_rnat_ids = [rnat_id_of(fe) for fe in frontends]
+    rows = [tuple(svc.lb_backends) for svc in services]
+    all_backends = tuple(b for row in rows for b in row)
+    row_base = np.concatenate([[0], np.cumsum(
+        [len(row) for row in rows], dtype=np.int64)]).astype(np.int64)
 
-    for svc in services:
-        if not svc.frontends:
-            continue
-        base = len(all_backends)
-        local = list(svc.lb_backends)
-        all_backends.extend(local)
-        row = maglev_table(local, cfg.maglev_m)
-        row = np.where(row >= 0, row + base, -1).astype(np.int32)
-        srow = len(maglev_rows)
-        maglev_rows.append(row)
-        for fe in svc.frontends:
-            frontends.append(fe)
-            fe_names.append(f"{svc.namespace}/{svc.name}")
-            fe_service.append(srow)
-            fe_rnat_ids.append(rnat_id_of(fe))
-
-    F = len(frontends)
-    B = len(all_backends)
-    S = len(maglev_rows)
+    F, B, S, m = len(frontends), len(all_backends), len(rows), cfg.maglev_m
     R = max(fe_rnat_ids) + 1 if fe_rnat_ids else 1
+    fe_addr16 = [parse_addr(fe.addr)[0] for fe in frontends]
+    fe_keys = np.zeros((max(F, 1), FE_KEY_WORDS), dtype=np.uint32)
+    if F:
+        fe_keys[:, :4] = np.frombuffer(b"".join(fe_addr16), dtype=">u4") \
+            .reshape(F, 4)
+        fe_keys[:, 4] = [fe.port for fe in frontends]
+        fe_keys[:, 5] = [fe.proto for fe in frontends]
+    seen_keys = {}
+    for i, (addr16, fe) in enumerate(zip(fe_addr16, frontends)):
+        first = seen_keys.setdefault((addr16, fe.port, fe.proto), i)
+        if first != i:
+            raise ValueError(
+                f"duplicate service frontend {fe.addr}:{fe.port}/{fe.proto}: "
+                f"declared by both {fe_names[first]} and {fe_names[i]}")
     rnat_addr = np.zeros((R, 4), dtype=np.uint32)
     rnat_port = np.zeros((R,), dtype=np.int32)
     rnat_valid = np.zeros((R,), dtype=bool)
-    fe_keys = np.zeros((max(F, 1), FE_KEY_WORDS), dtype=np.uint32)
-    seen_keys = {}
-    for i, fe in enumerate(frontends):
-        addr16, _v6 = parse_addr(fe.addr)
-        fe_keys[i] = _fe_key_words(addr16, fe.port, fe.proto)
-        k = (addr16, fe.port, fe.proto)
-        if k in seen_keys:
-            raise ValueError(
-                f"duplicate service frontend {fe.addr}:{fe.port}/{fe.proto}: "
-                f"declared by both {fe_names[seen_keys[k]]} and {fe_names[i]}")
-        seen_keys[k] = i
-        rid = fe_rnat_ids[i]
-        rnat_addr[rid] = fe_keys[i, :4]
-        rnat_port[rid] = fe.port
-        rnat_valid[rid] = True
+    if F:
+        rnat_addr[fe_rnat_ids] = fe_keys[:F, :4]
+        rnat_port[fe_rnat_ids] = fe_keys[:F, 4]
+        rnat_valid[fe_rnat_ids] = True
 
     be_addr = np.zeros((max(B, 1), 4), dtype=np.uint32)
     be_port = np.zeros((max(B, 1),), dtype=np.int32)
-    for i, b in enumerate(all_backends):
-        addr16, _v6 = parse_addr(b.addr)
-        be_addr[i] = np.array(addr_to_words(addr16), dtype=np.uint32)
-        be_port[i] = b.port
+    if B:
+        be_addr[:] = _addr_words([b.addr for b in all_backends])
+        be_port[:] = [b.port for b in all_backends]
 
-    maglev = (np.stack(maglev_rows) if S
-              else np.full((1, cfg.maglev_m), -1, dtype=np.int32))
+    # the Maglev rows, row-local indices shifted to global ones: taken from
+    # ``prev`` where the row's backends stand, populated together otherwise
+    fresh = list(range(S))
+    if prev is not None and prev.maglev.shape[1] == m and S \
+            and prev.backends == all_backends \
+            and np.array_equal(prev.row_base, row_base):
+        maglev, fresh = prev.maglev, []          # no service changed
+    else:
+        maglev = np.empty((max(S, 1), m), dtype=np.int32)
+        had = _row_keys(prev) if prev is not None \
+            and prev.maglev.shape[1] == m else {}
+        fresh = [r for r in range(S) if rows[r] not in had]
+        if len(fresh) == S:
+            maglev_rows(rows, m, out=maglev[:S])        # in place
+        elif fresh:
+            maglev[fresh] = maglev_rows([rows[r] for r in fresh], m)
+        kept = set(range(S)).difference(fresh)
+        for r in range(S):
+            shift = row_base[r]
+            if r in kept:
+                k = had[rows[r]]
+                maglev[r] = prev.maglev[k]
+                shift -= prev.row_base[k]
+            if rows[r] and shift:
+                maglev[r] += np.int32(shift)
+        if not S:
+            maglev[:] = -1
 
-    # open-addressed frontend table; grow until every key fits in the window
-    cap = 8
-    while cap < 2 * max(F, 1):
-        cap *= 2
-    while True:
-        tab_keys = np.zeros((cap, FE_KEY_WORDS), dtype=np.uint32)
-        tab_val = np.full((cap,), -1, dtype=np.int32)
-        ok = True
-        for i in range(F):
-            base_h = int(hash_words_np(fe_keys[i])[()]) & (cap - 1)
-            for d in range(cfg.probe_depth):
-                s = (base_h + d) & (cap - 1)
-                if tab_val[s] < 0:
-                    tab_keys[s] = fe_keys[i]
-                    tab_val[s] = i
-                    break
-            else:
-                ok = False
-                break
-        if ok:
-            break
-        cap *= 2
+    tab_keys, tab_val = _fill_frontend_table(fe_keys[:F], cfg.probe_depth)
 
     return LBTables(
         tab_keys=tab_keys, tab_val=tab_val,
-        fe_service=np.asarray(fe_service, dtype=np.int32)
-        if F else np.zeros((1,), dtype=np.int32),
+        fe_service=fe_service if F else np.zeros((1,), dtype=np.int32),
         fe_rnat_id=np.asarray(fe_rnat_ids, dtype=np.int32)
         if F else np.zeros((1,), dtype=np.int32),
         rnat_addr=rnat_addr, rnat_port=rnat_port, rnat_valid=rnat_valid,
         maglev=maglev, be_addr=be_addr, be_port=be_port,
         probe_depth=cfg.probe_depth,
         frontends=tuple(frontends), fe_names=tuple(fe_names),
-        backends=tuple(all_backends),
+        backends=all_backends, row_base=row_base, rows_built=len(fresh),
     )
 
 

@@ -134,8 +134,11 @@ def _proto_family_table() -> np.ndarray:
 def build_snapshot(repo: Repository, ctx: PolicyContext,
                    endpoints: Sequence[Endpoint],
                    ct_config: Optional[CTConfig] = None,
-                   lb_config: Optional[LBConfig] = None) -> PolicySnapshot:
-    """Compile the current control-plane state for ``endpoints``.
+                   lb_config: Optional[LBConfig] = None,
+                   lb: Optional[LBTables] = None) -> PolicySnapshot:
+    """Compile the current control-plane state for ``endpoints``. ``lb``:
+    the LB tables where the caller has built them (the engine does, under a
+    span of its own and with the active snapshot's rows to reuse).
 
     Mirrors the regeneration pipeline (SURVEY.md §3.2): resolve policy per
     endpoint → MapStates → dense tensors. Deterministic given (rules,
@@ -170,7 +173,8 @@ def build_snapshot(repo: Repository, ctx: PolicyContext,
     lpm = build_lpm(ipcache_snapshot, id_classes.index_of,
                     default_index=id_classes.index_of[C.IDENTITY_WORLD])
 
-    lb = build_lb(ctx.services, lb_config)  # registry → stable rev-NAT ids
+    if lb is None:
+        lb = build_lb(ctx.services, lb_config)  # registry: stable rev-NAT ids
 
     return PolicySnapshot(
         revision=repo.revision,

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from cilium_tpu.model.labels import Labels
 from cilium_tpu.model.selectors import EndpointSelector
@@ -25,6 +25,8 @@ SVC_CLUSTER_IP = "ClusterIP"
 SVC_NODEPORT = "NodePort"
 SVC_EXTERNAL_IP = "ExternalIP"
 SVC_LOADBALANCER = "LoadBalancer"
+NAME_LABEL = "k8s:io.kubernetes.service.name"
+NAMESPACE_LABEL = "k8s:io.kubernetes.service.namespace"
 
 
 @dataclass(frozen=True)
@@ -69,18 +71,24 @@ class Service:
 
     @property
     def labels(self) -> Labels:
-        base = {
-            "k8s:io.kubernetes.service.name": self.name,
-            "k8s:io.kubernetes.service.namespace": self.namespace,
-        }
+        base = {NAME_LABEL: self.name, NAMESPACE_LABEL: self.namespace}
         base.update({k: v for k, v in self.extra_labels})
         return Labels.parse([f"{k}={v}" if v else k for k, v in base.items()])
 
 
 class ServiceRegistry:
-    def __init__(self):
+    def __init__(self, lb_map_max: Optional[int] = None):
+        """``lb_map_max`` (``DaemonConfig.lb_map_max``, upstream's
+        ``bpf-lb-map-max``): the most frontends, and the most LB backends,
+        the registry's services may have together; None for no limit."""
         self._lock = threading.RLock()
+        self.lb_map_max = lb_map_max
+        self._n_frontends = 0
+        self._n_backends = 0
         self._services: Dict[Tuple[str, str], Service] = {}
+        # services whose extra labels restate the name or the namespace
+        # label: the only ones ``match`` cannot find by (namespace, name)
+        self._relabelled: set = set()
         self._observers: List[Callable[[], None]] = []
         # Stable rev-NAT id per frontend (addr16, port, proto) — the analog
         # of upstream's allocated RevNatID: ids survive service churn so
@@ -160,6 +168,18 @@ class ServiceRegistry:
                             f"service {svc.namespace}/{svc.name} conflicts "
                             f"with existing service {owner[0]}/{owner[1]}")
             old = self._services.get(me)
+            if validate and self.lb_map_max is not None:
+                for what, now, was, new in (
+                        ("frontends", self._n_frontends,
+                         old.frontends if old else (), svc.frontends),
+                        ("backends", self._n_backends,
+                         old.lb_backends if old else (), svc.lb_backends)):
+                    if now - len(was) + len(new) > self.lb_map_max:
+                        raise ValueError(
+                            f"service {svc.namespace}/{svc.name} would take "
+                            f"the load-balancer's {what} to "
+                            f"{now - len(was) + len(new)}, past lb_map_max "
+                            f"{self.lb_map_max} (bpf-lb-map-max)")
             freed = []
             if old is not None:
                 for fe in old.frontends:
@@ -172,6 +192,8 @@ class ServiceRegistry:
             for fe in svc.frontends:
                 self.rnat_id(fe)      # allocate eagerly, deterministically
             self._services[me] = svc
+            self._count(old, -1)
+            self._count(svc, +1)
             # a key this service no longer declares may have a shadowed
             # claimant (validate=False restores): hand ownership over so a
             # later validated upsert can't create an undetected live conflict
@@ -187,6 +209,7 @@ class ServiceRegistry:
             svc = self._services.pop((namespace, name), None)
             ok = svc is not None
             if ok:
+                self._count(svc, -1)
                 for fe in svc.frontends:
                     k = (parse_addr(fe.addr)[0], fe.port, fe.proto)
                     if self._fe_owner.get(k) == (namespace, name):
@@ -212,10 +235,33 @@ class ServiceRegistry:
                     self._fe_owner[key] = me
                     return
 
+    def _count(self, svc: Optional[Service], sign: int) -> None:
+        """Keep the totals ``lb_map_max`` limits, and ``_relabelled``, as a
+        service comes (+1) or goes (-1). Caller holds the lock."""
+        if svc is None:
+            return
+        self._n_frontends += sign * len(svc.frontends)
+        self._n_backends += sign * len(svc.lb_backends)
+        if any(k in (NAME_LABEL, NAMESPACE_LABEL)
+               for k, _v in svc.extra_labels):
+            me = (svc.namespace, svc.name)
+            (self._relabelled.add if sign > 0
+             else self._relabelled.discard)(me)
+
     def match(self, selector: EndpointSelector) -> List[Service]:
+        """Services the selector matches. One that names both the service's
+        name and its namespace (the ``k8sService`` form) can match only the
+        service registered under them: looked up, not scanned for."""
+        want = dict(selector.match_labels)
         with self._lock:
-            return [svc for svc in self._services.values()
-                    if selector.matches(svc.labels)]
+            if NAME_LABEL in want and NAMESPACE_LABEL in want:
+                keys = {(want[NAMESPACE_LABEL], want[NAME_LABEL])} \
+                    | self._relabelled
+                among = [self._services[k] for k in sorted(keys)
+                         if k in self._services]
+            else:
+                among = list(self._services.values())
+            return [svc for svc in among if selector.matches(svc.labels)]
 
     def all(self) -> List[Service]:
         with self._lock:

@@ -98,13 +98,36 @@ class CachedSelector:
             fn(added, removed)
 
 
+def _first_label(selector) -> Optional[Tuple[str, str]]:
+    """(key, value) of a selector's first ``matchLabels`` entry, the source
+    left off: an identity it matches carries a label of that key and value.
+    None for a selector without one (it may match any identity)."""
+    labels = getattr(selector, "match_labels", None)
+    if not labels:
+        return None
+    key, value = labels[0]
+    return key.split(":", 1)[-1], value
+
+
 class SelectorCache:
+    """Both sides are indexed by (label key, value), so a node that holds
+    thousands of selectors and identities (one CIDR selector a backend of
+    every service a ``toServices`` document names) pays for a new selector
+    or identity by those that share a label with it, not by all of them."""
+
     def __init__(self, allocator: IdentityAllocator):
         self._lock = threading.RLock()
         self._allocator = allocator
         self._selectors: Dict[str, CachedSelector] = {}
-        # Observe identities; replay=True seeds current identities.
-        allocator.add_observer(self._on_identities, replay=False)
+        # (key, value) → the identities that carry such a label, by id
+        self._identities_with: Dict[Tuple[str, str], Dict[int, Identity]] = {}
+        # (key, value) of a selector's first matchLabels entry → selectors;
+        # those without one are in _unindexed
+        self._selectors_on: Dict[Tuple[str, str], Dict[str, CachedSelector]] \
+            = {}
+        self._unindexed: Dict[str, CachedSelector] = {}
+        # replay seeds the identity index with what is allocated already
+        allocator.add_observer(self._on_identities, replay=True)
 
     def _key(self, selector) -> str:
         return f"{type(selector).__name__}:{selector}"
@@ -116,12 +139,17 @@ class SelectorCache:
             cached = self._selectors.get(key)
             if cached is None:
                 cached = CachedSelector(selector, self)
+                on = _first_label(selector)
+                among = self._allocator.all() if on is None \
+                    else self._identities_with.get(on, {}).values()
                 matched = {
-                    ident.id for ident in self._allocator.all()
+                    ident.id for ident in among
                     if selector.matches(ident.labels)
                 }
                 cached._apply(matched, set())
                 self._selectors[key] = cached
+                (self._unindexed if on is None
+                 else self._selectors_on.setdefault(on, {}))[key] = cached
             cached.refcount += 1
             return cached
 
@@ -129,11 +157,33 @@ class SelectorCache:
         with self._lock:
             cached.refcount -= 1
             if cached.refcount <= 0:
-                self._selectors.pop(self._key(cached.selector), None)
+                key = self._key(cached.selector)
+                self._selectors.pop(key, None)
+                on = _first_label(cached.selector)
+                if on is None:
+                    self._unindexed.pop(key, None)
+                elif key in self._selectors_on.get(on, ()):
+                    del self._selectors_on[on][key]
+                    if not self._selectors_on[on]:
+                        del self._selectors_on[on]
 
     def _on_identities(self, added: List[Identity], removed: List[Identity]) -> None:
         with self._lock:
-            for cached in self._selectors.values():
+            touched: Dict[str, CachedSelector] = dict(self._unindexed)
+            for ident in added:
+                for lbl in ident.labels:
+                    on = (lbl.key, lbl.value)
+                    self._identities_with.setdefault(on, {})[ident.id] = ident
+                    touched.update(self._selectors_on.get(on, ()))
+            for ident in removed:
+                for lbl in ident.labels:
+                    on = (lbl.key, lbl.value)
+                    if ident.id in self._identities_with.get(on, ()):
+                        del self._identities_with[on][ident.id]
+                        if not self._identities_with[on]:
+                            del self._identities_with[on]
+                    touched.update(self._selectors_on.get(on, ()))
+            for cached in touched.values():
                 add_ids = {i.id for i in added if cached.selector.matches(i.labels)}
                 rem_ids = {i.id for i in removed if i.id in cached._ids}
                 if add_ids or rem_ids:

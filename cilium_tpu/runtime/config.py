@@ -27,7 +27,13 @@ class DaemonConfig:
     probe_depth: int = 8
     batch_size: int = 8192
     v4_only: bool = False
-    maglev_m: int = 251            # Maglev table size (prime; prod: 16381)
+    maglev_m: int = 251            # Maglev table size (prime); upstream's
+    #                                default 16381 runs in the benchmark's
+    #                                svc10k-maglev, 10,000 rows of it
+    # upstream's bpf-lb-map-max at its default: the most service frontends,
+    # and the most LB backends, the registry takes (an upsert past it is
+    # refused as upstream's map insert fails)
+    lb_map_max: int = 65536
     # --- device/runtime ---
     # "tpu"/"cpu" are requirements (JITDatapath refuses to start on
     # anything else); "auto" serves on what JAX has and reports it

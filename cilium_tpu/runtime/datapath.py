@@ -671,16 +671,20 @@ class JITDatapath(DatapathBackend):
     def _hbm_group(name: str) -> str:
         """Placed-tensor name → ledger group. The groups mirror how an
         operator reasons about device memory: the verdict image (the thing
-        place_patch scatters), the LPM tries (the IPv6-at-scale risk), and
-        the remaining policy planes."""
+        place_patch scatters), the LPM tries (the IPv6-at-scale risk), the
+        load-balancer's tables (a Maglev row of M a service: the largest
+        group of a node that holds a cluster's services), and the remaining
+        policy planes."""
         if name == "verdict":
             return "verdict"
         if name.startswith("lpm"):
             return "tries"
+        if name.startswith("lb_"):
+            return "lb"
         return "policy"
 
     def _account_placed(self, placed: Dict, patched: bool) -> None:
-        groups = {"verdict": 0, "tries": 0, "policy": 0}
+        groups = {"verdict": 0, "tries": 0, "lb": 0, "policy": 0}
         for k, v in placed.items():
             groups[self._hbm_group(k)] += int(getattr(v, "nbytes", 0))
         with self._hbm_lock:

@@ -25,7 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from cilium_tpu.compile.ct_layout import CTConfig
-from cilium_tpu.compile.lb import LBConfig
+from cilium_tpu.compile.lb import LBConfig, build_lb
 from cilium_tpu.compile.snapshot import PolicySnapshot, build_snapshot
 from cilium_tpu.model.endpoint import Endpoint
 from cilium_tpu.model.identity import IdentityAllocator
@@ -78,7 +78,7 @@ class Engine:
             allocator=alloc,
             selector_cache=SelectorCache(alloc),
             ipcache=IPCache(),
-            services=ServiceRegistry(),
+            services=ServiceRegistry(lb_map_max=self.config.lb_map_max),
             enforcement_mode=self.config.enforcement_mode,
             allow_localhost=self.config.allow_localhost,
             fqdn_cache=FQDNCache(
@@ -421,9 +421,19 @@ class Engine:
         if full_build:
             with TRACER.span(trace_id, "engine.regen.compile"), \
                     self.metrics.span("snapshot_compile").timer():
+                # the LB tables under a span of their own: a row a service
+                # is kept from the active snapshot while its backends stand
+                with TRACER.span(trace_id, "engine.regen.lb") as lb_span:
+                    lb = build_lb(
+                        self.ctx.services, lb_cfg,
+                        prev=self._active.snapshot.lb
+                        if self._active is not None else None)
+                    lb_span.set(rows_built=lb.rows_built)
                 snap = build_snapshot(self.repo, self.ctx, eps,
-                                      ct_cfg, lb_cfg)
+                                      ct_cfg, lb=lb)
             self.metrics.inc_counter("regen_full_total")
+            self.metrics.inc_counter("lb_maglev_rows_built_total",
+                                     lb.rows_built)
 
         try:
             with TRACER.span(trace_id, "engine.regen.place",
@@ -485,6 +495,8 @@ class Engine:
             "lpm_prefixes": len(lpm.prefixes),
             "lb_frontends": lb.n_frontends,
             "lb_backends": len(lb.backends),
+            "lb_services": lb.n_services,
+            "lb_maglev_bytes": lb.maglev.nbytes if lb.n_services else 0,
         })
         # flight recorder: the revision trail is what makes a frozen bundle
         # attributable ("which policy world were these verdicts from")

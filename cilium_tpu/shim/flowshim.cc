@@ -9,6 +9,7 @@
 #include <array>
 #include <cstdio>
 #include <deque>
+#include <thread>
 #include <vector>
 
 #if defined(__linux__) && __has_include(<linux/if_xdp.h>)
@@ -522,6 +523,47 @@ uint32_t shim_flow_shard2(const Shim* s, const ShimRecord* rec,
     r.dport = new_dport;
   }
   return shim_flow_shard(&r, n_shards);
+}
+
+static void maglev_fill_row(const int64_t* offsets, const int64_t* skips,
+                            const int32_t* weights, int64_t n, int64_t m,
+                            int32_t* row) {
+  std::fill(row, row + m, int32_t(-1));
+  if (n == 0) return;
+  std::vector<int64_t> at(offsets, offsets + n);  // next permutation slot
+  for (int64_t filled = 0; filled < m;) {
+    for (int64_t i = 0; i < n && filled < m; i++) {
+      for (int32_t w = 0; w < weights[i] && filled < m; w++) {
+        int64_t c = at[i];
+        while (row[c] >= 0) {
+          c += skips[i];
+          if (c >= m) c -= m;
+        }
+        row[c] = int32_t(i);
+        filled++;
+        c += skips[i];
+        at[i] = c >= m ? c - m : c;
+      }
+    }
+  }
+}
+
+void shim_maglev_fill(const int64_t* offsets, const int64_t* skips,
+                      const int32_t* weights, const int64_t* row_start,
+                      uint32_t n_rows, uint32_t m, int32_t* out,
+                      uint32_t threads) {
+  auto rows = [&](uint32_t from, uint32_t step) {
+    for (uint32_t r = from; r < n_rows; r += step) {
+      int64_t b = row_start[r];
+      maglev_fill_row(offsets + b, skips + b, weights + b,
+                      row_start[r + 1] - b, m, out + size_t(r) * m);
+    }
+  };
+  uint32_t k = std::max(1u, std::min(threads, n_rows));
+  std::vector<std::thread> pool;
+  for (uint32_t t = 1; t < k; t++) pool.emplace_back(rows, t, k);
+  rows(0, k);
+  for (auto& th : pool) th.join();
 }
 
 // ---------------------------------------------------------------------------

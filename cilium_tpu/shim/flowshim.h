@@ -176,6 +176,22 @@ uint32_t shim_flow_shard2(const Shim* s, const ShimRecord* rec,
 // sharded mesh — kept for non-LB deployments).
 uint32_t shim_flow_shard(const ShimRecord* rec, uint32_t n_shards);
 
+// ---------------------------------------------------------------------------
+// Maglev population for compile/lb.py (needs no Shim): row r has the
+// backends [row_start[r], row_start[r+1]), each with the (offset, skip) of
+// its permutation of [0, m) and a weight; the backends take turns in order,
+// `weight` consecutive turns each, every turn claiming the backend's next
+// unclaimed permutation slot, until the row's m slots are claimed. out[r*m +
+// c] is the row-local index of slot c's backend, -1 throughout for a row
+// without backends. The table compile/lb.py:_maglev_rows_py gives, element
+// for element (tests/test_maglev_build.py). Rows are dealt over up to
+// `threads` threads.
+// ---------------------------------------------------------------------------
+void shim_maglev_fill(const int64_t* offsets, const int64_t* skips,
+                      const int32_t* weights, const int64_t* row_start,
+                      uint32_t n_rows, uint32_t m, int32_t* out,
+                      uint32_t threads);
+
 #ifdef __cplusplus
 }
 #endif

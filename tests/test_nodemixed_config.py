@@ -103,32 +103,52 @@ def test_the_guarantees_are_the_three_files_and_one_more():
 
 # -- (b) the manifest ----------------------------------------------------------------
 def test_the_configuration_and_the_cell_in_the_manifest(manifest):
-    entry = {c["name"]: c for c in manifest["configs"]}["node-mixed"]
-    assert entry == manifest["configs"][-1]
+    names = [c["name"] for c in manifest["configs"]]
+    entry = manifest["configs"][names.index("node-mixed")]
+    # after the three configurations that hold its planes alone
+    assert all(names.index(p) < names.index("node-mixed")
+               for p in PLANES.values())
     assert entry["reduced"] == [] and len(entry["source"]) <= 200
     assert entry["source"] == load("node-mixed")["source"]
     for part in ("north_star", "configs[1]", "configs[2]", "configs[3]"):
         assert part in entry["source"]
     assert entry["file"] == "benchmarks/configs/node-mixed.json"
-    cell = manifest["workloads"][-1]
+    cells = [w["name"] for w in manifest["workloads"]]
+    cell = manifest["workloads"][cells.index(CELL)]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
         == (CELL, "node-mixed", "saturate-longflows", 1)
+    # after the cells of the configurations that hold its planes alone
+    assert all(cells.index(c) < cells.index(CELL) for c in (
+        "pods10k-dualstack.steady80", "lpm100k-zipf.saturate-longflows",
+        "l7-http.saturate-longflows"))
     assert len(cell["why"]) <= 200
     for part in ("54-74", "75-180", "0.5 / 0.3 / 0.2", "100,000",
                  "gateway"):
         assert part in cell["why"], part
-    assert len(manifest["configs"]) == 6 and len(manifest["workloads"]) == 7
+    assert len(manifest["configs"]) >= 6 and len(manifest["workloads"]) >= 7
+    assert len(set(names)) == len(names) and len(set(cells)) == len(cells)
 
 
 @pytest.mark.parametrize("metric", REPORTS)
 def test_the_cell_reports(manifest, metric):
     entry = {m["name"]: m for m in manifest["end_to_end"]
              + manifest["per_layer"]}[metric]
-    assert entry["workloads"][-1] == CELL or metric in NEW
-    assert CELL in entry["workloads"]
+    # appended: the cells of the one-plane configurations stand before it
+    # on every list that held one when it came
+    on = entry["workloads"]
+    assert CELL in on
+    assert metric in NEW or all(
+        on.index(c) < on.index(CELL) for c in (
+            "ct1m-50k.saturate", "lpm100k-zipf.saturate-longflows",
+            "l7-http.saturate-longflows") if c in on)
     assert entry.get("moves", "verdicts_per_s") == "verdicts_per_s"
     if metric in NEW:
-        assert entry in manifest["per_layer"][-3:]
+        # the three came together, in this order, after every metric the
+        # manifest held before them
+        at = [m["name"] for m in manifest["per_layer"]]
+        first = at.index(NEW[0])
+        assert at[first:first + 3] == list(NEW)
+        assert at.index("host.flow_hashes_per_row") < first
     assert os.path.exists(os.path.join(
         REPO, "benchmarks", "layers" if "moves" in entry else "e2e",
         metric + ".py"))
@@ -150,9 +170,10 @@ def test_the_cell_stays_off_a_list_whose_premise_fails(manifest, metric):
 
 def test_the_one_plane_wires_stand_beside_it(manifest):
     entry = {m["name"]: m for m in manifest["per_layer"]}[NEW[0]]
-    assert entry["workloads"] == [CELL, "ct1m-50k.saturate",
-                                  "lpm100k-zipf.saturate-longflows",
-                                  "l7-http.saturate-longflows"]
+    # the four it came with, in the order it gave them (later cells after)
+    assert entry["workloads"][:4] == [CELL, "ct1m-50k.saturate",
+                                      "lpm100k-zipf.saturate-longflows",
+                                      "l7-http.saturate-longflows"]
     for name in NEW[1:]:
         assert {m["name"]: m for m in manifest["per_layer"]}[name][
             "workloads"] == [CELL]

@@ -119,3 +119,116 @@ def test_the_reader_sees_the_copy_in_the_form_that_had_one(one_chip):
     copies = table_copies(text)
     assert len(copies) == 1 and str(V4_NODES) in copies[0], copies
     assert memory.temp_size_in_bytes >= V4_NODES * 256 * 16
+
+
+# -- the LB step inside the whole classify program (PR 45) ----------------------
+#: the load-balancer's tables of ``svc10k-maglev``: 10,000 Maglev rows of
+#: 16,381 (655 MB), 14,001 frontends in 32,768 slots, 151,000 backends
+LB_SHAPES = {
+    "lb_maglev": (10000, 16381), "lb_tab_keys": (32768, 6),
+    "lb_tab_val": (32768,), "lb_fe_service": (14001,),
+    "lb_fe_rnat_id": (14001,), "lb_rnat_addr": (14001, 4),
+    "lb_rnat_port": (14001,), "lb_rnat_valid": (14001,),
+    "lb_be_addr": (151000, 4), "lb_be_port": (151000,)}
+
+
+@pytest.fixture(scope="module")
+def classify_program():
+    """→ (the datapath's jitted step, the shapes of one real dispatch of
+    1,024 rows): the tiny service world on the jitted datapath, the step
+    spied on as ``tests/test_lpm100k_config.py`` does."""
+    import json
+    import os
+
+    import jax
+    from benchmarks.frames import columns_of
+    from benchmarks.worlds import svclb
+    from cilium_tpu.runtime.config import DaemonConfig
+    from cilium_tpu.runtime.datapath import JITDatapath
+    from cilium_tpu.runtime.engine import Engine
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "data", "configs", "tiny-svclb.json")) as f:
+        world = svclb.build(json.load(f)["world"])
+    cfg = DaemonConfig(auto_regen=False, ct_capacity=1 << 12, maglev_m=251,
+                       lb_map_max=4096, batch_size=ROWS)
+    eng = Engine(cfg, datapath=JITDatapath(cfg))
+    try:
+        world.load(eng)
+        eng.regenerate()
+        dp, seen = eng.datapath, []
+        step = dp._classify
+
+        def spy(*args):
+            seen.append(jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), args))
+            return step(*args)
+        dp._classify = spy
+        flows = world.allowed_flows(np.random.default_rng(1), ROWS, 1, 40000)
+        eng.submit(columns_of(flows, world.ep_v4, world.ep_v6_words,
+                              0)).result(timeout=300)
+        assert eng.drain(timeout=60)
+        dp._classify = step
+    finally:
+        eng.stop()
+    return step, seen[-1]
+
+
+def at_the_deployments_size(shapes, one_chip, lb_shapes):
+    import jax
+    tensors = dict(shapes[0])
+    assert set(lb_shapes) == {k for k in tensors if k.startswith("lb_")}
+    for name, shape in lb_shapes.items():
+        tensors[name] = jax.ShapeDtypeStruct(shape, tensors[name].dtype)
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (tensors,) + tuple(shapes[1:]))
+
+
+def test_the_lb_step_reads_a_clusters_tables_where_they_lie(
+        one_chip, classify_program):
+    """The whole classify program, as the datapath dispatches it for 1,024
+    rows, over the load-balancer's tables at ``svc10k-maglev``'s shapes: the
+    optimised program holds no copy or transpose of 2^20 elements (no table
+    re-laid a batch under ``lb.step``), reads the Maglev table as the
+    parameter it is, and its temporaries are a thirtieth of the tables."""
+    step, shapes = classify_program
+    compiled = step.lower(*at_the_deployments_size(
+        shapes, one_chip, LB_SHAPES)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert table_copies(text) == []
+    assert "s32[10000,16381]{1,0:T(8,128)} parameter(" in text
+    tables = 10000 * 16384 * 4          # a row padded to 128 lanes
+    assert tables <= memory.argument_size_in_bytes < tables + (16 << 20)
+    assert memory.temp_size_in_bytes < tables // 30
+
+
+@pytest.mark.parametrize("form", ["trailing-axis", "transposed"])
+def test_the_reader_sees_a_maglev_table_handed_over_in_another_form(
+        one_chip, classify_program, form):
+    """The control: the same program handed the Maglev table as ``[S, M,
+    1]`` and dropping the axis itself re-lays 164 million entries a batch
+    (the tiles of the last two axes pad the one to 128 lanes). Handed over
+    transposed (``[M, S]``), as ISSUE 45 proposed for the control, it does
+    not: the compiler reads the transposed table through the gather's own
+    dimension numbers. Both are held, so that the reader is known to see
+    the one and the other is known not to be one."""
+    import jax
+    step, shapes = classify_program
+    inner = step.__wrapped__
+    shape, back = {
+        "trailing-axis": ((10000, 16381, 1),
+                          lambda m: m.reshape(10000, 16381)),
+        "transposed": ((16381, 10000), lambda m: m.T)}[form]
+
+    def handed(tensors, *rest):
+        return inner(dict(tensors, lb_maglev=back(tensors["lb_maglev"])),
+                     *rest)
+    compiled = jax.jit(handed).lower(*at_the_deployments_size(
+        shapes, one_chip, dict(LB_SHAPES, lb_maglev=shape))).compile()
+    copies = table_copies(compiled.as_text())
+    if form == "transposed":
+        assert copies == []
+    else:
+        assert len(copies) == 1 and "[10000,16381,1]" in copies[0], copies
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            >= 10000 * 16381 * 4
