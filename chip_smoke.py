@@ -555,10 +555,10 @@ def check_placement(run: Run, phase: str) -> None:
     n_dev = run.n_shards * run.rule_shards
     mesh_devs = {d.id for d in np.asarray(dp._mesh.devices).reshape(-1)}
     need(len(mesh_devs) == n_dev, phase, f"mesh has {mesh_devs}")
-    ct_devs = {d.id for d in dp._ct["keys"].sharding.device_set}
+    ct_devs = {d.id for d in dp._ct["expiry"].sharding.device_set}
     need(ct_devs == mesh_devs, phase, f"CT on {ct_devs}, mesh {mesh_devs}")
     shard_rows = {s.data.shape[0] for s in
-                  dp._ct["keys"].addressable_shards}
+                  dp._ct["expiry"].addressable_shards}
     need(shard_rows == {run.world.ct_capacity // run.n_shards}, phase,
          f"CT shard rows {shard_rows}")
     wire = jax.device_put(np.zeros((run.world.batch_size, 4), np.uint32),

@@ -417,8 +417,8 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
             ct, fwd_keys, rev_keys, now, probe_depth,
             interpret=fused_interpret)
     else:
-        fwd_slot = ctk.ct_probe(ct, fwd_keys, now, probe_depth)
-        rev_slot = ctk.ct_probe(ct, rev_keys, now, probe_depth)
+        fwd_slot, rev_slot = ctk.ct_probe_pair(ct, fwd_keys, rev_keys, now,
+                                               probe_depth)
     est = valid & (fwd_slot >= 0)
     reply = valid & ~est & (rev_slot >= 0)
     new = valid & ~est & ~reply

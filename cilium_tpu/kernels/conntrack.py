@@ -30,9 +30,10 @@ forever).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-from cilium_tpu.compile.ct_layout import PROBE_DEPTH
+from cilium_tpu.compile.ct_layout import KEY_PLANES, PROBE_DEPTH, key_planes
 from cilium_tpu.kernels.hashing import hash_words_jnp
 from cilium_tpu.kernels.records import ct_key_words_generic
 from cilium_tpu.utils import constants as C
@@ -70,29 +71,72 @@ def ct_key_words_pair(batch):
     return fwd, reverse_key_words_jnp(fwd)
 
 
-def ct_probe_core(tab_keys, expiry, keys, now,
+#: the key word the probe reads at every slot of its window before any
+#: other: ``sport << 16 | dport``, the word two flows of one window least
+#: often share
+FIRST_WORD = 8
+
+
+def ct_probe_core(planes, expiry, keys, now,
                   probe_depth: int = PROBE_DEPTH):
-    """The fusable probe core over plain arrays (tab_keys [cap,10] uint32,
-    expiry [cap] uint32): find each key's live slot → [N] int32 (-1 =
+    """The fusable probe core over plain arrays (``planes``: the ten key
+    planes, each [cap] uint32; expiry [cap] uint32): find each key's live
+    slot, the first of its window that holds the key → [N] int32 (-1 =
     miss). Shared verbatim by the XLA reference (``ct_probe``) and the
-    fused Pallas probe-pair body (kernels/fused.py), which calls it twice
-    on VMEM-resident table values — once per orientation — so the bucket
-    loads never round-trip through HBM between the probes."""
+    fused Pallas probe-pair body (kernels/fused.py).
+
+    Two stages, because a word gathered off a plane is what the probe
+    costs (≈8 µs a plane and 1,024 rows on a v5e, PERF.md §6 "PR 48"):
+    the window's *candidates* are its live slots whose ``FIRST_WORD`` is
+    the key's (one gather of that plane and one of ``expiry`` a slot);
+    then each row's candidates are settled in window order, the other nine
+    words read at one slot a row a round, until every row has found its
+    slot or has no candidate left. A hit takes one round, a miss none; a
+    window of flows that share their ports takes as many rounds as it has
+    such slots, ``probe_depth`` at most, which is what every probe took
+    before."""
     cap = expiry.shape[0]
     mask = cap - 1
     base = (hash_words_jnp(keys) & jnp.uint32(mask)).astype(jnp.int32)
-    found = jnp.full(base.shape, -1, dtype=jnp.int32)
+    offsets = jnp.arange(probe_depth, dtype=jnp.int32)
+    cand = []
     for i in range(probe_depth):
         s = (base + i) & mask
-        live = expiry[s] > now
-        eq = jnp.all(tab_keys[s] == keys, axis=-1) & live
-        found = jnp.where((found < 0) & eq, s, found)
+        cand.append((planes[FIRST_WORD][s] == keys[:, FIRST_WORD])
+                    & (expiry[s] > now))
+
+    def settle(state):
+        cand, found = state
+        first = jnp.argmax(cand, axis=1).astype(jnp.int32)
+        s = (base + first) & mask
+        eq = jnp.any(cand, axis=1)
+        for w, plane in enumerate(planes):
+            if w != FIRST_WORD:
+                eq = eq & (plane[s] == keys[:, w])
+        # a row that found its slot is settled; another loses this candidate
+        cand = cand & ~eq[:, None] & (offsets[None, :] != first[:, None])
+        return cand, jnp.where(eq, s, found)
+
+    _, found = jax.lax.while_loop(
+        lambda state: jnp.any(state[0]), settle,
+        (jnp.stack(cand, axis=1), jnp.full(base.shape, -1, dtype=jnp.int32)))
     return found
 
 
 def ct_probe(ct, keys, now, probe_depth: int = PROBE_DEPTH):
     """Find each key's live slot. → slot [N] int32 (-1 = miss)."""
-    return ct_probe_core(ct["keys"], ct["expiry"], keys, now, probe_depth)
+    return ct_probe_core(key_planes(ct), ct["expiry"], keys, now,
+                         probe_depth)
+
+
+def ct_probe_pair(ct, fwd_keys, rev_keys, now,
+                  probe_depth: int = PROBE_DEPTH):
+    """Both orientations in one probe of 2N keys (a gather's cost is mostly
+    the gather's own, not its rows') → (fwd_slot, rev_slot), each [N]."""
+    n = fwd_keys.shape[0]
+    slot = ct_probe(ct, jnp.concatenate([fwd_keys, rev_keys]), now,
+                    probe_depth)
+    return slot[:n], slot[n:]
 
 
 def _flag_delta(proto, tcp_flags, is_reply):
@@ -120,6 +164,12 @@ def _lifetime(proto, flags):
     return jnp.where(is_tcp, tcp_life, C.CT_LIFETIME_NONTCP).astype(jnp.uint32)
 
 
+def _slot_proto(planes):
+    """Every slot's protocol [cap] int32: the top of key word 9, read off
+    its own plane in order."""
+    return (planes[9] >> jnp.uint32(8)).astype(jnp.int32)
+
+
 def ct_evictable(slot_proto, flags):
     """Which live entries an exhausted insert may tail-evict: everything
     whose current lifetime class is NOT the established-TCP one — i.e.
@@ -140,6 +190,7 @@ def ct_insert_new(ct, keys, want_insert, now,
     """Deterministic parallel insert of new flows.
 
     Returns (new_keys, new_created, zero_mask, slot_of, fail, n_evicted):
+    - ``new_keys`` the ten key planes with the winners' keys written;
     - ``zero_mask`` [cap] marks freshly-claimed slots whose value arrays
       (flags/counters) must be reset before aggregation;
     - ``slot_of`` [N] is the entry slot for every packet whose flow now has
@@ -151,95 +202,99 @@ def ct_insert_new(ct, keys, want_insert, now,
     ``evict`` arms the insert-when-full tail eviction (module docstring);
     ``protected`` [cap] bool marks slots the batch probe-hit (never
     evicted — snapshot semantics demand a slot being updated by this batch
-    stays this batch's)."""
+    stays this batch's).
+
+    A slot claimed in this batch is known by its ``owner`` (the winning
+    packet's index; ``n`` = unclaimed), and a claimed slot's key is its
+    owner's: the adoption checks compare the batch's own key rows, and the
+    table's planes are written once, after the last round, each winner's
+    ten words and its ``created``."""
     cap = ct["expiry"].shape[0]
     mask = cap - 1
     n = keys.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
     base = (hash_words_jnp(keys) & jnp.uint32(mask)).astype(jnp.int32)
 
-    keys_arr = ct["keys"]
-    created_arr = ct["created"]
-    claimed = jnp.zeros((cap,), dtype=bool)
-    zero_mask = jnp.zeros((cap,), dtype=bool)
+    owner = jnp.full((cap + 1,), n, dtype=jnp.int32)
     slot_of = jnp.full((n,), -1, dtype=jnp.int32)
+    won_at = jnp.full((n,), cap, dtype=jnp.int32)
     pending = want_insert
 
-    for r in range(probe_depth):
-        if r > 0:
-            # adoption: my previous round's target may now hold my key,
-            # inserted by a lower-indexed duplicate of my flow
-            sprev = (base + (r - 1)) & mask
-            adopted = (pending & claimed[sprev]
-                       & jnp.all(keys_arr[sprev] == keys, axis=-1))
-            slot_of = jnp.where(adopted, sprev, slot_of)
-            pending = pending & ~adopted
-        s = (base + r) & mask
-        free = (ct["expiry"][s] <= now) & ~claimed[s]
-        attempt = pending & free
+    def adopt(s, o, slot_of, pending):
+        # slot s, claimed by packet o, may hold my key, inserted by a
+        # lower-indexed duplicate of my flow (o = n, unclaimed, reads the
+        # clamped last row: excluded by o < n)
+        adopted = pending & (o < n) & jnp.all(keys[o] == keys, axis=-1)
+        return jnp.where(adopted, s, slot_of), pending & ~adopted
+
+    def claim(target, attempt, owner, slot_of, won_at, pending):
         # lowest packet index wins each contested slot
-        scat = jnp.where(attempt, s, cap)
-        claim = jnp.full((cap + 1,), n, dtype=jnp.int32).at[scat].min(idx)
-        winner = attempt & (claim[s] == idx)
-        ws = jnp.where(winner, s, cap)
-        keys_arr = keys_arr.at[ws].set(keys, mode="drop")
-        created_arr = created_arr.at[ws].set(now, mode="drop")
-        claimed = claimed.at[ws].set(True, mode="drop")
-        zero_mask = zero_mask.at[ws].set(True, mode="drop")
-        slot_of = jnp.where(winner, s, slot_of)
-        pending = pending & ~winner
+        owner = owner.at[jnp.where(attempt, target, cap)].min(idx)
+        now_owned_by = owner[target]
+        winner = attempt & (now_owned_by == idx)
+        return (owner, jnp.where(winner, target, slot_of),
+                jnp.where(winner, target, won_at), pending & ~winner,
+                now_owned_by, winner)
 
-    # final adoption sweep: stragglers whose duplicate won at a slot they
-    # already passed
     for r in range(probe_depth):
         s = (base + r) & mask
-        adopted = (pending & claimed[s]
-                   & jnp.all(keys_arr[s] == keys, axis=-1))
-        slot_of = jnp.where(adopted, s, slot_of)
-        pending = pending & ~adopted
+        if r > 0:
+            # adoption at my previous round's target, as that round left it
+            slot_of, pending = adopt(sprev, now_owned_by, slot_of, pending)
+        free = (ct["expiry"][s] <= now) & (owner[s] == n)
+        owner, slot_of, won_at, pending, now_owned_by, _ = claim(
+            s, pending & free, owner, slot_of, won_at, pending)
+        sprev = s
 
-    n_evicted = jnp.uint32(0)
-    if evict:
+    def stragglers(state):
+        """What is left for the rows the rounds did not settle: a last
+        adoption sweep, then the eviction round. Most batches have no such
+        row and skip it."""
+        owner, slot_of, won_at, pending = state
+        # final adoption sweep: stragglers whose duplicate won at a slot
+        # they already passed
+        for r in range(probe_depth):
+            s = (base + r) & mask
+            slot_of, pending = adopt(s, owner[s], slot_of, pending)
+        if not evict:
+            return (owner, slot_of, won_at, pending), jnp.uint32(0)
         # tail-eviction round (batch-start state throughout): victim =
         # the window slot with the smallest expiry among live, evictable,
         # unclaimed, unprotected entries; ties break to the earliest probe
         # offset (strict <), contested victims to the lowest packet index
         exp0 = ct["expiry"]
-        slot_proto = (ct["keys"][:, 9] >> jnp.uint32(8)).astype(jnp.int32)
-        candidate = (exp0 > now) & ct_evictable(slot_proto, ct["flags"])
+        candidate = (exp0 > now) & ct_evictable(
+            _slot_proto(key_planes(ct)), ct["flags"])
         if protected is not None:
             candidate = candidate & ~protected
         best_s = jnp.full((n,), -1, dtype=jnp.int32)
         best_e = jnp.full((n,), 0xFFFFFFFF, dtype=jnp.uint32)
         for r in range(probe_depth):
             s = (base + r) & mask
-            cand = pending & candidate[s] & ~claimed[s]
+            cand = pending & candidate[s] & (owner[s] == n)
             e = exp0[s]
             better = cand & ((best_s < 0) | (e < best_e))
             best_s = jnp.where(better, s, best_s)
             best_e = jnp.where(better, e, best_e)
-        attempt = pending & (best_s >= 0)
-        scat = jnp.where(attempt, best_s, cap)
-        claim = jnp.full((cap + 1,), n, dtype=jnp.int32).at[scat].min(idx)
-        bs = jnp.where(best_s >= 0, best_s, 0)
-        winner = attempt & (claim[bs] == idx)
-        ws = jnp.where(winner, best_s, cap)
-        keys_arr = keys_arr.at[ws].set(keys, mode="drop")
-        created_arr = created_arr.at[ws].set(now, mode="drop")
-        claimed = claimed.at[ws].set(True, mode="drop")
-        zero_mask = zero_mask.at[ws].set(True, mode="drop")
-        slot_of = jnp.where(winner, best_s, slot_of)
-        pending = pending & ~winner
-        n_evicted = winner.sum().astype(jnp.uint32)
+        owner, slot_of, won_at, pending, _, winner = claim(
+            jnp.where(best_s >= 0, best_s, 0), pending & (best_s >= 0),
+            owner, slot_of, won_at, pending)
         # adoption: duplicates of an evict-winner's key ride its new slot
         for r in range(probe_depth):
             s = (base + r) & mask
-            adopted = (pending & claimed[s]
-                       & jnp.all(keys_arr[s] == keys, axis=-1))
-            slot_of = jnp.where(adopted, s, slot_of)
-            pending = pending & ~adopted
+            slot_of, pending = adopt(s, owner[s], slot_of, pending)
+        return ((owner, slot_of, won_at, pending),
+                winner.sum().astype(jnp.uint32))
 
-    return keys_arr, created_arr, zero_mask, slot_of, pending, n_evicted
+    (owner, slot_of, won_at, pending), n_evicted = jax.lax.cond(
+        jnp.any(pending), stragglers,
+        lambda state: (state, jnp.uint32(0)),
+        (owner, slot_of, won_at, pending))
+
+    new_keys = tuple(p.at[won_at].set(keys[:, w], mode="drop")
+                     for w, p in enumerate(key_planes(ct)))
+    new_created = ct["created"].at[won_at].set(now, mode="drop")
+    return new_keys, new_created, owner[:cap] < n, slot_of, pending, n_evicted
 
 
 def ct_apply(ct, batch, slot, is_reply, contrib, now,
@@ -254,7 +309,7 @@ def ct_apply(ct, batch, slot, is_reply, contrib, now,
     Returns the new ct pytree.
     """
     cap = ct["expiry"].shape[0]
-    keys_arr = new_keys if new_keys is not None else ct["keys"]
+    planes = new_keys if new_keys is not None else key_planes(ct)
     created_arr = new_created if new_created is not None else ct["created"]
     flags = ct["flags"]
     fwd = ct["pkts_fwd"]
@@ -290,12 +345,11 @@ def ct_apply(ct, batch, slot, is_reply, contrib, now,
     rev = rev.at[jnp.where(contrib & is_reply, slot, cap)].add(one, mode="drop")
 
     touched = jnp.zeros((cap,), dtype=bool).at[scat].set(True, mode="drop")
-    slot_proto = (keys_arr[:, 9] >> jnp.uint32(8)).astype(jnp.int32)
-    new_expiry = now + _lifetime(slot_proto, flags)
+    new_expiry = now + _lifetime(_slot_proto(planes), flags)
     expiry = jnp.where(touched, new_expiry, ct["expiry"])
 
     return {
-        "keys": keys_arr,
+        **dict(zip(KEY_PLANES, planes)),
         "expiry": expiry,
         "created": created_arr,
         "flags": flags,
@@ -309,15 +363,7 @@ def _sweep_mask(ct, dead):
     """Clear every entry under ``dead`` [cap] bool → new ct pytree (shared
     by the whole-table sweep and the chunked epoch sweep)."""
     zero32 = jnp.uint32(0)
-    new_ct = dict(ct)
-    new_ct["expiry"] = jnp.where(dead, zero32, ct["expiry"])
-    new_ct["keys"] = jnp.where(dead[:, None], zero32, ct["keys"])
-    new_ct["flags"] = jnp.where(dead, zero32, ct["flags"])
-    new_ct["pkts_fwd"] = jnp.where(dead, zero32, ct["pkts_fwd"])
-    new_ct["pkts_rev"] = jnp.where(dead, zero32, ct["pkts_rev"])
-    new_ct["created"] = jnp.where(dead, zero32, ct["created"])
-    new_ct["rev_nat"] = jnp.where(dead, zero32, ct["rev_nat"])
-    return new_ct
+    return {k: jnp.where(dead, zero32, v) for k, v in ct.items()}
 
 
 def ct_sweep(ct, now):

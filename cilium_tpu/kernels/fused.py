@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from cilium_tpu.compile.ct_layout import key_planes
 from cilium_tpu.kernels.classify import classify_interior_core
 from cilium_tpu.kernels.conntrack import ct_probe_core
 from cilium_tpu.kernels.lpm import lpm_walk_prov_core
@@ -115,7 +116,7 @@ def fuse_plan(tensors, ct, v4_only: bool = False, rule_axis=None,
     budget = budget or FUSED_TABLE_BYTES
     lpm_bytes = _nbytes(tensors["lpm_v4"]) \
         + (0 if v4_only else _nbytes(tensors["lpm_v6"]))
-    ct_bytes = _nbytes(ct["keys"]) + _nbytes(ct["expiry"])
+    ct_bytes = sum(map(_nbytes, key_planes(ct))) + _nbytes(ct["expiry"])
     policy_bytes = sum(_nbytes(tensors[k]) for k in POLICY_TENSOR_KEYS)
     can = TPU_COMPILED_STAGES if compiled else FusePlan(True, True, True)
     return FusePlan(
@@ -210,11 +211,14 @@ def ct_probe_pair_fused(ct, fwd_keys, rev_keys, now, probe_depth: int,
     loop is conntrack.ct_probe_core — identical to the reference."""
     n = fwd_keys.shape[0]
     blk, grid = _row_grid(n)
-    tab_keys, expiry = ct["keys"], ct["expiry"]
+    # the kernel takes the key table as one [cap, 10] operand: stacked here,
+    # at its edge, from the placed planes (compile/ct_layout)
+    tab_keys, expiry = jnp.stack(key_planes(ct), axis=1), ct["expiry"]
 
     def kernel(now_ref, tab_ref, exp_ref, fwd_ref, rev_ref,
                fwd_out, rev_out):
         tab = tab_ref[...]
+        tab = tuple(tab[:, w] for w in range(tab.shape[1]))
         exp = exp_ref[...]
         now_s = now_ref[0]
         fwd_out[...] = ct_probe_core(tab, exp, fwd_ref[...], now_s,

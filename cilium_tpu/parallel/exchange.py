@@ -217,8 +217,8 @@ def ct_exchange_serve(ct, req_flat, axis_name: str, n_shards: int, now,
             ct, fwd_keys, rev_keys, now, probe_depth,
             interpret=fused_interpret)
     else:
-        fwd_slot = ctk.ct_probe(ct, fwd_keys, now, probe_depth)
-        rev_slot = ctk.ct_probe(ct, rev_keys, now, probe_depth)
+        fwd_slot, rev_slot = ctk.ct_probe_pair(ct, fwd_keys, rev_keys, now,
+                                               probe_depth)
     est = valid & (fwd_slot >= 0)
     reply = valid & ~est & (rev_slot >= 0)
     new = valid & ~est & ~reply

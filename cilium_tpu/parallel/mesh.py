@@ -28,7 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-from cilium_tpu.compile.ct_layout import PROBE_DEPTH
+from cilium_tpu.compile.ct_layout import CT_PLACED_KEYS, PROBE_DEPTH
 from cilium_tpu.kernels.hashing import hash_words_np
 from cilium_tpu.kernels.records import BatchArrays, ct_key_words
 
@@ -433,9 +433,7 @@ def _make_meshed_classify(mesh, body, donate_ct: bool = True,
         return out, new_ct, counters
 
     verdict_spec = P(None, None, "rules", None) if rule_sharded else P()
-    ct_spec = {k: P("flows") for k in
-               ("keys", "expiry", "created", "flags", "pkts_fwd", "pkts_rev",
-                "rev_nat")}
+    ct_spec = {k: P("flows") for k in CT_PLACED_KEYS}
     batch_spec = {k: P("flows") for k in
                   ("src", "dst", "sport", "dport", "proto", "tcp_flags",
                    "is_v6", "ep_slot", "direction", "http_method",

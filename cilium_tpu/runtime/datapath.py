@@ -32,7 +32,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
+from cilium_tpu.compile.ct_layout import (CTConfig, logical_ct_arrays,
+                                          make_ct_arrays, place_ct_arrays)
 from cilium_tpu.compile.lpm import PFX_LEN_MASK
 from cilium_tpu.compile.snapshot import PolicySnapshot
 from cilium_tpu.kernels.records import unpack_out
@@ -622,9 +623,8 @@ class JITDatapath(DatapathBackend):
         from cilium_tpu.kernels.fused import fuse_plan
         # inside shard_map the kernels see one flow shard's local table
         local = self._ct_capacity // self.n_flow_shards
-        ct = {k: jax.ShapeDtypeStruct((local,) + self._ct[k].shape[1:],
-                                      self._ct[k].dtype)
-              for k in ("keys", "expiry")}
+        ct = {k: jax.ShapeDtypeStruct((local,), v.dtype)
+              for k, v in self._ct.items()}
         self._fuse_plan = fuse_plan(
             placed, ct, v4_only=self.config.v4_only,
             rule_axis="rules" if self.n_rule_shards > 1 else None,
@@ -1546,13 +1546,26 @@ class JITDatapath(DatapathBackend):
         }
 
     def ct_arrays(self) -> Dict[str, np.ndarray]:
+        # copy the arrays out under the lock (they are donated into the next
+        # step); re-join the planes as rows outside it
         with self._ct_lock:
-            return {k: np.asarray(v) for k, v in self._ct.items()}
+            host = {k: np.array(v) for k, v in self._ct.items()}
+        return logical_ct_arrays(host)
+
+    def _place_ct(self, arrays: Dict[str, np.ndarray]) -> None:
+        """Logical host arrays → the device table in its placed form (ct
+        lock held): the one way a table reaches the device after start."""
+        placed = place_ct_arrays(arrays)
+        if self._sharded:
+            import jax
+            self._ct = {k: jax.device_put(v, self._ct_sharding)
+                        for k, v in placed.items()}
+        else:
+            self._ct = {k: self._jnp.asarray(v) for k, v in placed.items()}
 
     def load_ct_arrays(self, arrays: Dict[str, np.ndarray]) -> None:
         import logging
         from cilium_tpu.parallel.mesh import rehash_ct_arrays
-        jnp = self._jnp
         arrays = normalize_ct_arrays(arrays)
         # re-place entries for THIS backend's probe geometry: imported tables
         # may come from a different shard count or the dense fake export
@@ -1565,12 +1578,7 @@ class JITDatapath(DatapathBackend):
                 "during rehash into %d shard(s))", dropped,
                 self.n_flow_shards)
         with self._ct_lock:
-            if self._sharded:
-                import jax
-                self._ct = {k: jax.device_put(v, self._ct_sharding)
-                            for k, v in arrays.items()}
-            else:
-                self._ct = {k: jnp.asarray(v) for k, v in arrays.items()}
+            self._place_ct(arrays)
         self._account_ct_hbm()
 
     # -- mesh self-healing (ISSUE 19: device loss → fenced re-mesh onto
@@ -1721,9 +1729,9 @@ class JITDatapath(DatapathBackend):
             arrays: Optional[Dict[str, np.ndarray]] = None
             try:
                 FAULTS.fire("device.collective")
-                # np.array (not asarray): the gather view off a jax buffer
-                # is read-only, and the lost-shard zeroing below mutates
-                arrays = {k: np.array(v) for k, v in self._ct.items()}
+                # a host copy (the gather view off a jax buffer is
+                # read-only, and the lost-shard zeroing below mutates)
+                arrays = logical_ct_arrays(self._ct)
             except Exception:   # noqa: BLE001 — counted, archive/cold floor
                 self.remesh_stats["remesh_gather_failures"] += 1
             lost = 0
@@ -1736,8 +1744,9 @@ class JITDatapath(DatapathBackend):
                 arrays = {k: np.array(v) for k, v in salvage_floor.items()}
             else:
                 source = "cold"
-                arrays = make_ct_arrays(CTConfig(self.config.ct_capacity,
-                                                 self.config.probe_depth))
+                arrays = logical_ct_arrays(make_ct_arrays(
+                    CTConfig(self.config.ct_capacity,
+                             self.config.probe_depth)))
             new_cap = degraded_ct_capacity(self.config.ct_capacity, n_new)
             arrays, dropped = rehash_ct_arrays(
                 arrays, n_new, self.config.probe_depth, capacity=new_cap)
@@ -1770,8 +1779,7 @@ class JITDatapath(DatapathBackend):
              self._batch_sharding, self._verdict_sharding,
              self._classify) = cached
             shard_ct_arrays(arrays, n_new)    # divisibility fail-fast
-            self._ct = {k: jax.device_put(v, self._ct_sharding)
-                        for k, v in arrays.items()}
+            self._place_ct(arrays)
             self.n_flow_shards = n_new
             self._live_ordinals = live
             self._ct_capacity = new_cap
@@ -1959,7 +1967,8 @@ class FakeDatapath(DatapathBackend):
         import logging
         from cilium_tpu.kernels.records import ct_key_words
         cap = self.config.ct_capacity
-        arrays = make_ct_arrays(CTConfig(cap, self.config.probe_depth))
+        arrays = logical_ct_arrays(
+            make_ct_arrays(CTConfig(cap, self.config.probe_depth)))
         with self._lock:
             items = list(self._ct_table.entries.items())
             overflow = len(items) - cap

@@ -334,8 +334,9 @@ class TestSalvageReadsTheCarriedHash:
 # --------------------------------------------------------------------------- #
 class TestCTArchive:
     def _arrays(self, cap=64, live=5):
-        from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
-        a = make_ct_arrays(CTConfig(capacity=cap))
+        from cilium_tpu.compile.ct_layout import (
+            CTConfig, logical_ct_arrays, make_ct_arrays)
+        a = logical_ct_arrays(make_ct_arrays(CTConfig(capacity=cap)))
         a["expiry"][:live] = 10_000 + np.arange(live)
         return a
 

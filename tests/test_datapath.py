@@ -141,6 +141,153 @@ class TestFakeDatapath:
         assert fake.ct_stats(10**9)["live"] == 0
 
 
+def jit_engine(**mesh):
+    cfg = DaemonConfig(ct_capacity=2048, auto_regen=False, device="cpu",
+                       **mesh)
+    return fixture_engine(JITDatapath(cfg))
+
+
+def table_form(dp):
+    return {k: (v.shape, str(v.dtype)) for k, v in dp._ct.items()}
+
+
+ONE_CHIP_AND_MESH = [pytest.param({}, id="one-chip"),
+                     pytest.param({"n_shards": 4}, id="mesh4")]
+
+
+class TestPlacedConntrack:
+    """The device table lies as ten key planes (compile/ct_layout: the
+    placed form); everything off the hot path sees ``keys [cap, 10]``."""
+
+    @pytest.mark.parametrize("mesh", ONE_CHIP_AND_MESH)
+    def test_ct_arrays_is_the_logical_view_of_the_placed_table(self, mesh):
+        from cilium_tpu.compile.ct_layout import CT_PLACED_KEYS
+        from cilium_tpu.runtime.datapath import CT_SCHEMA_KEYS
+        eng_fake = fixture_engine(FakeDatapath(DaemonConfig(ct_capacity=2048)))
+        eng = jit_engine(**mesh)
+        batch = batch_from_records(TRAFFIC, eng.active.snapshot.ep_slot_of)
+        eng_fake.classify(dict(batch), now=1000)
+        eng.classify(dict(batch), now=1000)
+        assert table_form(eng.datapath) == {
+            k: ((2048,), "uint32") for k in CT_PLACED_KEYS}
+        arrays = eng.ct_arrays()
+        assert set(arrays) == CT_SCHEMA_KEYS
+        assert arrays["keys"].shape == (2048, 10)
+        assert all(arrays[k].shape == (2048,) for k in arrays if k != "keys")
+        # the same flows, word for word, as the oracle's table exports
+        want = eng_fake.ct_arrays()
+
+        def entries(a):
+            live = a["expiry"] > 0
+            return sorted(
+                (a["keys"][s].tobytes(),
+                 tuple(int(a[k][s]) for k in sorted(a) if k != "keys"))
+                for s in np.nonzero(live)[0])
+        assert entries(arrays) == entries(want) and entries(arrays)
+
+    @pytest.mark.parametrize("mesh", ONE_CHIP_AND_MESH)
+    def test_load_ct_arrays_takes_the_logical_view_and_probes_hit(self,
+                                                                  mesh):
+        src = jit_engine()
+        batch = batch_from_records(TRAFFIC, src.active.snapshot.ep_slot_of)
+        first = src.classify(dict(batch), now=1000)
+        eng = jit_engine(**mesh)
+        eng.load_ct_arrays(src.ct_arrays())
+        assert eng.ct_stats(1000) == src.ct_stats(1000)
+        again = eng.classify(dict(batch), now=1005)
+        allowed = np.asarray(first["allow"])
+        assert allowed.any()
+        assert (np.asarray(again["status"])[allowed]
+                == C.CTStatus.ESTABLISHED).all()
+
+    @pytest.mark.parametrize("mesh", ONE_CHIP_AND_MESH)
+    def test_a_checkpoint_in_the_matrix_schema_loads_and_probes_hit(
+            self, mesh, tmp_path):
+        """``ct.npz`` as every build before PR 48 wrote it — the device
+        table read back array for array, ``keys`` one ``[cap, 10]`` matrix
+        — restores into the placed table, and its flows are found."""
+        from cilium_tpu.runtime import checkpoint
+        old = fixture_engine(FakeDatapath(DaemonConfig(ct_capacity=2048)))
+        batch = batch_from_records(TRAFFIC, old.active.snapshot.ep_slot_of)
+        first = old.classify(dict(batch), now=1000)
+        checkpoint.save(old, str(tmp_path))
+        with np.load(os.path.join(str(tmp_path), checkpoint.CT_FILE)) as z:
+            assert z["keys"].shape == (2048, 10)
+            assert z["expiry"].shape == (2048,)
+        cfg = DaemonConfig(ct_capacity=2048, auto_regen=False, device="cpu",
+                           **mesh)
+        eng = Engine(cfg, datapath=JITDatapath(cfg))
+        assert checkpoint.restore(eng, str(tmp_path))
+        assert eng.ct_stats(1000) == old.ct_stats(1000)
+        again = eng.classify(dict(batch), now=1005)
+        allowed = np.asarray(first["allow"])
+        assert (np.asarray(again["status"])[allowed]
+                == C.CTStatus.ESTABLISHED).all()
+
+    @pytest.mark.parametrize("mesh", ONE_CHIP_AND_MESH)
+    def test_every_owner_hands_back_the_form_placement_made(self, mesh):
+        """Classify, the GC tick and the whole-table sweep each take the
+        table and return it: same keys, shapes and dtypes throughout."""
+        eng = jit_engine(**mesh)
+        dp = eng.datapath
+        placed = table_form(dp)
+        batch = batch_from_records(TRAFFIC, eng.active.snapshot.ep_slot_of)
+        eng.classify(dict(batch), now=1000)
+        assert table_form(dp) == placed
+        live = dp.ct_stats(1000)["live"]
+        assert live > 0
+        for _ in range(3):
+            tick = dp.sweep_step(1000, 1024)
+            assert table_form(dp) == placed
+        assert tick["epoch"] == 1 and tick["live"] == live
+        assert dp.sweep(10**9) == live
+        assert table_form(dp) == placed
+        assert dp.ct_stats(10**9) == {"capacity": 2048, "live": 0,
+                                      "stale": 0}
+        assert not any(np.asarray(v).any() for v in dp._ct.values())
+
+    def test_a_remesh_to_a_smaller_table_keeps_every_surviving_flow(self):
+        """4 → 3 chips rehashes 4,096 slots into 3,072 through the logical
+        view: the lost shard's flows go, every other flow stays, value
+        columns and all, and healing back keeps them again."""
+        from cilium_tpu.compile.ct_layout import (
+            CT_PLACED_KEYS, CTConfig, logical_ct_arrays, make_ct_arrays)
+        cfg = DaemonConfig(ct_capacity=4096, auto_regen=False, device="cpu",
+                           n_shards=4)
+        dp = JITDatapath(cfg)
+        rng = np.random.default_rng(48)
+        arrays = logical_ct_arrays(make_ct_arrays(CTConfig(4096)))
+        n = 600
+        arrays["keys"][:n] = rng.integers(0, 2**32, (n, 10), dtype=np.uint32)
+        arrays["keys"][:n, 9] = (6 << 8) | (arrays["keys"][:n, 9] & 1)
+        arrays["expiry"][:n] = 5000 + np.arange(n)
+        arrays["pkts_fwd"][:n] = 1 + np.arange(n)
+        dp.load_ct_arrays(arrays)
+
+        def entries(a, slots):
+            return {a["keys"][s].tobytes():
+                    (int(a["expiry"][s]), int(a["pkts_fwd"][s]))
+                    for s in slots}
+        before = dp.ct_arrays()
+        live = np.nonzero(before["expiry"] > 0)[0]
+        assert len(entries(before, live)) == n
+        survivors = entries(before, live[live // 1024 != 2])
+        res = dp.remesh([0, 1, 3])
+        assert res["ct_capacity"] == 3072
+        assert res["ct_lost"] == n - len(survivors) > 0
+        assert res["ct_dropped"] == 0 and res["ct_salvaged"] == len(survivors)
+        assert table_form(dp) == {k: ((3072,), "uint32")
+                                  for k in CT_PLACED_KEYS}
+        after = dp.ct_arrays()
+        assert after["keys"].shape == (3072, 10)
+        assert entries(after, np.nonzero(after["expiry"] > 0)[0]) == survivors
+        res = dp.remesh([0, 1, 2, 3])
+        assert res["ct_capacity"] == 4096 and res["ct_lost"] == 0
+        healed = dp.ct_arrays()
+        assert entries(healed, np.nonzero(healed["expiry"] > 0)[0]) \
+            == survivors
+
+
 class TestJaxFreeBoundary:
     def test_engine_with_fake_never_imports_jax(self):
         """The boundary is real only if an Engine(FakeDatapath) session runs

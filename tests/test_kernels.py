@@ -296,6 +296,52 @@ class TestCTKernel:
         assert int(np.asarray(fail).sum()) >= 4  # 8 slots, 12 flows
         assert int(np.asarray(zm).sum()) == 8
 
+    def test_probe_settles_a_window_of_flows_that_share_their_ports(self):
+        """The probe reads the ports word of every slot of the window first
+        and the rest of the key at its candidates only, in window order:
+        eight flows with one port pair in one window (capacity 8) are each
+        found at their own slot, a ninth with those ports and another
+        address at none, a flow with other ports at none, and an expired
+        candidate is no candidate. Both orientations in one probe read as
+        the two probes do."""
+        ct = self._jnp_ct(cap=8)
+        same_ports = [(f"10.0.{i}.1", "10.9.9.9", 4242, 443, 6, 0)
+                      for i in range(8)]
+        b = {k: jnp.asarray(v) for k, v in
+             _mk_batch(8, same_ports).items()}
+        keys = ctk.ct_key_words_jnp(b)
+        assert len(set(np.asarray(keys)[:, ctk.FIRST_WORD])) == 1
+        want = jnp.ones(8, bool)
+        nk, ncr, zm, slot, fail, _ev = ctk.ct_insert_new(
+            ct, keys, want, jnp.uint32(100))
+        assert sorted(np.asarray(slot)) == list(range(8))
+        ct2 = ctk.ct_apply(ct, b, slot, jnp.zeros(8, bool), want,
+                           jnp.uint32(100), new_keys=nk, new_created=ncr,
+                           zero_mask=zm)
+        np.testing.assert_array_equal(
+            np.asarray(ctk.ct_probe(ct2, keys, jnp.uint32(101))),
+            np.asarray(slot))
+        others = {k: jnp.asarray(v) for k, v in _mk_batch(2, [
+            ("10.0.8.1", "10.9.9.9", 4242, 443, 6, 0),
+            ("10.0.0.1", "10.9.9.9", 4243, 443, 6, 0)]).items()}
+        other_keys = ctk.ct_key_words_jnp(others)
+        assert (np.asarray(ctk.ct_probe(ct2, other_keys, jnp.uint32(101)))
+                == -1).all()
+        # slot 3's entry expires: its flow misses, its neighbours still hit
+        gone = int(np.asarray(slot)[3])
+        ct3 = dict(ct2, expiry=ct2["expiry"].at[gone].set(50))
+        got = np.asarray(ctk.ct_probe(ct3, keys, jnp.uint32(101)))
+        assert got[3] == -1
+        np.testing.assert_array_equal(np.delete(got, 3),
+                                      np.delete(np.asarray(slot), 3))
+        rev = ctk.reverse_key_words_jnp(keys)
+        f, r = ctk.ct_probe_pair(ct2, keys, rev, jnp.uint32(101))
+        np.testing.assert_array_equal(np.asarray(f), np.asarray(slot))
+        assert (np.asarray(r) == -1).all()
+        f, r = ctk.ct_probe_pair(ct2, rev, keys, jnp.uint32(101))
+        assert (np.asarray(f) == -1).all()
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(slot))
+
     def test_sweep_reclaims(self):
         ct = self._jnp_ct()
         raw = _mk_batch(1, [("10.0.0.1", "10.0.0.2", 7, 80, 6, 0)])

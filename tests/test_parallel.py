@@ -239,8 +239,9 @@ class TestShardedEngine:
         and lands where the importing geometry's probe expects it —
         asserted behaviorally above, structurally here."""
         rng = np.random.default_rng(5)
-        from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
-        arrays = make_ct_arrays(CTConfig(capacity=1024))
+        from cilium_tpu.compile.ct_layout import (
+            CTConfig, logical_ct_arrays, make_ct_arrays)
+        arrays = logical_ct_arrays(make_ct_arrays(CTConfig(capacity=1024)))
         n = 200
         arrays["keys"][:n] = rng.integers(0, 2**32, (n, 10), dtype=np.uint32)
         arrays["keys"][:n, 9] = (arrays["keys"][:n, 9] & ~np.uint32(0xFF)) \

@@ -15,7 +15,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
+from cilium_tpu.compile.ct_layout import (CTConfig, logical_ct_arrays,
+                                          make_ct_arrays)
 from cilium_tpu.compile.snapshot import build_snapshot
 from cilium_tpu.kernels.classify import classify_step
 from cilium_tpu.kernels.records import batch_from_records
@@ -137,7 +138,7 @@ def random_packet(rng, prior):
 def extract_device_ct(ct_dev, now):
     """Device table → {CTKey: (flags, expiry, pkts_fwd, pkts_rev)} for live
     entries."""
-    keys = np.asarray(ct_dev["keys"])
+    keys = logical_ct_arrays(ct_dev)["keys"]
     expiry = np.asarray(ct_dev["expiry"])
     flags = np.asarray(ct_dev["flags"])
     fwd = np.asarray(ct_dev["pkts_fwd"])

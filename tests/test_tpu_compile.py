@@ -232,3 +232,137 @@ def test_the_reader_sees_a_maglev_table_handed_over_in_another_form(
         assert len(copies) == 1 and "[10000,16381,1]" in copies[0], copies
         assert compiled.memory_analysis().temp_size_in_bytes \
             >= 10000 * 16381 * 4
+
+
+# -- conntrack's key table in every program that owns it (PR 48) ---------------
+#: the benchmark's table sizes: ``l7-http``; ``pods10k-dualstack``,
+#: ``lpm100k-zipf``, ``node-mixed`` and ``svc10k-maglev``; one chip's shard of
+#: ``ct1m-50k-mesh4``; ``ct1m-50k``
+CT_CAPACITIES = (1 << 16, 1 << 18, 1 << 19, 1 << 21)
+#: the programs that take ``JITDatapath._ct`` and hand it back: the serving
+#: step's conntrack part at a full batch and at a mesh shard's 256 rows, the
+#: GC tick (``sweep_step``'s own ``jax.jit``) and the whole-table ``sweep``
+CT_OWNERS = ("serve-1024", "serve-256", "gc-tick", "sweep")
+
+
+def conntrack_of_the_step(ct, fwd, rev, proto, tcp_flags, allow, rnat, now):
+    """Steps 2 and 6 of ``classify_step``: both probes, then the insert,
+    the eviction round and the update, through the stage the step calls."""
+    import jax.numpy as jnp
+    from cilium_tpu.kernels import conntrack as ctk
+    from cilium_tpu.kernels.classify import ct_update_stage
+    fwd_slot, rev_slot = ctk.ct_probe_pair(ct, fwd, rev, now)
+    hit = (fwd_slot >= 0) | (rev_slot >= 0)
+    new_ct, ct_full, entry_rnat, n_evicted = ct_update_stage(
+        ct, fwd, proto, tcp_flags, hit,
+        jnp.where(fwd_slot >= 0, fwd_slot, rev_slot),
+        (fwd_slot < 0) & (rev_slot >= 0), ~hit, allow, rnat, now)
+    return new_ct, (ct_full, entry_rnat, n_evicted)
+
+
+def compiled_owner(one_chip, owner, cap):
+    """One owner of the table, compiled for the described chip over a
+    placed table of ``cap`` slots, donated as the datapath donates it."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from cilium_tpu.compile.ct_layout import CT_PLACED_KEYS
+    from cilium_tpu.kernels import conntrack as ctk
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    ct = {k: spec((cap,), jnp.uint32) for k in CT_PLACED_KEYS}
+    now = spec((), jnp.uint32)
+    if owner == "sweep":
+        return jax.jit(ctk.ct_sweep).lower(ct, now).compile()
+    if owner == "gc-tick":
+        return jax.jit(
+            functools.partial(ctk.ct_sweep_chunk, chunk_rows=cap // 16),
+            donate_argnums=(0,)).lower(ct, now, now, count_now=now).compile()
+    rows = int(owner.split("-")[1])
+    return jax.jit(conntrack_of_the_step, donate_argnums=(0,)).lower(
+        ct, spec((rows, 10), jnp.uint32), spec((rows, 10), jnp.uint32),
+        spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+        spec((rows,), jnp.bool_), spec((rows,), jnp.int32), now).compile()
+
+
+def re_laid(text):
+    """``table_copies`` less the compiler's own staging: a ``copy`` whose
+    operand has the result's shape and layout and lies in another memory
+    space (``S(1)``, the chip's fast memory) moves one array back to HBM
+    where the compiler chose to compute it there (ROADMAP S3 (b)'s kind:
+    8 MB and ≈22 µs for one ``u32[2^21]`` column of the 1,024-row step);
+    it re-lays nothing."""
+    def form(type_text):
+        return re.sub(r"S\(\d+\)", "", type_text)
+    out = []
+    for line in table_copies(text):
+        m = re.match(r"%?\S+ = (\S+) copy\(%?([\w.\-]+)\)", line)
+        src = m and re.search(
+            r"^\s*(?:ROOT )?%?" + re.escape(m.group(2)) + r" = (\S+) ", text,
+            re.M)
+        if not (src and form(src.group(1)) == form(m.group(1))
+                and src.group(1) != m.group(1)):
+            out.append(line)
+    return out
+
+
+@pytest.mark.parametrize("cap", CT_CAPACITIES)
+@pytest.mark.parametrize("owner", CT_OWNERS)
+def test_every_owner_of_the_key_table_keeps_its_one_form(one_chip, owner,
+                                                         cap):
+    """Each program that owns the conntrack table takes every one of its
+    arrays, the ten key planes among them, as ``u32[cap]`` in the layout
+    the shape has by itself and returns it so: no program hands the next
+    one a table to re-lay. None re-lays 2^20 elements (at most one column
+    is staged through the fast memory, ``re_laid``), and none needs 16 MB
+    of temporaries (the ``[cap, 10]`` matrix cost 137 MB of them at
+    2^18)."""
+    from cilium_tpu.compile.ct_layout import CT_PLACED_KEYS
+    compiled = compiled_owner(one_chip, owner, cap)
+    text = compiled.as_text()
+    assert re_laid(text) == []
+    assert len(table_copies(text)) <= 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    taken = compiled.input_formats[0][0]
+    handed_back = compiled.output_formats[0]
+    assert set(taken) == set(handed_back) == set(CT_PLACED_KEYS)
+    layouts = {str(f.layout) for f in taken.values()} \
+        | {str(f.layout) for f in handed_back.values()}
+    assert len(layouts) == 1 and "major_to_minor=(0,)" in layouts.pop()
+
+
+@pytest.mark.parametrize("cap", CT_CAPACITIES)
+def test_the_reader_sees_the_key_table_re_laid_as_a_matrix(one_chip, cap):
+    """The control: the table as it stood until PR 48, one ``[cap, 10]``
+    matrix probed by row gathers and written by row scatters. At 2^18 the
+    compiled program re-lays all of it before the gathers and back after
+    the scatters (0.385 ms of every dispatch on the chip, and 134 MB of
+    temporaries); at the other sizes the compiler picks one layout and
+    the reader, rightly, sees none."""
+    import jax
+    import jax.numpy as jnp
+    from cilium_tpu.kernels.hashing import hash_words_jnp
+
+    def matrix_step(tab, expiry, keys, now):
+        base = (hash_words_jnp(keys) & jnp.uint32(cap - 1)).astype(jnp.int32)
+        found = jnp.full(base.shape, -1, jnp.int32)
+        for i in range(8):
+            s = (base + i) & (cap - 1)
+            eq = jnp.all(tab[s] == keys, axis=-1) & (expiry[s] > now)
+            found = jnp.where((found < 0) & eq, s, found)
+        fresh = jnp.where(found < 0, base, cap)
+        return tab.at[fresh].set(keys, mode="drop"), found
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(matrix_step, donate_argnums=(0,)).lower(
+        spec((cap, 10), jnp.uint32), spec((cap,), jnp.uint32),
+        spec((ROWS, 10), jnp.uint32), spec((), jnp.uint32)).compile()
+    copies = table_copies(compiled.as_text())
+    if cap == 1 << 18:
+        assert len(copies) == 2 and all("[262144,10]" in c for c in copies)
+        assert compiled.memory_analysis().temp_size_in_bytes >= cap * 128 * 4
+    else:
+        assert copies == []

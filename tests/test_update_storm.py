@@ -437,7 +437,7 @@ class TestOverlappedCTGC:
         n = cap // 2
         slots = rng.choice(cap, size=n, replace=False)
         ct["expiry"][slots] = rng.integers(1, 200, n).astype(np.uint32)
-        ct["keys"][slots, 0] = np.arange(n, dtype=np.uint32) + 1
+        ct["key0"][slots] = np.arange(n, dtype=np.uint32) + 1
         return {k: jnp.asarray(v) for k, v in ct.items()}
 
     def test_chunked_epoch_equals_whole_table_sweep(self):
