@@ -18,8 +18,6 @@ it, so the run exits non-zero naming the phase:
 
   device   JAX's first device is a TPU (no accelerator → exit, no result)
   build    libflowshim.so built from flowshim.cc by the shim's Makefile
-  kernels  each fused Pallas stage: compiles and is bit-identical to its
-           jnp reference, or is statically deselected and still refused
   load     policy build + place; engine, controllers, shim, feeder up
   serve1   >=100k frames of the cfg5 mix; every frame gets a verdict
   serve2   the same flows again: ESTABLISHED / REPLY off the device CT
@@ -27,7 +25,7 @@ it, so the run exits non-zero naming the phase:
   sweep    one whole-table device sweep
   parity   auditor clean with rows audited; 8192-row batch == oracle twin
            (the twin answers every 8th row)
-  health   pipeline counters, engine health, fused executor state
+  health   pipeline counters, engine health, the packed verdict slab
 
 The last line of stdout is one JSON object, ``{"ok": true, "device":
 {"platform": ..., "kind": ..., "count": ...}}``; the line before it
@@ -419,96 +417,6 @@ def phase_build(run: Run) -> None:
     say("build", lib=LIB_PATH)
 
 
-def kernel_cases(rows: int = 2048):
-    """The three fused stages on a small world's real tensors: stage →
-    (fused fn, reference fn), both taking no arguments."""
-    import jax
-    import jax.numpy as jnp
-    from cilium_tpu.compile.ct_layout import CTConfig, make_ct_arrays
-    from cilium_tpu.kernels import conntrack as ctk
-    from cilium_tpu.kernels import fused as fk
-    from cilium_tpu.kernels.classify import classify_interior_core
-    from cilium_tpu.kernels.lpm import lpm_lookup_prov_batch
-    from cilium_tpu.runtime.config import DaemonConfig
-    from cilium_tpu.runtime.datapath import FakeDatapath
-    from cilium_tpu.runtime.engine import Engine
-
-    w = World(n_ids=64, n_rules=512, port_span=256, ct_capacity=1 << 12)
-    cfg = DaemonConfig(ct_capacity=w.ct_capacity, auto_regen=False)
-    host = Engine(cfg, datapath=FakeDatapath(cfg))
-    load_world(host, w)
-    snap = host.active.snapshot
-    host.stop()
-    t = {k: jnp.asarray(v) for k, v in snap.tensors().items()}
-    ct = {k: jnp.asarray(v) for k, v in
-          make_ct_arrays(CTConfig(w.ct_capacity, cfg.probe_depth)).items()}
-    rng = np.random.default_rng(0)
-    b = {k: jnp.asarray(v) for k, v in columns(
-        pod_ip(rng.integers(0, w.n_ids, rows)),
-        rng.integers(20000, 60000, rows),
-        1024 + rng.integers(0, w.port_span, rows)).items()}
-    fwd, rev = ctk.ct_key_words_pair(b)
-    now = jnp.uint32(5)
-    idx = jnp.asarray(rng.integers(0, t["id_class_of"].shape[0], rows),
-                      jnp.int32)
-    no = jnp.zeros((rows,), bool)
-    interior = (b["ep_slot"], b["direction"], idx, b["proto"], b["dport"],
-                b["http_method"], b["http_path"], no, no, b["valid"])
-    wi = snap.world_index
-    return {
-        "lpm": (
-            jax.jit(lambda: fk.lpm_lookup_fused(
-                t["lpm_v4"], t["lpm_v6"], b["src"], b["is_v6"], wi)),
-            jax.jit(lambda: lpm_lookup_prov_batch(
-                t["lpm_v4"], t["lpm_v6"], b["src"], b["is_v6"], wi))),
-        "ct": (
-            jax.jit(lambda: fk.ct_probe_pair_fused(
-                ct, fwd, rev, now, cfg.probe_depth)),
-            jax.jit(lambda: (ctk.ct_probe(ct, fwd, now, cfg.probe_depth),
-                             ctk.ct_probe(ct, rev, now, cfg.probe_depth)))),
-        "policy": (
-            jax.jit(lambda: fk.policy_verdict_fused(t, *interior)),
-            jax.jit(lambda: classify_interior_core(t, *interior))),
-    }
-
-
-def phase_kernels(run: Run) -> None:
-    """The chip's verdict on each fused Pallas stage, against the static
-    table the product selects from (kernels/fused.TPU_COMPILED_STAGES): a
-    selected stage must compile and equal its jnp reference bit for bit; a
-    deselected one must still be refused — when the compiler starts taking
-    it, the table is stale and this phase says so. Nothing here falls back:
-    serving never compiles a deselected stage."""
-    import jax
-    from jax.experimental.pallas import tpu as pltpu
-    from cilium_tpu.kernels.fused import TPU_COMPILED_STAGES
-    refusals = (NotImplementedError, ValueError, pltpu.LoweringException,
-                jax.errors.JaxRuntimeError)
-    outcome = {}
-    for stage, (fused_fn, ref_fn) in kernel_cases().items():
-        selected = getattr(TPU_COMPILED_STAGES, stage)
-        try:
-            got = jax.block_until_ready(fused_fn())
-        except refusals as e:
-            need(not selected, "kernels",
-                 f"stage {stage} is selected for the TPU but does not "
-                 f"compile: {type(e).__name__}: {e}")
-            outcome[stage] = f"refused: {type(e).__name__}: " \
-                + str(e).splitlines()[0][:200]
-            continue
-        need(selected, "kernels",
-             f"stage {stage} now compiles on this TPU but "
-             f"TPU_COMPILED_STAGES deselects it — update the table")
-        want = ref_fn()
-        same = all(np.array_equal(np.asarray(g), np.asarray(x))
-                   for g, x in zip(got, want))
-        need(same, "kernels", f"stage {stage} differs from its reference")
-        outcome[stage] = "compiled, bit-identical"
-    for stage, text in outcome.items():
-        say("kernels", stage=stage, outcome=repr(text))
-    run.report["kernels"] = outcome
-
-
 def make_config(run: Run):
     from cilium_tpu.runtime.config import DaemonConfig
     w = run.world
@@ -865,7 +773,6 @@ def phase_health(run: Run) -> None:
     ps = eng.pipeline_stats()
     fs = eng.feeder_stats()
     h = eng.health()
-    fz = eng.datapath.fused_state
     cold = sorted(run.compiles, key=lambda c: -c[1])[:3]
     say("health", pipeline=json.dumps({k: ps[k] for k in (
         "state", "restarts", "dispatch_errors", "dispatch_faults",
@@ -875,8 +782,7 @@ def phase_health(run: Run) -> None:
     say("health", feeder=json.dumps({k: fs[k] for k in (
         "harvested_batches", "applied_batches", "rejected_batches",
         "errors", "harvest_faults", "prio_shed_rows")}))
-    say("health", engine=h["state"], fused=json.dumps(fz),
-        pack=json.dumps(eng.datapath.pack_stats))
+    say("health", engine=h["state"], pack=json.dumps(eng.datapath.pack_stats))
     say("health", compiles=len(run.compiles),
         compile_s_total=round(sum(c[1] for c in run.compiles), 2),
         longest=json.dumps([(n, round(s, 2)) for n, s in cold]),
@@ -892,8 +798,6 @@ def phase_health(run: Run) -> None:
          and fs["prio_shed_rows"] == 0 and fs["alive"], "health",
          f"feeder: {fs}")
     need(h["state"] == C.HEALTH_OK, "health", f"engine health: {h}")
-    need(fz["interpret"] is False, "health",
-         f"Pallas interpret mode on the serving path: {fz}")
     if run.n_shards * run.rule_shards > 1:
         check_placement(run, "health")
     # one chip or a mesh: batches came back in one packed verdict slab
@@ -904,7 +808,6 @@ def phase_health(run: Run) -> None:
     run.report["compile_s_total"] = round(sum(c[1] for c in run.compiles), 2)
     run.report["compiles"] = len(run.compiles)
     run.report["cache"] = dict(run.cache_events)
-    run.report["fuse_plan"] = fz["plan"]
 
 
 def shutdown(run: Run) -> None:
@@ -914,10 +817,10 @@ def shutdown(run: Run) -> None:
         run.shim.close()
 
 
-PHASES = (("kernels", phase_kernels), ("load", phase_load),
-          ("serve1", phase_serve1), ("serve2", phase_serve2),
-          ("update", phase_update), ("sweep", phase_sweep),
-          ("parity", phase_parity), ("health", phase_health))
+PHASES = (("load", phase_load), ("serve1", phase_serve1),
+          ("serve2", phase_serve2), ("update", phase_update),
+          ("sweep", phase_sweep), ("parity", phase_parity),
+          ("health", phase_health))
 
 
 def main(argv=None) -> int:

@@ -12,7 +12,7 @@ re-designed TPU-first:
                   (analog of ``pkg/policy``).
 - ``compile/``  — the "loader": MapState/ipcache/CT-config → dense device tensor
                   images (analog of ``pkg/datapath/loader`` — XLA replaces clang).
-- ``kernels/``  — batched JAX/Pallas datapath kernels: LPM gather, policy lookup,
+- ``kernels/``  — batched JAX datapath kernels: LPM gather, policy lookup,
                   conntrack probe, L7-lite match, fused classify step (analog of
                   ``bpf/``).
 - ``runtime/``  — host engine: snapshot double-buffering with revision fencing,

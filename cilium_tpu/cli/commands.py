@@ -394,13 +394,6 @@ def _cmd_status(args) -> int:
             print(f"Device:           {dev['platform']} ({dev['device_kind']}"
                   f" x{dev['count']}, serving on {dev['serving']};"
                   f" configured {dev['configured']})")
-        fz = d.get("fused_kernels")
-        if fz:
-            plan = fz.get("plan") or {}
-            print(f"Fused kernels:    mode={fz['mode']}"
-                  f" active={fz['active']} interpret={fz['interpret']}"
-                  " stages=" + (",".join(k for k, v in plan.items() if v)
-                                or "none"))
         pl = d.get("pipeline")
         if pl:
             fl = pl.get("flush_reasons", {})

@@ -194,7 +194,7 @@ def lpm_lookup_host(tables: LPMTables, addr16: bytes, is_v6: bool) -> int:
 def lpm_lookup_host_prov(tables: LPMTables, addr16: bytes,
                          is_v6: bool) -> Tuple[int, int]:
     """Reference walk returning (identity index, packed lpm_prefix
-    provenance) — the host mirror of kernels/lpm.lpm_walk_prov_core."""
+    provenance) — the host mirror of kernels/lpm.lpm_lookup_prov_batch."""
     nodes = tables.v6_nodes if is_v6 else tables.v4_nodes
     data = addr16 if is_v6 else addr16[12:]
     levels = V6_LEVELS if is_v6 else V4_LEVELS

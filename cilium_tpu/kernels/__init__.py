@@ -1,5 +1,5 @@
 """Batched JAX datapath kernels (analog of upstream ``bpf/`` — SURVEY.md §2
-native checklist item 1: "JAX/Pallas TPU kernels (LPM lookup, policy match,
+native checklist item 1: "JAX TPU kernels (LPM lookup, policy match,
 conntrack probe, L7-lite token match) — device-native, not Python loops").
 
 Everything here is shape-static, branch-free (masked select instead of

@@ -8,21 +8,11 @@ Branch-free: every packet takes every path, masks select. XLA fuses the
 elementwise pipeline between the gathers; the scatters at the end form the
 CT write phase.
 
-Two executors serve the hot interior (LPM walk → CT probe pair → policy
-ladder + L7 + verdict composition):
-
-- the **jnp reference** (default): plain XLA ops — portable, and the
-  semantics baseline every other path is pinned against;
-- the **Pallas megakernel path** (``fused=True``): kernels/fused.py runs
-  the same shared core functions inside explicit TPU kernels that keep the
-  walk/probe/ladder state in registers/VMEM instead of materializing ~20
-  intermediate [N] arrays in HBM between stages. On CPU the fused path runs
-  in Pallas interpret mode (``fused_interpret=True``) so CI pins it
-  bit-identical to the reference and the oracle without TPU hardware.
-
-The CT insert/apply phase (scatter-heavy, order-defined aggregation) stays
-on XLA in both modes — scatters are what XLA already does well, and the
-deterministic-winner semantics live in kernels/conntrack.py either way.
+The interior (LPM walk → CT probe pair → policy ladder + L7 + verdict
+composition) and the CT insert/apply phase are plain ``jnp`` operations:
+one program, the one every chip has served, held to the host oracle by
+tests/test_parity.py. The deterministic-winner semantics of the CT write
+phase live in kernels/conntrack.py.
 """
 
 from __future__ import annotations
@@ -69,11 +59,11 @@ COUNTER_KEYS = ("by_reason_dir", "insert_fail", "ct_evicted",
 def compose_verdict(decision, enforced, cell_redirect, l7_fail,
                     est, reply, valid):
     """Step 5 of the datapath: (decision, l7_fail) → allow/reason/status/
-    redirect, with no intermediate leaving the caller's scope. Shared
-    verbatim by the jnp reference and the fused Pallas kernel body
-    (kernels/fused.py) — the single source of the composition semantics;
-    the L7-gating inputs (``cell_redirect``/``l7_fail``) come from
-    :func:`classify_interior_core`, their single source."""
+    redirect. The single source of the composition semantics: the step
+    composes once after its probe, the device-RSS exchange
+    (parallel/exchange.py) before the hop, to know what to insert, and
+    again after it; the L7-gating inputs (``cell_redirect``/``l7_fail``)
+    come from :func:`interior_pre_core`."""
     hit = est | reply
     new_allow = jnp.where(
         decision == C.VERDICT_DENY, False,
@@ -105,10 +95,9 @@ def interior_pre_core(tensors, ep_slot, direction, id_idx, proto,
     Nothing here depends on the CT probe result — only the final
     ``compose_verdict`` does — which is exactly what lets the device-RSS
     exchange path (parallel/exchange.py) run this BEFORE the ring
-    ``ppermute`` CT hop and compose after the replies land, while the
-    steered/serial paths keep calling it through
-    :func:`classify_interior_core` unchanged. One source of the ladder/L7
-    semantics either way."""
+    ``ppermute`` CT hop and compose after the replies land. One source of
+    the ladder/L7 semantics for every step (:func:`classify_pre_ct` calls
+    it)."""
     decision, l7_cell, enforced, mrule = policy_lookup_batch(
         tensors, ep_slot, direction, id_idx, proto, dport,
         rule_axis=rule_axis)
@@ -135,8 +124,7 @@ def has_l7_tokens(http_method, http_path):
 
 def tally_l7(http_method, http_path, valid, redirect, reason):
     """Rows the L7 lane judged, once a batch, from the composed verdict's
-    own columns (so the fused interior, which hands back nothing else,
-    counts the same): ``l7_checked``, the valid rows that carry a request
+    own columns: ``l7_checked``, the valid rows that carry a request
     and whose cell redirects to a rule set (``redirect`` is ``valid &
     cell_redirect``, and a REDIRECT cell always names a set: ids are
     1-based), and ``l7_refused``, those of them the set refused
@@ -147,35 +135,6 @@ def tally_l7(http_method, http_path, valid, redirect, reason):
         "l7_refused": (valid & (reason == int(C.DropReason.POLICY_L7))
                        ).sum().astype(jnp.uint32),
     }
-
-
-def classify_interior_core(tensors, ep_slot, direction, id_idx, proto,
-                           dport, http_method, http_path, est, reply, valid,
-                           rule_axis=None):
-    """Steps 3-5 of the datapath (policy ladder → L7 token match → verdict
-    composition) as one pure function of the snapshot tensor dict + row
-    columns. This is the *fusable core*: the jnp reference calls it on XLA
-    arrays, the Pallas verdict kernel (kernels/fused.py) calls the exact
-    same function on values read from VMEM refs — so
-    ``decision → l7_cell → l7_match → allow/reason`` never round-trips
-    through HBM on the fused path, and bit-identity between the executors
-    holds by construction.
-
-    → (allow [N] bool, reason [N] int32, status [N] int32,
-    redirect [N] bool, matched_rule [N] int32); the NO_SERVICE override for
-    LB no-backend drops is the caller's job (it precedes this stage's
-    inputs either way). ``matched_rule`` is the ladder's provenance column
-    (kernels/policy.py): the resolved cell coordinate where a ladder
-    actually ran (valid row, enforced direction), -1 otherwise — identical
-    across the jnp reference, the fused kernel and the oracle."""
-    decision, enforced, cell_redirect, l7_fail, mrule = interior_pre_core(
-        tensors, ep_slot, direction, id_idx, proto, dport, http_method,
-        http_path, rule_axis=rule_axis)
-    allow, reason, status, redirect = compose_verdict(
-        decision, enforced, cell_redirect, l7_fail, est, reply, valid)
-    matched_rule = jnp.where(valid & enforced, mrule,
-                             jnp.int32(-1)).astype(jnp.int32)
-    return allow, reason, status, redirect, matched_rule
 
 
 def tally_pre_ct(valid0, translated, no_backend, pfx_meta):
@@ -197,13 +156,13 @@ def tally_pre_ct(valid0, translated, no_backend, pfx_meta):
 
 
 def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
-                    rule_axis=None, lb_probe_depth: int = 8, plan=None,
-                    fused_interpret: bool = False,
-                    split_interior: bool = False):
-    """Steps 0-1 of the datapath (service LB → ipcache LPM) plus the CT
-    key derivation, as one pure function shared by :func:`classify_step`
-    and the device-RSS exchange path (parallel/exchange.py) — the single
-    source of everything that happens BEFORE the conntrack stage.
+                    rule_axis=None, lb_probe_depth: int = 8):
+    """Steps 0-1 of the datapath (service LB → ipcache LPM), the CT key
+    derivation and the CT-independent half of steps 3-5 (ladder + L7,
+    :func:`interior_pre_core`), as one pure function shared by
+    :func:`classify_step` and the device-RSS exchange path
+    (parallel/exchange.py) — the single source of everything that happens
+    BEFORE the conntrack stage.
 
     Returns a dict:
       ``batch``  — the post-DNAT column dict (dst/dport rewritten),
@@ -212,14 +171,11 @@ def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
       ``id_idx`` / ``remote_identity`` / ``lpm_prefix`` — the LPM result
       + provenance (masked by the ORIGINAL valid, like classify_step),
       ``fwd_keys`` / ``rev_keys`` — the post-DNAT CT key pair,
-      ``tally`` — :func:`tally_pre_ct`'s three counters of this stage.
-
-    ``split_interior=True`` additionally runs :func:`interior_pre_core`
-    (ladder + L7, no compose) and adds ``decision``/``enforced``/
-    ``cell_redirect``/``l7_fail``/``mrule`` — the form the exchange path
-    needs, since est/reply only exist after the ppermute hop. ``plan``
-    (kernels/fused.fuse_plan) routes the LPM walk through the Pallas
-    kernel when eligible, exactly like classify_step."""
+      ``tally`` — :func:`tally_pre_ct`'s three counters of this stage,
+      ``decision`` / ``enforced`` / ``cell_redirect`` / ``l7_fail`` /
+      ``mrule`` — the ladder and the L7 match, not yet composed: est/reply
+      exist only after the probe (after the ppermute hop, on the exchange
+      path)."""
     valid0 = batch["valid"]
     direction = batch["direction"]
     # 0. service LB (bpf/lib/lb.h analog): frontend match → Maglev backend
@@ -248,16 +204,9 @@ def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
     remote_words = jnp.where((direction == C.DIR_EGRESS)[:, None],
                              batch["dst"], batch["src"])
     with jax.named_scope(SCOPE_LPM):
-        if plan is not None and plan.lpm:
-            from cilium_tpu.kernels import fused as fk
-            id_idx, pfx_meta = fk.lpm_lookup_fused(
-                tensors["lpm_v4"], tensors["lpm_v6"], remote_words,
-                batch["is_v6"], world_index, v4_only=v4_only,
-                interpret=fused_interpret)
-        else:
-            id_idx, pfx_meta = lpm_lookup_prov_batch(
-                tensors["lpm_v4"], tensors["lpm_v6"], remote_words,
-                batch["is_v6"], default_index=world_index, v4_only=v4_only)
+        id_idx, pfx_meta = lpm_lookup_prov_batch(
+            tensors["lpm_v4"], tensors["lpm_v6"], remote_words,
+            batch["is_v6"], default_index=world_index, v4_only=v4_only)
     remote_identity = tensors["identity_ids"][id_idx].astype(jnp.uint32)
     # provenance masking follows the same truth the columns they explain
     # use: lpm_prefix for every row that was valid at ingest (NO_SERVICE
@@ -269,23 +218,19 @@ def classify_pre_ct(tensors, batch, world_index, *, v4_only: bool = False,
     # the forward key — normalized once, derived twice
     fwd_keys, rev_keys = ctk.ct_key_words_pair(batch)
 
-    pre = {
+    tally = tally_pre_ct(valid0, svc & valid, no_backend, pfx_meta)
+    decision, enforced, cell_redirect, l7_fail, mrule = interior_pre_core(
+        tensors, batch["ep_slot"], direction, id_idx, batch["proto"],
+        batch["dport"], batch["http_method"], batch["http_path"],
+        rule_axis=rule_axis)
+    return {
         "batch": batch, "valid": valid, "svc": svc, "rev_nat": rev_nat,
         "no_backend": no_backend, "id_idx": id_idx,
         "remote_identity": remote_identity, "lpm_prefix": lpm_prefix,
-        "fwd_keys": fwd_keys, "rev_keys": rev_keys,
-        "tally": tally_pre_ct(valid0, svc & valid, no_backend, pfx_meta),
+        "fwd_keys": fwd_keys, "rev_keys": rev_keys, "tally": tally,
+        "decision": decision, "enforced": enforced,
+        "cell_redirect": cell_redirect, "l7_fail": l7_fail, "mrule": mrule,
     }
-    if split_interior:
-        decision, enforced, cell_redirect, l7_fail, mrule = \
-            interior_pre_core(
-                tensors, batch["ep_slot"], direction, id_idx,
-                batch["proto"], batch["dport"], batch["http_method"],
-                batch["http_path"], rule_axis=rule_axis)
-        pre.update(decision=decision, enforced=enforced,
-                   cell_redirect=cell_redirect, l7_fail=l7_fail,
-                   mrule=mrule)
-    return pre
 
 
 def ct_update_stage(ct, fwd_keys, proto, tcp_flags, hit, hit_slot, reply,
@@ -360,8 +305,7 @@ def tally_by_reason_dir(reason, direction, counted):
 
 def classify_step(tensors, ct, batch, now, world_index=0, *,
                   probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
-                  rule_axis=None, lb_probe_depth: int = 8,
-                  fused: bool = False, fused_interpret: bool = False):
+                  rule_axis=None, lb_probe_depth: int = 8):
     # ``world_index`` is a traced scalar (not static): it changes whenever the
     # identity table grows, and baking it in would force a re-jit per snapshot.
     # ``rule_axis`` names a mesh axis for rule-space (verdict-row) sharding.
@@ -378,32 +322,12 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
     counters: by_reason_dir [COUNTER_CELLS] uint32 (reasons x directions),
     insert_fail uint32 scalar, ct_evicted uint32 scalar (live entries
     tail-evicted by saturated inserts), :func:`tally_pre_ct`'s three and
-    :func:`tally_l7`'s two.
-
-    ``fused=True`` routes the interior through the Pallas kernels of
-    kernels/fused.py where each stage's static geometry permits
-    (kernels/fused.fuse_plan — VMEM-resident tables, no rule-axis psum);
-    ineligible stages fall back to the jnp reference per stage, so the
-    choice is a per-shape trace-time constant, never data-dependent.
-    ``fused_interpret`` runs those kernels in the Pallas interpreter (the
-    CPU-CI bit-identity mode)."""
-    if fused:
-        from cilium_tpu.kernels import fused as fk
-        plan = fk.fuse_plan(tensors, ct, v4_only=v4_only,
-                            rule_axis=rule_axis,
-                            compiled=not fused_interpret)
-    else:
-        plan = None
-
-    # 0-1. service LB + ipcache LPM + CT key derivation — the shared
-    # pre-CT stage (classify_pre_ct; also the device-RSS exchange's local
-    # prologue). The interior splits (ladder/L7 before compose) exactly
-    # when the fused policy kernel is NOT taking the whole stage.
-    split = plan is None or not plan.policy
+    :func:`tally_l7`'s two."""
+    # 0-1. service LB + ipcache LPM + CT key derivation, and the ladder
+    # and L7 match of 3-5 — the shared pre-CT stage (classify_pre_ct; also
+    # the device-RSS exchange's local prologue)
     pre = classify_pre_ct(tensors, batch, world_index, v4_only=v4_only,
-                          rule_axis=rule_axis, lb_probe_depth=lb_probe_depth,
-                          plan=plan, fused_interpret=fused_interpret,
-                          split_interior=split)
+                          rule_axis=rule_axis, lb_probe_depth=lb_probe_depth)
     batch = pre["batch"]
     valid = pre["valid"]
     direction = batch["direction"]
@@ -412,35 +336,23 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
     fwd_keys, rev_keys = pre["fwd_keys"], pre["rev_keys"]
 
     # 2. conntrack probe (batch-start snapshot)
-    if plan is not None and plan.ct:
-        fwd_slot, rev_slot = fk.ct_probe_pair_fused(
-            ct, fwd_keys, rev_keys, now, probe_depth,
-            interpret=fused_interpret)
-    else:
-        fwd_slot, rev_slot = ctk.ct_probe_pair(ct, fwd_keys, rev_keys, now,
-                                               probe_depth)
+    fwd_slot, rev_slot = ctk.ct_probe_pair(ct, fwd_keys, rev_keys, now,
+                                           probe_depth)
     est = valid & (fwd_slot >= 0)
     reply = valid & ~est & (rev_slot >= 0)
     new = valid & ~est & ~reply
     hit = est | reply
     hit_slot = jnp.where(est, fwd_slot, jnp.where(reply, rev_slot, 0))
 
-    # 3-5. policy ladder + L7 token match + verdict composition (the fused
-    # interior, or the split jnp core composed here — same semantics, see
-    # classify_interior_core)
-    if plan is not None and plan.policy:
-        allow, reason, status, redirect, matched_rule = \
-            fk.policy_verdict_fused(
-                tensors, batch["ep_slot"], direction, pre["id_idx"],
-                batch["proto"], batch["dport"], batch["http_method"],
-                batch["http_path"], est, reply, valid,
-                interpret=fused_interpret)
-    else:
-        allow, reason, status, redirect = compose_verdict(
-            pre["decision"], pre["enforced"], pre["cell_redirect"],
-            pre["l7_fail"], est, reply, valid)
-        matched_rule = jnp.where(valid & pre["enforced"], pre["mrule"],
-                                 jnp.int32(-1)).astype(jnp.int32)
+    # 3-5. verdict composition over the ladder's and the L7 match's
+    # answers. ``matched_rule`` is the ladder's provenance column
+    # (kernels/policy.py): the resolved cell coordinate where a ladder
+    # actually ran (valid row, enforced direction), -1 otherwise
+    allow, reason, status, redirect = compose_verdict(
+        pre["decision"], pre["enforced"], pre["cell_redirect"],
+        pre["l7_fail"], est, reply, valid)
+    matched_rule = jnp.where(valid & pre["enforced"], pre["mrule"],
+                             jnp.int32(-1)).astype(jnp.int32)
     l7_tally = tally_l7(batch["http_method"], batch["http_path"], valid,
                         redirect, reason)
     reason = jnp.where(no_backend, int(C.DropReason.NO_SERVICE), reason)
@@ -489,7 +401,7 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
         # and the CT probe class as-of classification (ct_state_pre; an
         # explicit alias of ``status``, pinned as its own column so the
         # provenance contract survives any future post-mutation semantics
-        # of status). Bit-identical across jnp / fused / oracle.
+        # of status). Bit-identical to the oracle's.
         "matched_rule": matched_rule,
         "lpm_prefix": lpm_prefix,
         "ct_state_pre": status,
@@ -509,7 +421,7 @@ def classify_step(tensors, ct, batch, now, world_index=0, *,
 #: jitted fn per static-config key; jax's own cache then dedupes per shape.
 #:
 #: LRU-bounded: a long-lived daemon cycling many distinct static configs
-#: (probe depths, lb depths, fused toggles across restarts/tests) must not
+#: (probe depths, lb depths, return forms across restarts/tests) must not
 #: grow the memo — and the jit caches it pins — without bound. Cap
 #: overridable via CILIUM_TPU_CLASSIFY_FN_CACHE; evictions are counted and
 #: exported by Engine.render_metrics (classify_fn_cache_evictions_total).
@@ -547,8 +459,7 @@ jax.tree_util.register_dataclass(OutSlab, data_fields=["words"],
 
 def make_classify_fn(probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
                      donate_ct: bool = True, packed: bool = False,
-                     lb_probe_depth: int = 8, fused: bool = False,
-                     fused_interpret: bool = False, slab: bool = False):
+                     lb_probe_depth: int = 8, slab: bool = False):
     """jit-compiled classify step. CT buffers are donated (in-place update,
     no double allocation); re-traces only when array shapes change.
 
@@ -564,10 +475,6 @@ def make_classify_fn(probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
     wire width selects the variant at trace time: 4 words = compact v4
     (pack_batch_v4), otherwise the full/L7 layout.
 
-    ``fused``/``fused_interpret``: route the classify interior through the
-    Pallas kernels (kernels/fused.py), optionally in interpreter mode (the
-    CPU-CI bit-identity configuration) — see classify_step.
-
     ``slab=True``: the step returns ``(OutSlab, new_ct)`` instead of
     ``(out, new_ct, counters)`` — a last stage inside the same jit packs
     every out column and counter into one uint32 vector
@@ -575,8 +482,7 @@ def make_classify_fn(probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
     back in one transfer; ``records.unpack_out(np.asarray(s.words),
     s.layout)`` is ``(out, counters)`` again, bit for bit. The one-chip
     serving path's return form; the column form stays for tests."""
-    key = (probe_depth, v4_only, donate_ct, packed, lb_probe_depth,
-           fused, fused_interpret, slab)
+    key = (probe_depth, v4_only, donate_ct, packed, lb_probe_depth, slab)
     with _FN_LOCK:
         fn = _FN_CACHE.get(key)
         if fn is not None:
@@ -590,8 +496,7 @@ def make_classify_fn(probe_depth: int = PROBE_DEPTH, v4_only: bool = False,
         out, new_ct, counters = classify_step(
             tensors, ct, batch, now, world_index,
             probe_depth=probe_depth, v4_only=v4_only,
-            lb_probe_depth=lb_probe_depth, fused=fused,
-            fused_interpret=fused_interpret)
+            lb_probe_depth=lb_probe_depth)
         if slab:
             from cilium_tpu.kernels.records import pack_out_jnp
             return OutSlab(*pack_out_jnp(out, counters)), new_ct

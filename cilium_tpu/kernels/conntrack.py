@@ -65,8 +65,7 @@ def ct_key_words_pair(batch):
     """→ (fwd_keys, rev_keys), both [N,10] uint32, sharing one pass over
     the tuple columns. ``classify_step`` previously normalized the same
     src/dst/port/proto fields twice (forward + reverse stacks); the reverse
-    orientation is a cheap word permutation of the forward words, so the
-    jnp fallback path wins this independently of any Pallas fusion."""
+    orientation is a cheap word permutation of the forward words."""
     fwd = ct_key_words_jnp(batch, reverse=False)
     return fwd, reverse_key_words_jnp(fwd)
 
@@ -77,13 +76,10 @@ def ct_key_words_pair(batch):
 FIRST_WORD = 8
 
 
-def ct_probe_core(planes, expiry, keys, now,
-                  probe_depth: int = PROBE_DEPTH):
-    """The fusable probe core over plain arrays (``planes``: the ten key
-    planes, each [cap] uint32; expiry [cap] uint32): find each key's live
-    slot, the first of its window that holds the key → [N] int32 (-1 =
-    miss). Shared verbatim by the XLA reference (``ct_probe``) and the
-    fused Pallas probe-pair body (kernels/fused.py).
+def ct_probe(ct, keys, now, probe_depth: int = PROBE_DEPTH):
+    """Find each key's live slot, the first of its window that holds the
+    key → slot [N] int32 (-1 = miss), over the table's placed form (the
+    ten key planes, each [cap] uint32; expiry [cap] uint32).
 
     Two stages, because a word gathered off a plane is what the probe
     costs (≈8 µs a plane and 1,024 rows on a v5e, PERF.md §6 "PR 48"):
@@ -95,6 +91,7 @@ def ct_probe_core(planes, expiry, keys, now,
     window of flows that share their ports takes as many rounds as it has
     such slots, ``probe_depth`` at most, which is what every probe took
     before."""
+    planes, expiry = key_planes(ct), ct["expiry"]
     cap = expiry.shape[0]
     mask = cap - 1
     base = (hash_words_jnp(keys) & jnp.uint32(mask)).astype(jnp.int32)
@@ -121,12 +118,6 @@ def ct_probe_core(planes, expiry, keys, now,
         lambda state: jnp.any(state[0]), settle,
         (jnp.stack(cand, axis=1), jnp.full(base.shape, -1, dtype=jnp.int32)))
     return found
-
-
-def ct_probe(ct, keys, now, probe_depth: int = PROBE_DEPTH):
-    """Find each key's live slot. → slot [N] int32 (-1 = miss)."""
-    return ct_probe_core(key_planes(ct), ct["expiry"], keys, now,
-                         probe_depth)
 
 
 def ct_probe_pair(ct, fwd_keys, rev_keys, now,
@@ -174,9 +165,9 @@ def ct_evictable(slot_proto, flags):
     """Which live entries an exhausted insert may tail-evict: everything
     whose current lifetime class is NOT the established-TCP one — i.e.
     TCP entries still in the handshake (no SEEN_NON_SYN) or closing, and
-    all non-TCP entries. One predicate, three executors (this jnp form,
-    the oracle's ``_ct_expirable``, and — by shared-core construction —
-    the fused path), so the protected class can never drift."""
+    all non-TCP entries. One predicate, two executors (this jnp form and
+    the oracle's ``_ct_expirable``), so the protected class can never
+    drift."""
     is_tcp = slot_proto == C.PROTO_TCP
     non_syn = (flags & jnp.uint32(C.CT_FLAG_SEEN_NON_SYN)) != 0
     closing = (flags & jnp.uint32(C.CT_FLAG_TX_CLOSING

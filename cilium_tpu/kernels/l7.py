@@ -3,10 +3,6 @@
 Per packet: gather its rule set's tensors and do one vectorized
 method+prefix compare across all rules of the set. Set id 0 (no redirect)
 vacuously matches.
-
-``l7_match_core`` is the *fusable core* shared verbatim by the XLA
-reference and the fused Pallas verdict kernel (kernels/fused.py) — same
-jnp ops, two compilation contexts, bit-identity by construction.
 """
 
 from __future__ import annotations
@@ -16,7 +12,7 @@ import jax.numpy as jnp
 from cilium_tpu.utils import constants as C
 
 
-def l7_match_core(tensors, set_id, method, path):
+def l7_match_batch(tensors, set_id, method, path):
     """set_id [N] int32 (0 = none), method [N] int32, path [N,64] uint8
     → matched [N] bool (True for set_id == 0)."""
     sid = jnp.clip(set_id, 0, tensors["l7_methods"].shape[0] - 1)
@@ -30,9 +26,3 @@ def l7_match_core(tensors, set_id, method, path):
     p_ok = byte_ok.all(axis=-1)
     any_rule = (valid & m_ok & p_ok).any(axis=-1)
     return jnp.where(set_id <= 0, True, any_rule)
-
-
-def l7_match_batch(tensors, set_id, method, path):
-    """set_id [N] int32 (0 = none), method [N] int32, path [N,64] uint8
-    → matched [N] bool (True for set_id == 0)."""
-    return l7_match_core(tensors, set_id, method, path)

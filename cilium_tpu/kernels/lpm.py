@@ -5,15 +5,11 @@ the sentinel node. A mixed-family batch walks both tries and selects by the
 family bit (mirroring upstream's two LPM maps); ``v4_only=True`` (static)
 skips the 16-level v6 walk for pure-IPv4 workloads (BASELINE config 1).
 
-``lpm_walk_prov_core`` is the *fusable core*: pure jnp over plain arrays, so
-the exact same function executes (a) as the XLA reference here and (b) inside
-the Pallas megakernel body (kernels/fused.py) over values read from refs —
-bit-identity between the two paths holds by construction, not by test luck.
-It returns BOTH the identity index and the packed match provenance
-``(prefix_slot << 8) | plen`` carried in the trie's third plane
-(compile/lpm.py): the walk that resolves the identity IS the walk that names
-the winning prefix, so the two can never disagree. ``lpm_walk_core`` /
-``lpm_lookup_batch`` keep the index-only contract for callers that do not
+``lpm_lookup_prov_batch`` returns BOTH the identity index and the packed
+match provenance ``(prefix_slot << 8) | plen`` carried in the trie's third
+plane (compile/lpm.py): the walk that resolves the identity IS the walk that
+names the winning prefix, so the two can never disagree.
+``lpm_lookup_batch`` keeps the index-only contract for callers that do not
 need provenance.
 
 The tries arrive in their placed form, one 2-D table ``[n * 256, 3]`` a
@@ -65,12 +61,12 @@ def _walk(flat, addr_words, byte_index, levels, default_index):
     return best, best_meta
 
 
-def lpm_walk_prov_core(lpm_v4, lpm_v6, addr_words, is_v6, default_index,
-                       v4_only: bool = False):
-    """The fusable core: [N,4] v4-mapped address words → (identity index
-    [N] int32, packed lpm_prefix provenance [N] int32, -1 on miss).
-    ``is_v6`` may be bool or a 0/1 integer mask (the Pallas body ships it as
-    int32). ``v4_only`` (static) elides the 16-level v6 chain."""
+def lpm_lookup_prov_batch(lpm_v4, lpm_v6, addr_words, is_v6, default_index,
+                          v4_only: bool = False):
+    """addr_words [N,4] uint32 (16-byte normalized, v4-mapped) → (identity
+    index [N] int32, packed lpm_prefix provenance [N] int32, -1 on miss).
+    ``default_index`` may be a traced scalar. ``v4_only`` (static) elides
+    the 16-level v6 chain."""
     r4, m4 = _walk(lpm_v4, addr_words, lambda l: 12 + l, V4_LEVELS,
                    default_index)
     if v4_only:
@@ -80,25 +76,9 @@ def lpm_walk_prov_core(lpm_v4, lpm_v6, addr_words, is_v6, default_index,
     return jnp.where(v6, r6, r4), jnp.where(v6, m6, m4)
 
 
-def lpm_walk_core(lpm_v4, lpm_v6, addr_words, is_v6, default_index,
-                  v4_only: bool = False):
-    """Index-only view of :func:`lpm_walk_prov_core` (compat surface for
-    callers that predate match provenance)."""
-    return lpm_walk_prov_core(lpm_v4, lpm_v6, addr_words, is_v6,
-                              default_index, v4_only=v4_only)[0]
-
-
-def lpm_lookup_prov_batch(lpm_v4, lpm_v6, addr_words, is_v6,
-                          default_index: int, v4_only: bool = False):
-    """addr_words [N,4] uint32 (16-byte normalized, v4-mapped) →
-    (identity index [N] int32, packed lpm_prefix [N] int32)."""
-    return lpm_walk_prov_core(lpm_v4, lpm_v6, addr_words, is_v6,
-                              default_index, v4_only=v4_only)
-
-
 def lpm_lookup_batch(lpm_v4, lpm_v6, addr_words, is_v6, default_index: int,
                      v4_only: bool = False):
     """addr_words [N,4] uint32 (16-byte normalized, v4-mapped) → identity
     index [N] int32."""
-    return lpm_walk_prov_core(lpm_v4, lpm_v6, addr_words, is_v6,
-                              default_index, v4_only=v4_only)[0]
+    return lpm_lookup_prov_batch(lpm_v4, lpm_v6, addr_words, is_v6,
+                                 default_index, v4_only=v4_only)[0]

@@ -2,28 +2,25 @@
 (upstream: bpf/lib/policy.h policy_can_access's 6-lookup ladder, resolved at
 compile time by compile/policy_image.py).
 
-``policy_core`` is the *fusable core*: pure jnp over the snapshot's tensor
-dict, shared verbatim by the XLA reference and the fused Pallas verdict
-kernel (kernels/fused.py). Every row-derived index is explicitly clipped —
-the clip reproduces jax's out-of-bounds clamp semantics exactly (so garbage
-rows cannot diverge between the two executors) — and every table is
-gathered from in the shape it is placed in. A flattened take (``v.reshape(-1)[…]``)
-is not free on the TPU: the image's last dimension is no multiple of the
-128-lane tile, so XLA runs the reshape as a physical copy of the whole
-image in every batch (the chip's trace: 1.58 ms of every dispatch for
-``ct1m-50k``'s 100 MB, PERF.md §6 PR 35), and it buys nothing: Mosaic
-lowers none of the fused bodies, flat or not (kernels/fused.py:
-``TPU_COMPILED_STAGES``).
+``policy_lookup_batch`` is pure jnp over the snapshot's tensor dict. On
+the dense (un-sharded) image every row-derived index is explicitly clipped
+— the clip is jax's own out-of-bounds clamp, written down, so a garbage
+row reads the cell the host ladder of tests/test_policy_ladder.py reads —
+and every table is gathered from in the shape it is placed in. A flattened
+take (``v.reshape(-1)[…]``) is not free on the TPU: the image's last
+dimension is no multiple of the 128-lane tile, so XLA runs the reshape as a
+physical copy of the whole image in every batch (the chip's trace: 1.58 ms
+of every dispatch for ``ct1m-50k``'s 100 MB, PERF.md §6 PR 35).
 
 Besides the cell, the lookup emits ``matched_rule``: the (id_class,
 port_class) coordinate of the resolved verdict cell, packed
 ``id_cls * n_port_classes + port_cls`` — layout-independent (never an index
-into the possibly rule-shard-padded image), identical across the jnp
-reference, the fused kernel, the rule-sharded mesh and the host oracle by
-construction. Callers mask it to -1 where no ladder ran (invalid row or
-unenforced direction); together with the endpoint slot and direction it
-names the exact policy-map row that decided the verdict (the flowlog /
-observer provenance of ISSUE 11).
+into the possibly rule-shard-padded image), identical across the dense
+lookup, the rule-sharded mesh and the host oracle by construction. Callers
+mask it to -1 where no ladder ran (invalid row or unenforced direction);
+together with the endpoint slot and direction it names the exact policy-map
+row that decided the verdict (the flowlog / observer provenance of
+ISSUE 11).
 """
 
 from __future__ import annotations
@@ -33,33 +30,10 @@ import jax.numpy as jnp
 from cilium_tpu.utils import constants as C
 
 
-def policy_core(tensors, ep_slot, direction, id_index, proto, dport):
-    """→ (decision [N] int32, l7_id [N] int32, enforced [N] bool,
-    matched_rule [N] int32 — unmasked cell coordinate) against the dense
-    (un-sharded) verdict image."""
-    n_ids = tensors["id_class_of"].shape[0]
-    id_cls = tensors["id_class_of"][jnp.clip(id_index, 0, n_ids - 1)]
-    fam = tensors["proto_family"][jnp.clip(proto, 0, 255)]
-    n_ports = tensors["port_class"].shape[1]
-    pcls = tensors["port_class"][fam, jnp.clip(dport, 0, n_ports - 1)]
-    v = tensors["verdict"]
-    n_eps, _, n_rows, n_cols = v.shape
-    ep = jnp.clip(ep_slot, 0, n_eps - 1)
-    d = jnp.clip(direction, 0, 1)
-    cls = jnp.clip(id_cls, 0, n_rows - 1)
-    pc = jnp.clip(pcls, 0, n_cols - 1)
-    cell = v[ep, d, cls, pc].astype(jnp.int32)
-    enforced = tensors["enforced"][ep, d].astype(bool)
-    decision = cell & C.VERDICT_DECISION_MASK
-    l7_id = cell >> C.VERDICT_L7_SHIFT
-    matched_rule = (id_cls * n_cols + pcls).astype(jnp.int32)
-    return decision, l7_id, enforced, matched_rule
-
-
 def policy_lookup_batch(tensors, ep_slot, direction, id_index, proto, dport,
                         rule_axis=None):
     """→ (decision [N] int32, l7_id [N] int32, enforced [N] bool,
-    matched_rule [N] int32).
+    matched_rule [N] int32 — the unmasked cell coordinate).
 
     ``rule_axis``: name of a mesh axis over which the verdict tensor's
     id-class rows are sharded (the "tensor parallelism over rule space" of
@@ -71,8 +45,23 @@ def policy_lookup_batch(tensors, ep_slot, direction, id_index, proto, dport,
     no collective needed.
     """
     if rule_axis is None:
-        return policy_core(tensors, ep_slot, direction, id_index, proto,
-                           dport)
+        n_ids = tensors["id_class_of"].shape[0]
+        id_cls = tensors["id_class_of"][jnp.clip(id_index, 0, n_ids - 1)]
+        fam = tensors["proto_family"][jnp.clip(proto, 0, 255)]
+        n_ports = tensors["port_class"].shape[1]
+        pcls = tensors["port_class"][fam, jnp.clip(dport, 0, n_ports - 1)]
+        v = tensors["verdict"]
+        n_eps, _, n_rows, n_cols = v.shape
+        ep = jnp.clip(ep_slot, 0, n_eps - 1)
+        d = jnp.clip(direction, 0, 1)
+        cls = jnp.clip(id_cls, 0, n_rows - 1)
+        pc = jnp.clip(pcls, 0, n_cols - 1)
+        cell = v[ep, d, cls, pc].astype(jnp.int32)
+        enforced = tensors["enforced"][ep, d].astype(bool)
+        decision = cell & C.VERDICT_DECISION_MASK
+        l7_id = cell >> C.VERDICT_L7_SHIFT
+        matched_rule = (id_cls * n_cols + pcls).astype(jnp.int32)
+        return decision, l7_id, enforced, matched_rule
     import jax
     id_cls = tensors["id_class_of"][id_index]
     fam = tensors["proto_family"][jnp.clip(proto, 0, 255)]

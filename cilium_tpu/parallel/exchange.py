@@ -187,8 +187,7 @@ def unpack_replies(rep):
 # The owner-side CT stage
 # --------------------------------------------------------------------------- #
 def ct_exchange_serve(ct, req_flat, axis_name: str, n_shards: int, now,
-                      probe_depth: int = PROBE_DEPTH, plan=None,
-                      fused_interpret: bool = False):
+                      probe_depth: int = PROBE_DEPTH):
     """Serve the gathered request set against THIS chip's local CT shard:
     probe pair → est/reply/new → insert-when-full → aggregate apply →
     batch-start rev-NAT read — the exact CT stage classify_step runs,
@@ -211,14 +210,8 @@ def ct_exchange_serve(ct, req_flat, axis_name: str, n_shards: int, now,
     mine = flow_shard_of_keys(fwd_keys, rev_keys, n_shards) == my
     valid = valid & mine
 
-    if plan is not None and plan.ct:
-        from cilium_tpu.kernels import fused as fk
-        fwd_slot, rev_slot = fk.ct_probe_pair_fused(
-            ct, fwd_keys, rev_keys, now, probe_depth,
-            interpret=fused_interpret)
-    else:
-        fwd_slot, rev_slot = ctk.ct_probe_pair(ct, fwd_keys, rev_keys, now,
-                                               probe_depth)
+    fwd_slot, rev_slot = ctk.ct_probe_pair(ct, fwd_keys, rev_keys, now,
+                                           probe_depth)
     est = valid & (fwd_slot >= 0)
     reply = valid & ~est & (rev_slot >= 0)
     new = valid & ~est & ~reply
@@ -244,30 +237,17 @@ def classify_step_exchange(tensors, ct, batch, now, world_index=0, *,
                            axis_name: str = "flows", n_shards: int,
                            probe_depth: int = PROBE_DEPTH,
                            v4_only: bool = False, rule_axis=None,
-                           lb_probe_depth: int = 8, fused: bool = False,
-                           fused_interpret: bool = False):
+                           lb_probe_depth: int = 8):
     """→ (out, new_ct, counters) — the device-RSS twin of
     kernels/classify.classify_step over THIS chip's arrival-order rows.
 
-    Structure: the shared pre-CT stage (LB → LPM → split interior) runs
+    Structure: the shared pre-CT stage (LB → LPM → ladder + L7) runs
     locally, the CT stage resolves through the ring ppermute exchange
     (module docstring), and the verdict composes locally from the replies
-    — every semantic block is the same shared core the steered path runs,
-    so bit-identity holds by construction. ``fused`` honors the LPM and
-    CT-probe Pallas kernels (fuse_plan); the policy stage always runs the
-    split jnp core here — the fused interior composes est/reply inside
-    one kernel, which cannot straddle the exchange."""
-    if fused:
-        from cilium_tpu.kernels import fused as fk
-        plan = fk.fuse_plan(tensors, ct, v4_only=v4_only,
-                            rule_axis=rule_axis,
-                            compiled=not fused_interpret)
-    else:
-        plan = None
+    — every semantic block is the same function the steered path runs,
+    so bit-identity holds by construction."""
     pre = classify_pre_ct(tensors, batch, world_index, v4_only=v4_only,
-                          rule_axis=rule_axis, lb_probe_depth=lb_probe_depth,
-                          plan=plan, fused_interpret=fused_interpret,
-                          split_interior=True)
+                          rule_axis=rule_axis, lb_probe_depth=lb_probe_depth)
     b = pre["batch"]
     valid = pre["valid"]
     direction = b["direction"]
@@ -295,8 +275,7 @@ def classify_step_exchange(tensors, ct, batch, now, world_index=0, *,
     with jax.named_scope("rss.owner_ct"):
         rep_all, new_ct, insert_fail, n_evicted = ct_exchange_serve(
             ct, gathered.reshape(n_shards * local_rows, REQ_WORDS),
-            axis_name, n_shards, now, probe_depth, plan=plan,
-            fused_interpret=fused_interpret)
+            axis_name, n_shards, now, probe_depth)
     with jax.named_scope("rss.reply_scatter"):
         rep = ring_reduce_scatter(
             rep_all.reshape(n_shards, local_rows, REP_WORDS), axis_name,

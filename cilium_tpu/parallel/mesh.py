@@ -313,18 +313,12 @@ def rehash_ct_arrays(arrays: Dict[str, np.ndarray], n_flow_shards: int,
 # --------------------------------------------------------------------------- #
 def make_sharded_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
                              v4_only: bool = False, donate_ct: bool = True,
-                             fused: bool = False,
-                             fused_interpret: bool = False,
                              slab: bool = False):
     """shard_map'd + jitted classify step over ``mesh`` ('flows','rules').
 
-    ``fused``/``fused_interpret`` route each shard's classify interior
-    through the Pallas megakernels (kernels/fused.py) exactly like the
-    single-chip ``make_classify_fn`` — the kernels run on per-shard local
-    arrays inside the shard_map body, so the mesh geometry is unchanged.
-    With rule sharding the policy/L7 stage stays on the jnp reference (its
-    psum must remain in the shard_map body); LPM and the CT probe pair
-    still fuse per shard.
+    Each shard runs the single-chip ``classify_step`` on its local arrays
+    inside the shard_map body; with rule sharding the ladder's psum over
+    'rules' is the body's one collective besides the counters'.
 
     Call with (tensors, ct, batch, now, world_index) where batch rows are
     steered (steer_batch) and verdict rows padded (pad_snapshot_tensors).
@@ -356,16 +350,13 @@ def make_sharded_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
     def body(tensors, ct, batch, now, world_index):
         return classify_step(
             tensors, ct, batch, now, world_index,
-            probe_depth=probe_depth, v4_only=v4_only, rule_axis=rule_axis,
-            fused=fused, fused_interpret=fused_interpret)
+            probe_depth=probe_depth, v4_only=v4_only, rule_axis=rule_axis)
 
     return _make_meshed_classify(mesh, body, donate_ct=donate_ct, slab=slab)
 
 
 def make_unsteered_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
                                v4_only: bool = False, donate_ct: bool = True,
-                               fused: bool = False,
-                               fused_interpret: bool = False,
                                slab: bool = False):
     """shard_map'd + jitted DEVICE-RSS classify step over ``mesh``
     ('flows','rules'): batch rows shard over 'flows' in plain ARRIVAL
@@ -381,10 +372,8 @@ def make_unsteered_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
     The collective set inside the body stays bounded and documented: the
     counter psum over 'flows' (+ the policy-cell psum over 'rules' when
     rule-sharded) plus the 2(n-1) ring ppermute hops of the exchange.
-    ``fused`` honors the LPM and CT-probe Pallas kernels; the policy
-    stage runs the split jnp core (see classify_step_exchange). The only
-    shape contract: batch rows must divide the 'flows' axis (each chip
-    takes an equal arrival-order slice).
+    The only shape contract: batch rows must divide the 'flows' axis (each
+    chip takes an equal arrival-order slice).
 
     Accepts the same batch forms as :func:`make_sharded_classify_fn`
     (column dict, packed wire, (wire, path_dict)) and gives the same
@@ -399,8 +388,7 @@ def make_unsteered_classify_fn(mesh, probe_depth: int = PROBE_DEPTH,
         return classify_step_exchange(
             tensors, ct, batch, now, world_index,
             axis_name="flows", n_shards=n_flow,
-            probe_depth=probe_depth, v4_only=v4_only, rule_axis=rule_axis,
-            fused=fused, fused_interpret=fused_interpret)
+            probe_depth=probe_depth, v4_only=v4_only, rule_axis=rule_axis)
 
     return _make_meshed_classify(mesh, body, donate_ct=donate_ct, slab=slab)
 

@@ -198,10 +198,6 @@ def status_doc(engine: "Engine") -> Dict:
         # which device serves (None on jax-free backends): what the config
         # asked for, and the platform / device_kind / count JAX reports
         "device": getattr(engine.datapath, "device_state", None),
-        # Pallas megakernel selector state incl. the per-stage fuse plan
-        # (None on jax-free backends — the oracle-backed fake has no
-        # kernels to fuse)
-        "fused_kernels": getattr(engine.datapath, "fused_state", None),
         # flow→shard resolution surface (None on jax-free backends): host
         # steering vs the device-side ppermute exchange (rss_mode)
         "rss": getattr(engine.datapath, "rss_state", None),

@@ -51,13 +51,6 @@ class DaemonConfig:
     # mode; verdicts stay bit-identical to the steered path.
     rss_mode: str = "host"         # host | device
     donate_ct: bool = True
-    # Pallas megakernel selector for the classify interior (kernels/fused.py):
-    # "auto" uses the stages Mosaic compiles on a TPU (none today —
-    # kernels/fused.TPU_COMPILED_STAGES) and the jnp reference elsewhere;
-    # "on" forces the kernels (Pallas interpret mode off-TPU — the CPU-CI
-    # bit-identity configuration; refused on a TPU while no stage compiles
-    # there); "off" pins the jnp reference.
-    fused_kernels: str = "auto"    # auto | on | off
     # --- lifecycle ---
     state_dir: str = "/var/run/cilium-tpu"
     sweep_interval_s: float = 30.0
@@ -304,10 +297,6 @@ class DaemonConfig:
         if self.device not in ("auto", "cpu", "tpu"):
             raise ValueError(
                 f"bad device {self.device!r} (auto | cpu | tpu)")
-        if self.fused_kernels not in ("auto", "on", "off"):
-            raise ValueError(
-                f"bad fused_kernels mode {self.fused_kernels!r} "
-                "(auto | on | off)")
         if self.rss_mode not in ("host", "device"):
             raise ValueError(
                 f"bad rss_mode {self.rss_mode!r} (host | device)")

@@ -121,33 +121,6 @@ class PlacedTensors(dict):
         self.dead = False
 
 
-def resolve_fused(config: DaemonConfig) -> Tuple[bool, bool]:
-    """``DaemonConfig.fused_kernels`` → (fused, interpret) for the Pallas
-    classify-interior kernels (kernels/fused.py). Off the TPU ``auto``
-    keeps the jnp reference and ``on`` runs the kernels in Pallas interpret
-    mode — the configuration CPU CI uses to pin them bit-identical to the
-    reference and the oracle. On the TPU the kernels are compiled or not
-    used at all, never interpreted: ``auto`` is fused exactly when some
-    stage compiles there (kernels/fused.TPU_COMPILED_STAGES), and ``on``
-    with no such stage is refused rather than quietly served by the
-    reference."""
-    mode = config.fused_kernels
-    if mode == "off":
-        return False, False
-    import jax
-    if jax.default_backend() != "tpu":
-        return (True, True) if mode == "on" else (False, False)
-    from cilium_tpu.kernels.fused import TPU_COMPILED_STAGES
-    if TPU_COMPILED_STAGES.any:
-        return True, False
-    if mode == "on":
-        raise ValueError(
-            "fused_kernels='on' on a TPU, but Mosaic compiles none of the "
-            "fused stages (kernels/fused.TPU_COMPILED_STAGES); use 'auto' "
-            "or 'off'")
-    return False, False
-
-
 def normalize_ct_arrays(arrays: Dict[str, np.ndarray]
                         ) -> Dict[str, np.ndarray]:
     """Validate/upgrade a ct_layout checkpoint to the current schema —
@@ -318,10 +291,6 @@ class JITDatapath(DatapathBackend):
         self._sharded = self.n_flow_shards * self.n_rule_shards > 1
         ct_host = make_ct_arrays(CTConfig(self.config.ct_capacity,
                                           self.config.probe_depth))
-        # Pallas megakernel selector (kernels/fused.py): trace-time static,
-        # so both classify fns below bake the choice into their jit keys
-        self._fused, self._fused_interpret = resolve_fused(self.config)
-        self._fuse_plan: Optional[Dict[str, bool]] = None
         # device-side RSS (rss_mode="device", parallel/exchange.py): rows
         # arrive on chips in plain FIFO order and cross-shard CT resolves
         # with the in-kernel ring ppermute exchange — no host steering, no
@@ -364,8 +333,6 @@ class JITDatapath(DatapathBackend):
                 probe_depth=self.config.probe_depth,
                 v4_only=self.config.v4_only,
                 donate_ct=self.config.donate_ct,
-                fused=self._fused,
-                fused_interpret=self._fused_interpret,
                 # one packed verdict slab a batch, a segment a chip, as
                 # on one chip below (remesh builds its survivors alike)
                 slab=True)
@@ -388,8 +355,6 @@ class JITDatapath(DatapathBackend):
                 v4_only=self.config.v4_only,
                 donate_ct=self.config.donate_ct,
                 packed=True,
-                fused=self._fused,
-                fused_interpret=self._fused_interpret,
                 # ...and read-back-bound the other way: every out column
                 # and counter comes back in one packed slab, not 18 reads
                 slab=True)
@@ -583,21 +548,6 @@ class JITDatapath(DatapathBackend):
                     "exchange_batches_total": self._exchange_batches_total}
 
     @property
-    def fused_state(self) -> Dict[str, Any]:
-        """Operator-facing view of the megakernel selector: the configured
-        mode, whether the fused path is active, whether it runs in Pallas
-        interpret mode (off-TPU ``fused_kernels=on`` — the CI bit-identity
-        configuration, not a serving configuration), and which stages
-        ``fuse_plan`` engaged for the geometry placed last (None before
-        the first placement)."""
-        return {
-            "mode": self.config.fused_kernels,
-            "active": self._fused,
-            "interpret": self._fused_interpret,
-            "plan": self._fuse_plan,
-        }
-
-    @property
     def device_state(self) -> Dict[str, Any]:
         """Which device serves: what the config asked for and what JAX
         has (platform, kind, count), plus how many of them this backend's
@@ -612,23 +562,6 @@ class JITDatapath(DatapathBackend):
             "serving": self.n_flow_shards * self.n_rule_shards,
             "compile_cache_dir": self.compile_cache_dir,
         }
-
-    def _note_fuse_plan(self, placed: Dict) -> None:
-        """Record the per-stage fuse plan of a placement, from shapes alone
-        (no device buffer is read) — the same call classify_step makes at
-        trace time, so status shows what the compiled program contains."""
-        if not self._fused:
-            return
-        import jax
-        from cilium_tpu.kernels.fused import fuse_plan
-        # inside shard_map the kernels see one flow shard's local table
-        local = self._ct_capacity // self.n_flow_shards
-        ct = {k: jax.ShapeDtypeStruct((local,), v.dtype)
-              for k, v in self._ct.items()}
-        self._fuse_plan = fuse_plan(
-            placed, ct, v4_only=self.config.v4_only,
-            rule_axis="rules" if self.n_rule_shards > 1 else None,
-            compiled=not self._fused_interpret)._asdict()
 
     def _maybe_reset_wire_flags(self, snap: PolicySnapshot) -> None:
         """Un-stick the widened wire formats when the NEW snapshot provably
@@ -745,7 +678,6 @@ class JITDatapath(DatapathBackend):
             placed = PlacedTensors(
                 {k: jnp.asarray(v) for k, v in snap.tensors().items()})
             self._account_placed(placed, patched=False)
-            self._note_fuse_plan(placed)
             return placed
         import jax
         from cilium_tpu.parallel.mesh import pad_snapshot_tensors
@@ -754,7 +686,6 @@ class JITDatapath(DatapathBackend):
             v, self._verdict_sharding if k == "verdict"
             else self._repl_sharding) for k, v in tensors.items()})
         self._account_placed(placed, patched=False)
-        self._note_fuse_plan(placed)
         return placed
 
     def _put_tensor(self, name, v):
@@ -889,7 +820,6 @@ class JITDatapath(DatapathBackend):
         else:
             self.patch_stats["patch_full"] += 1
         self._account_placed(new_placed, patched=True)
-        self._note_fuse_plan(new_placed)
         return new_placed
 
     def classify(self, placed, snap, batch, now):
@@ -1123,13 +1053,10 @@ class JITDatapath(DatapathBackend):
             raise
 
         def finalize():
-            # the ``fused`` tag attributes compute time to the executor
-            # that produced it (Pallas megakernels vs the jnp reference);
             # stages inside one jit are not separately timeable from the
             # host, so there is no per-kernel span
             try:
-                with tracer.span(trace_id, "datapath.compute", WAIT,
-                                 fused=int(self._fused)):
+                with tracer.span(trace_id, "datapath.compute", WAIT):
                     words = np.asarray(slab.words)
             except BaseException:
                 # a failed materialization (device error) never releases:
@@ -1242,8 +1169,7 @@ class JITDatapath(DatapathBackend):
         and a failed materialization sheds it and is checked for a dead
         chip's signature."""
         try:
-            with tracer.span(trace_id, "datapath.compute", WAIT,
-                             fused=int(self._fused)):
+            with tracer.span(trace_id, "datapath.compute", WAIT):
                 with tracer.span(trace_id, "datapath.readback", WAIT,
                                  arrays=1, shards=shards):
                     words = np.asarray(slab.words)
@@ -1771,8 +1697,6 @@ class JITDatapath(DatapathBackend):
                             probe_depth=self.config.probe_depth,
                             v4_only=self.config.v4_only,
                             donate_ct=self.config.donate_ct,
-                            fused=self._fused,
-                            fused_interpret=self._fused_interpret,
                             slab=True))
                 self._mesh_cache[key] = cached
             (self._mesh, self._ct_sharding, self._repl_sharding,

@@ -39,8 +39,7 @@ def test_phases_on_a_tiny_world_and_a_failing_one_stops_the_run():
     cs.phase_build(run)
     try:
         for name, phase in cs.PHASES:
-            if name != "kernels":       # three small compiles: chip only
-                phase(run)
+            phase(run)
         assert run.ct_full > 0 and run.established.any()
         # nothing survives a failed phase: the auditor's own corruption
         # drill flips captured verdicts, and phase parity raises
@@ -73,6 +72,27 @@ def test_status_names_the_serving_device():
         assert (dev["platform"], dev["device_kind"], dev["count"]) \
             == (d0.platform, d0.device_kind, len(jax.devices()))
         assert dev["configured"] == "auto" and dev["serving"] == 1
+    finally:
+        eng.stop()
+
+
+def test_status_carries_no_kernel_selector(tmp_path, capsys):
+    """One classify interior (PR 52): the status document and the CLI's
+    ``status`` text of a live jitted engine name the serving device and
+    carry no selector beside it."""
+    from cilium_tpu.cli.main import main as cli_main
+    from cilium_tpu.runtime.api import status_doc
+    sock = str(tmp_path / "api.sock")
+    eng = Engine(DaemonConfig(ct_capacity=1024, auto_regen=False,
+                              api_socket=sock))
+    try:
+        doc = status_doc(eng)
+        assert doc["device"]["platform"] == "cpu"
+        assert "fused_kernels" not in doc
+        eng.start_background()
+        assert cli_main(["status", "--api", sock]) == 0
+        out = capsys.readouterr().out
+        assert "Device:" in out and "fused" not in out.lower()
     finally:
         eng.stop()
 

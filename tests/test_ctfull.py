@@ -1,7 +1,7 @@
 """CT exhaustion semantics (ISSUE 10 tentpole): insert-when-full.
 
-The contract under test, bit-identical across the jnp core, the fused
-(interpret-mode) Pallas path and the bounded oracle/FakeDatapath:
+The contract under test, bit-identical across the jitted step and the
+bounded oracle/FakeDatapath:
 
 - a NEW allowed flow whose probe window holds no free slot tail-evicts the
   window's soonest-expiring *evictable* entry (everything except
@@ -46,9 +46,8 @@ def _clean_faults():
     FAULTS.reset()
 
 
-def make_engine(datapath_cls, fused="off", cap=CT_CAP):
-    cfg = DaemonConfig(ct_capacity=cap, auto_regen=False,
-                       fused_kernels=fused)
+def make_engine(datapath_cls, cap=CT_CAP):
+    cfg = DaemonConfig(ct_capacity=cap, auto_regen=False)
     eng = Engine(cfg, datapath=datapath_cls(cfg))
     eng.add_endpoint(["k8s:app=web"], ips=("192.168.1.10",), ep_id=1)
     eng.add_endpoint(["k8s:peer=p0", "k8s:group=g0"],
@@ -245,17 +244,17 @@ class TestBoundedOracle:
 
 
 # --------------------------------------------------------------------------- #
-# the bit-identity contract: jnp core / fused interpret / bounded oracle
+# the bit-identity contract: the jitted step / bounded oracle
 # --------------------------------------------------------------------------- #
 class TestSaturationParity:
-    def _run_flood(self, eng_a, eng_b, fused_label):
+    def _run_flood(self, eng_a, eng_b, label):
         slots = eng_a.active.snapshot.ep_slot_of
         now = 1000
         # establish a protected population (ACK → SEEN_NON_SYN)
         est = flows(slots, range(30000, 30016), flags=0x10)
         assert_same(eng_a.classify(dict(est), now=now),
                     eng_b.classify(dict(est), now=now),
-                    f"{fused_label}:establish")
+                    f"{label}:establish")
         # flood: distinct SYN flows, several times the table capacity —
         # saturation, tail evictions, CT_FULL fails
         for wave in range(4):
@@ -264,12 +263,12 @@ class TestSaturationParity:
                                     40000 + (wave + 1) * CT_CAP))
             assert_same(eng_a.classify(dict(fl), now=now),
                         eng_b.classify(dict(fl), now=now),
-                        f"{fused_label}:wave{wave}")
+                        f"{label}:wave{wave}")
         # the established population survives the saturated table
         now += 1
         a = eng_a.classify(dict(est), now=now)
         b = eng_b.classify(dict(est), now=now)
-        assert_same(a, b, f"{fused_label}:revisit")
+        assert_same(a, b, f"{label}:revisit")
         assert (np.asarray(a["status"])[np.asarray(est["valid"])]
                 == int(C.CTStatus.ESTABLISHED)).all()
         assert bool(np.asarray(a["allow"])[np.asarray(est["valid"])].all())
@@ -286,15 +285,6 @@ class TestSaturationParity:
         eng_b = make_engine(FakeDatapath)
         try:
             self._run_flood(eng_a, eng_b, "jnp")
-        finally:
-            eng_a.stop()
-            eng_b.stop()
-
-    def test_fused_interpret_vs_bounded_oracle_under_saturation(self):
-        eng_a = make_engine(JITDatapath, fused="on")
-        eng_b = make_engine(FakeDatapath)
-        try:
-            self._run_flood(eng_a, eng_b, "fused")
         finally:
             eng_a.stop()
             eng_b.stop()

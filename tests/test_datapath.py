@@ -609,8 +609,7 @@ class TestVerdictSlab:
         rec = dp._classify = _StepRecorder(dp._classify)
         columns = make_classify_fn(
             probe_depth=dp.config.probe_depth, v4_only=dp.config.v4_only,
-            donate_ct=False, packed=True, fused=dp._fused,
-            fused_interpret=dp._fused_interpret)
+            donate_ct=False, packed=True)
         act = eng.active
         # two dispatches of the same flows: new ones, then established
         # and reply rows on the table the first one left

@@ -26,7 +26,7 @@ from cilium_tpu.kernels.records import (PACK4_L7_WORDS, PACK4_WORDS,
 from cilium_tpu.model.rules import HTTPRule
 from cilium_tpu.utils import constants as C
 from cilium_tpu.utils.ip import parse_addr
-from tests.test_fused import _fuzz_addresses, _random_prefix_set
+from tests.test_lpm_fuzz import _fuzz_addresses, _random_prefix_set
 
 
 class TestHash:
@@ -367,6 +367,79 @@ class TestCTKernel:
             jnp_words = np.asarray(ctk.ct_key_words_jnp(
                 {k: jnp.asarray(v) for k, v in b.items()}, reverse=rev))
             np.testing.assert_array_equal(np_words, jnp_words)
+
+    def test_pair_matches_two_sided_normalization(self):
+        """``ct_key_words_pair`` derives the reverse key as a word
+        permutation of the forward one: both equal the two-sided
+        normalization, on random mixed-family batches."""
+        import random
+
+        from cilium_tpu.kernels.records import batch_from_records
+        from oracle import PacketRecord
+        rng = random.Random(3)
+        for _trial in range(3):
+            recs = []
+            for _ in range(64):
+                v6 = rng.random() < 0.25
+                net = ("2001:db8::%x", "2001:db9::%x") if v6 else \
+                    ("10.0.7.%d", "10.1.9.%d")
+                recs.append(PacketRecord(
+                    parse_addr(net[0] % rng.randrange(1, 255))[0],
+                    parse_addr(net[1] % rng.randrange(1, 255))[0],
+                    rng.randrange(1024, 65535), rng.randrange(1, 65535),
+                    rng.choice([C.PROTO_TCP, C.PROTO_UDP]), C.TCP_SYN, v6,
+                    1, rng.choice([C.DIR_EGRESS, C.DIR_INGRESS])))
+            b = {k: jnp.asarray(v)
+                 for k, v in batch_from_records(recs, {1: 0}).items()}
+            fwd, rev = ctk.ct_key_words_pair(b)
+            np.testing.assert_array_equal(
+                np.asarray(fwd),
+                np.asarray(ctk.ct_key_words_jnp(b, reverse=False)))
+            np.testing.assert_array_equal(
+                np.asarray(rev),
+                np.asarray(ctk.ct_key_words_jnp(b, reverse=True)))
+
+
+def test_no_module_of_the_program_imports_pallas():
+    """The classify interior is the ``jnp`` one (PR 52): a kernel comes
+    back with the cell whose bottleneck it is, and through this test."""
+    import ast
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = [os.path.join(root, "chip_smoke.py"),
+             os.path.join(root, "__graft_entry__.py")]
+    for where, _dirs, names in os.walk(os.path.join(root, "cilium_tpu")):
+        files += [os.path.join(where, n) for n in names if n.endswith(".py")]
+    assert len(files) > 50
+    found = []
+    for path in files:
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            names = []
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names = [f"{node.module}.{a.name}" for a in node.names]
+            found += [(os.path.relpath(path, root), n) for n in names
+                      if "pallas" in n]
+    assert found == []
+
+
+class TestMakeClassifyFnMemo:
+    def test_same_static_config_shares_one_callable(self):
+        """Repeated placements must not re-trace: one jitted callable a
+        static configuration, another for each argument that differs."""
+        from cilium_tpu.kernels.classify import make_classify_fn
+        a = make_classify_fn(8, False, donate_ct=False)
+        assert a is make_classify_fn(8, False, donate_ct=False)
+        assert a is not make_classify_fn(8, True, donate_ct=False)
+        assert a is not make_classify_fn(8, False, donate_ct=False,
+                                         packed=True)
+        assert a is not make_classify_fn(8, False, donate_ct=False,
+                                         slab=True)
+        assert a is not make_classify_fn(8, False, donate_ct=False,
+                                         lb_probe_depth=4)
 
 
 class TestPackOutVariants:

@@ -51,8 +51,7 @@ def test_sharded_slab_equals_column_read(rss, wire, kind, mesh):
     make_fn = (make_unsteered_classify_fn if dp._rss_device
                else make_sharded_classify_fn)
     columns = make_fn(dp._mesh, probe_depth=dp.config.probe_depth,
-                      v4_only=dp.config.v4_only, donate_ct=False,
-                      fused=dp._fused, fused_interpret=dp._fused_interpret)
+                      v4_only=dp.config.v4_only, donate_ct=False)
     act = eng.active
     # two dispatches of the same flows: new ones, then established and
     # reply rows on the table the first one left

@@ -280,7 +280,12 @@ class TestFollowRacesWraparound:
             stop.set()
 
         cur = FollowCursor(FlowObserver(log))
-        delivered = []
+        # the follower holds a cursor before the writer starts: a fresh
+        # attach past a wrapped ring is no gap (above), so a wrap ahead of
+        # the first poll would be owed no marker
+        fill(log, 1, now=0)
+        delivered = [r["seq"] for r in cur.poll(limit=16)]
+        assert delivered == [1]
         t = threading.Thread(target=writer)
         t.start()
         while not (stop.is_set() and cur.cursor >= log.newest_seq):
@@ -288,7 +293,7 @@ class TestFollowRacesWraparound:
                 if not r.get("gap"):
                     delivered.append(r["seq"])
         t.join()
-        total = n_batches * per
+        total = 1 + n_batches * per
         assert log.newest_seq == total
         # a guaranteed lap (scheduling-independent): one burst larger than
         # the whole ring lands between two polls — also exercises the

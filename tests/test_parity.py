@@ -211,12 +211,7 @@ def test_packed_path_bit_identical():
         now += 40
 
 
-def run_parity(seed, n_batches=6, batch=96, cap=4096, time_step=40,
-               classify_kwargs=None):
-    """``classify_kwargs`` forwards extra static options to classify_step —
-    tests/test_fused.py reruns this exact suite with
-    {"fused": True, "fused_interpret": True} to pin the Pallas megakernel
-    path against the oracle."""
+def run_parity(seed, n_batches=6, batch=96, cap=4096, time_step=40):
     rng = random.Random(seed)
     ctx, repo, eps = build_world()
     snap = build_snapshot(repo, ctx, eps, CTConfig(capacity=cap))
@@ -236,7 +231,7 @@ def run_parity(seed, n_batches=6, batch=96, cap=4096, time_step=40,
              batch_from_records(packets, snap.ep_slot_of).items()}
         out, ct_dev, counters = classify_step(
             tensors, ct_dev, b, jnp.uint32(now),
-            world_index=snap.world_index, **(classify_kwargs or {}))
+            world_index=snap.world_index)
         got_allow = np.asarray(out["allow"])
         got_reason = np.asarray(out["reason"])
         got_status = np.asarray(out["status"])

@@ -1,4 +1,4 @@
-"""``kernels/policy.policy_core`` against a plain ladder, row by row, on
+"""``kernels/policy.policy_lookup_batch`` against a plain ladder, row by row, on
 rows no sound batch holds: every index negative or past its table's end,
 two endpoints, class tables whose own values leave the image, and an image
 whose column count (131) is no multiple of the chip's 128-lane tile.
@@ -6,9 +6,9 @@ whose column count (131) is no multiple of the chip's 128-lane tile.
 The ladder reads each placed table in the shape it is placed in, with every
 index clamped first; the plain ladder below clamps the same way and reads
 the image's flat form, so the two agree exactly when the clipped direct
-gather names the flat form's cell. Held under ``jit`` and inside the
-interpreted fused verdict kernel (``kernels/fused.policy_verdict_fused``),
-which shares the one function.
+gather names the flat form's cell. Held under ``jit``, for the lookup alone
+and for the step's ladder, L7 match and verdict composition over it
+(``kernels/classify.interior_pre_core``, ``compose_verdict``).
 """
 
 import itertools
@@ -18,8 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cilium_tpu.kernels import fused as fk
-from cilium_tpu.kernels.policy import policy_core
+from cilium_tpu.kernels.classify import compose_verdict, interior_pre_core
+from cilium_tpu.kernels.policy import policy_lookup_batch
 from cilium_tpu.utils import constants as C
 
 N_EPS, N_IDS, N_ROWS, N_COLS = 2, 11, 7, 131
@@ -41,7 +41,7 @@ def tables():
         "verdict": rng.integers(
             0, 1 << 16, (N_EPS, 2, N_ROWS, N_COLS)).astype(np.uint16),
         "enforced": np.array([[True, False], [False, True]]),
-        # one empty L7 set: the fused kernel keeps these resident
+        # one empty L7 set, as a snapshot with no L7 rule places it
         "l7_methods": np.zeros((1, 1), np.uint8),
         "l7_valid": np.zeros((1, 1), bool),
         "l7_path_len": np.zeros((1, 1), np.int32),
@@ -95,22 +95,71 @@ def case():
 
 def test_the_clipped_direct_gather_reads_the_flat_forms_cell_under_jit(case):
     dev, cols, want = case
-    got = jax.jit(policy_core)(dev, *cols)
+    got = jax.jit(policy_lookup_batch)(dev, *cols)
     for name, w, g in zip(("decision", "l7_id", "enforced", "matched_rule"),
                           want, got):
         np.testing.assert_array_equal(np.asarray(g), w, name)
 
 
-def test_and_inside_the_interpreted_fused_kernel(case):
-    """The fused kernel hands back the composed verdict; of new, token-less,
-    valid rows it shows the cell's decision, ``enforced`` and the masked
-    ``matched_rule``."""
+def test_compose_verdict_over_every_combination_of_its_inputs():
+    """``compose_verdict`` against a row at a time of plain ``if``s, over
+    every combination of decision, ``enforced``, ``l7_fail``, probe class
+    and ``valid``: the device-RSS exchange composes with est/reply pinned
+    (a hit that is a DENY cell, a reply that fails its L7 set), rows that
+    no random stream of the parity suites need ever draw."""
+    ok, deny, policy, l7 = (int(C.DropReason.OK),
+                            int(C.DropReason.POLICY_DENY),
+                            int(C.DropReason.POLICY),
+                            int(C.DropReason.POLICY_L7))
+    grid = list(itertools.product(
+        (C.VERDICT_MISS, C.VERDICT_ALLOW, C.VERDICT_DENY,
+         C.VERDICT_REDIRECT),
+        (False, True), (False, True), ("new", "est", "reply"),
+        (False, True)))
+    assert len({d for d, *_ in grid}) == 4
+    want = []
+    for decision, enforced, l7_fail, probe, valid in grid:
+        if probe != "new":
+            allow, reason = not l7_fail, l7 if l7_fail else ok
+        elif decision == C.VERDICT_DENY:
+            allow, reason = False, deny
+        elif decision == C.VERDICT_MISS:
+            allow, reason = not enforced, policy if enforced else ok
+        else:
+            allow, reason = not l7_fail, l7 if l7_fail else ok
+        status = {"est": C.CTStatus.ESTABLISHED, "reply": C.CTStatus.REPLY,
+                  "new": C.CTStatus.NEW}[probe]
+        want.append((allow and valid, reason, int(status),
+                     valid and decision == C.VERDICT_REDIRECT))
+    decision, enforced, l7_fail, probe, valid = zip(*grid)
+    decision = jnp.asarray(decision, jnp.int32)
+    got = jax.jit(compose_verdict)(
+        decision, jnp.asarray(enforced), decision == C.VERDICT_REDIRECT,
+        jnp.asarray(l7_fail), jnp.asarray([p == "est" for p in probe]),
+        jnp.asarray([p == "reply" for p in probe]), jnp.asarray(valid))
+    for name, g, w in zip(("allow", "reason", "status", "redirect"), got,
+                          zip(*want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+
+
+def test_and_the_steps_composition_over_it_judges_those_rows_alike(case):
+    """The step's interior over the same rows, composed: of new, token-less,
+    valid rows the verdict shows the cell's decision, ``enforced`` and the
+    ladder's ``matched_rule``, and no row fails an L7 set it never named."""
     dev, cols, (decision, _, enforced, matched) = case
     n = cols[0].shape[0]
     no = jnp.zeros((n,), bool)
-    allow, reason, _, redirect, mrule = fk.policy_verdict_fused(
-        dev, *cols, jnp.full((n,), C.HTTP_METHOD_ANY, jnp.int32),
-        jnp.zeros((n, 64), jnp.uint8), no, no, ~no, interpret=True)
+
+    @jax.jit
+    def interior(dev, *cols):
+        dec, enf, cell_redirect, l7_fail, mrule = interior_pre_core(
+            dev, *cols, jnp.full((n,), C.HTTP_METHOD_ANY, jnp.int32),
+            jnp.zeros((n, 64), jnp.uint8))
+        return compose_verdict(dec, enf, cell_redirect, l7_fail, no, no,
+                               ~no) + (l7_fail, jnp.where(enf, mrule, -1))
+    allow, reason, status, redirect, l7_fail, mrule = interior(dev, *cols)
+    assert not np.asarray(l7_fail).any()
+    assert (np.asarray(status) == int(C.CTStatus.NEW)).all()
     np.testing.assert_array_equal(
         np.asarray(allow),
         np.where(decision == C.VERDICT_DENY, False,

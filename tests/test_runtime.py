@@ -306,6 +306,20 @@ class TestConfig:
         with pytest.raises(ValueError):
             DaemonConfig.load(config_file=str(cfg_file), env={})
 
+    @pytest.mark.parametrize("how", ["constructor", "file"])
+    def test_the_retired_kernel_selector_is_an_unknown_key(self, how,
+                                                           tmp_path):
+        """``fused_kernels`` went with the kernels it selected (PR 52): no
+        alias, no shim; a deployment that still names it is told so."""
+        if how == "constructor":
+            with pytest.raises(TypeError, match="fused_kernels"):
+                DaemonConfig(fused_kernels="auto")
+        else:
+            cfg_file = tmp_path / "cfg.json"
+            cfg_file.write_text(json.dumps({"fused_kernels": "off"}))
+            with pytest.raises(ValueError, match="fused_kernels"):
+                DaemonConfig.load(config_file=str(cfg_file), env={})
+
     def test_validation(self):
         with pytest.raises(ValueError):
             DaemonConfig(ct_capacity=1000)

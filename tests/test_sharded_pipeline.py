@@ -552,6 +552,22 @@ class TestShardedParity:
             for e in (serial, eng1, eng8):
                 e.stop()
 
+    def test_4shard_pipeline_bit_identical_to_serial(self):
+        """The mesh cell's width (``ct1m-50k-mesh4``; the case above runs
+        eight): one stream of padded, odd-sized chunks through the 1-shard
+        and the 4-shard pipelines, each equal to the oracle-backed serial
+        path and the two to each other on the whole out geometry."""
+        serial = fake_serial_engine()
+        pipes = [jit_pipeline_engine(1), jit_pipeline_engine(4)]
+        try:
+            chunks = _mk_phase(serial.active.snapshot.ep_slot_of, 5,
+                               (28, 37), seed=13)
+            _run_phase(serial, pipes, chunks, now0=300)
+            assert pipes[1].pipeline_stats()["n_shards"] == 4
+        finally:
+            for e in [serial] + pipes:
+                e.stop()
+
     def test_sharded_engine_health_carries_shards(self):
         eng = jit_pipeline_engine(2)
         try:
