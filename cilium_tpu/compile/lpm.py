@@ -37,10 +37,11 @@ fallback.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Tuple
+from typing import Dict, Tuple
 
 import numpy as np
 
+from cilium_tpu.observe.trace import LPM_BUILD_SPAN, active as active_trace
 from cilium_tpu.utils.ip import parse_prefix
 
 V4_LEVELS = 4     # bytes 12..15 of the v4-mapped address
@@ -98,61 +99,61 @@ class LPMTables:
         return {"slot": slot, "prefix": self.prefixes[slot], "plen": plen}
 
 
-class _TrieBuilder:
-    def __init__(self):
-        # node 0 is the root; each node is {byte: child_idx} + per-byte value
-        self.children: List[Dict[int, int]] = [{}]
-        # values[node][b] = (plen_bits, identity_index, packed_provenance)
-        self.values: List[Dict[int, Tuple[int, int, int]]] = [{}]
+def _build_trie(addr: np.ndarray, plen: np.ndarray, value: np.ndarray,
+                meta: np.ndarray) -> np.ndarray:
+    """One family's trie, a level at a time with numpy. ``addr`` [m, L]
+    uint8 network addresses, ``plen`` [m] bits, ``value`` / ``meta`` [m]
+    what the winner of a cell carries, the prefixes **in slot order**.
 
-    def _new_node(self) -> int:
-        self.children.append({})
-        self.values.append({})
-        return len(self.children) - 1
-
-    def insert(self, addr_bytes: bytes, plen_bits: int, value: int,
-               meta: int = -1) -> None:
-        """Insert a prefix of ``plen_bits`` (multiple-of-8 boundary handled by
-        expansion: a /12 covers 2^(16-12)=16 byte-values at level 2).
-        ``meta`` is the packed provenance stored alongside the value — the
-        winner of a cell carries both, so value and provenance can never
-        name different prefixes."""
-        node = 0
-        full_bytes, rem_bits = divmod(plen_bits, 8)
-        for level in range(full_bytes):
-            b = addr_bytes[level]
-            if level == full_bytes - 1 and rem_bits == 0:
-                old = self.values[node].get(b)
-                if old is None or old[0] <= plen_bits:
-                    self.values[node][b] = (plen_bits, value, meta)
-                return
-            child = self.children[node].get(b)
-            if child is None:
-                child = self._new_node()
-                self.children[node][b] = child
-            node = child
-        # partial byte: expand the remaining bits over the byte range
-        b0 = addr_bytes[full_bytes] & (0xFF << (8 - rem_bits)) if rem_bits else 0
-        span = 1 << (8 - rem_bits) if rem_bits else 256
-        for b in range(b0, b0 + span):
-            old = self.values[node].get(b)
-            if old is None or old[0] <= plen_bits:
-                self.values[node][b] = (plen_bits, value, meta)
-
-    def to_array(self) -> np.ndarray:
-        n = len(self.children)
-        arr = np.full((n + 1, 256, 3), -1, dtype=np.int32)  # +1 dead node
-        for idx in range(n):
-            for b, child in self.children[idx].items():
-                arr[idx, b, 0] = child
-            for b, (_plen, value, meta) in self.values[idx].items():
-                arr[idx, b, 1] = value
-                arr[idx, b, 2] = meta
-        return arr
-
-    @property
-    def dead_node(self) -> int:
-        return len(self.children)
+    The array is the one that inserting the prefixes one after the other in
+    that order gives (``tests/test_lpm_build.py`` keeps that loop, word for
+    word, and holds this to it array for array). A prefix of ``plen`` bits
+    decides cells of the node ``depth = (plen - 1) // 8`` bytes down its
+    path: the ``2 ** (8 * (depth + 1) - plen)`` byte values it covers there
+    (a /12 covers 16 values of its second byte; /0 all 256 of the root),
+    and the longest prefix that covers a cell wins it. Nodes are numbered
+    as the loop makes them: the root 0, then in the order prefixes first
+    need them, a prefix's own from the root down; the dead node last."""
+    m = plen.shape[0]
+    depth = np.maximum(plen - 1, 0) // 8
+    # -- the nodes, a level at a time: a node is its parent and a byte --------
+    at = np.zeros((m,), np.int64)       # provisional id of each prefix's node
+    parent_of, byte_of, first_of, depth_of = [], [], [], []
+    n_prov = 1
+    for d in range(1, int(depth.max(initial=0)) + 1):
+        sel = np.nonzero(depth >= d)[0]
+        key = at[sel] * 256 + addr[sel, d - 1]
+        uniq, first, inverse = np.unique(key, return_index=True,
+                                         return_inverse=True)
+        at[sel] = n_prov + inverse
+        parent_of.append(uniq // 256)
+        byte_of.append(uniq % 256)
+        first_of.append(sel[first])     # sel ascends: the first that needs it
+        depth_of.append(np.full(uniq.shape, d, np.int64))
+        n_prov += uniq.size
+    nodes = np.full((n_prov + 1, 256, 3), -1, dtype=np.int32)  # +1 dead node
+    final = np.zeros((n_prov,), np.int64)
+    if n_prov > 1:
+        parent_of, byte_of, first_of, depth_of = (
+            np.concatenate(x) for x in (parent_of, byte_of, first_of,
+                                        depth_of))
+        final[1 + np.lexsort((depth_of, first_of))] = np.arange(1, n_prov)
+        nodes[final[parent_of], byte_of, 0] = final[1:]
+    # -- the cells: shorter prefixes first, so that the longest stays ---------
+    flat = nodes.reshape(-1, 3)
+    covered = plen - 8 * depth          # bits of the node's byte: 0 for /0
+    for bits in range(0, 9):
+        rows = np.nonzero(covered == bits)[0]
+        if not rows.size:
+            continue
+        span = 1 << (8 - bits)
+        b0 = (addr[rows, depth[rows]].astype(np.int64) >> (8 - bits)) \
+            << (8 - bits) if bits else np.zeros(rows.shape, np.int64)
+        cells = ((final[at[rows]] * 256 + b0)[:, None]
+                 + np.arange(span)[None, :]).ravel()
+        flat[cells, 1] = np.repeat(value[rows], span)
+        flat[cells, 2] = np.repeat(meta[rows], span)
+    return nodes
 
 
 def build_lpm(ipcache_entries: Dict[str, int],
@@ -164,24 +165,33 @@ def build_lpm(ipcache_entries: Dict[str, int],
     entries referencing unknown identities raise (the compiler must be handed
     a consistent snapshot). Prefix slots are assigned in sorted canonical
     order — deterministic for any snapshot content, independent of the
-    ipcache dict's insertion history.
-    """
-    b4, b6 = _TrieBuilder(), _TrieBuilder()
-    prefixes = tuple(sorted(ipcache_entries))
-    pfx_slot_of = {p: s for s, p in enumerate(prefixes)}
-    for prefix in prefixes:
-        ident = ipcache_entries[prefix]
-        addr16, plen, is_v6 = parse_prefix(prefix)
-        idx = identity_index[ident]
-        meta = pack_pfx(pfx_slot_of[prefix], plen)
-        if is_v6:
-            b6.insert(addr16, plen, idx, meta)
-        else:
-            # v4: trie over the last 4 bytes; /96+p → p bits here
-            b4.insert(addr16[12:], plen - 96, idx, meta)
-    return LPMTables(v4_nodes=b4.to_array(), v6_nodes=b6.to_array(),
-                     default_index=default_index,
-                     prefixes=prefixes, pfx_slot_of=pfx_slot_of)
+    ipcache dict's insertion history. Recorded as the span
+    ``engine.regen.lpm`` of the regeneration it runs in (full build or
+    incremental rebuild alike)."""
+    tracer, trace_id = active_trace()
+    with tracer.span(trace_id, LPM_BUILD_SPAN) as span:
+        prefixes = tuple(sorted(ipcache_entries))
+        pfx_slot_of = {p: s for s, p in enumerate(prefixes)}
+        n = len(prefixes)
+        packed, plen = [], np.empty((n,), np.int64)
+        value, is_v6 = np.empty((n,), np.int32), np.empty((n,), bool)
+        for slot, prefix in enumerate(prefixes):
+            addr16, plen[slot], is_v6[slot] = parse_prefix(prefix)
+            packed.append(addr16)
+            value[slot] = identity_index[ipcache_entries[prefix]]
+        addr = np.frombuffer(b"".join(packed), np.uint8).reshape(n, 16)
+        meta = pack_pfx(np.arange(n, dtype=np.int64), plen).astype(np.int32)
+        # v4: the trie is over the last 4 bytes; /96+p → p bits there
+        i4, i6 = np.nonzero(~is_v6)[0], np.nonzero(is_v6)[0]
+        tables = LPMTables(
+            v4_nodes=_build_trie(addr[i4, 12:], plen[i4] - 96, value[i4],
+                                 meta[i4]),
+            v6_nodes=_build_trie(addr[i6], plen[i6], value[i6], meta[i6]),
+            default_index=default_index,
+            prefixes=prefixes, pfx_slot_of=pfx_slot_of)
+        span.set(nodes_v4=tables.v4_nodes.shape[0],
+                 nodes_v6=tables.v6_nodes.shape[0], prefixes=n)
+    return tables
 
 
 def lpm_lookup_host(tables: LPMTables, addr16: bytes, is_v6: bool) -> int:

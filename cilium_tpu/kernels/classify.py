@@ -30,7 +30,9 @@ from cilium_tpu.compile.lpm import PFX_LEN_MASK
 from cilium_tpu.kernels import conntrack as ctk
 from cilium_tpu.kernels.l7 import l7_match_batch
 from cilium_tpu.kernels.lb import lb_step
-from cilium_tpu.kernels.lpm import lpm_lookup_prov_batch
+from cilium_tpu.kernels.lpm import (SCOPE_V4 as SCOPE_LPM_V4,  # noqa: F401
+                                    SCOPE_V6 as SCOPE_LPM_V6,  # noqa: F401
+                                    lpm_lookup_prov_batch)
 from cilium_tpu.kernels.policy import policy_lookup_batch
 from cilium_tpu.utils import constants as C
 
@@ -42,6 +44,8 @@ N_REASON_BINS = C.DROP_REASON_BINS   # counter-tensor geometry (one source)
 #: before the scopes existed carries none, the cache key leaves metadata out)
 SCOPE_LB = "lb.step"
 SCOPE_LPM = "lpm.walk"
+#: ... and inside it ``SCOPE_LPM_V4`` / ``SCOPE_LPM_V6`` ("lpm.walk.v4",
+#: "lpm.walk.v6": kernels/lpm.py, which names each family's chain)
 #: ... and of the three counters of that stage, so that what they cost the
 #: device can be read the same way
 SCOPE_TALLY = "pre_ct.tally"

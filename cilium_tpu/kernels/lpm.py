@@ -29,9 +29,17 @@ node or the dead sentinel, byte is masked to 0..255).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from cilium_tpu.compile.lpm import V4_LEVELS, V6_LEVELS
+
+#: each family's chain by name in the program's op metadata, inside the
+#: caller's ``lpm.walk`` (kernels/classify.py), so that a profiler trace's
+#: device time under the walk reads by family. Names only: the
+#: instructions are what they were
+SCOPE_V4 = "lpm.walk.v4"
+SCOPE_V6 = "lpm.walk.v6"
 
 
 def _walk(flat, addr_words, byte_index, levels, default_index):
@@ -67,11 +75,14 @@ def lpm_lookup_prov_batch(lpm_v4, lpm_v6, addr_words, is_v6, default_index,
     index [N] int32, packed lpm_prefix provenance [N] int32, -1 on miss).
     ``default_index`` may be a traced scalar. ``v4_only`` (static) elides
     the 16-level v6 chain."""
-    r4, m4 = _walk(lpm_v4, addr_words, lambda l: 12 + l, V4_LEVELS,
-                   default_index)
+    with jax.named_scope(SCOPE_V4):
+        r4, m4 = _walk(lpm_v4, addr_words, lambda l: 12 + l, V4_LEVELS,
+                       default_index)
     if v4_only:
         return r4, m4
-    r6, m6 = _walk(lpm_v6, addr_words, lambda l: l, V6_LEVELS, default_index)
+    with jax.named_scope(SCOPE_V6):
+        r6, m6 = _walk(lpm_v6, addr_words, lambda l: l, V6_LEVELS,
+                       default_index)
     v6 = is_v6.astype(bool)
     return jnp.where(v6, r6, r4), jnp.where(v6, m6, m4)
 

@@ -85,6 +85,11 @@ class IdentityAllocator:
         self._next_cluster = C.CLUSTER_IDENTITY_BASE
         self._next_local = C.LOCAL_IDENTITY_SCOPE
         self._observers: List[IdentityObserver] = []
+        # canonical prefix → the live identity ``allocate_cidr`` made for
+        # it: a prefix asked for again costs no label set (a /128's is 130
+        # labels through ``ipaddress``)
+        self._by_cidr: Dict[str, Identity] = {}
+        self._cidr_of: Dict[int, str] = {}      # its inverse, for release
         for name, num in C.RESERVED_IDENTITIES.items():
             if num == C.IDENTITY_UNKNOWN:
                 continue
@@ -128,7 +133,19 @@ class IdentityAllocator:
             return ident
 
     def allocate_cidr(self, prefix: str) -> Identity:
-        return self.allocate(cidr_identity_labels(prefix))
+        """Allocate (or ref) the identity of a CIDR prefix. The label set
+        is built the first time a prefix is asked for; while that identity
+        lives, asking again is a reference more on it."""
+        key = normalize_prefix(prefix)
+        with self._lock:
+            held = self._by_cidr.get(key)
+            if held is not None and self._by_id.get(held.id) is held:
+                self._refcount[held.id] += 1
+                return held
+            ident = self.allocate(cidr_identity_labels(key))
+            self._by_cidr[key] = ident
+            self._cidr_of[ident.id] = key
+            return ident
 
     def release(self, ident: Identity) -> bool:
         """Unref; returns True when the identity was fully removed."""
@@ -141,6 +158,9 @@ class IdentityAllocator:
             del self._refcount[ident.id]
             del self._by_id[ident.id]
             del self._by_labels[ident.labels]
+            key = self._cidr_of.pop(ident.id, None)
+            if key is not None and self._by_cidr.get(key) is ident:
+                del self._by_cidr[key]
             self._notify([], [ident])
             return True
 

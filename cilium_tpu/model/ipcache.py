@@ -9,7 +9,7 @@ matching the datapath's behavior (eps.h: no entry → WORLD_ID).
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 from cilium_tpu.utils import constants as C
 from cilium_tpu.utils.ip import normalize_prefix, parse_addr, parse_prefix
@@ -23,6 +23,9 @@ class IPCache:
         self._entries: Dict[str, int] = {}
         self._revision = 0
         self._observers: List[Callable[[], None]] = []
+        #: calls of ``upsert_many`` so far (the engine renders it as
+        #: ``ipcache_bulk_upserts_total``)
+        self.bulk_upserts = 0
 
     def add_observer(self, obs: Callable[[], None]) -> None:
         self._observers.append(obs)
@@ -42,6 +45,26 @@ class IPCache:
                                 # the LPM or trigger regeneration
             self._entries[key] = identity_id
             self._changed()
+
+    def upsert_many(self, entries: Iterable[Tuple[str, int]]) -> int:
+        """Many prefixes at once, as a routing table or a cluster's
+        identity sync arrives: each text made canonical once, one hold of
+        the lock, and — if any entry is new or changed — one revision and
+        one call of the observers, so one regeneration. What the cache
+        then holds is what ``upsert`` of each pair in turn leaves (a
+        prefix named twice keeps its last identity). → prefixes that were
+        new or changed."""
+        fresh = {normalize_prefix(prefix): identity_id
+                 for prefix, identity_id in entries}
+        with self._lock:
+            held = self._entries
+            changed = {key: identity_id for key, identity_id in fresh.items()
+                       if held.get(key) != identity_id}
+            held.update(changed)
+            self.bulk_upserts += 1
+            if changed:
+                self._changed()
+            return len(changed)
 
     def delete(self, prefix: str) -> bool:
         with self._lock:

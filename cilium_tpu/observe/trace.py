@@ -84,6 +84,10 @@ CT_GC_SPAN = "datapath.ct.gc"
 #: dictionary's build (JITDatapath._pack_wire; attrs rows, distinct,
 #: dict_rows, bytes)
 L7_DICT_SPAN = "datapath.pack.l7dict"
+#: inside a regeneration, full or incremental: the build of the two LPM
+#: tries from the ipcache (compile/lpm.build_lpm; attrs nodes_v4, nodes_v6,
+#: prefixes), as ``engine.regen.lb`` is the load-balancer tables'
+LPM_BUILD_SPAN = "engine.regen.lpm"
 
 
 class _NullSpan:

@@ -121,6 +121,14 @@ class PlacedTensors(dict):
         self.dead = False
 
 
+def placed_bytes(arr) -> int:
+    """Bytes one device holds of a placed array: its tiled, padded size
+    where the backend states one (a TPU holds a 32-bit ``[rows, 3]`` table
+    at 16 bytes a row), else the array's own ``nbytes`` (numpy, the CPU)."""
+    on_device = getattr(arr, "on_device_size_in_bytes", None)
+    return int(on_device()) if on_device is not None else int(arr.nbytes)
+
+
 def normalize_ct_arrays(arrays: Dict[str, np.ndarray]
                         ) -> Dict[str, np.ndarray]:
     """Validate/upgrade a ct_layout checkpoint to the current schema —

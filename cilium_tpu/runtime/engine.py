@@ -43,7 +43,8 @@ from cilium_tpu.policy.repository import PolicyContext, Repository
 from cilium_tpu.policy.selectorcache import SelectorCache
 from cilium_tpu.runtime.config import DaemonConfig
 from cilium_tpu.runtime.controller import ControllerManager, Trigger
-from cilium_tpu.runtime.datapath import DatapathBackend, StalePlacement
+from cilium_tpu.runtime.datapath import (DatapathBackend, StalePlacement,
+                                         placed_bytes)
 from cilium_tpu.runtime.faults import FAULTS
 from cilium_tpu.runtime.flowlog import FlowLog
 from cilium_tpu.runtime.metrics import Metrics
@@ -482,16 +483,21 @@ class Engine:
         self.metrics.set_gauge("engine_degraded", 0)
         self.metrics.set_gauge("regen_consecutive_failures", 0)
         # what the LPM walk and the LB step were placed with (the resource
-        # ledger's ``hbm`` row holds the tries' bytes only in total). The
-        # bytes are the host tables' own, 12 an entry; a TPU tiles the
-        # placed ``[n * 256, 3]`` form 4 x 128 and holds 16 an entry, 4/3
-        # of the gauge (compile/lpm.py)
+        # ledger's ``hbm`` row holds the tries' bytes only in total).
+        # ``lpm_trie_bytes`` is the host tables' own, 12 an entry;
+        # ``lpm_trie_placed_bytes`` what the device holds of the placed
+        # ``[n * 256, 3]`` form, which a TPU tiles 4 x 128 and pads to 16
+        # an entry (compile/lpm.py)
         lpm, lb = snap.lpm, snap.lb
         self.metrics.set_gauges({
             'lpm_trie_nodes{family="v4"}': lpm.v4_nodes.shape[0],
             'lpm_trie_nodes{family="v6"}': lpm.v6_nodes.shape[0],
             'lpm_trie_bytes{family="v4"}': lpm.v4_nodes.nbytes,
             'lpm_trie_bytes{family="v6"}': lpm.v6_nodes.nbytes,
+            'lpm_trie_placed_bytes{family="v4"}':
+                placed_bytes(tensors["lpm_v4"]),
+            'lpm_trie_placed_bytes{family="v6"}':
+                placed_bytes(tensors["lpm_v6"]),
             "lpm_prefixes": len(lpm.prefixes),
             "lb_frontends": lb.n_frontends,
             "lb_backends": len(lb.backends),
@@ -2132,6 +2138,14 @@ class Engine:
                             name = f"datapath_{k}_total"
                         self.metrics.inc_counter(name, d)
                         self._pack_stats_seen[k] = v
+        # the ipcache's bulk entries (IPCache.upsert_many calls) — same
+        # delta-fold
+        with self._pack_fold_lock:
+            bulk = self.ctx.ipcache.bulk_upserts
+            d = bulk - self._pack_stats_seen.get("ipcache:bulk", 0)
+            if d:
+                self.metrics.inc_counter("ipcache_bulk_upserts_total", d)
+                self._pack_stats_seen["ipcache:bulk"] = bulk
         # live-patch attribution (delta scatter-applies vs full re-places,
         # rows moved, stale-placement fence trips) — same delta-fold
         patch = getattr(self.datapath, "patch_stats", None)

@@ -8,7 +8,7 @@ import pytest
 
 from cilium_tpu.compile.idclass import build_identity_classes
 from cilium_tpu.compile.l7 import L7SetInterner, build_l7_tensors, l7_match_host
-from cilium_tpu.compile.lpm import build_lpm, lpm_lookup_host
+from cilium_tpu.compile.lpm import lpm_lookup_host
 from cilium_tpu.compile.policy_image import build_policy_image
 from cilium_tpu.compile.portclass import build_port_classes
 from cilium_tpu.compile.snapshot import build_snapshot
@@ -23,6 +23,9 @@ from cilium_tpu.policy.selectorcache import SelectorCache
 from cilium_tpu.utils import constants as C
 from cilium_tpu.utils.ip import parse_addr
 from oracle.datapath import l7_match
+# every table here is built by the vectorised builder AND by the loop it
+# replaced, and the two compared array for array (PR 53)
+from tests.test_lpm_build import checked_build_lpm as build_lpm
 
 
 class TestLPM:

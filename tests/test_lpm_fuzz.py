@@ -13,10 +13,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cilium_tpu.compile.lpm import (build_lpm, lpm_lookup_host,
-                                    lpm_lookup_host_prov)
+from cilium_tpu.compile.lpm import lpm_lookup_host, lpm_lookup_host_prov
 from cilium_tpu.kernels.lpm import lpm_lookup_prov_batch
 from cilium_tpu.utils.ip import parse_addr
+# every table here is built by the vectorised builder AND by the loop it
+# replaced, and the two compared array for array (PR 53)
+from tests.test_lpm_build import checked_build_lpm as build_lpm
 
 
 def _random_prefix_set(rng, n_v4, n_v6, max_ident=50):

@@ -132,16 +132,40 @@ LB_SHAPES = {
     "lb_be_addr": (151000, 4), "lb_be_port": (151000,)}
 
 
+def spied_dispatches(eng, world, rows_list):
+    """→ {rows: (the datapath's jitted step, the shapes of one real
+    dispatch of that many rows of the world's allowed flows)}: the step
+    spied on as ``tests/test_lpm100k_config.py`` does."""
+    import jax
+    from benchmarks.frames import columns_of
+    dp, seen, out = eng.datapath, [], {}
+    step = dp._classify
+
+    def spy(*args):
+        seen.append(jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), args))
+        return step(*args)
+    dp._classify = spy
+    try:
+        for rows in rows_list:
+            flows = world.allowed_flows(np.random.default_rng(1), rows, 1,
+                                        40000)
+            eng.submit(columns_of(flows, world.ep_v4, world.ep_v6_words,
+                                  0)).result(timeout=300)
+            assert eng.drain(timeout=60)
+            out[rows] = (step, seen[-1])
+    finally:
+        dp._classify = step
+    return out
+
+
 @pytest.fixture(scope="module")
 def classify_program():
     """→ (the datapath's jitted step, the shapes of one real dispatch of
-    1,024 rows): the tiny service world on the jitted datapath, the step
-    spied on as ``tests/test_lpm100k_config.py`` does."""
+    1,024 rows): the tiny service world on the jitted datapath."""
     import json
     import os
 
-    import jax
-    from benchmarks.frames import columns_of
     from benchmarks.worlds import svclb
     from cilium_tpu.runtime.config import DaemonConfig
     from cilium_tpu.runtime.datapath import JITDatapath
@@ -155,22 +179,9 @@ def classify_program():
     try:
         world.load(eng)
         eng.regenerate()
-        dp, seen = eng.datapath, []
-        step = dp._classify
-
-        def spy(*args):
-            seen.append(jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), args))
-            return step(*args)
-        dp._classify = spy
-        flows = world.allowed_flows(np.random.default_rng(1), ROWS, 1, 40000)
-        eng.submit(columns_of(flows, world.ep_v4, world.ep_v6_words,
-                              0)).result(timeout=300)
-        assert eng.drain(timeout=60)
-        dp._classify = step
+        return spied_dispatches(eng, world, [ROWS])[ROWS]
     finally:
         eng.stop()
-    return step, seen[-1]
 
 
 def at_the_deployments_size(shapes, one_chip, lb_shapes):
@@ -366,3 +377,136 @@ def test_the_reader_sees_the_key_table_re_laid_as_a_matrix(one_chip, cap):
         assert compiled.memory_analysis().temp_size_in_bytes >= cap * 128 * 4
     else:
         assert copies == []
+
+
+# -- the walk over a whole routing table, both families (PR 53) --------------------
+#: the tries of ``dfz-dualstack`` as the program builds them: 40,228 v4 nodes
+#: (165 MB placed) and 188,035 v6 nodes (770 MB), where the cases above hold
+#: the walk at 39,667 and 20,084
+DFZ_V4_NODES, DFZ_V6_NODES = 40228, 188035
+DFZ_ROWS = (1024, 256)
+
+
+def test_the_walk_reads_a_whole_routing_table_where_it_lies(one_chip):
+    """Both chains over tries of the deployment's sizes in one program:
+    twenty gathers, no copy or transpose of 2^20 elements, the two tables
+    taken as the parameters they are placed as (16 bytes an entry: 935 MB)
+    and no temporary to speak of. ``node * 256 + byte`` stays in 31 bits."""
+    import jax
+    import jax.numpy as jnp
+    from cilium_tpu.kernels.lpm import lpm_lookup_prov_batch
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def walk(v4, v6, addr_words, is_v6):
+        return lpm_lookup_prov_batch(v4, v6, addr_words, is_v6, 0)
+    assert DFZ_V6_NODES * 256 < 1 << 31
+    for rows in DFZ_ROWS:
+        compiled = jax.jit(walk).lower(
+            spec((DFZ_V4_NODES * 256, 3), jnp.int32),
+            spec((DFZ_V6_NODES * 256, 3), jnp.int32),
+            spec((rows, 4), jnp.uint32), spec((rows,), jnp.bool_)).compile()
+        text, memory = compiled.as_text(), compiled.memory_analysis()
+        assert len(re.findall(r" gather\(", text)) == 4 + 16
+        assert table_copies(text) == []
+        placed = (DFZ_V4_NODES + DFZ_V6_NODES) * 256 * 16
+        assert placed == 934_965_248
+        assert placed <= memory.argument_size_in_bytes < placed + (1 << 20)
+        assert memory.temp_size_in_bytes < placed // 200
+
+
+@pytest.fixture(scope="module")
+def dfz_program():
+    """→ {rows: (the datapath's jitted step, the shapes of one real
+    dispatch of that many rows)}: the tiny routing-table world on the
+    jitted datapath, dual-stack frames on the wide wire."""
+    import json
+    import os
+
+    from benchmarks.worlds import dfz
+    from cilium_tpu.runtime.config import DaemonConfig
+    from cilium_tpu.runtime.datapath import JITDatapath
+    from cilium_tpu.runtime.engine import Engine
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "data", "configs", "tiny-dfz.json")) as f:
+        world = dfz.build(json.load(f)["world"])
+    cfg = DaemonConfig(auto_regen=False, ct_capacity=1 << 12,
+                       batch_size=ROWS)
+    eng = Engine(cfg, datapath=JITDatapath(cfg))
+    try:
+        world.load(eng)
+        eng.regenerate()
+        return spied_dispatches(eng, world, DFZ_ROWS)
+    finally:
+        eng.stop()
+
+
+def with_the_deployments_tries(shapes, one_chip):
+    import jax
+    tensors = dict(shapes[0])
+    for name, nodes in (("lpm_v4", DFZ_V4_NODES), ("lpm_v6", DFZ_V6_NODES)):
+        assert tensors[name].shape[1:] == (3,)
+        tensors[name] = jax.ShapeDtypeStruct((nodes * 256, 3),
+                                             tensors[name].dtype)
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (tensors,) + tuple(shapes[1:]))
+
+
+@pytest.mark.parametrize("rows", DFZ_ROWS)
+def test_the_whole_step_over_a_whole_routing_table(one_chip, dfz_program,
+                                                   rows):
+    """The whole classify program, as the datapath dispatches it for 1,024
+    and for 256 rows on the wide wire, over the two tries at
+    ``dfz-dualstack``'s sizes: **every** copy or transpose of 2^20 elements
+    is listed, and none stands (``re_laid``'s rule: the compiler staging one
+    array through its fast memory re-lays nothing; a trie is not even
+    staged). Both tries reach the program as the 2-D parameters they are
+    placed as, and the temporaries are a hundredth of them."""
+    step, shapes = dfz_program[rows]
+    compiled = step.lower(*with_the_deployments_tries(
+        shapes, one_chip)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    copies = table_copies(text)
+    assert re_laid(text) == [], copies
+    assert not any(str(DFZ_V4_NODES * 256) in c or str(DFZ_V6_NODES * 256)
+                   in c for c in copies), copies
+    for nodes in (DFZ_V4_NODES, DFZ_V6_NODES):
+        assert re.search(r"s32\[%d,3\]\{[^}]*\} parameter\(" % (nodes * 256),
+                         text), nodes
+    placed = (DFZ_V4_NODES + DFZ_V6_NODES) * 256 * 16
+    assert placed <= memory.argument_size_in_bytes < placed + (64 << 20)
+    assert memory.temp_size_in_bytes < placed // 100
+
+
+def test_the_families_names_are_names_alone(one_chip, dfz_program,
+                                            monkeypatch):
+    """``lpm.walk.v4`` / ``lpm.walk.v6`` are metadata: the optimised
+    program of the whole step with the two names blanked is, line for line,
+    the optimised program of the same step traced without the scopes (the
+    parent's), metadata and all."""
+    import contextlib
+    import types
+
+    import jax
+    from cilium_tpu.kernels import lpm
+    step, shapes = dfz_program[ROWS]
+    args = with_the_deployments_tries(shapes, one_chip)
+    inner_step = step.__wrapped__
+    texts = {}
+    for scoped in (True, False):
+        if not scoped:
+            monkeypatch.setattr(lpm, "jax", types.SimpleNamespace(
+                named_scope=lambda name: contextlib.nullcontext()))
+        # a function of its own each time (a jit of the same one would hand
+        # back the trace it has), lowered from this one line (the program's
+        # text holds its call sites)
+        texts[scoped] = jax.jit(lambda *a: inner_step(*a)).lower(
+            *args).compile().as_text()
+    named, bare = texts[True], texts[False]
+    assert named.count("/lpm.walk/lpm.walk.v6/") >= 16 \
+        and named.count("/lpm.walk/lpm.walk.v4/") >= 4
+    assert "lpm.walk.v" not in bare and "/lpm.walk/" in bare
+    blanked = named.replace("/lpm.walk.v4", "").replace("/lpm.walk.v6", "")
+    assert blanked.splitlines() == bare.splitlines()
